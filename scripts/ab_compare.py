@@ -4,8 +4,8 @@ back-to-back on the same host in the same hour — the controlled
 comparison docs/PERFORMANCE.md is built from — write both artifacts,
 and emit the markdown delta table.
 
-Same-day pairing is the whole point: this rig's run-to-run interference
-(BASELINE.md) makes cross-day absolute numbers incomparable, so every
+Same-day pairing is the whole point: a shared rig's run-to-run
+interference makes cross-day absolute numbers incomparable, so every
 fusion claim rides an `_on`/`_off` pair produced by ONE invocation of
 this script.
 
@@ -86,7 +86,7 @@ def run_bench(extra: list[str], bench_args: list[str], label: str) -> dict:
     cmd = [sys.executable, BENCH, *bench_args, *extra]
     print(f"[ab_compare] {label}: {' '.join(cmd)}", file=sys.stderr)
     proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True)
-    # the artifact is the last stdout line (supervisor chatter is stderr)
+    # the artifact is the last stdout line (logs go to stderr)
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     if not lines:
         raise RuntimeError(f"{label}: bench produced no artifact "

@@ -42,6 +42,7 @@ from sitewhere_tpu.kernel.lifecycle import (
     LifecycleComponent,
     LifecycleProgressMonitor,
 )
+from sitewhere_tpu.utils.backend import device_summary
 
 logger = logging.getLogger(__name__)
 
@@ -169,8 +170,12 @@ class FleetWorker(LifecycleComponent):
 
     def signals(self) -> dict:
         """TelemetryBeat-derived load signals for the autoscaler."""
+        platform, kind, count = device_summary()
+        # the worker is the process that holds the chip, so ITS report
+        # is where a launcher (which must stay off JAX) learns the device
         out: dict = {"dlq": int(self.runtime.metrics.counter(
-            "dlq.quarantined").value)}
+            "dlq.quarantined").value),
+            "device": {"platform": platform, "kind": kind, "count": count}}
         beat = getattr(self.runtime, "beat", None)
         sample = beat.samples[-1] if beat is not None and beat.samples \
             else None

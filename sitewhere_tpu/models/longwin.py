@@ -33,7 +33,6 @@ from sitewhere_tpu.models.common import dense_init
 from sitewhere_tpu.parallel.ring import (
     dense_attention_reference,
     ring_attention,
-    shard_map,
 )
 
 
@@ -161,7 +160,7 @@ class LongWindowModel:
         def body(xn, valid):
             return self._stack(params, xn, valid, ax)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh, in_specs=(spec_x, spec_x),
             out_specs=P(None, ax, None))(xn, valid)
 

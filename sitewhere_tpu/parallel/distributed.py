@@ -65,11 +65,7 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
         # explicit cpu, or nothing requested (a bare CPU-only host
         # resolves to cpu too): selecting gloo only configures the CPU
         # backend's collectives — accelerator backends are untouched
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jaxlibs lack the option
-            logger.warning("could not select gloo CPU collectives; "
-                           "multi-process CPU runs may fail")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

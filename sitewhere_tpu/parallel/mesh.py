@@ -17,14 +17,11 @@ including the CPU host-platform mesh used by tests and the driver's
 
 from __future__ import annotations
 
-import logging
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-logger = logging.getLogger(__name__)
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -44,48 +41,27 @@ def make_mesh(data: Optional[int] = None, model: int = 1,
 
 
 def mesh_from_spec(spec: Optional[dict]) -> Optional[Mesh]:
-    """Build the serving mesh from a `{data: D, model: M}` config spec,
-    degrading gracefully to whatever THIS process actually has — the
-    contract that lets one config serve the 1-core CI rig and a TPU pod
-    (docs/PERFORMANCE.md mesh serving):
+    """Build the serving mesh from a `{data: D, model: M}` config spec
+    over this process's devices — exactly that shape, or an error.
 
-    - exact fit (D×M == devices): the requested mesh;
-    - fewer devices: shrink the model axis to the largest divisor of
-      the device count ≤ M (tenant shards must tile the axis), data
-      takes the rest — the axis ROLES survive even when the shape
-      can't;
-    - one device (or no/empty spec): None — the single-chip degenerate
-      case where the stacked dispatch is simply device-resident.
-
-    More devices than the spec asks for uses only D×M of them (an
+    Only an empty spec means no mesh. `data` omitted takes every device
+    the `model` axis divides. A spec that asks for more devices than the
+    process has raises `ValueError` naming both: a mesh that quietly
+    shrank (or vanished) would let a "mesh on" run measure some other
+    configuration. More devices than D×M uses the first D×M of them (an
     explicit spec is a budget, not a floor)."""
     if not spec:
         return None
-    model = max(int(spec.get("model", 1) or 1), 1)
-    data = spec.get("data")
+    model = max(int(spec.get("model") or 1), 1)
     devices = jax.devices()
     n = len(devices)
-    if n <= 1:
-        if int(spec.get("data") or 1) * int(spec.get("model") or 1) > 1:
-            # the other degrade branch logs its fit; a spec collapsing
-            # all the way to meshless must be just as loud, or an A/B's
-            # "mesh on" leg can silently measure the off configuration
-            logger.warning(
-                "scoring mesh spec %s: this process has %d device(s) — "
-                "running meshless (single-device stacked dispatch)",
-                spec, n)
-        return None
-    want = (int(data) if data else max(n // model, 1)) * model
-    if want > n:
-        model = min(model, n)
-        while n % model:
-            model -= 1
-        logger.warning(
-            "scoring mesh spec %s wants %d devices, have %d — fitting "
-            "{data: %d, model: %d}", spec, want, n, n // model, model)
-        return make_mesh(data=n // model, model=model, devices=devices)
-    return make_mesh(data=want // model, model=model,
-                     devices=devices[:want])
+    data = int(spec.get("data") or 0) or n // model
+    if data < 1 or data * model > n:
+        raise ValueError(
+            f"scoring mesh spec {spec} does not fit: this process has "
+            f"{n} {devices[0].platform} device(s)")
+    return make_mesh(data=data, model=model,
+                     devices=devices[:data * model])
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -31,17 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # stabilized as jax.shard_map in newer JAX releases
-    shard_map = jax.shard_map
-except AttributeError:  # this image's 0.4.x still ships it experimental
-    from jax.experimental.shard_map import shard_map
-
-try:  # newer JAX types device-varying values explicitly
-    _pvary = jax.lax.pvary
-except AttributeError:  # 0.4.x has no varying-type system: identity
-    def _pvary(x, axes):
-        return x
-
 NEG_INF = -1e30
 
 
@@ -85,11 +74,14 @@ def ring_attention(q, k, v, valid, axis_name: str, causal: bool = False,
         return block_owner * T_l + jnp.arange(T_l)
 
     # online-softmax state: accumulator o, running max m, running denom l
-    # (pvary: the carries become device-varying after the first fold, so
-    # their init must be typed device-varying for shard_map's scan)
-    o = _pvary(jnp.zeros((B, T_l, H, Dh), jnp.float32), (axis_name,))
-    m = _pvary(jnp.full((B, H, T_l), NEG_INF, jnp.float32), (axis_name,))
-    l = _pvary(jnp.zeros((B, H, T_l), jnp.float32), (axis_name,))
+    # (pcast to varying: the carries become device-varying after the
+    # first fold, so their init must be typed so for shard_map's scan)
+    def varying(x):
+        return jax.lax.pcast(x, (axis_name,), to="varying")
+
+    o = varying(jnp.zeros((B, T_l, H, Dh), jnp.float32))
+    m = varying(jnp.full((B, H, T_l), NEG_INF, jnp.float32))
+    l = varying(jnp.zeros((B, H, T_l), jnp.float32))
 
     perm = [(i, (i + 1) % P_sz) for i in range(P_sz)]
 
@@ -138,7 +130,7 @@ def ring_attention_sharded(q, k, v, valid, mesh: Mesh, seq_axis: str,
         return ring_attention(q, k, v, valid, seq_axis, causal=causal,
                               axis_size=axis_size)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_valid),
         out_specs=spec_qkv)

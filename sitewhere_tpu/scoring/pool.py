@@ -169,6 +169,10 @@ class TenantSlot:
         return self.pool.ready
 
     @property
+    def warmup_error(self) -> Optional[Exception]:
+        return self.pool.warmup_error
+
+    @property
     def flush_due(self) -> bool:
         return self.pool.flush_due
 
@@ -239,18 +243,13 @@ class TenantSlot:
             # reseed this tenant's rows from its host history, same as
             # ScoringSession.swap_params (reusing the params in hand, not
             # a device→host gather of the slice just written)
-            self.pool._seed_tenant_ring(
-                self.tenant_id, self.pool.stack.slots[self.tenant_id],
-                self.pool.tenants[self.tenant_id].telemetry, params=params)
+            self.pool.reseed(self.tenant_id, params=params)
         return version
 
     def reload_history(self) -> None:
         """Re-seed this tenant's ring slice from its host store (bulk
         imports that bypassed admit) — mirrors ScoringSession's."""
-        entry = self.pool.tenants[self.tenant_id]
-        self.pool._seed_tenant_ring(self.tenant_id,
-                                    self.pool.stack.slots[self.tenant_id],
-                                    entry.telemetry)
+        self.pool.reseed(self.tenant_id)
 
 
 class SharedScoringPool:
@@ -274,6 +273,9 @@ class SharedScoringPool:
         self.ring: Optional[StackedDeviceRing] = None  # created on first register
         self.tenants: dict[str, _TenantEntry] = {}
         self.ready = True          # flips False while capacity warms up
+        # newest warm-up failure, None once a pass succeeds (see
+        # ScoringSession.warmup_error)
+        self.warmup_error: Optional[Exception] = None
         self.inflight = 0
         self.dispatch_count = 0
         self.settled_count = 0
@@ -472,6 +474,20 @@ class SharedScoringPool:
         else:
             self.ring.load_tenant(slot, x, cnt)
 
+    def reseed(self, tenant_id: str, params: Optional[dict] = None) -> None:
+        """Re-seed one tenant's ring rows from its host store. The ring
+        is sized from the host store at register time, so a store that
+        has grown since (a bulk import, a bootstrapped fleet) grows the
+        ring here — and the compiled buckets with it: re-warm behind
+        the ready gate rather than let the next flush compile on the
+        hot path (on a v5e that was a 0.6 s loop stall, long enough for
+        the overload controller to reject frames)."""
+        self._seed_tenant_ring(tenant_id, self.stack.slots[tenant_id],
+                               self.tenants[tenant_id].telemetry,
+                               params=params)
+        if self._current_key() != self._warmed_key:
+            self._start_warmup()
+
     def unregister(self, tenant_id: str) -> None:
         entry = self.tenants.pop(tenant_id, None)
         slot = self.stack.slots.get(tenant_id)
@@ -537,9 +553,13 @@ class SharedScoringPool:
                     self._warmed_key = key
                     return
 
+        def failed(exc: Exception) -> None:
+            self.warmup_error = exc
+
         await retry_backoff(
             attempt, lambda: self._recover_ring(restart_warmup=False),
-            logger, "pool warmup")
+            logger, "pool warmup", on_error=failed)
+        self.warmup_error = None
         self.ready = True
         self._wake.set()
 

@@ -15,10 +15,14 @@ from typing import Awaitable, Callable, Optional
 async def retry_backoff(attempt_fn: Callable[[], Awaitable[None]],
                         recover_fn: Optional[Callable[[], None]],
                         logger: logging.Logger, what: str,
-                        max_sleep: float = 30.0) -> None:
-    """Run `attempt_fn` until it succeeds; on failure run `recover_fn`
-    (its own failure is logged, never raised) and sleep with exponential
-    backoff. Cancellation propagates."""
+                        max_sleep: float = 30.0,
+                        on_error: Optional[Callable[[Exception], None]] = None
+                        ) -> None:
+    """Run `attempt_fn` until it succeeds; on failure hand the exception
+    to `on_error` (so a caller with a deadline can report WHY the gate
+    is still closed), run `recover_fn` (its own failure is logged, never
+    raised) and sleep with exponential backoff. Cancellation
+    propagates."""
     attempt = 0
     while True:
         try:
@@ -26,8 +30,10 @@ async def retry_backoff(attempt_fn: Callable[[], Awaitable[None]],
             return
         except asyncio.CancelledError:
             raise
-        except Exception:
+        except Exception as exc:
             logger.exception("%s failed (attempt %d); retrying", what, attempt)
+            if on_error is not None:
+                on_error(exc)
             if recover_fn is not None:
                 try:
                     recover_fn()

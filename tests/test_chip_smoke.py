@@ -1,0 +1,85 @@
+"""chip_smoke.py's body, tiny, on the conftest's 8 virtual CPU devices —
+the control flow the chip check runs at full size — plus the bring-up
+contracts that have no chip in them: the compile-cache helper, the
+platform refusals of chip_smoke.py and bench.py.
+
+The other two no-fallback contracts are pinned where their subjects
+already had tests: `mesh_from_spec` raising on a spec that does not fit
+(tests/test_mesh_serving.py) and a selected fused step whose compile
+fails raising out of the ring (tests/test_pallas.py).
+"""
+
+import os
+from types import SimpleNamespace
+
+import jax
+import pytest
+
+import bench
+import chip_smoke
+from sitewhere_tpu.utils.backend import use_compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY = chip_smoke.Sizes(devices=64, ticks=6, pool_tenants=2,
+                        pool_devices=64, kernel_bucket=256,
+                        anomaly_rate=0.05, interval_s=0.05)
+
+
+def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
+    # cache placed from outside: the helper must then set nothing, so
+    # the rest of the test session keeps JAX's config as it found it
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    summary = chip_smoke.run_smoke("cpu", TINY)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert summary["ok"], summary
+    assert summary["device"] == {"platform": "cpu", "kind": "cpu",
+                                 "count": 8}
+    assert summary["compile_cache"] == str(tmp_path)
+    a, b, c = (summary["phases"][k] for k in "ABC")
+    want = TINY.devices * TINY.ticks
+    assert a["sent"] == a["scored"] == a["published"] == want
+    assert a["alerts"] >= 1 and a["compiles_after_warmup"] == 0
+    assert a["feeder_exit"] == 0
+    assert b["ok"] and b["skipped"] == "pallas_ok false on cpu"
+    assert c["mesh"] == {"data": 2, "model": 2}
+    assert c["scored_per_tenant"] == [want]
+    assert c["megabatch_dispatches"] > 0 and c["swap_version"] == 1
+
+
+def test_smoke_refuses_the_wrong_platform():
+    """`python chip_smoke.py` hard-codes "tpu": on a CPU it must stop at
+    the platform check, before any phase, with a non-zero exit."""
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.run_smoke("tpu", TINY)
+    assert exc.value.code not in (0, None)
+
+
+def test_bench_refuses_cpu_without_force_cpu():
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench._require_tpu(SimpleNamespace(force_cpu=False), "cpu")
+    bench._require_tpu(SimpleNamespace(force_cpu=True), "cpu")
+    bench._require_tpu(SimpleNamespace(force_cpu=False), "tpu")
+    # the peak lookup chip_smoke gates on knows the v5e as JAX names it
+    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
+    assert bench.peak_bf16_flops("cpu") is None
+
+
+def test_compile_cache_env_wins(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert use_compile_cache() == path      # fixed: same every call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -489,7 +489,6 @@ def test_cross_worker_trace_continuity(tmp_path):
     import os
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cache_dir = os.path.join(repo, ".jax_cache")
 
     async def main():
         from sitewhere_tpu.kernel.bus import EventBus
@@ -526,7 +525,7 @@ def test_cross_worker_trace_continuity(tmp_path):
                 cfg = {
                     "worker_id": wid, "host": "127.0.0.1",
                     "port": broker.port, "instance_id": "fleet-obs",
-                    "force_cpu": True, "jax_cache": cache_dir,
+                    "force_cpu": True,
                     "api_port": api_ports[wid], "log_level": "WARNING",
                     "settings": {
                         "trace_sample": 1,

@@ -272,6 +272,43 @@ def test_param_hot_swap_version_fence(run):
 
 # -- tenant add/remove under load -------------------------------------------
 
+def test_reload_history_that_grows_the_ring_rewarms_behind_the_gate(run):
+    """The pool sizes its ring from the host store at register time. A
+    store that grew afterwards (bootstrapped fleet, bulk import) grows
+    the ring at `reload_history` — which must close the ready gate and
+    recompile there, not leave the next flush to compile on the hot
+    path (on a v5e that stall was long enough to shed frames)."""
+    async def main():
+        pool = SharedScoringPool(
+            build_model("zscore", window=8), MetricsRegistry(),
+            PoolConfig(batch_buckets=(32,), batch_window_ms=50.0))
+
+        async def deliver(_scored) -> None:
+            return None
+
+        store = TelemetryStore(history=16)
+        slot = pool.register("a", store, 6.0, deliver)
+        await wait_until(lambda: pool.ready)
+        assert pool.ring.device_cap == 1024
+        n = 1500                                  # outgrows the ring
+        for k in range(10):
+            store.append_measurements(MeasurementBatch(
+                BatchContext(tenant_id="a", source="import"),
+                np.arange(n, dtype=np.uint32), np.zeros(n, np.uint16),
+                np.full(n, 20.0 + k, np.float32), np.full(n, float(k))))
+        slot.reload_history()
+        assert pool.ring.device_cap == 2048
+        assert not pool.ready                     # gate closed: re-warming
+        await wait_until(lambda: pool.ready)
+        assert pool._warmed_key == pool._current_key()
+        # a reseed at unchanged shapes leaves the gate alone
+        slot.reload_history()
+        assert pool.ready
+        pool.close()
+
+    run(main())
+
+
 def test_tenant_add_remove_under_load(run):
     async def main():
         metrics = MetricsRegistry()

@@ -55,17 +55,23 @@ MESH = {"data": 4, "model": 2}
 
 # -- wiring / fit -----------------------------------------------------------
 
-def test_mesh_from_spec_fits_available_devices():
+def test_mesh_from_spec_is_exact_or_raises():
     assert jax.device_count() == 8  # the conftest contract
     m = mesh_from_spec(MESH)
     assert dict(m.shape) == {"data": 4, "model": 2}
-    # oversized: 8x2 wants 16 devices — shrink, keep the axis roles
-    fit = mesh_from_spec({"data": 8, "model": 2})
-    assert dict(fit.shape) == {"data": 4, "model": 2}
-    # model axis larger than the device count: largest divisor wins
-    fit = mesh_from_spec({"data": 16, "model": 16})
-    assert dict(fit.shape) == {"data": 1, "model": 8}
-    # no spec → no mesh (the single-device stacked dispatch)
+    # a spec is a budget, not a floor: 2x2 takes the first four devices
+    assert dict(mesh_from_spec({"data": 2, "model": 2}).shape) == {
+        "data": 2, "model": 2}
+    # `data` omitted: every device the model axis divides
+    assert dict(mesh_from_spec({"model": 2}).shape) == {"data": 4,
+                                                        "model": 2}
+    # oversized specs raise, naming the spec and the device count — no
+    # shrink, no silent meshless run
+    for spec in ({"data": 8, "model": 2}, {"data": 16, "model": 16},
+                 {"model": 16}):
+        with pytest.raises(ValueError, match="this process has 8"):
+            mesh_from_spec(spec)
+    # only an empty spec means no mesh (the single-device dispatch)
     assert mesh_from_spec(None) is None
     assert mesh_from_spec({}) is None
 

@@ -4,7 +4,8 @@ the lax.scan reference path it replaces on TPU.
 
 Interpret mode executes the kernel's memory/grid semantics in the
 Pallas interpreter on CPU, so these tests pin correctness everywhere;
-the real-TPU compile is exercised by `bench.py --model lstm` on the rig.
+Mosaic's own compile and on-chip parity are checked by chip_smoke.py
+phase B.
 """
 
 import jax
@@ -111,63 +112,60 @@ def test_pallas_ok_predicate():
         pallas_ok()                        # args are required
 
 
-def test_ring_falls_back_when_fused_scorer_fails_to_compile(monkeypatch):
-    """A fused scorer that fails at trace/compile time must degrade to
-    the reference scan path, not wedge warmup (the kernel is an
-    optimization, never a dependency); the broken verdict is remembered
-    ring-wide so other buckets skip the doomed compile."""
+def test_ring_raises_when_selected_fused_scorer_fails_to_compile(
+        monkeypatch):
+    """A fused scorer that was SELECTED and fails at trace/compile time
+    raises out of the ring — no silent rebuild on the scan path. The
+    ring's donated state is untouched (AOT compile executes nothing),
+    and every later attempt raises again rather than remembering a
+    degraded verdict."""
     from sitewhere_tpu.ops import lstm_kernel
     from sitewhere_tpu.scoring.ring import DeviceRing
 
-    # force the fused gate open (CPU would normally skip the probe)
+    # force the fused gate open (CPU would normally decline)
     monkeypatch.setattr(lstm_kernel, "pallas_ok", lambda *a, **k: True)
 
     model = LstmAnomalyModel(LstmConfig(window=16))
     params = model.init(jax.random.PRNGKey(0))
 
-    calls = {"fused": 0}
-
     def broken_fused(p, x, valid):
-        calls["fused"] += 1
         raise RuntimeError("mosaic said no")
 
     model.score_fused = broken_fused
     ring = DeviceRing(window=16, capacity=64)
     dev = np.arange(8, dtype=np.int32)
     v = np.ones(8, np.float32)
-    scores = np.asarray(ring.update_and_score(model, params, dev, v, 64))
-    assert calls["fused"] == 1          # probed once, then abandoned
-    assert scores.shape == (64,) and np.isfinite(scores[:8]).all()
-    assert not ring.faulted and ring._fused_broken
-    # second flush reuses the cached fallback without re-probing
-    ring.update_and_score(model, params, dev, v, 64)
-    assert calls["fused"] == 1
-    # a NEW bucket skips the doomed probe entirely (verdict remembered)
-    ring.update_and_score(model, params, dev[:4], v[:4], 32)
-    assert calls["fused"] == 1
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="mosaic said no"):
+            ring.update_and_score(model, params, dev, v, 64)
+    assert ring.fused_status is None and not ring.faulted
+    assert not ring._update_score_fns
 
 
 def test_ring_probe_keeps_compiled_fn(monkeypatch):
-    """When the fused path compiles, the probe's Compiled object is
-    kept — dispatch must not pay a second identical compile — and the
-    scores match the plain scan path."""
+    """When the fused path compiles, the AOT Compiled object is kept —
+    dispatch must not pay a second identical compile — and the scores
+    match the plain scan path."""
     from sitewhere_tpu.ops import lstm_kernel
     from sitewhere_tpu.scoring.ring import DeviceRing
 
-    monkeypatch.setattr(lstm_kernel, "pallas_ok", lambda *a, **k: True)
     model = LstmAnomalyModel(LstmConfig(window=16))
     params = model.init(jax.random.PRNGKey(0))
-    # a fused scorer with a compilable body (the monkeypatched gate
-    # would otherwise push score_fused onto the real Pallas path, which
-    # cannot compile on CPU): the probe machinery runs end to end
-    model.score_fused = model.score
-    ring = DeviceRing(window=16, capacity=64)
     dev = np.arange(8, dtype=np.int32)
     v = np.ones(8, np.float32)
+    # reference first: on CPU the predicate declines, so this is the scan
+    ref = DeviceRing(window=16, capacity=64)
+    ref_scores = np.asarray(ref.update_and_score(model, params, dev, v, 64))
+    assert ref.fused_status is None
+
+    monkeypatch.setattr(lstm_kernel, "pallas_ok", lambda *a, **k: True)
+    # a fused scorer with a compilable body (the monkeypatched gate
+    # would otherwise push score_fused onto the real Pallas path, which
+    # cannot compile on CPU): the AOT machinery runs end to end
+    model.score_fused = model.score
+    ring = DeviceRing(window=16, capacity=64)
     scores = np.asarray(ring.update_and_score(model, params, dev, v, 64))
     fn = ring._update_score_fns[(ring.capacity, 64)]
     assert not hasattr(fn, "lower")     # AOT Compiled, not a jit wrapper
-    ref = DeviceRing(window=16, capacity=64)
-    ref_scores = np.asarray(ref.update_and_score(
-        LstmAnomalyModel(LstmConfig(window=16)), params, dev, v, 64))
+    assert ring.fused_status == "compiled"
     np.testing.assert_allclose(scores[:8], ref_scores[:8], atol=1e-5)
