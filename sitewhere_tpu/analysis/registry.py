@@ -20,7 +20,9 @@ import-time check at the bottom fails the build on a conflict.
 Adding a trace stage: add `(name, kind)` to `TRACE_STAGES` in pipeline
 order (kind: "queue" = time spent waiting, "service" = time spent
 working — the critical-path analyzer's split), then record it via
-`tracer.record(trace_id, name, ...)`; TRC01 resolves the literal here.
+`tracer.record(trace_id, name, ...)` or, around synchronous code,
+`with tracer.span(name, ...)`; TRC01 resolves the literal here. A stage
+that splits another names it in `TRACE_STAGE_PARENT`.
 """
 
 from __future__ import annotations
@@ -72,6 +74,13 @@ TRACE_STAGES: tuple[tuple[str, str], ...] = (
     ("event-management.persist", "service"), # columnar store scatter
     ("rule-processing.dispatch", "queue"),   # admission → jit dispatch
     ("rule-processing.score", "service"),    # dispatch → scores on host
+    # ...and the three children that tile it (scoring/settle.py), with
+    # the settle thread's own blocking read inside the second
+    ("rule-processing.score.enqueue", "service"),   # → jit call returned
+    ("rule-processing.score.device", "service"),    # → a thread holds the bytes
+    ("rule-processing.score.readback", "service"),  # np.asarray, settle thread
+    ("rule-processing.score.wake", "queue"),        # → the loop resumes the task
+    ("rule-processing.assemble", "service"), # scores → ScoredBatch at the sink
     ("egress.publish", "service"),           # settled → published
     ("flow.defer", "service"),               # overload spool publish
     ("flow.replay", "queue"),                # deferred drain re-admission
@@ -86,6 +95,19 @@ TRACE_STAGES: tuple[tuple[str, str], ...] = (
 TRACE_STAGE_KINDS: dict[str, str] = dict(TRACE_STAGES)
 if len(TRACE_STAGE_KINDS) != len(TRACE_STAGES):
     raise ValueError("duplicate trace stage in TRACE_STAGES")
+
+
+# child stage -> the stage it is a part of. The critical-path split sums
+# only stages that have no parent, so a split stage is not counted twice.
+TRACE_STAGE_PARENT: dict[str, str] = {
+    "rule-processing.score.enqueue": "rule-processing.score",
+    "rule-processing.score.device": "rule-processing.score",
+    "rule-processing.score.readback": "rule-processing.score.device",
+    "rule-processing.score.wake": "rule-processing.score",
+}
+if not set(TRACE_STAGE_PARENT) | set(TRACE_STAGE_PARENT.values()) \
+        <= set(TRACE_STAGE_KINDS):
+    raise ValueError("TRACE_STAGE_PARENT names a stage TRACE_STAGES lacks")
 
 
 def trace_stage_kind(name: str) -> str | None:
@@ -245,12 +267,15 @@ METERS = (
 
 HISTOGRAMS = (
     "scoring.e2e_latency_s",
-    "scoring.batch_latency_s",
     "scoring.batch_size",
     "scoring.stage_admit_s",
     "scoring.stage_batch_s",
     "scoring.stage_device_s",
     "scoring.stage_sink_s",
+    # stage_device_s in three parts that add up to it (scoring/settle.py)
+    "scoring.device_enqueue_s",
+    "scoring.device_wait_s",
+    "scoring.settle_wake_s",
     "scoring.megabatch_tenants_per_dispatch",
     # flight recorder (kernel/observe.py): event-loop lag per beat
     "observe.loop_lag_s",
@@ -259,9 +284,11 @@ HISTOGRAMS = (
 )
 
 # f-string metric names whose suffix is computed at runtime
-# (FlowController.count builds f"flow.{name}"); MET01 accepts an
-# f-string whose literal prefix matches one of these exactly.
-DYNAMIC_METRIC_PREFIXES = ("flow.",)
+# (FlowController.count builds f"flow.{name}", Tracer.add_busy builds
+# f"busy.{stage}": seconds a trace stage, or "gc", kept a thread busy);
+# MET01 accepts an f-string whose literal prefix matches one of these
+# exactly.
+DYNAMIC_METRIC_PREFIXES = ("flow.", "busy.")
 
 # name -> kind; built with a conflict check so a metric registered under
 # two kinds fails at import (and therefore fails the build / meta-test).

@@ -500,6 +500,26 @@ async def cmd_quota(args) -> int:
         return 1
 
 
+def _render_stages(cp: dict) -> list[str]:
+    """The critical path's stage table. A stage that is part of another
+    (it carries `parent`) is listed under it by its own last name,
+    indented: its time is inside its parent's, not beside it."""
+    lines = [f"  {'stage':<28} {'kind':<8} {'count':>6} "
+             f"{'p50ms':>8} {'p95ms':>8} {'p99ms':>8}"]
+    stages = cp.get("stages") or {}
+    for stage, row in stages.items():
+        depth, up = 0, row.get("parent")
+        while up is not None:
+            depth, up = depth + 1, (stages.get(up) or {}).get("parent")
+        if depth:
+            stage = "  " * depth + "." + stage.rsplit(".", 1)[1]
+        lines.append(
+            f"  {stage:<28} {row.get('kind', '?'):<8} "
+            f"{row.get('count', 0):>6} {row.get('p50_ms', 0):>8.2f} "
+            f"{row.get('p95_ms', 0):>8.2f} {row.get('p99_ms', 0):>8.2f}")
+    return lines
+
+
 def render_top(report: dict) -> str:
     """Render one flight-recorder report (`GET /api/instance/observe`)
     as the `swx top` screen. Pure function — tests and --json callers
@@ -536,13 +556,7 @@ def render_top(report: dict) -> str:
                  f"{cp.get('span_count', 0)} spans) — queue-wait p99 "
                  f"{cp.get('queue_wait_p99_ms', 0):.2f}ms vs service p99 "
                  f"{cp.get('service_p99_ms', 0):.2f}ms")
-    lines.append(f"  {'stage':<28} {'kind':<8} {'count':>6} "
-                 f"{'p50ms':>8} {'p95ms':>8} {'p99ms':>8}")
-    for stage, row in (cp.get("stages") or {}).items():
-        lines.append(
-            f"  {stage:<28} {row.get('kind', '?'):<8} "
-            f"{row.get('count', 0):>6} {row.get('p50_ms', 0):>8.2f} "
-            f"{row.get('p95_ms', 0):>8.2f} {row.get('p99_ms', 0):>8.2f}")
+    lines.extend(_render_stages(cp))
     if not cp.get("stages"):
         lines.append("  (no sampled spans yet)")
     last = (beat or {}).get("last") or {}
@@ -598,13 +612,7 @@ def render_fleet_top(report: dict) -> str:
         f"{cp.get('workers_merged', 0)} process(es)) — queue-wait p99 "
         f"{cp.get('queue_wait_p99_ms', 0):.2f}ms vs service p99 "
         f"{cp.get('service_p99_ms', 0):.2f}ms")
-    lines.append(f"  {'stage':<28} {'kind':<8} {'count':>6} "
-                 f"{'p50ms':>8} {'p95ms':>8} {'p99ms':>8}")
-    for stage, row in (cp.get("stages") or {}).items():
-        lines.append(
-            f"  {stage:<28} {row.get('kind', '?'):<8} "
-            f"{row.get('count', 0):>6} {row.get('p50_ms', 0):>8.2f} "
-            f"{row.get('p95_ms', 0):>8.2f} {row.get('p99_ms', 0):>8.2f}")
+    lines.extend(_render_stages(cp))
     if not cp.get("stages"):
         lines.append("  (no merged spans yet)")
     if workers:

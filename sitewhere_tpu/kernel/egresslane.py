@@ -362,20 +362,18 @@ class EgressStage:
             # produce re-raises it into the dead_letter hook, which
             # reports the ownership loss instead of quarantining
             try:
-                self._produce_nowait(self.scored_topic, scored, key=key,
-                                     fence=self.engine.fence_token())
-            except Exception:  # noqa: BLE001 - shard path quarantines
-                pass  # fall through: the shard publishes (or DLQs) it
-            else:
-                now = time.monotonic()
-                self.stage_sink.observe(now - t_submit)
                 # the trace spine's egress terminus: the sampled trace
                 # of a scored event ends at this publish (sync fast
                 # path — the span IS the bare append)
-                self.tracer.record(
-                    getattr(scored.ctx, "trace_id", 0), "egress.publish",
-                    self.engine.tenant_id, t_submit, now - t_submit,
-                    len(scored))
+                with self.tracer.span(
+                        "egress.publish", getattr(scored.ctx, "trace_id", 0),
+                        self.engine.tenant_id, len(scored)) as publish:
+                    self._produce_nowait(self.scored_topic, scored, key=key,
+                                         fence=self.engine.fence_token())
+            except Exception:  # noqa: BLE001 - shard path quarantines
+                pass  # fall through: the shard publishes (or DLQs) it
+            else:
+                self.stage_sink.observe(publish.t_end - t_submit)
                 self.published_meter.mark(len(scored))
                 self.accounted += 1
                 if (self.engine.emit_alerts

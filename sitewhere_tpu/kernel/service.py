@@ -369,7 +369,8 @@ class ServiceRuntime(LifecycleComponent):
         self.naming = TopicNaming(settings.instance_id)
         self.metrics = MetricsRegistry()
         from sitewhere_tpu.kernel.tracing import Tracer
-        self.tracer = Tracer(sample=settings.trace_sample)
+        self.tracer = Tracer(sample=settings.trace_sample,
+                             metrics=self.metrics)
         # `bus` may be a RemoteEventBus (kernel/wire.py): this process
         # then shares one broker's topics with peer processes — the
         # process-split deployment the reference runs as 14 JVMs
@@ -658,7 +659,12 @@ class ServiceRuntime(LifecycleComponent):
         if eb is not None:
             await eb.initialize()
 
+    async def _do_start(self, monitor: LifecycleProgressMonitor) -> None:
+        # the collector's pauses, as `busy.gc` and on a profiler trace
+        self.tracer.watch_gc()
+
     async def _do_stop(self, monitor: LifecycleProgressMonitor) -> None:
+        self.tracer.unwatch_gc()
         eb = getattr(self, "_external_bus", None)
         if eb is not None:
             await eb.stop()

@@ -30,23 +30,39 @@ span per batch per stage, so only every `sample`-th trace id records
 (trace ids are dense counters, so modulo sampling is uniform). Spans
 ring per STAGE (one chatty stage — a busy egress shard, a flapping DLQ
 — can no longer evict every other stage's spans from a shared ring).
-The model plane's profiler story is `jax.profiler` (bench.py --profile).
 
 `Tracer.spans()` / `Tracer.trace(trace_id)` are the query surface (REST
 exposes them, with tenant filtering and pagination); `record()` is the
-single write path (kept lean: the hot pipeline calls it per batch per
-stage).
+single write path into the rings (kept lean: the hot pipeline calls it
+per batch per stage).
+
+`Tracer.span(stage, ...)` is the primitive for a stage that is
+synchronous code (no `await` inside): one `with` that (a) adds the
+elapsed seconds to the counter `busy.<stage>`, every time and not only
+for sampled traces, so that a layer's busy share is a window delta over
+seconds; (b) records the sampled span as `record()` does; (c) is a
+`jax.profiler.TraceAnnotation` named `stage`, so that while a
+`jax.profiler` trace runs (`start_trace` with `host_tracer_level >= 1`)
+the span lands in the trace's `/host:CPU` plane on the line of the
+thread that ran it, on the same clock as the device's operations
+(benchmarks/hostspans.py lays idle gaps of the device to them). With no
+trace running an annotation costs well under a microsecond. A stage
+whose interval holds an `await` keeps `record()`: its wall time is not
+busy time, and an annotation left open across a suspension would cover
+whatever else the loop ran meanwhile.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
+import time
 import zlib
 from collections import deque
 from dataclasses import dataclass
 from typing import Iterable, Optional
 
-from sitewhere_tpu.kernel.metrics import Histogram
+from sitewhere_tpu.kernel.metrics import Counter, Histogram, MetricsRegistry
 
 
 @dataclass(frozen=True, slots=True)
@@ -65,14 +81,50 @@ class Span:
                 "n_events": self.n_events}
 
 
+class _Span:
+    """One `Tracer.span()` in flight. `t_start` and, after the block,
+    `t_end` are its `time.monotonic()` instants; `n_events` may be set
+    inside the block (a decode learns its count by decoding)."""
+
+    __slots__ = ("_tracer", "_annotation", "stage", "trace_id", "tenant_id",
+                 "n_events", "t_start", "t_end")
+
+    def __init__(self, tracer: "Tracer", stage: str, trace_id: int,
+                 tenant_id: str, n_events: int):
+        self._tracer = tracer
+        self._annotation = tracer._annotate(stage)
+        self.stage = stage
+        self.trace_id = trace_id
+        self.tenant_id = tenant_id
+        self.n_events = n_events
+        self.t_start = self.t_end = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
+        self.t_start = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t_end = time.monotonic()
+        self._annotation.__exit__(*exc)
+        self._tracer._close(self)
+
+
 class Tracer:
-    """Bounded per-stage span rings with modulo sampling. One per
-    runtime. `capacity` is the total span budget; each stage's ring gets
-    `stage_capacity` (default `capacity // 8`, min 64) so stages evict
-    only their own history."""
+    """Bounded per-stage span rings with modulo sampling, and the
+    `busy.<stage>` counters of `span()`. One per runtime. `capacity` is
+    the total span budget; each stage's ring gets `stage_capacity`
+    (default `capacity // 8`, min 64) so stages evict only their own
+    history. `metrics` is the registry the busy counters live in (the
+    runtime's; a tracer built without one keeps its own)."""
 
     def __init__(self, capacity: int = 4096, sample: int = 64,
-                 stage_capacity: int = 0):
+                 stage_capacity: int = 0,
+                 metrics: Optional[MetricsRegistry] = None):
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self._busy: dict[str, Counter] = {}
+        self._annotation_cls = None
+        self._gc_open: Optional[tuple] = None
         self.sample = max(int(sample), 1)
         self.stage_capacity = (max(int(stage_capacity), 1)
                                if stage_capacity
@@ -117,6 +169,69 @@ class Tracer:
             ring = self._rings[stage] = deque(maxlen=self.stage_capacity)
         ring.append(Span(trace_id, stage, tenant_id, t_start,
                          duration_s, n_events))
+
+    # -- busy time and the profiler's clock ----------------------------------
+
+    def _annotation_class(self):
+        """`jax.profiler.TraceAnnotation`, imported when first asked for:
+        nothing under kernel/ imports JAX while it is imported."""
+        cls = self._annotation_cls
+        if cls is None:
+            from jax.profiler import TraceAnnotation as cls
+
+            self._annotation_cls = cls
+        return cls
+
+    def _annotate(self, name: str):
+        return self._annotation_class()(name)
+
+    def span(self, stage: str, trace_id: int = 0, tenant_id: str = "",
+             n_events: int = 0) -> _Span:
+        """`with tracer.span(stage, ...):` around synchronous code: busy
+        seconds, the sampled span, and an annotation on a running
+        `jax.profiler` trace (the module's docstring)."""
+        return _Span(self, stage, trace_id, tenant_id, n_events)
+
+    def _close(self, span: _Span) -> None:
+        seconds = span.t_end - span.t_start
+        self.add_busy(span.stage, seconds)
+        self.record(span.trace_id, span.stage, span.tenant_id,
+                    span.t_start, seconds, span.n_events)
+
+    def add_busy(self, stage: str, seconds: float) -> None:
+        """Add to `busy.<stage>`. A plain float add, so from the thread
+        that owns the counter only: a worker thread hands its instants
+        back and the event loop adds them."""
+        counter = self._busy.get(stage)
+        if counter is None:
+            counter = self._busy[stage] = self.metrics.counter(
+                f"busy.{stage}")
+        counter.inc(seconds)
+
+    def watch_gc(self) -> None:
+        """Put the collector's pauses on both clocks: each collection is
+        an annotation `gc.gen<N>` on the thread it ran on, and its
+        seconds go to `busy.gc`. (A collection runs with the interpreter
+        lock held and never inside another, so the add is safe from
+        whichever thread tripped it.)"""
+        self._annotation_class()        # imported here, not in a collection
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def unwatch_gc(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            annotation = self._annotate(f"gc.gen{info['generation']}")
+            annotation.__enter__()
+            self._gc_open = (annotation, time.monotonic())
+        elif self._gc_open is not None:
+            annotation, t0 = self._gc_open
+            self._gc_open = None
+            self.add_busy("gc", time.monotonic() - t0)
+            annotation.__exit__(None, None, None)
 
     # -- query surface -----------------------------------------------------
 
@@ -222,30 +337,42 @@ class Tracer:
         Unregistered stages (tests, future drift) still report, with
         kind "unknown"; TRC01 is the gate that keeps the live tree's
         stages registered."""
-        from sitewhere_tpu.analysis.registry import TRACE_STAGES
+        report = _critical_path(self.stage_summary(tenant=tenant))
+        report["sample"] = self.sample
+        return report
 
-        kinds = dict(TRACE_STAGES)
-        order = {name: i for i, (name, _) in enumerate(TRACE_STAGES)}
-        summary = self.stage_summary(tenant=tenant)
-        stages: dict[str, dict] = {}
-        queue_p99 = service_p99 = 0.0
-        span_count = 0
-        for stage in sorted(summary, key=lambda s: order.get(s, 1000)):
-            kind = kinds.get(stage, "unknown")
-            row = {**summary[stage], "kind": kind}
-            stages[stage] = row
-            span_count += row["count"]
-            if kind == "queue":
-                queue_p99 += row["p99_ms"]
-            elif kind == "service":
-                service_p99 += row["p99_ms"]
-        return {
-            "stages": stages,
-            "span_count": span_count,
-            "queue_wait_p99_ms": round(queue_p99, 3),
-            "service_p99_ms": round(service_p99, 3),
-            "sample": self.sample,
-        }
+
+def _critical_path(rows: dict[str, dict]) -> dict:
+    """Per-stage summary rows (`p99_ms`, `count`, ...) to the report:
+    pipeline order, each stage's kind, and the queue-wait vs service
+    p99 sums. A stage that is part of another (`TRACE_STAGE_PARENT`)
+    carries its `parent` and stays out of the sums: its time is already
+    in its parent's."""
+    from sitewhere_tpu.analysis.registry import (
+        TRACE_STAGE_PARENT,
+        TRACE_STAGES,
+    )
+
+    kinds = dict(TRACE_STAGES)
+    order = {name: i for i, (name, _) in enumerate(TRACE_STAGES)}
+    stages: dict[str, dict] = {}
+    split = {"queue": 0.0, "service": 0.0}
+    span_count = 0
+    for stage in sorted(rows, key=lambda s: order.get(s, 1000)):
+        kind = kinds.get(stage, "unknown")
+        row = stages[stage] = {**rows[stage], "kind": kind}
+        span_count += row["count"]
+        parent = TRACE_STAGE_PARENT.get(stage)
+        if parent is not None:
+            row["parent"] = parent
+        elif kind in split:
+            split[kind] += row["p99_ms"]
+    return {
+        "stages": stages,
+        "span_count": span_count,
+        "queue_wait_p99_ms": round(split["queue"], 3),
+        "service_p99_ms": round(split["service"], 3),
+    }
 
 
 def merge_stage_exports(exports: Iterable[dict]) -> dict:
@@ -255,8 +382,6 @@ def merge_stage_exports(exports: Iterable[dict]) -> dict:
     computed exactly as `Tracer.critical_path` does locally — the
     fleet-level answer to "where does paced p99 live" when the spine
     crosses worker processes (fleet/observer.py)."""
-    from sitewhere_tpu.analysis.registry import TRACE_STAGES
-
     merged: dict[str, dict] = {}
     for export in exports:
         for stage, row in (export or {}).items():
@@ -284,13 +409,8 @@ def merge_stage_exports(exports: Iterable[dict]) -> dict:
                 # report quantiles as the max upper bound below, the
                 # same answer whatever order exports arrive in
                 agg["mixed"] = True
-    kinds = dict(TRACE_STAGES)
-    order = {name: i for i, (name, _) in enumerate(TRACE_STAGES)}
-    stages: dict[str, dict] = {}
-    queue_p99 = service_p99 = 0.0
-    span_count = 0
-    for stage in sorted(merged, key=lambda s: order.get(s, 1000)):
-        agg = merged[stage]
+    rows: dict[str, dict] = {}
+    for stage, agg in merged.items():
         if agg["mixed"]:
             # count-only merge: the honest quantile is unknowable, so
             # every quantile reports the conservative max upper bound
@@ -303,8 +423,7 @@ def merge_stage_exports(exports: Iterable[dict]) -> dict:
             hist._max = agg["max_s"]
             q50, q95, q99 = (hist.quantile(0.50), hist.quantile(0.95),
                              hist.quantile(0.99))
-        kind = kinds.get(stage, "unknown")
-        row = {
+        rows[stage] = {
             "count": agg["count"],
             "p50_ms": round(q50 * 1e3, 3),
             "p95_ms": round(q95 * 1e3, 3),
@@ -312,17 +431,5 @@ def merge_stage_exports(exports: Iterable[dict]) -> dict:
             "mean_ms": round(agg["total_s"] / max(agg["count"], 1) * 1e3, 3),
             "max_ms": round(agg["max_s"] * 1e3, 3),
             "events": agg["events"],
-            "kind": kind,
         }
-        stages[stage] = row
-        span_count += agg["count"]
-        if kind == "queue":
-            queue_p99 += row["p99_ms"]
-        elif kind == "service":
-            service_p99 += row["p99_ms"]
-    return {
-        "stages": stages,
-        "span_count": span_count,
-        "queue_wait_p99_ms": round(queue_p99, 3),
-        "service_p99_ms": round(service_p99, 3),
-    }
+    return _critical_path(rows)

@@ -61,10 +61,11 @@ import numpy as np
 from sitewhere_tpu.domain.batch import BatchContext, MeasurementBatch, ScoredBatch
 from sitewhere_tpu.kernel.egresslane import deliver_scored
 from sitewhere_tpu.kernel.metrics import MetricsRegistry
+from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.parallel.tenant_stack import TenantStack
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.ring import StackedDeviceRing
-from sitewhere_tpu.scoring.settle import SETTLE_POOL
+from sitewhere_tpu.scoring.settle import SETTLE_POOL, DeviceStage, to_host
 from sitewhere_tpu.utils.retry import retry_backoff
 
 logger = logging.getLogger(__name__)
@@ -262,7 +263,10 @@ class SharedScoringPool:
         self.model = model
         self.cfg = cfg
         self.mesh = mesh
-        self.tracer = tracer
+        # a pool built without the runtime's tracer (tests, tools)
+        # keeps one of its own: the hot path has one shape
+        self.tracer = tracer if tracer is not None else Tracer(
+            metrics=metrics)
         # chaos seam (kernel/faults.py "scoring.megabatch"): consulted
         # at admission — the one pool surface reached from inside a
         # consumer loop's per-record quarantine, so an injected fault
@@ -292,7 +296,6 @@ class SharedScoringPool:
         self._warmed_key: tuple = ()
         self.scored_meter = metrics.meter("scoring.events_scored")
         self.latency = metrics.histogram("scoring.e2e_latency_s")
-        self.batch_latency = metrics.histogram("scoring.batch_latency_s")
         self.anomalies = metrics.counter("scoring.anomalies_detected")
         self.anomaly_overflow = metrics.counter("scoring.anomaly_overflow")
         self.flush_rounds = metrics.counter("scoring.pool_flush_rounds")
@@ -314,10 +317,12 @@ class SharedScoringPool:
         self.stack_rebuilds = metrics.counter("scoring.stack_rebuilds")
         self._rebuilds_seen = 0
         # latency decomposition, pool-wide (same stage semantics as
-        # ScoringSession: admit → batch → device → sink)
+        # ScoringSession: admit → batch → device → sink, the device
+        # stage in the same three parts)
         self.stage_admit = metrics.histogram("scoring.stage_admit_s")
         self.stage_batch = metrics.histogram("scoring.stage_batch_s")
-        self.stage_device = metrics.histogram("scoring.stage_device_s")
+        self.device_stage = DeviceStage(metrics, self.tracer)
+        self.stage_device = self.device_stage.total
         self.stage_sink = metrics.histogram("scoring.stage_sink_s")
         # mesh-sharded serving observability: how many devices the
         # stacked dispatch actually spans (0 = single-device), plus the
@@ -932,20 +937,22 @@ class SharedScoringPool:
         t0 = time.monotonic()
         dispatches = []
         try:
-            for parts in round_parts:
-                b = self._bucket_for(max(p[1].shape[0] for p in parts))
-                dev_in = np.full((t_cap, b), d_cap, np.int32)  # scratch pad
-                val_in = np.zeros((t_cap, b), np.float32)
-                for slot, rdev, rval in parts:
-                    dev_in[slot, :rdev.shape[0]] = rdev
-                    val_in[slot, :rdev.shape[0]] = rval
-                if getattr(self.ring, "sparse", False):
-                    dispatches.append(self.ring.update_and_score(
-                        self.model, self.stack.stacked, dev_in, val_in,
-                        thresholds=self._thresholds()))
-                else:
-                    dispatches.append(self.ring.update_and_score(
-                        self.model, self.stack.stacked, dev_in, val_in))
+            with self.tracer.span(
+                    "rule-processing.score.enqueue") as enqueue:
+                for parts in round_parts:
+                    b = self._bucket_for(max(p[1].shape[0] for p in parts))
+                    dev_in = np.full((t_cap, b), d_cap, np.int32)  # scratch
+                    val_in = np.zeros((t_cap, b), np.float32)
+                    for slot, rdev, rval in parts:
+                        dev_in[slot, :rdev.shape[0]] = rdev
+                        val_in[slot, :rdev.shape[0]] = rval
+                    if getattr(self.ring, "sparse", False):
+                        dispatches.append(self.ring.update_and_score(
+                            self.model, self.stack.stacked, dev_in, val_in,
+                            thresholds=self._thresholds()))
+                    else:
+                        dispatches.append(self.ring.update_and_score(
+                            self.model, self.stack.stacked, dev_in, val_in))
         except Exception:
             logger.exception("pool dispatch failed; reseeding ring")
             self.dropped.inc(sum(m[2] for m in metas))
@@ -955,17 +962,16 @@ class SharedScoringPool:
         self.megabatch_dispatches.inc(len(dispatches))
         self.megabatch_tenants.observe(float(len(metas)))
         self._tune_window(len(metas))
-        if self.tracer is not None:
-            # dispatch/settle split with megabatch tenant attribution:
-            # every packed tenant's traces get a queue-wait span here
-            # (its own admit time → this stacked dispatch) and the
-            # settle records the shared device half per tenant below
-            for tid, _slot, _n, _dev, _ts, _ing, traces, *_ in metas:
-                for trace_id, n_ev, t_admit in traces:
-                    self.tracer.record(trace_id,
-                                       "rule-processing.dispatch", tid,
-                                       t_admit, max(t0 - t_admit, 0.0),
-                                       n_ev)
+        # dispatch/settle split with megabatch tenant attribution:
+        # every packed tenant's traces get a queue-wait span here
+        # (its own admit time → this stacked dispatch) and the
+        # settle records the shared device half per tenant below
+        for tid, _slot, _n, _dev, _ts, _ing, traces, *_ in metas:
+            for trace_id, n_ev, t_admit in traces:
+                self.tracer.record(trace_id,
+                                   "rule-processing.dispatch", tid,
+                                   t_admit, max(t0 - t_admit, 0.0),
+                                   n_ev)
         self.inflight += 1
         seq = self.dispatch_count
         self.dispatch_count += 1
@@ -975,7 +981,8 @@ class SharedScoringPool:
             if e is not None:
                 e.inflight += 1
         task = asyncio.get_running_loop().create_task(
-            self._settle_and_deliver(dispatches, metas, t0, seq))
+            self._settle_and_deliver(dispatches, metas, t0,
+                                     enqueue.t_end, seq))
         self._settle_tasks.add(task)
         task.add_done_callback(self._settle_task_done)
 
@@ -989,16 +996,12 @@ class SharedScoringPool:
                          exc_info=task.exception())
 
     async def _settle_and_deliver(self, dispatches, metas, t0: float,
+                                  t_enq: float,
                                   seq: Optional[int] = None) -> None:
         loop = asyncio.get_running_loop()
-        from sitewhere_tpu.scoring.stream import (
-            result_to_host as to_host,
-            sparse_take,
-        )
-
         try:
             try:
-                settled = await asyncio.gather(*[
+                reads = await asyncio.gather(*[
                     loop.run_in_executor(SETTLE_POOL, to_host, s)
                     for s in dispatches])
             except BaseException as exc:
@@ -1007,69 +1010,17 @@ class SharedScoringPool:
                     logger.exception("pool settle failed")
                     return
                 raise
-            now = time.monotonic()
-            self.batch_latency.observe(now - t0)
-            self.stage_device.observe(now - t0)
-            self._note_device_throughput(
-                sum(m[2] for m in metas), now - t0)
-            sparse = bool(settled) and isinstance(settled[0], tuple)
-            deliveries: list[tuple[str, Deliver, ScoredBatch]] = []
-            for (tid, slot, n, dev, ts, ing, traces, ev_rounds, ctx,
-                 version) in metas:
-                e = self.tenants.get(tid)
-                if e is None:  # unregistered mid-flight
-                    continue
-                self.scored_meter.mark(n)
-                self.latency.observe_array(now - ing)
-                if sparse:
-                    # per-tenant anomalous subset: remap round-local
-                    # positions back to this tenant's take positions
-                    anom_pos: list[np.ndarray] = []
-                    anom_scores: list[np.ndarray] = []
-                    for r, rpos, k in ev_rounds:
-                        p, v_, overflow = sparse_take(
-                            settled[r][0][slot], settled[r][1][slot],
-                            settled[r][2][slot], k)
-                        if overflow:
-                            self.anomaly_overflow.inc(overflow)
-                        if p.shape[0] == 0:
-                            continue
-                        anom_pos.append(p if rpos is None else rpos[p])
-                        anom_scores.append(v_)
-                    if anom_pos:
-                        fpos = np.concatenate(anom_pos)
-                        a_scores = np.concatenate(anom_scores)
-                    else:
-                        fpos = np.empty(0, np.int64)
-                        a_scores = np.empty(0, np.float32)
-                    self.anomalies.inc(int(fpos.shape[0]))
-                    scored = ScoredBatch(
-                        ctx, dev[fpos], a_scores,
-                        np.ones(fpos.shape[0], bool), ts[fpos],
-                        # the version snapshotted at DISPATCH, not the
-                        # live one: a swap landing mid-flight must not
-                        # claim scores the old weights computed
-                        model_version=version,
-                        total_scored=n)
-                else:
-                    scores = np.empty(n, np.float32)
-                    for r, rpos, k in ev_rounds:
-                        if rpos is None:
-                            scores[:k] = settled[r][slot, :k]
-                        else:
-                            scores[rpos] = settled[r][slot, :k]
-                    is_anom = scores >= e.threshold
-                    n_anom = int(is_anom.sum())
-                    if n_anom:
-                        self.anomalies.inc(n_anom)
-                    scored = ScoredBatch(
-                        ctx, dev, scores, is_anom, ts,
-                        model_version=version)
-                if self.tracer is not None:
-                    for trace_id, n_ev, *_ in traces:
-                        self.tracer.record(trace_id, "rule-processing.score",
-                                           tid, t0, now - t0, n_ev)
-                deliveries.append((tid, e.deliver, scored))
+            # from here to the sinks the loop itself works (scores
+            # scattered back per tenant, thresholds, ScoredBatches): a
+            # span, which starts where the device stage's last part ends
+            with self.tracer.span("rule-processing.assemble") as assemble:
+                now = assemble.t_start
+                settled, instants = self.device_stage.observe(
+                    reads, t0, t_enq, now)
+                deliveries = self._assemble(settled, metas, now, t0)
+            for tid, _slot, _n, _dev, _ts, _ing, traces, *_ in metas:
+                self.device_stage.record(traces, tid, instants,
+                                         assemble.t_end)
             # settle fan-out (kernel/egresslane.py deliver_scored — the
             # ONE delivery contract with the dedicated session): every
             # tenant of the megabatch delivers CONCURRENTLY, failures
@@ -1089,6 +1040,69 @@ class SharedScoringPool:
                 e = self.tenants.get(tid)
                 if e is not None:
                     e.inflight = max(0, e.inflight - 1)
+
+    def _assemble(self, settled, metas, now: float,
+                  t0: float) -> list[tuple[str, Deliver, ScoredBatch]]:
+        """Settled rounds → one `ScoredBatch` a tenant still registered,
+        with the per-tenant accounting."""
+        from sitewhere_tpu.scoring.stream import sparse_take
+
+        self._note_device_throughput(sum(m[2] for m in metas), now - t0)
+        sparse = bool(settled) and isinstance(settled[0], tuple)
+        deliveries: list[tuple[str, Deliver, ScoredBatch]] = []
+        for (tid, slot, n, dev, ts, ing, traces, ev_rounds, ctx,
+             version) in metas:
+            e = self.tenants.get(tid)
+            if e is None:  # unregistered mid-flight
+                continue
+            self.scored_meter.mark(n)
+            self.latency.observe_array(now - ing)
+            if sparse:
+                # per-tenant anomalous subset: remap round-local
+                # positions back to this tenant's take positions
+                anom_pos: list[np.ndarray] = []
+                anom_scores: list[np.ndarray] = []
+                for r, rpos, k in ev_rounds:
+                    p, v_, overflow = sparse_take(
+                        settled[r][0][slot], settled[r][1][slot],
+                        settled[r][2][slot], k)
+                    if overflow:
+                        self.anomaly_overflow.inc(overflow)
+                    if p.shape[0] == 0:
+                        continue
+                    anom_pos.append(p if rpos is None else rpos[p])
+                    anom_scores.append(v_)
+                if anom_pos:
+                    fpos = np.concatenate(anom_pos)
+                    a_scores = np.concatenate(anom_scores)
+                else:
+                    fpos = np.empty(0, np.int64)
+                    a_scores = np.empty(0, np.float32)
+                self.anomalies.inc(int(fpos.shape[0]))
+                scored = ScoredBatch(
+                    ctx, dev[fpos], a_scores,
+                    np.ones(fpos.shape[0], bool), ts[fpos],
+                    # the version snapshotted at DISPATCH, not the
+                    # live one: a swap landing mid-flight must not
+                    # claim scores the old weights computed
+                    model_version=version,
+                    total_scored=n)
+            else:
+                scores = np.empty(n, np.float32)
+                for r, rpos, k in ev_rounds:
+                    if rpos is None:
+                        scores[:k] = settled[r][slot, :k]
+                    else:
+                        scores[rpos] = settled[r][slot, :k]
+                is_anom = scores >= e.threshold
+                n_anom = int(is_anom.sum())
+                if n_anom:
+                    self.anomalies.inc(n_anom)
+                scored = ScoredBatch(
+                    ctx, dev, scores, is_anom, ts,
+                    model_version=version)
+            deliveries.append((tid, e.deliver, scored))
+        return deliveries
 
     def _recover_ring(self, restart_warmup: bool = True) -> None:
         self.ring = self._new_ring(

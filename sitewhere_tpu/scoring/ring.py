@@ -31,6 +31,27 @@ from sitewhere_tpu.utils import grow_pow2
 logger = logging.getLogger(__name__)
 
 
+def _append_window_score(w: int, score: Callable, out_dtype,
+                         params, vals, cnt, cur, dev, v):
+    """The fused update's body, one ring's (the stacked ring vmaps it
+    over tenants), under the `jax.named_scope`s a profile shows its
+    parts by: the append, the window gather, the model's score."""
+    with jax.named_scope("ring_scatter"):
+        pos = cur[dev]
+        vals = vals.at[dev, pos].set(v, mode="drop")
+        cur = cur.at[dev].set((pos + 1) % w, mode="drop")
+        cnt = jnp.minimum(cnt.at[dev].add(1, mode="drop"), w)
+    with jax.named_scope("ring_gather"):
+        idx = (cur[dev][:, None] - w + jnp.arange(w)[None, :]) % w
+        x = vals[dev[:, None], idx]
+        valid = jnp.arange(w)[None, :] >= (w - cnt[dev])[:, None]
+    with jax.named_scope("window_score"):
+        scores = score(params, x, valid)
+        if out_dtype is not None:
+            scores = scores.astype(out_dtype)
+    return vals, cnt, cur, scores
+
+
 class DeviceRing:
     """Ring of one scalar channel for up to `capacity` devices, resident
     on `device` (default backend device)."""
@@ -105,17 +126,8 @@ class DeviceRing:
                  if prefer_fused else model.score)
 
         def step(params, vals, cnt, cur, dev, v):
-            pos = cur[dev]
-            vals = vals.at[dev, pos].set(v, mode="drop")
-            cur = cur.at[dev].set((pos + 1) % w, mode="drop")
-            cnt = jnp.minimum(cnt.at[dev].add(1, mode="drop"), w)
-            idx = (cur[dev][:, None] - w + jnp.arange(w)[None, :]) % w
-            x = vals[dev[:, None], idx]
-            valid = jnp.arange(w)[None, :] >= (w - cnt[dev])[:, None]
-            scores = score(params, x, valid)
-            if out_dtype is not None:
-                scores = scores.astype(out_dtype)
-            return vals, cnt, cur, scores
+            return _append_window_score(w, score, out_dtype,
+                                        params, vals, cnt, cur, dev, v)
 
         return jax.jit(step, donate_argnums=(1, 2, 3))
 
@@ -270,17 +282,8 @@ class StackedDeviceRing:
         out_dtype = self.score_dtype
 
         def tenant_step(params, vals, cnt, cur, dev, v):
-            pos = cur[dev]
-            vals = vals.at[dev, pos].set(v, mode="drop")
-            cur = cur.at[dev].set((pos + 1) % w, mode="drop")
-            cnt = jnp.minimum(cnt.at[dev].add(1, mode="drop"), w)
-            idx = (cur[dev][:, None] - w + jnp.arange(w)[None, :]) % w
-            x = vals[dev[:, None], idx]
-            valid = jnp.arange(w)[None, :] >= (w - cnt[dev])[:, None]
-            scores = model.score(params, x, valid)
-            if out_dtype is not None:
-                scores = scores.astype(out_dtype)
-            return vals, cnt, cur, scores
+            return _append_window_score(w, model.score, out_dtype,
+                                        params, vals, cnt, cur, dev, v)
 
         return jax.jit(jax.vmap(tenant_step), donate_argnums=(1, 2, 3))
 

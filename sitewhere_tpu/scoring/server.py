@@ -42,9 +42,10 @@ import numpy as np
 from sitewhere_tpu.domain.batch import BatchContext, MeasurementBatch, ScoredBatch
 from sitewhere_tpu.kernel.egresslane import deliver_scored
 from sitewhere_tpu.kernel.metrics import MetricsRegistry
+from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.ring import DeviceRing
-from sitewhere_tpu.scoring.settle import SETTLE_POOL
+from sitewhere_tpu.scoring.settle import SETTLE_POOL, DeviceStage, to_host
 from sitewhere_tpu.utils.retry import retry_backoff
 
 logger = logging.getLogger(__name__)
@@ -112,7 +113,10 @@ class ScoringSession:
         self.telemetry = telemetry
         self.cfg = cfg
         self.sink = sink
-        self.tracer = tracer
+        # a session built without the runtime's tracer (tests, tools)
+        # keeps one of its own: the hot path has one shape
+        self.tracer = tracer if tracer is not None else Tracer(
+            metrics=metrics)
         # chaos seam (kernel/faults.py "scoring.dispatch"): consulted
         # before a flush takes its pending admissions, so an injected
         # crash loses nothing — the supervisor restarts the consuming
@@ -156,7 +160,6 @@ class ScoringSession:
         # metrics (judge's metrics are first-class [SURVEY.md §5.5])
         self.scored_meter = metrics.meter("scoring.events_scored")
         self.latency = metrics.histogram("scoring.e2e_latency_s")
-        self.batch_latency = metrics.histogram("scoring.batch_latency_s")
         self.batch_size_hist = metrics.histogram(
             "scoring.batch_size", buckets=[float(b) for b in cfg.buckets])
         self.anomalies = metrics.counter("scoring.anomalies_detected")
@@ -174,11 +177,13 @@ class ScoringSession:
         # single opaque number):
         #   admit  = receiver arrival → admission (decode + bus hops + queue)
         #   batch  = admission → dispatch (deadline batching + inflight gate)
-        #   device = dispatch → scores on host (XLA queue + compute + sync)
+        #   device = dispatch → scores on host (XLA queue + compute + sync),
+        #            itself in three parts (scoring/settle.py DeviceStage)
         #   sink   = settled → published (delivery/alert fan-out)
         self.stage_admit = metrics.histogram("scoring.stage_admit_s")
         self.stage_batch = metrics.histogram("scoring.stage_batch_s")
-        self.stage_device = metrics.histogram("scoring.stage_device_s")
+        self.device_stage = DeviceStage(metrics, self.tracer)
+        self.stage_device = self.device_stage.total
         self.stage_sink = metrics.histogram("scoring.stage_sink_s")
 
     def _new_ring(self, capacity: int):
@@ -506,7 +511,7 @@ class ScoringSession:
         return dispatches
 
     async def _settle_and_deliver(self, dispatches, dev, ts,
-                                  ingest, ctx, t0: float,
+                                  ingest, ctx, t0: float, t_enq: float,
                                   fut: Optional[asyncio.Future] = None,
                                   seq: Optional[int] = None,
                                   traces: Optional[list] = None):
@@ -514,12 +519,9 @@ class ScoringSession:
         # commit gate must not consider a flush done until its scored
         # output has been published
         loop = asyncio.get_running_loop()
-
-        from sitewhere_tpu.scoring.stream import result_to_host as to_host
-
         try:
             try:
-                settled = await asyncio.gather(*[
+                reads = await asyncio.gather(*[
                     loop.run_in_executor(SETTLE_POOL, to_host, s)
                     for s, _, _ in dispatches])
             except BaseException as exc:
@@ -534,61 +536,18 @@ class ScoringSession:
                     logger.exception("scoring settle failed")
                     return
                 raise
-            # mode-independent accounting: BOTH paths scored every event
-            # on device (sparse just ships fewer scores home)
-            now = time.monotonic()
-            self.stage_device.observe(now - t0)
-            self.scored_meter.mark(dev.shape[0])
-            self.latency.observe_array(now - ingest)
-            self.batch_latency.observe(now - t0)
-            if settled and isinstance(settled[0], tuple):
-                # sparse anomaly readback: reconstruct the anomalous
-                # subset only
-                from sitewhere_tpu.scoring.stream import sparse_take
-
-                anom_flush_pos: list[np.ndarray] = []
-                anom_scores: list[np.ndarray] = []
-                for (n_anom, pos, vals), (_, n, rpos) in zip(settled,
-                                                             dispatches):
-                    p, v_, overflow = sparse_take(n_anom, pos, vals, n)
-                    if overflow:
-                        self.anomaly_overflow.inc(overflow)
-                    if p.shape[0] == 0:
-                        continue
-                    # rounds remap duplicate-device chunks back to the
-                    # original flush positions
-                    anom_flush_pos.append(p if rpos is None else rpos[p])
-                    anom_scores.append(v_)
-                if anom_flush_pos:
-                    fpos = np.concatenate(anom_flush_pos)
-                    a_scores = np.concatenate(anom_scores)
-                else:
-                    fpos = np.empty(0, np.int64)
-                    a_scores = np.empty(0, np.float32)
-                self.anomalies.inc(int(fpos.shape[0]))
-                scored = ScoredBatch(
-                    ctx, dev[fpos], a_scores,
-                    np.ones(fpos.shape[0], bool), ts[fpos],
-                    model_version=self.version,
-                    total_scored=int(dev.shape[0]))
-            else:
-                scores = np.empty(dev.shape[0], np.float32)
-                for scores_u, (_, n, rpos) in zip(settled, dispatches):
-                    if rpos is None:
-                        scores[:n] = scores_u[:n]
-                    else:
-                        scores[rpos] = scores_u[:n]
-                is_anom = scores >= self.cfg.threshold
-                n_anom = int(is_anom.sum())
-                if n_anom:
-                    self.anomalies.inc(n_anom)
-                scored = ScoredBatch(ctx, dev, scores, is_anom, ts,
-                                     model_version=self.version)
-            if self.tracer is not None:
-                for trace_id, n_ev, *_ in (traces or [(ctx.trace_id,
-                                                       dev.shape[0])]):
-                    self.tracer.record(trace_id, "rule-processing.score",
-                                       ctx.tenant_id, t0, now - t0, n_ev)
+            # the stretch from here to the sink (scores scattered back,
+            # threshold, ScoredBatch) is the loop's own work: a span,
+            # which starts where the device stage's last part ends
+            with self.tracer.span("rule-processing.assemble") as assemble:
+                now = assemble.t_start
+                settled, instants = self.device_stage.observe(
+                    reads, t0, t_enq, now)
+                scored = self._assemble(settled, dispatches, dev, ts,
+                                        ingest, ctx, now)
+            self.device_stage.record(
+                traces or [(ctx.trace_id, dev.shape[0])], ctx.tenant_id,
+                instants, assemble.t_end)
             if fut is not None and not fut.done():
                 fut.set_result(scored)
             if self.sink is not None:
@@ -603,6 +562,56 @@ class ScoringSession:
             if seq is not None:
                 self._outstanding.discard(seq)
 
+    def _assemble(self, settled, dispatches, dev, ts, ingest, ctx,
+                  now: float) -> ScoredBatch:
+        """Settled rounds → the chunk's `ScoredBatch`, with the
+        mode-independent accounting: BOTH read-back paths scored every
+        event on device (sparse just ships fewer scores home)."""
+        self.scored_meter.mark(dev.shape[0])
+        self.latency.observe_array(now - ingest)
+        if settled and isinstance(settled[0], tuple):
+            # sparse anomaly readback: reconstruct the anomalous
+            # subset only
+            from sitewhere_tpu.scoring.stream import sparse_take
+
+            anom_flush_pos: list[np.ndarray] = []
+            anom_scores: list[np.ndarray] = []
+            for (n_anom, pos, vals), (_, n, rpos) in zip(settled,
+                                                         dispatches):
+                p, v_, overflow = sparse_take(n_anom, pos, vals, n)
+                if overflow:
+                    self.anomaly_overflow.inc(overflow)
+                if p.shape[0] == 0:
+                    continue
+                # rounds remap duplicate-device chunks back to the
+                # original flush positions
+                anom_flush_pos.append(p if rpos is None else rpos[p])
+                anom_scores.append(v_)
+            if anom_flush_pos:
+                fpos = np.concatenate(anom_flush_pos)
+                a_scores = np.concatenate(anom_scores)
+            else:
+                fpos = np.empty(0, np.int64)
+                a_scores = np.empty(0, np.float32)
+            self.anomalies.inc(int(fpos.shape[0]))
+            return ScoredBatch(
+                ctx, dev[fpos], a_scores,
+                np.ones(fpos.shape[0], bool), ts[fpos],
+                model_version=self.version,
+                total_scored=int(dev.shape[0]))
+        scores = np.empty(dev.shape[0], np.float32)
+        for scores_u, (_, n, rpos) in zip(settled, dispatches):
+            if rpos is None:
+                scores[:n] = scores_u[:n]
+            else:
+                scores[rpos] = scores_u[:n]
+        is_anom = scores >= self.cfg.threshold
+        n_anom = int(is_anom.sum())
+        if n_anom:
+            self.anomalies.inc(n_anom)
+        return ScoredBatch(ctx, dev, scores, is_anom, ts,
+                           model_version=self.version)
+
     def _dispatch_chunks(self, dev, val, ts, ingest, ctx, t0,
                          futs: Optional[list] = None,
                          traces: Optional[list] = None) -> tuple:
@@ -611,7 +620,7 @@ class ScoringSession:
         arrival order across chunks. Returns chunks dispatched."""
         loop = asyncio.get_running_loop()
         max_b = self.cfg.buckets[-1]
-        if self.tracer is not None and traces:
+        if traces:
             # the dispatch/settle split: this span is pure QUEUE WAIT
             # (admission → jit dispatch: batching window + inflight
             # gate); the settle records "rule-processing.score" for the
@@ -624,7 +633,9 @@ class ScoringSession:
         for lo in range(0, dev.shape[0], max_b):
             hi = lo + max_b
             try:
-                dispatches = self._dispatch(dev[lo:hi], val[lo:hi])
+                with self.tracer.span(
+                        "rule-processing.score.enqueue") as enqueue:
+                    dispatches = self._dispatch(dev[lo:hi], val[lo:hi])
             except Exception:
                 logger.exception("scoring dispatch failed; reloading ring")
                 self.dropped.inc(dev.shape[0] - lo)
@@ -639,7 +650,7 @@ class ScoringSession:
                 futs.append(fut)
             task = loop.create_task(self._settle_and_deliver(
                 dispatches, dev[lo:hi], ts[lo:hi],
-                ingest[lo:hi], ctx, t0, fut, seq,
+                ingest[lo:hi], ctx, t0, enqueue.t_end, fut, seq,
                 traces if lo == 0 else None))
             self._settle_tasks.add(task)
             task.add_done_callback(self._settle_task_done)

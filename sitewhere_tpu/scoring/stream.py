@@ -34,10 +34,26 @@ import numpy as np
 from sitewhere_tpu.utils import grow_pow2
 
 
+def _gather_step_scatter(model, params, state, dev, v):
+    """The ring step's three parts, under the `jax.named_scope`s a
+    profile shows them by: rows of `dev` out of the table, one cell step
+    on them, the new rows back (padding lands in the scratch row)."""
+    with jax.named_scope("ring_gather"):
+        rows = jax.tree.map(lambda leaf: leaf[dev], state)
+    with jax.named_scope("cell_step"):
+        scores, new_rows = model.step_score(params, rows, v)
+    with jax.named_scope("ring_scatter"):
+        state = jax.tree.map(
+            lambda leaf, rows_new: leaf.at[dev].set(rows_new, mode="drop"),
+            state, new_rows)
+    return state, scores
+
+
 def streaming_step(model, out_dtype=None) -> Callable:
     """The fused gather→step_score→scatter step body, shared by the
     dedicated ring (jit) and the stacked ring (jit∘vmap) so the two hot
-    paths cannot diverge.
+    paths cannot diverge. The inner function keeps the name `step`: the
+    benchmark's trace reduction finds its runs as module `jit_step`.
 
     `out_dtype` narrows the returned scores at the jit boundary (model
     state stays float32): float16 scores halve the only per-event
@@ -46,15 +62,10 @@ def streaming_step(model, out_dtype=None) -> Callable:
     upcasts on assignment into its float32 result array."""
 
     def step(params, state, dev, v):
-        rows = jax.tree.map(lambda leaf: leaf[dev], state)
-        scores, new_rows = model.step_score(params, rows, v)
+        state, scores = _gather_step_scatter(model, params, state, dev, v)
         if out_dtype is not None:
             scores = scores.astype(out_dtype)
-
-        def scatter(leaf, rows_new):
-            return leaf.at[dev].set(rows_new, mode="drop")
-
-        return jax.tree.map(scatter, state, new_rows), scores
+        return state, scores
 
     return step
 
@@ -84,21 +95,16 @@ def streaming_step_sparse(model, k: int,
     own alert bar) so threshold changes never recompile."""
 
     def step(params, state, dev, v, threshold):
-        rows = jax.tree.map(lambda leaf: leaf[dev], state)
-        scores, new_rows = model.step_score(params, rows, v)
-
-        def scatter(leaf, rows_new):
-            return leaf.at[dev].set(rows_new, mode="drop")
-
-        state = jax.tree.map(scatter, state, new_rows)
-        # scratch-row padding must never report: its state absorbs
-        # arbitrary writes, so its score is garbage by design
-        is_anom = (scores >= threshold) & (dev != scratch_index)
-        n_anom = is_anom.sum().astype(jnp.int32)
-        masked = jnp.where(is_anom, scores, -jnp.inf)
-        top_scores, top_pos = jax.lax.top_k(masked, k)
-        if out_dtype is not None:
-            top_scores = top_scores.astype(out_dtype)
+        state, scores = _gather_step_scatter(model, params, state, dev, v)
+        with jax.named_scope("sparse_topk"):
+            # scratch-row padding must never report: its state absorbs
+            # arbitrary writes, so its score is garbage by design
+            is_anom = (scores >= threshold) & (dev != scratch_index)
+            n_anom = is_anom.sum().astype(jnp.int32)
+            masked = jnp.where(is_anom, scores, -jnp.inf)
+            top_scores, top_pos = jax.lax.top_k(masked, k)
+            if out_dtype is not None:
+                top_scores = top_scores.astype(out_dtype)
         return state, (n_anom, top_pos.astype(jnp.int32), top_scores)
 
     return step

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import time
 from typing import Sequence
 
 from sitewhere_tpu.config import TenantConfig
@@ -254,37 +253,34 @@ class EventPersister(BackgroundTaskComponent):
 
     def _persist(self, record, spi, runtime, tenant_id, persisted) -> None:
         batch = record.value
-        t_span = time.monotonic()
-        if isinstance(batch, MeasurementBatch):
-            persisted.mark(spi.add_measurements(batch))
-        elif isinstance(batch, LocationBatch):
-            persisted.mark(spi.add_locations(batch))
-        elif isinstance(batch, AlertBatch):
-            persisted.mark(len(spi.add_alert_batch(batch)))
-        elif isinstance(batch, list):  # cold per-event objects
-            stored = 0
-            for ev in batch:
-                if isinstance(ev, DeviceAlert):
-                    spi.add_alerts([ev])
-                elif isinstance(ev, DeviceCommandResponse):
-                    spi.add_command_responses([ev])
-                elif isinstance(ev, DeviceStateChange):
-                    spi.add_state_changes([ev])
-                else:
-                    logger.warning("event-mgmt: unpersistable cold"
-                                   " event %r", type(ev))
-                    continue
-                stored += 1
-            persisted.mark(stored)
-        else:
-            logger.warning("event-mgmt: unknown record %r", type(batch))
-            raise _Skip()
         ctx = getattr(batch, "ctx", None)
-        if ctx is not None:
-            runtime.tracer.record(
-                ctx.trace_id, "event-management.persist",
-                tenant_id, t_span, time.monotonic() - t_span,
-                len(batch))
+        with runtime.tracer.span(
+                "event-management.persist", getattr(ctx, "trace_id", 0),
+                tenant_id, len(batch) if ctx is not None else 0):
+            if isinstance(batch, MeasurementBatch):
+                persisted.mark(spi.add_measurements(batch))
+            elif isinstance(batch, LocationBatch):
+                persisted.mark(spi.add_locations(batch))
+            elif isinstance(batch, AlertBatch):
+                persisted.mark(len(spi.add_alert_batch(batch)))
+            elif isinstance(batch, list):  # cold per-event objects
+                stored = 0
+                for ev in batch:
+                    if isinstance(ev, DeviceAlert):
+                        spi.add_alerts([ev])
+                    elif isinstance(ev, DeviceCommandResponse):
+                        spi.add_command_responses([ev])
+                    elif isinstance(ev, DeviceStateChange):
+                        spi.add_state_changes([ev])
+                    else:
+                        logger.warning("event-mgmt: unpersistable cold"
+                                       " event %r", type(ev))
+                        continue
+                    stored += 1
+                persisted.mark(stored)
+            else:
+                logger.warning("event-mgmt: unknown record %r", type(batch))
+                raise _Skip()
 
 
 class EventManagementService(Service):
