@@ -9,7 +9,10 @@ repo's own means:
      service), one `lstm-stream` tenant, a TCP SWB1 gateway fed by ONE
      child process that never touches JAX; scored == sent == published,
      alerts emitted, state on the expected devices, no restarts / dead
-     letters / publish failures, no compile after warm-up.
+     letters / publish failures, no compile after warm-up; the compiled
+     ring step moves no whole table (no `copy` / `transpose` of a leaf
+     of two or more dimensions), and the summary says how each state
+     leaf rests on the device.
   B  the Pallas kernel (ops/lstm_kernel.py): a dedicated windowed-`lstm`
      session must select it, compile it under Mosaic (not interpret
      mode) and agree with the `lax.scan` scorer on the same windows.
@@ -34,6 +37,7 @@ from __future__ import annotations
 import asyncio
 import json
 import os
+import re
 import sys
 import time
 import traceback
@@ -199,6 +203,43 @@ def _shard_devices(tree) -> set:
             for shard in leaf.addressable_shards}
 
 
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]\S* (\w[\w-]*)\(")
+
+
+def _table_moves(hlo: str, rows: int) -> list[str]:
+    """`copy` / `transpose` operations of a compiled step whose output is
+    a table of `rows` rows and two or more dimensions, whichever axis
+    the rows lie on: a leaf that rests in another layout than the
+    scatter wants shows up here, twice a step (PERF.md, PR 27)."""
+    moves = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m and m.group(3) in ("copy", "transpose"):
+            dims = m.group(2).split(",")
+            if len(dims) >= 2 and str(rows) in dims:
+                moves.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    return moves
+
+
+def _ring_step_checks(ph: Phase, session) -> None:
+    """Compile the dedicated ring's warmed step once more, for its text
+    (a compile before the warm-up mark), and read the state's layouts."""
+    ring = session.ring
+    bucket = session.cfg.buckets[-1]
+    dev, v = ring._pad(np.zeros(0, np.int32), np.zeros(0, np.float32),
+                       bucket)
+    hlo = ring._fns[ring.capacity, bucket].lower(
+        session.params, ring.state, dev, v).compile().as_text()
+    moves = _table_moves(hlo, ring.capacity + 1)
+    ph.out["table_moves"] = moves
+    ph.check(not moves, f"the ring step moves no whole table (got {moves})")
+    ph.out["state_layouts"] = {
+        name: f"{list(leaf.shape)} major_to_minor="
+              f"{leaf.format.layout.major_to_minor} "
+              f"tiling={leaf.format.layout.tiling}"
+        for name, leaf in sorted(ring.state.items())}
+
+
 def _tenant_sections(devices: int, **rule_extra) -> dict:
     return {
         "rule-processing": {
@@ -323,6 +364,7 @@ async def phase_server(ph: Phase, expect_platform: str,
         ph.check(platforms == {expect_platform},
                  f"ring state on {expect_platform} devices "
                  f"(got {sorted(platforms)})")
+        _ring_step_checks(ph, session)
 
         consumer = rt.bus.subscribe(
             rt.naming.tenant_topic(tid, "scored-events"), group="chip-smoke")

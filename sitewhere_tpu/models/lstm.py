@@ -188,23 +188,37 @@ class StreamingLstmModel(LstmAnomalyModel):
 
     `score`/`loss` (whole-window paths: query/REST, training) are
     inherited unchanged — only the resident hot path differs.
+
+    State leaves: `pred`, `mean`, `var` (f32 `[rows]`), `count` (i32
+    `[rows]`) and ONE leaf `hc` for the recurrent state of all layers,
+    f32 `[rows, round_up(2 * hidden * layers, 128)]`, columns in the
+    order `h0 ‖ c0 ‖ h1 ‖ c1 ...` then zero padding that is never read.
+    Whole 128-lane tiles in the minor dimension keep the table row-major
+    at rest, so the ring scatters rows into its donated buffer in place
+    (scoring/stream.py, "Contract with the model").
     """
 
     name = "lstm-stream"
     streaming = True
 
+    def _hc_width(self) -> int:
+        return -(-2 * self.cfg.hidden * self.cfg.layers // 128) * 128
+
+    def _hc_join(self, parts: list) -> jax.Array:
+        """`[h0, c0, h1, c1, ...]`, each `[B, hidden]` → `[B, width]`."""
+        pad = self._hc_width() - len(parts) * self.cfg.hidden
+        if pad:
+            parts = parts + [jnp.zeros((parts[0].shape[0], pad), jnp.float32)]
+        return jnp.concatenate(parts, axis=-1)
+
     def init_state(self, cap: int) -> dict:
         """Zero per-device streaming state for `cap` rows (callers add
         their own scratch row before passing a capacity here)."""
-        h = self.cfg.hidden
-        state = {"pred": jnp.zeros(cap, jnp.float32),
-                 "mean": jnp.zeros(cap, jnp.float32),
-                 "var": jnp.ones(cap, jnp.float32),
-                 "count": jnp.zeros(cap, jnp.int32)}
-        for layer in range(self.cfg.layers):
-            state[f"h{layer}"] = jnp.zeros((cap, h), jnp.float32)
-            state[f"c{layer}"] = jnp.zeros((cap, h), jnp.float32)
-        return state
+        return {"pred": jnp.zeros(cap, jnp.float32),
+                "mean": jnp.zeros(cap, jnp.float32),
+                "var": jnp.ones(cap, jnp.float32),
+                "count": jnp.zeros(cap, jnp.int32),
+                "hc": jnp.zeros((cap, self._hc_width()), jnp.float32)}
 
     def _cell(self, params: dict, layer: int, x: jax.Array,
               h: jax.Array, c: jax.Array):
@@ -222,9 +236,10 @@ class StreamingLstmModel(LstmAnomalyModel):
     def step_score(self, params: dict, rows: dict, v: jax.Array):
         """Score + advance gathered state rows for one event each.
 
-        rows: state leaves indexed down to the event batch ([B] / [B, h]);
-        v: [B] raw values. Returns (scores [B], new rows)."""
+        rows: state leaves indexed down to the event batch ([B] /
+        [B, width]); v: [B] raw values. Returns (scores [B], new rows)."""
         cfg = self.cfg
+        hid = cfg.hidden
         mean, var, cnt = rows["mean"], rows["var"], rows["count"]
         sd = jnp.sqrt(var + 1e-6)
         xn = (v - mean) / sd
@@ -240,11 +255,14 @@ class StreamingLstmModel(LstmAnomalyModel):
         x = ((v - mean1) / jnp.sqrt(var1 + 1e-6))[:, None]
         out = dict(rows)
         out["mean"], out["var"], out["count"] = mean1, var1, cnt1
+        hc, parts = rows["hc"], []
         for layer in range(cfg.layers):
-            h, c = self._cell(params, layer, x, rows[f"h{layer}"],
-                              rows[f"c{layer}"])
-            out[f"h{layer}"], out[f"c{layer}"] = h, c
+            at = 2 * layer * hid
+            h, c = self._cell(params, layer, x, hc[:, at:at + hid],
+                              hc[:, at + hid:at + 2 * hid])
+            parts += [h, c]
             x = h
+        out["hc"] = self._hc_join(parts)
         head = params["head"]
         out["pred"] = (x @ head["w"] + head["b"])[:, 0]
         return score, out
@@ -262,13 +280,13 @@ class StreamingLstmModel(LstmAnomalyModel):
         var = (((x - mean[:, None]) * v) ** 2).sum(-1) / n
         xn = ((x - mean[:, None]) / jnp.sqrt(var + 1e-6)[:, None]) * v
         state = self.init_state(x.shape[0])
-        seq = xn[:, :, None]
+        seq, parts = xn[:, :, None], []
         for layer in range(cfg.layers):
             seq, (h, c) = lstm_scan(params[f"lstm{layer}"], seq,
                                     cfg.compute_dtype)
             seq = seq.astype(cfg.compute_dtype)
-            state[f"h{layer}"] = h
-            state[f"c{layer}"] = c
+            parts += [h, c]
+        state["hc"] = self._hc_join(parts)
         head = params["head"]
         pred = (seq[:, -1, :].astype(jnp.float32) @ head["w"] + head["b"])[:, 0]
         state["pred"] = pred

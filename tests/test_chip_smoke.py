@@ -42,10 +42,33 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert a["sent"] == a["scored"] == a["published"] == want
     assert a["alerts"] >= 1 and a["compiles_after_warmup"] == 0
     assert a["feeder_exit"] == 0
+    assert a["table_moves"] == []
+    assert sorted(a["state_layouts"]) == ["count", "hc", "mean", "pred", "var"]
     assert b["ok"] and b["skipped"] == "pallas_ok false on cpu"
     assert c["mesh"] == {"data": 2, "model": 2}
     assert c["scored_per_tenant"] == [want]
     assert c["megabatch_dispatches"] > 0 and c["swap_version"] == 1
+
+
+@pytest.mark.parametrize("line,found", [
+    # the parent's step at the cell's size (PERF.md, PR 27): a leaf that
+    # rests column-major is copied to row-major and back
+    ("  %copy.6 = f32[524289,64]{1,0:T(8,128)} copy(%state__c0__.1), "
+     "sharding={replicated}", ["%copy.6 copy[524289,64]"]),
+    ("  ROOT %transpose.1 = f32[64,524289]{1,0} transpose(%x), "
+     "dimensions={1,0}", ["%transpose.1 transpose[64,524289]"]),
+    # not a move of a table: the scatter itself, a bucket's rows, a
+    # one-dimensional leaf staged into faster memory
+    ("  %fusion.6 = f32[524289,128]{1,0:T(8,128)} fusion(%state__hc__.1, "
+     "%gte.10, %copy.2), kind=kCustom", []),
+    ("  %copy.2 = f32[16384,128]{1,0:T(8,128)} copy(%fusion.3)", []),
+    ("  %copy-done.2 = f32[524289]{0:T(1024)S(1)} copy-done(%copy-start.2)",
+     []),
+    ("  %copy.8 = f32[524289]{0} copy(%state__pred__.1)", []),
+])
+def test_table_moves_reads_whole_table_copies_off_the_compiled_text(
+        line, found):
+    assert chip_smoke._table_moves(line + "\n", 524289) == found
 
 
 def test_smoke_refuses_the_wrong_platform():

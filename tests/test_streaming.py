@@ -8,6 +8,7 @@ recovery, and the checkpoint-rollout reseed."""
 import asyncio
 
 import numpy as np
+import pytest
 
 from sitewhere_tpu.kernel.metrics import MetricsRegistry
 from sitewhere_tpu.models import build_model
@@ -170,6 +171,164 @@ def test_streaming_swap_params_reseeds_state(run):
         fresh.close()
 
     run(main())
+
+
+# -- the state's layout in the table: one leaf `hc` for h and c --------------
+
+WIDTHS = [(16, 1), (64, 1), (64, 2)]     # (hidden, layers)
+W, D, B, TICKS = 16, 40, 24, 24          # window, devices, bucket, ticks
+
+
+def _plain_seed(model, params, x):
+    """`warm_state` by hand on full windows: h and c of each layer as
+    arrays of their own."""
+    import jax.numpy as jnp
+
+    from sitewhere_tpu.models.common import lstm_scan
+
+    cdt = model.cfg.compute_dtype
+    mean = x.mean(-1)
+    var = ((x - mean[:, None]) ** 2).mean(-1)
+    seq = ((x - mean[:, None]) / jnp.sqrt(var + 1e-6)[:, None])[:, :, None]
+    hs, cs = [], []
+    for layer in range(model.cfg.layers):
+        seq, (h, c) = lstm_scan(params[f"lstm{layer}"], seq, cdt)
+        seq = seq.astype(cdt)
+        hs.append(h)
+        cs.append(c)
+    pred = (seq[:, -1].astype(jnp.float32) @ params["head"]["w"]
+            + params["head"]["b"])[:, 0]
+    count = jnp.full(x.shape[0], min(x.shape[1], model.cfg.window), jnp.int32)
+    return pred, mean, jnp.maximum(var, 1e-6), count, hs, cs
+
+
+def _plain_tick(model, params, st, dev, v):
+    """One event a row of `dev`, the equations of `step_score` on state
+    that keeps every layer's h and c apart; returns (state, scores)."""
+    import jax.numpy as jnp
+
+    cfg = model.cfg
+    pred, mean, var, count, hs, cs = st
+    m, s2, n = mean[dev], var[dev], count[dev]
+    score = jnp.clip(
+        jnp.where(n >= max(8, cfg.window // 8),
+                  jnp.abs((v - m) / jnp.sqrt(s2 + 1e-6) - pred[dev]), 0.0),
+        0.0, cfg.score_clip)
+    n1 = jnp.minimum(n + 1, cfg.window)
+    m1 = m + (v - m) / n1
+    s21 = s2 + ((v - m1) * (v - m) - s2) / n1
+    x = ((v - m1) / jnp.sqrt(s21 + 1e-6))[:, None]
+    hs, cs = list(hs), list(cs)
+    for layer in range(cfg.layers):
+        h, c = model._cell(params, layer, x, hs[layer][dev], cs[layer][dev])
+        hs[layer] = hs[layer].at[dev].set(h)
+        cs[layer] = cs[layer].at[dev].set(c)
+        x = h
+    p1 = (x @ params["head"]["w"] + params["head"]["b"])[:, 0]
+    return (pred.at[dev].set(p1), mean.at[dev].set(m1), var.at[dev].set(s21),
+            count.at[dev].set(n1), hs, cs), score
+
+
+@pytest.mark.parametrize("start", ["init_state", "warm_state"])
+@pytest.mark.parametrize("vmapped", [False, True],
+                         ids=["dedicated", "vmapped"])
+@pytest.mark.parametrize("hidden,layers", WIDTHS)
+def test_step_over_hc_matches_a_loop_that_keeps_h_and_c_apart(
+        hidden, layers, vmapped, start):
+    """24 ticks through the ring's jitted step, cold and seeded, dedicated
+    and under `vmap`, against a plain loop with h and c of each layer in
+    arrays of their own: packing them into `hc` moves no value."""
+    import jax
+    import jax.numpy as jnp
+
+    from sitewhere_tpu.scoring.stream import streaming_step
+
+    model = build_model("lstm-stream", window=W, hidden=hidden,
+                        layers=layers)
+    rng = np.random.default_rng(hidden + layers)
+    tenants = 2 if vmapped else 1
+    step = streaming_step(model)
+    step = jax.jit(jax.vmap(step) if vmapped else step)
+    plain = jax.jit(lambda params, st, dev, v:
+                    _plain_tick(model, params, st, dev, v))
+    hist = rng.normal(20.0, 3.0, (tenants, D + 1, W)).astype(np.float32)
+    states, plains, params = [], [], []
+    for t in range(tenants):
+        p = model.init(jax.random.PRNGKey(7 + t))
+        params.append(p)
+        if start == "warm_state":
+            states.append(jax.jit(model.warm_state)(
+                p, jnp.asarray(hist[t]), jnp.ones((D + 1, W), bool)))
+            plains.append(_plain_seed(model, p, jnp.asarray(hist[t])))
+        else:
+            states.append(model.init_state(D + 1))
+            zeros = [jnp.zeros((D + 1, hidden), jnp.float32)] * layers
+            plains.append((jnp.zeros(D + 1), jnp.zeros(D + 1),
+                           jnp.ones(D + 1), jnp.zeros(D + 1, jnp.int32),
+                           zeros, zeros))
+    if vmapped:
+        state = jax.tree.map(lambda *leaves: jnp.stack(leaves), *states)
+        stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *params)
+    else:
+        state, stacked = states[0], params[0]
+    for _ in range(TICKS):
+        # unique ids a tenant, the tail of the bucket on the scratch row
+        dev = np.full((tenants, B), D, np.int32)
+        for t in range(tenants):
+            dev[t, :B - 3] = rng.permutation(D)[:B - 3]
+        v = rng.normal(20.0, 3.0, (tenants, B)).astype(np.float32)
+        if vmapped:
+            state, got = step(stacked, state, dev, v)
+        else:
+            state, got = step(stacked, state, dev[0], v[0])
+            got = got[None]
+        for t in range(tenants):
+            plains[t], want = plain(params[t], plains[t], dev[t], v[t])
+            np.testing.assert_allclose(np.asarray(got[t, :B - 3]),
+                                       np.asarray(want[:B - 3]),
+                                       rtol=1e-6, atol=1e-6)
+    assert float(np.asarray(got).max()) > 0.0       # the gate opened
+
+
+@pytest.mark.parametrize("hidden,layers", WIDTHS)
+def test_state_tree_is_one_shape_and_rests_in_whole_tiles(hidden, layers):
+    """`init_state` and `warm_state` declare the same tree, and every
+    leaf of two or more dimensions a minor dimension of whole 128-lane
+    tiles (scoring/stream.py, "Contract with the model")."""
+    import jax
+    import jax.numpy as jnp
+
+    model = build_model("lstm-stream", window=W, hidden=hidden,
+                        layers=layers)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cold = jax.eval_shape(lambda: model.init_state(D))
+    warm = jax.eval_shape(model.warm_state, params,
+                          jax.ShapeDtypeStruct((D, W), jnp.float32),
+                          jax.ShapeDtypeStruct((D, W), jnp.bool_))
+    assert jax.tree.structure(cold) == jax.tree.structure(warm)
+    assert jax.tree.leaves(cold) == jax.tree.leaves(warm)
+    assert cold["hc"].shape == (D, -(-2 * hidden * layers // 128) * 128)
+    for leaf in jax.tree.leaves(cold):
+        assert leaf.shape[0] == D
+        assert leaf.ndim == 1 or leaf.shape[-1] % 128 == 0, leaf
+
+
+@pytest.mark.parametrize("hidden,layers", WIDTHS)
+def test_lowered_step_scatters_once_a_state_leaf(hidden, layers):
+    """One scatter a leaf, five in all whatever the depth: a leaf added
+    later shows up here, in review."""
+    import jax
+    import jax.numpy as jnp
+
+    from sitewhere_tpu.scoring.stream import streaming_step
+
+    model = build_model("lstm-stream", window=W, hidden=hidden,
+                        layers=layers)
+    state = model.init_state(D + 1)
+    text = jax.jit(streaming_step(model)).lower(
+        model.init(jax.random.PRNGKey(0)), state,
+        jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.float32)).as_text()
+    assert text.count('"stablehlo.scatter"(') == len(state) == 5
 
 
 # -- pooled streaming (config 4 at streaming speed) -------------------------
