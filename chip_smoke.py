@@ -19,6 +19,12 @@ repo's own means:
   C  the shared pool: tenants on `lstm-stream` through the megabatch
      pool, one param hot-swap under load; with four or more devices the
      pool must shard over exactly `{data: 2, model: 2}`.
+  D  `dsv3-stream` (DeepSeek-V3's block at the published widths, the
+     benchmark configuration's share of it) behind the same gateway at a
+     small fleet: the session holds no weights until the first set is
+     bound, then scored == sent == published; the compiled step copies
+     or transposes no context leaf; the arrays' peak stayed under one
+     set of weights and a half, so two sets were never resident.
 
 This process is the one that holds the chip. It takes no flags, reads
 no switch of its own and never sets JAX_PLATFORMS: `python chip_smoke.py`
@@ -69,10 +75,15 @@ class Sizes:
     kernel_bucket: int      # phase B
     anomaly_rate: float     # share of events the feeder spikes
     interval_s: float       # feeder's tick period
+    dsv3_devices: int       # phase D fleet = bucket = ring capacity
+    # phase D `model_config`; None: the one the benchmark's DeepSeek-V3
+    # configuration runs (benchmarks/configs/deepseek-v3-ep16.json)
+    dsv3_config: dict | None = None
 
 
 FULL = Sizes(devices=16384, ticks=32, pool_tenants=8, pool_devices=2048,
-             kernel_bucket=4096, anomaly_rate=0.001, interval_s=0.125)
+             kernel_bucket=4096, anomaly_rate=0.001, interval_s=0.125,
+             dsv3_devices=1024)
 
 # the whole run must fit the chip check's 1200 s, compilation included
 WARM_DEADLINE_S = 420.0
@@ -221,9 +232,10 @@ def _table_moves(hlo: str, rows: int) -> list[str]:
     return moves
 
 
-def _ring_step_checks(ph: Phase, session) -> None:
+def _ring_step_checks(ph: Phase, session, enforce: bool = True) -> None:
     """Compile the dedicated ring's warmed step once more, for its text
-    (a compile before the warm-up mark), and read the state's layouts."""
+    (a compile before the warm-up mark), and read the state's layouts.
+    `enforce` False records the moves without failing on them."""
     ring = session.ring
     bucket = session.cfg.buckets[-1]
     dev, v = ring._pad(np.zeros(0, np.int32), np.zeros(0, np.float32),
@@ -232,7 +244,8 @@ def _ring_step_checks(ph: Phase, session) -> None:
         session.params, ring.state, dev, v).compile().as_text()
     moves = _table_moves(hlo, ring.capacity + 1)
     ph.out["table_moves"] = moves
-    ph.check(not moves, f"the ring step moves no whole table (got {moves})")
+    ph.check(not (moves and enforce),
+             f"the ring step moves no whole table (got {moves})")
     ph.out["state_layouts"] = {
         name: f"{list(leaf.shape)} major_to_minor="
               f"{leaf.format.layout.major_to_minor} "
@@ -240,11 +253,13 @@ def _ring_step_checks(ph: Phase, session) -> None:
         for name, leaf in sorted(ring.state.items())}
 
 
-def _tenant_sections(devices: int, **rule_extra) -> dict:
+def _tenant_sections(devices: int, model: str = "lstm-stream",
+                     model_config: dict | None = None, **rule_extra) -> dict:
     return {
         "rule-processing": {
-            "model": "lstm-stream",
-            "model_config": {"window": WINDOW, "hidden": HIDDEN},
+            "model": model,
+            "model_config": model_config or {"window": WINDOW,
+                                             "hidden": HIDDEN},
             "threshold": THRESHOLD,
             # bucket = ring capacity = fleet, as bench.py sizes them
             "buckets": [devices], "capacity": devices,
@@ -598,6 +613,94 @@ async def phase_pool(ph: Phase, expect_platform: str, n_devices: int,
     log(f"phase C: {ph.out}")
 
 
+# -- phase D: a model whose weights fit once ---------------------------------
+
+async def phase_dsv3(ph: Phase, expect_platform: str, sizes: Sizes) -> None:
+    from sitewhere_tpu.utils.backend import device_memory_bytes
+
+    tid, devices = "dsv3", sizes.dsv3_devices
+    model_config = sizes.dsv3_config
+    if model_config is None:
+        with open(os.path.join(REPO, "benchmarks", "configs",
+                               "deepseek-v3-ep16.json")) as fh:
+            model_config = json.load(fh)["model_config"]
+    rt = await _start_runtime("chip-smoke-d")
+    proc = None
+    try:
+        t_warm = time.monotonic()
+        im = rt.services["instance-management"]
+        await im.create_tenant(tid, "Dsv3", _tenant_sections(
+            devices, "dsv3-stream", model_config,
+            score_dtype="float32", threshold=1e9))
+        _seed_history(rt, tid, devices)
+        engine = rt.api("rule-processing").engine(tid)
+        session = engine.session
+        await _wait_warm(session, "session ready for weights")
+        limit = device_memory_bytes()
+        weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+            jax.eval_shape(session.model.init, jax.random.PRNGKey(0))))
+        ph.out.update(weights_bytes=weights, device_bytes=limit,
+                      one_set_only=session.one_set_only)
+        if limit is not None and 2 * weights > limit:
+            ph.check(session.params is None,
+                     "a session whose weights fit once holds none until "
+                     "the first set is bound")
+        engine.swap_model_params(session.model.init(jax.random.PRNGKey(7)))
+        await _wait_warm(session, "seeding and warm-up under the weights")
+        jax.block_until_ready(session.ring.state)
+        ph.out["warmup_s"] = round(time.monotonic() - t_warm, 2)
+        log(f"phase D: warm in {ph.out['warmup_s']}s")
+        # the TPU's compiler is the one held to it: the CPU's copies a
+        # table it both gathers from and scatters into round a loop
+        _ring_step_checks(ph, session, enforce=expect_platform == "tpu")
+
+        consumer = rt.bus.subscribe(
+            rt.naming.tenant_topic(tid, "scored-events"), group="chip-smoke")
+        seen = {"events": 0, "versions": set()}
+        scored0 = session.latency.count
+        warm_mark = ph.compiles()
+        proc = await _feed(rt, [tid], devices, sizes)
+        want = devices * sizes.ticks
+
+        def progress() -> int:
+            _drain_topic(consumer, seen)
+            return min(session.latency.count - scored0, seen["events"])
+
+        try:
+            await _wait_stream(progress, want, proc)
+        except TimeoutError as exc:
+            ph.check(False, str(exc))
+        rc, sent = await _reap(proc)
+        await asyncio.sleep(0.25)
+        _drain_topic(consumer, seen)
+        consumer.close()
+        scored = session.latency.count - scored0
+        held = int(rt.metrics.counter("scoring.moe.assignments_held").value)
+        stats = jax.local_devices()[0].memory_stats() or {}
+        ph.out.update(sent=sent, scored=scored, published=seen["events"],
+                      feeder_exit=rc, assignments_held=held,
+                      compiles_after_warmup=ph.compiles() - warm_mark,
+                      arrays_peak_bytes=stats.get("peak_bytes_in_use"))
+        ph.check(rc == 0 and sent == want,
+                 f"feeder sent {want} and exited 0 (said {sent}, {rc})")
+        ph.check(scored == sent == seen["events"],
+                 f"scored == sent == published "
+                 f"({scored} / {sent} / {seen['events']})")
+        ph.check(held > 0, "token-expert pairs landed on the held experts")
+        ph.check(ph.out["compiles_after_warmup"] == 0,
+                 f"no compile after warm-up "
+                 f"(got {ph.out['compiles_after_warmup']})")
+        if stats.get("peak_bytes_in_use") is not None:
+            ph.check(stats["peak_bytes_in_use"] < 1.5 * weights,
+                     f"two sets of weights were never resident (arrays' "
+                     f"peak {stats['peak_bytes_in_use']}, one set {weights})")
+        _health_checks(ph, rt)
+    finally:
+        await _stop_feeder(proc)
+        await asyncio.wait_for(rt.stop(), 60.0)
+    log(f"phase D: {ph.out}")
+
+
 # -- the body ----------------------------------------------------------------
 
 async def _run_phases(expect_platform: str, n_devices: int,
@@ -606,6 +709,7 @@ async def _run_phases(expect_platform: str, n_devices: int,
         "A": lambda ph: phase_server(ph, expect_platform, sizes),
         "B": lambda ph: phase_kernel(ph, expect_platform, sizes),
         "C": lambda ph: phase_pool(ph, expect_platform, n_devices, sizes),
+        "D": lambda ph: phase_dsv3(ph, expect_platform, sizes),
     }
     results = {}
     for name, run in phases.items():

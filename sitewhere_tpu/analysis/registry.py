@@ -72,6 +72,7 @@ TRACE_STAGES: tuple[tuple[str, str], ...] = (
     ("wire.poll", "queue"),                  # broker append → delivery
     ("inbound.enrich", "service"),           # mask validate + split
     ("event-management.persist", "service"), # columnar store scatter
+    ("rule-processing.seed", "service"),     # stored windows → ring state
     ("rule-processing.dispatch", "queue"),   # admission → jit dispatch
     ("rule-processing.score", "service"),    # dispatch → scores on host
     # ...and the three children that tile it (scoring/settle.py), with
@@ -127,6 +128,11 @@ COUNTERS = (
     "scoring.sink_failures",
     "scoring.bus_records_lost",
     "scoring.dispatches",
+    # what a step of a model with routed experts and window leaves
+    # returns beside its scores (models/dsv3.py `step_stats`)
+    "scoring.moe.assignments_held",
+    "scoring.moe.assignments",
+    "scoring.ctx.reseeds",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services
@@ -276,6 +282,8 @@ HISTOGRAMS = (
     "scoring.device_enqueue_s",
     "scoring.device_wait_s",
     "scoring.settle_wake_s",
+    "scoring.moe.expert_max_tokens",
+    "scoring.ctx.positions",
     "scoring.megabatch_tenants_per_dispatch",
     # flight recorder (kernel/observe.py): event-loop lag per beat
     "observe.loop_lag_s",

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Any
 
+from sitewhere_tpu.models.dsv3 import Dsv3Config, Dsv3StreamModel
 from sitewhere_tpu.models.longwin import LongWindowConfig, LongWindowModel
 from sitewhere_tpu.models.lstm import (
     LstmAnomalyModel,
@@ -25,6 +26,8 @@ from sitewhere_tpu.models.zscore import ZScoreConfig, ZScoreModel
 MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     "lstm": (LstmConfig, LstmAnomalyModel),
     "lstm-stream": (LstmConfig, StreamingLstmModel),
+    # DeepSeek-V3's block (MLA, routed experts) as a streaming scorer
+    "dsv3-stream": (Dsv3Config, Dsv3StreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

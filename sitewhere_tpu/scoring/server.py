@@ -46,6 +46,7 @@ from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.ring import DeviceRing
 from sitewhere_tpu.scoring.settle import SETTLE_POOL, DeviceStage, to_host
+from sitewhere_tpu.utils.backend import device_memory_bytes
 from sitewhere_tpu.utils.retry import retry_backoff
 
 logger = logging.getLogger(__name__)
@@ -122,9 +123,20 @@ class ScoringSession:
         # crash loses nothing — the supervisor restarts the consuming
         # loop and the still-pending events flush on the next tick
         self.faults = faults
-        self.params = jax.device_put(
-            params if params is not None
-            else model.init(jax.random.PRNGKey(cfg.seed)))
+        # weights of which the device cannot hold two sets (what the code
+        # can see: their bytes against the device's memory) are never
+        # resident twice: the session builds none of its own and holds
+        # none until the first set is bound (`swap_params`, which then
+        # seeds and warms), and a later set takes its predecessor's place
+        # leaf by leaf
+        limit = device_memory_bytes()
+        self.one_set_only = limit is not None and 2 * sum(
+            x.size * x.dtype.itemsize for x in jax.tree.leaves(
+                jax.eval_shape(model.init, jax.random.PRNGKey(cfg.seed)))
+        ) > limit
+        if params is None and not self.one_set_only:
+            params = model.init(jax.random.PRNGKey(cfg.seed))
+        self.params = None if params is None else jax.device_put(params)
         self.version = 0
         host = telemetry.channels.get(cfg.mtype)
         self.ring = self._new_ring(max(
@@ -185,6 +197,21 @@ class ScoringSession:
         self.device_stage = DeviceStage(metrics, self.tracer)
         self.stage_device = self.device_stage.total
         self.stage_sink = metrics.histogram("scoring.stage_sink_s")
+        # the numbers a model's step returns beside its scores
+        # (`model.step_stats` names them), fed as they settle
+        octaves = [2.0 ** (i / 4) for i in range(53)]      # 1 to 8,192
+        feeds = {
+            "moe.assignments_held": lambda: metrics.counter(
+                "scoring.moe.assignments_held").inc,
+            "moe.assignments": lambda: metrics.counter(
+                "scoring.moe.assignments").inc,
+            "moe.expert_max_tokens": lambda: metrics.histogram(
+                "scoring.moe.expert_max_tokens", buckets=octaves).observe,
+            "ctx.positions": lambda: metrics.histogram(
+                "scoring.ctx.positions", buckets=octaves).observe}
+        self._step_stats = [feeds[name]()
+                            for name in getattr(model, "step_stats", ())]
+        self.reseeds = metrics.counter("scoring.ctx.reseeds")
 
     def _new_ring(self, capacity: int):
         """Window ring (raw history, per-event window rescore) or
@@ -200,7 +227,8 @@ class ScoringSession:
                                   if self.cfg.readback == "anomalies"
                                   else None),
                 sparse_k=self.cfg.sparse_k)
-            ring.bind_params(self.params)
+            if self.params is not None:
+                ring.bind_params(self.params)
             return ring
         if self.cfg.readback == "anomalies":
             logger.warning("readback='anomalies' needs a streaming "
@@ -249,6 +277,11 @@ class ScoringSession:
         forever: recover the ring and retry with backoff (the retry
         helper keeps recovery inside the protected scope, so even a
         failing recovery cannot kill the task)."""
+        if self.params is None:
+            # nothing to seed or compile with: ready to be handed
+            # weights, and `flush_due` holds every flush until then
+            self.ready = True
+            return
         self.ready = False
 
         async def attempt():
@@ -277,7 +310,11 @@ class ScoringSession:
         w = self.model.cfg.window
         devices = np.arange(host.capacity)
         x, _ = host.window(devices, w)
-        self.ring.load(x, np.minimum(host.count, w))
+        with self.tracer.span("rule-processing.seed"):
+            self.ring.load(x, np.minimum(host.count, w))
+            # a streaming ring seeds on the device: the span ends when
+            # that work has (the window ring only uploads)
+            jax.block_until_ready(getattr(self.ring, "state", None))
 
     def reload_history(self) -> None:
         """Re-sync the device ring from the host store (bulk-import path:
@@ -285,14 +322,30 @@ class ScoringSession:
         self._load_ring()
 
     def swap_params(self, new_params: dict) -> int:
-        """Hot-swap trained params (checkpoint rollout); bumps version."""
+        """Hot-swap trained params (checkpoint rollout); bumps version.
+        The first set a session without weights is handed starts its
+        warm-up (`ready` goes False until every bucket is compiled)."""
+        first = self.params is None
+        if self.one_set_only and not first:
+            # the old set goes before the new one is placed; a step in
+            # flight keeps its own hold on the buffers it reads
+            for leaf in jax.tree.leaves(self.params):
+                leaf.delete()
         self.params = jax.device_put(new_params)
         if hasattr(self.ring, "bind_params"):
             # streaming state (h/c/pred) is a function of the weights —
             # carrying old-weight state into new-weight steps mis-scores
             # every device until it washes out. Reseed from host history.
             self.ring.bind_params(self.params)
-            self._load_ring()
+            if not first:
+                self._load_ring()
+        if first:
+            self.ready = False
+            try:
+                self._warm_task = asyncio.get_running_loop().create_task(
+                    self.warmup_async(), name="scoring-first-weights")
+            except RuntimeError:          # no loop: a tool or a test
+                self.warmup()
         self.version += 1
         return self.version
 
@@ -416,7 +469,7 @@ class ScoringSession:
 
     @property
     def flush_due(self) -> bool:
-        if self._pending_n == 0 or not self.ready:
+        if self._pending_n == 0 or not self.ready or self.params is None:
             return False
         if self.inflight >= self.cfg.max_inflight:
             return False  # backpressure: let settles catch up
@@ -508,6 +561,11 @@ class ScoringSession:
             self.batch_size_hist.observe(float(rdev.shape[0]))
             self.dispatches.inc()
             dispatches.append((scores_dev, rdev.shape[0], rpos))
+        # rows whose windows were full and were seeded again on the way
+        reseeded = getattr(self.ring, "reseeded", 0)
+        if reseeded:
+            self.reseeds.inc(reseeded)
+            self.ring.reseeded = 0
         return dispatches
 
     async def _settle_and_deliver(self, dispatches, dev, ts,
@@ -601,6 +659,10 @@ class ScoringSession:
                 total_scored=int(dev.shape[0]))
         scores = np.empty(dev.shape[0], np.float32)
         for scores_u, (_, n, rpos) in zip(settled, dispatches):
+            if self._step_stats:
+                stats = scores_u[-len(self._step_stats):]
+                for feed, value in zip(self._step_stats, stats):
+                    feed(float(value))
             if rpos is None:
                 scores[:n] = scores_u[:n]
             else:
@@ -757,5 +819,8 @@ class ScoringSession:
             await asyncio.sleep(0.01)
 
     def close(self) -> None:
+        """A closed session holds nothing of the device: no compiled
+        program, no state, no weights."""
         self._fns.clear()
         self.ring.close()
+        self.params = None

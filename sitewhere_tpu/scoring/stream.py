@@ -23,6 +23,22 @@ a `[rows, 64]` leaf rests column-major on a TPU, and each step copied
 both tables to row-major and back round their row scatters, 4.8 of a
 6.2 ms step over 524,289 rows on a v5e (PERF.md section 6, PR 27).
 
+A model may declare WINDOW leaves, `windows = {leaf: position leaf}`: a
+bounded window `[rows, positions, width]` a row. The step gathers the
+batch's rows of it for reading, and `step_score` returns for it not the
+rows but ONE `[B, width]` entry, which the ring writes at `(row, the
+row's own position)` into the donated buffer: a 1 MB context is read
+whole, as attention must, and 1 KB of it is written. Such a model's
+`step_score` also takes `live` (the rows that are no padding) and may
+return a third value, the numbers of `model.step_stats`, which ride
+home at the end of the score vector. A row whose window is full is
+seeded again before its next event, exactly as at warm-up, from the last
+`window` values the ring was given for it (its own host record of them:
+what the host store holds for the row once it has taken the same
+events, without a race against the persister); `model.seed_rows`, where
+declared, is how many rows one seeding call takes (a prefill's
+activations have to fit beside the weights).
+
 The host `TelemetryStore` stays the durable copy; `load()` rebuilds
 state from it at warmup or after a fault (same recovery story as the
 window ring).
@@ -39,19 +55,39 @@ import numpy as np
 from sitewhere_tpu.utils import grow_pow2
 
 
-def _gather_step_scatter(model, params, state, dev, v):
+def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     """The ring step's three parts, under the `jax.named_scope`s a
     profile shows them by: rows of `dev` out of the table, one cell step
-    on them, the new rows back (padding lands in the scratch row)."""
+    on them, the new rows back (padding lands in the scratch row). A
+    window leaf takes one entry a row, at the row's own position.
+    -> (state, scores, the step's numbers or None)."""
+    windows = getattr(model, "windows", None)
     with jax.named_scope("ring_gather"):
         rows = jax.tree.map(lambda leaf: leaf[dev], state)
+    stats = None
     with jax.named_scope("cell_step"):
-        scores, new_rows = model.step_score(params, rows, v)
+        if windows is None:
+            scores, new_rows = model.step_score(params, rows, v)
+        else:
+            scores, new_rows, stats = model.step_score(
+                params, rows, v, live=dev != scratch)
+    if windows is None:
+        with jax.named_scope("ring_scatter"):
+            state = jax.tree.map(
+                lambda leaf, rows_new: leaf.at[dev].set(rows_new,
+                                                        mode="drop"),
+                state, new_rows)
+        return state, scores, stats
+    out = {}
+    with jax.named_scope("ctx_append"):
+        for name, at in windows.items():
+            out[name] = state[name].at[dev, rows[at]].set(new_rows[name],
+                                                          mode="drop")
     with jax.named_scope("ring_scatter"):
-        state = jax.tree.map(
-            lambda leaf, rows_new: leaf.at[dev].set(rows_new, mode="drop"),
-            state, new_rows)
-    return state, scores
+        for name, leaf in state.items():
+            if name not in windows:
+                out[name] = leaf.at[dev].set(new_rows[name], mode="drop")
+    return out, scores, stats
 
 
 def streaming_step(model, out_dtype=None) -> Callable:
@@ -67,9 +103,14 @@ def streaming_step(model, out_dtype=None) -> Callable:
     upcasts on assignment into its float32 result array."""
 
     def step(params, state, dev, v):
-        state, scores = _gather_step_scatter(model, params, state, dev, v)
+        # the scratch row is the table's last: padding is sent there
+        scratch = jax.tree.leaves(state)[0].shape[0] - 1
+        state, scores, stats = _gather_step_scatter(model, params, state,
+                                                    dev, v, scratch)
         if out_dtype is not None:
             scores = scores.astype(out_dtype)
+        if stats is not None:
+            scores = jnp.concatenate([scores, stats.astype(scores.dtype)])
         return state, scores
 
     return step
@@ -100,7 +141,8 @@ def streaming_step_sparse(model, k: int,
     own alert bar) so threshold changes never recompile."""
 
     def step(params, state, dev, v, threshold):
-        state, scores = _gather_step_scatter(model, params, state, dev, v)
+        state, scores, _ = _gather_step_scatter(model, params, state, dev,
+                                                v, scratch_index)
         with jax.named_scope("sparse_topk"):
             # scratch-row padding must never report: its state absorbs
             # arbitrary writes, so its score is garbage by design
@@ -169,8 +211,28 @@ class StreamingRing:
         # closure is new each time), which put seconds of compile on the
         # event loop at each reload and each hot-swap on the chip
         self._warm_state = jax.jit(model.warm_state)
+        # seeded rows into the donated table, any rows: the table is
+        # never copied for a block of them
+        self._put = jax.jit(
+            lambda state, seeded, rows: jax.tree.map(
+                lambda leaf, new: leaf.at[rows].set(new, mode="drop"),
+                state, seeded), donate_argnums=(0,))
         self.faulted = False
         self.state = jax.device_put(model.init_state(self.capacity + 1))
+        # a model with window leaves: how many positions a row's windows
+        # hold, and on the host how many each row has filled (the device's
+        # `pos` leaf, mirrored so that a full row is known without a
+        # read-back) and the last `window` values it was given (a ring of
+        # them a row, `_oldest` where the oldest lies): what a full row is
+        # seeded again from. `reseeded` counts such rows.
+        windows = getattr(model, "windows", None)
+        self._positions = (self.state[next(iter(windows))].shape[1]
+                           if windows else 0)
+        rows = self.capacity + 1 if windows else 0
+        self._filled = np.zeros(rows, np.int32)
+        self._recent = np.zeros((rows, self.window), np.float32)
+        self._oldest = np.zeros(rows, np.int32)
+        self.reseeded = 0
 
     def ensure_capacity(self, max_index: int) -> None:
         if max_index < self.capacity:
@@ -183,15 +245,26 @@ class StreamingRing:
             return jnp.concatenate([leaf[:-1], pad], axis=0)
 
         self.state = jax.tree.map(extend, self.state, fresh)
+        if self._positions:
+            def more(a):
+                return np.concatenate(
+                    [a[:-1], np.zeros((grow + 1,) + a.shape[1:], a.dtype)])
+
+            self._filled, self._recent, self._oldest = (
+                more(self._filled), more(self._recent), more(self._oldest))
         self.capacity = new_cap
 
     def load(self, values: np.ndarray, count: np.ndarray,
-             start: int = 0) -> None:
-        """Seed rows `start..start+n` by replaying host windows
-        (`TelemetryStore.window` layout: chronological, left-padded)."""
+             rows: Optional[np.ndarray] = None) -> None:
+        """Seed `rows` (the first `n` where none are named) by replaying
+        host windows (`TelemetryStore.window` layout: chronological,
+        left-padded), `model.seed_rows` of them a call where the model
+        says how many its seeding can take at once."""
         n, w = values.shape
         assert w == self.window
-        self.ensure_capacity(start + n - 1 if n else 0)
+        if rows is None:
+            rows = np.arange(n, dtype=np.int32)
+        self.ensure_capacity(int(rows.max()) if n else 0)
         if n == 0:
             self.faulted = False
             return
@@ -200,14 +273,36 @@ class StreamingRing:
         if params is None:
             raise RuntimeError("StreamingRing.load needs params bound via "
                                "bind_params() before seeding")
-        seeded = self._warm_state(params, jnp.asarray(values, jnp.float32),
-                                  jnp.asarray(valid))
-
-        def put(leaf, rows):
-            return leaf.at[start:start + n].set(rows)
-
-        self.state = jax.tree.map(put, self.state, seeded)
+        block = getattr(self.model, "seed_rows", None) or n
+        for lo in range(0, n, block):
+            x, ok, at = (values[lo:lo + block], valid[lo:lo + block],
+                         rows[lo:lo + block])
+            short = block - x.shape[0]
+            if short:            # one compiled shape; padding -> scratch
+                x = np.concatenate([x, np.zeros((short, w), x.dtype)])
+                ok = np.concatenate([ok, np.zeros((short, w), bool)])
+                at = np.concatenate([at, np.full(short, self.capacity,
+                                                 at.dtype)])
+            seeded = self._warm_state(params, jnp.asarray(x, jnp.float32),
+                                      jnp.asarray(ok))
+            self.state = self._put(self.state, seeded,
+                                   jnp.asarray(at, jnp.int32))
+        if self._positions:
+            self._filled[rows] = np.minimum(count, w)
+            self._recent[rows], self._oldest[rows] = values, 0
         self.faulted = False
+
+    def _reseed_full(self, dev: np.ndarray) -> None:
+        """Rows of `dev` whose windows have no position left start again
+        from their last `window` values, before this event of theirs."""
+        full = dev[self._filled[dev] >= self._positions]
+        if full.size == 0:
+            return
+        order = (self._oldest[full, None] + np.arange(self.window)) \
+            % self.window
+        self.load(np.take_along_axis(self._recent[full], order, axis=1),
+                  np.full(full.size, self.window), rows=full)
+        self.reseeded += int(full.size)
 
     def bind_params(self, params: dict) -> None:
         """Streaming state depends on the weights (h/c/pred are functions
@@ -240,6 +335,8 @@ class StreamingRing:
         """Advance + score one event per row of `dev` (unique ids!);
         returns `[bucket]` scores on device (async)."""
         self._params = params
+        if self._positions and dev.size:
+            self._reseed_full(dev)
         key = (self.capacity, bucket)
         fn = self._fns.get(key)
         if fn is None:
@@ -255,10 +352,18 @@ class StreamingRing:
         except Exception:
             self.faulted = True  # donated state is gone; needs load()
             raise
+        if self._positions:
+            self._filled[dev] += 1
+            at = self._oldest[dev]
+            self._recent[dev, at] = v
+            self._oldest[dev] = (at + 1) % self.window
         return scores
 
     def close(self) -> None:
+        """Compiled steps and the table go: a closed ring holds nothing
+        of the device."""
         self._fns.clear()
+        self.state = self._params = None
 
 
 class StackedStreamingRing:

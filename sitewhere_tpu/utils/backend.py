@@ -38,3 +38,12 @@ def device_summary() -> tuple[str, str, int]:
 
     devices = jax.devices()
     return devices[0].platform, devices[0].device_kind, len(devices)
+
+
+def device_memory_bytes():
+    """What one local device can hold, as its runtime reports it; None
+    where it reports nothing (the CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")

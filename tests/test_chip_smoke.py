@@ -21,9 +21,16 @@ from sitewhere_tpu.utils.backend import use_compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-TINY = chip_smoke.Sizes(devices=64, ticks=6, pool_tenants=2,
-                        pool_devices=64, kernel_bucket=256,
-                        anomaly_rate=0.05, interval_s=0.05)
+TINY = chip_smoke.Sizes(
+    devices=64, ticks=6, pool_tenants=2, pool_devices=64, kernel_bucket=256,
+    anomaly_rate=0.05, interval_s=0.05, dsv3_devices=32, dsv3_config=dict(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=4,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=16, n_group=2,
+        topk_group=1, num_experts_per_tok=4, vocab_size=64,
+        n_routed_experts_held=4, mtp_modules=0, window=64,
+        context_positions=96))
 
 
 def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
@@ -37,7 +44,7 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert summary["device"] == {"platform": "cpu", "kind": "cpu",
                                  "count": 8}
     assert summary["compile_cache"] == str(tmp_path)
-    a, b, c = (summary["phases"][k] for k in "ABC")
+    a, b, c, d = (summary["phases"][k] for k in "ABCD")
     want = TINY.devices * TINY.ticks
     assert a["sent"] == a["scored"] == a["published"] == want
     assert a["alerts"] >= 1 and a["compiles_after_warmup"] == 0
@@ -48,6 +55,12 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert c["mesh"] == {"data": 2, "model": 2}
     assert c["scored_per_tenant"] == [want]
     assert c["megabatch_dispatches"] > 0 and c["swap_version"] == 1
+    # the CPU reports no memory, so its session builds weights of its own;
+    # the first swap still seeds again, and the step moves no context
+    assert d["sent"] == d["scored"] == d["published"] == 32 * TINY.ticks
+    assert isinstance(d["table_moves"], list) and d["assignments_held"] > 0
+    assert d["compiles_after_warmup"] == 0 and d["device_bytes"] is None
+    assert {"ctx0", "ctx1", "hn", "pos"} <= set(d["state_layouts"])
 
 
 @pytest.mark.parametrize("line,found", [
