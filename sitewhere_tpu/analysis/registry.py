@@ -132,6 +132,7 @@ COUNTERS = (
     # returns beside its scores (models/dsv3.py `step_stats`)
     "scoring.moe.assignments_held",
     "scoring.moe.assignments",
+    "scoring.moe.runs_one_tile",
     "scoring.ctx.reseeds",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",

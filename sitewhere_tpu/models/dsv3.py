@@ -70,6 +70,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sitewhere_tpu.ops import expert_kernel
+
 EXPERT_TILE = 128        # rows of one held expert's products at a time
 SEED_TOKENS = 2048       # tokens of one seeding call: its activations
                          # (under 1 GB at the published widths) fit
@@ -205,7 +207,8 @@ class Dsv3StreamModel:
     # the numbers `step_score` returns beside the scores, by the names
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("moe.assignments_held", "moe.assignments",
-                  "moe.expert_max_tokens", "ctx.positions")
+                  "moe.expert_max_tokens", "ctx.positions",
+                  "moe.runs_one_tile")
 
     def __init__(self, cfg: Dsv3Config = Dsv3Config()):
         for key, want in (("scoring_func", "sigmoid"), ("hidden_act", "silu"),
@@ -231,6 +234,10 @@ class Dsv3StreamModel:
         self._cos, self._sin = rope_tables(cfg, cfg.context_positions)
         self._scale = softmax_scale(cfg)
         self._gate = max(8, cfg.window // 8)
+        # one trace and one lowering for all of a program's expert
+        # layers, whose shapes are the same: traced a layer, the grouped
+        # pass cost a start three seconds (PERF.md, PR 29)
+        self._routed = jax.jit(self.routed)
 
     def _is_moe(self, layer: int) -> bool:
         return layer >= self.cfg.first_k_dense_replace
@@ -260,9 +267,9 @@ class Dsv3StreamModel:
             block["router"] = {"w": ((c.n_routed_experts, h), f),
                                "bias": ((c.n_routed_experts,), f)}
             block["shared"] = mlp(c.moe_intermediate_size)
-            # a leaf an expert: the step hands each to its own loop as
-            # it rests (sliced out of one stacked leaf, all of them were
-            # copied at every step: 17 of a 69 ms step, PERF.md PR 28)
+            # a leaf an expert: the step reads each where it rests
+            # (sliced out of one stacked leaf, all of them were copied
+            # at every step: 17 of a 69 ms step, PERF.md PR 28)
             block["experts"] = {f"e{e}": mlp(c.moe_intermediate_size)
                                 for e in range(c.experts_held)}
         else:
@@ -406,46 +413,90 @@ class Dsv3StreamModel:
         w = w / w.sum(-1, keepdims=True) * c.routed_scaling_factor
         return idx.astype(jnp.int32), w
 
-    def routed(self, p, x, idx, w, live):
+    def routed(self, p, x, idx, w, live, tile=EXPERT_TILE):
         """What the held experts give for tokens `x` `[T, hidden]`:
         `sum_k w * expert_k(x)` over the chosen experts held here, and
         each held expert's token count `[held]` (rows not `live` count
-        and compute nothing). The pairs are sorted by expert and each
-        held expert takes its run in tiles of `EXPERT_TILE` rows, as
-        many as its run is long: nothing is dropped, nothing absent is
-        computed."""
+        and compute nothing). The pairs are sorted by expert and the
+        layer is ONE grouped pass over them: the token rows of every
+        held expert's first `tile` pairs are gathered once, laid at
+        `e * tile`, each expert's three products run over its tile with
+        no loop round them (an expert's leaves are read once, one after
+        another), and the weighted rows are summed into the tokens. A
+        run longer than `tile` takes its further tiles in a loop that
+        is entered only where some run is that long: nothing is
+        dropped, no expert has a capacity."""
         c = self.cfg
         t, k = idx.shape
-        held, tile = c.experts_held, EXPERT_TILE
+        held = c.experts_held
         local = idx.reshape(-1) - c.first_expert
         here = (local >= 0) & (local < held) & jnp.repeat(live, k)
         group = jnp.where(here, local, held)
-        order = jnp.argsort(group, stable=True)
-        counts = jnp.zeros(held + 1, jnp.int32).at[group].add(1)[:held]
-        ends = jnp.cumsum(counts)
-        pad = jnp.zeros(tile, jnp.int32)
-        token = jnp.concatenate([(order // k).astype(jnp.int32), pad])
-        weight = jnp.concatenate([w.reshape(-1)[order],
-                                  jnp.zeros(tile, jnp.float32)])
+        _, token, weight = jax.lax.sort(
+            (group, jnp.arange(t * k, dtype=jnp.int32) // k, w.reshape(-1)),
+            num_keys=1, is_stable=True)
+        counts = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+        starts = jnp.cumsum(counts) - counts
+        token = jnp.concatenate([token, jnp.zeros(tile, jnp.int32)])
+        weight = jnp.concatenate([weight, jnp.zeros(tile, jnp.float32)])
         xc = x.astype(c.compute_dtype)
-        out = jnp.zeros(x.shape, jnp.float32)
         lane = jnp.arange(tile)
-        for e in range(held):
-            start, end = ends[e] - counts[e], ends[e]
-            expert = p[f"e{e}"]
 
-            def one_tile(i, out, start=start, end=end, expert=expert):
-                lo = start + i * tile
-                rows = jax.lax.dynamic_slice(token, (lo,), (tile,))
-                wt = jnp.where(lo + lane < end,
-                               jax.lax.dynamic_slice(weight, (lo,), (tile,)),
-                               0.0)
-                y = self._mlp(expert, xc[rows])
-                return out.at[rows].add(y * wt[:, None])
+        def tile_of(lo, end):
+            """Token rows and weights of the `tile` pairs from `lo` on
+            (weight 0 from `end` on), for one run or `[held]` runs."""
+            at = lo[..., None] + lane
+            return token[at], jnp.where(at < end[..., None], weight[at], 0.0)
 
-            out = jax.lax.fori_loop(0, (counts[e] + tile - 1) // tile,
-                                    one_tile, out)
-        return out, counts
+        rows, wt = tile_of(starts, starts + counts)
+        out = self._first_tiles([p[f"e{e}"] for e in range(held)], xc,
+                                rows.reshape(-1), wt.reshape(-1), counts)
+
+        def further_tiles(out):
+            for e in range(held):
+                start, end = starts[e], starts[e] + counts[e]
+                expert = p[f"e{e}"]
+
+                def further(i, out, start=start, end=end, expert=expert):
+                    rows, wt = tile_of(start + i * tile, end)
+                    return out.at[rows].add(
+                        self._mlp(expert, xc[rows]) * wt[:, None])
+
+                out = jax.lax.fori_loop(1, (counts[e] + tile - 1) // tile,
+                                        further, out)
+            return out
+
+        return jax.lax.cond((counts > tile).any(), further_tiles,
+                            lambda out: out, out), counts
+
+    def _first_tiles(self, experts, xc, rows, wt, counts):
+        """`sum w * expert(x)` over every held expert's first tile of
+        pairs: `rows`, `wt` `[held * tile]` are the pairs' tokens and
+        weights (0 past a run's end), tile `e` expert `e`'s. ->
+        `[T, hidden]` float32. On a TPU, in bfloat16 and at shapes it
+        takes, one kernel that streams the leaves where they rest and
+        sums in place (ops/expert_kernel.py); elsewhere the same three
+        products and a scatter-add an expert."""
+        t, tile = xc.shape[0], rows.shape[0] // len(experts)
+
+        def plain(experts, xs, rows, wt, counts):
+            # a scatter-add an expert: one of every tile's rows at once
+            # took twice their time on a v5e (PERF.md, PR 29); `counts`
+            # is for the kernel, here `wt` is 0 past a run's end
+            out = jnp.zeros((t, xs.shape[1]), jnp.float32)
+            for e, expert in enumerate(experts):
+                at = slice(e * tile, (e + 1) * tile)
+                out = out.at[rows[at]].add(
+                    self._mlp(expert, xs[at]) * wt[at, None])
+            return out
+
+        hidden, inter = experts[0]["gate"].shape
+        if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
+                or not expert_kernel.fits(t, hidden, inter, tile)):
+            return plain(experts, xc[rows], rows, wt, counts)
+        return jax.lax.platform_dependent(
+            experts, xc[rows], rows, wt, counts, default=plain,
+            tpu=functools.partial(expert_kernel.expert_tiles, tokens=t))
 
     def _ffn(self, p, x, live):
         """The block's second half on normed tokens `[T, hidden]`; the
@@ -456,7 +507,7 @@ class Dsv3StreamModel:
         with jax.named_scope("moe_route"):
             idx, w = self.route(p["router"], x)
         with jax.named_scope("moe_experts"):
-            routed, counts = self.routed(p["experts"], x, idx, w, live)
+            routed, counts = self._routed(p["experts"], x, idx, w, live)
             return self._mlp(p["shared"], x) + routed, counts
 
     def _block_prefill(self, p, x, count, cos, sin):
@@ -589,8 +640,7 @@ class Dsv3StreamModel:
         var1 = var + ((v - mean1) * delta - var) / cnt1
         out = {"mean": mean1, "var": var1, "count": cnt1, "pos": pos + 1}
         x = params["embed"][token].astype(jnp.float32)
-        held = jnp.zeros((), jnp.int32)
-        busiest = jnp.zeros((), jnp.int32)
+        held = busiest = one_tile = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
             x, entry, counts = self._block_decode(
                 params[f"layer{l}"], x, rows[f"ctx{l}"], pos, live)
@@ -598,6 +648,7 @@ class Dsv3StreamModel:
             if counts is not None:
                 held += counts.sum()
                 busiest = jnp.maximum(busiest, counts.max())
+                one_tile += runs_one_tile(counts)
         out["hn"] = _rms(x, params["norm"], c.rms_norm_eps).astype(
             c.compute_dtype)
         n_live = live.sum()
@@ -606,7 +657,8 @@ class Dsv3StreamModel:
             held.astype(jnp.float32),
             (n_live * (c.num_experts_per_tok * n_moe)).astype(jnp.float32),
             busiest.astype(jnp.float32),
-            jnp.where(live, pos, 0).sum() / jnp.maximum(n_live, 1)])
+            jnp.where(live, pos, 0).sum() / jnp.maximum(n_live, 1),
+            one_tile.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:
@@ -686,6 +738,13 @@ class Dsv3StreamModel:
         bins = jnp.stack([draft, jnp.argmax(after, -1)], 1)
         xn = (bins + 0.5) * (16.0 / self.cfg.vocab) - 8.0
         return (xn * sd[:, None] + mean[:, None])[..., None]
+
+
+def runs_one_tile(counts, tile=EXPERT_TILE):
+    """Of the held experts' runs `counts` `[held]`, those that
+    `Dsv3StreamModel.routed`'s straight-line pass serves whole (an
+    empty run too); the others enter its overflow loop."""
+    return (counts <= tile).sum()
 
 
 def _precision(cdt):

@@ -1,0 +1,205 @@
+"""Pallas TPU kernel: a layer's held experts over their tiles of tokens.
+
+Why this op: a held expert of `dsv3-stream` sees a few dozen tokens a
+step (models/dsv3.py `routed`), so its three products are bound by
+reading the expert's weights (88 MB in bf16 at the published widths,
+0.1 ms at a v5e's 819 GB/s), not by arithmetic. One call takes the
+whole layer: every expert's leaves stay where they rest in HBM, one
+leaf an expert a projection, and are streamed through VMEM once, in
+blocks of the intermediate width, one expert after another with no gap
+between them:
+
+    step s = (expert e, block j):
+        g = x_e @ gate_e[:, j]        [tile, block]  f32
+        u = x_e @ up_e[:, j]
+        acc (+)= bf16(silu(g) * u) @ down_e[j, :]    [tile, hidden] f32
+    after e's last block, for each of its pairs i:
+        out[rows[e, i]] += acc[i] * wts[e, i]
+
+While step `s` computes, the three weight blocks of step `s + 1` (the
+next expert's first blocks after an expert's last) are on their way
+into the other half of a double buffer, and expert `e + 1`'s token tile
+is fetched during expert `e`. The layer's output `[tokens, hidden]`
+lives in VMEM for the whole call, so the sum over a token's experts is
+a row added in place, and is written out once at the end. (Measured on
+a v5e, PERF.md PR 29: 118 us an expert, 746 GB/s; the same sum as 16
+XLA scatter-adds of 128 rows cost 33 us each.)
+
+Numbers as the plain path's (`Dsv3StreamModel._mlp`): operands bf16,
+accumulation f32, `silu(g) * u` in f32 and rounded to bf16 once before
+the down product, the pair's weight applied in f32, the sum over a
+token's experts in f32, expert by expert. The down product is summed
+block by block in f32, so the two paths differ by the order of a
+float32 sum.
+
+VMEM: the output (28 MiB for 1,024 tokens at hidden 7168), the token
+tile twice, one tile of sums, three weight blocks twice (`BLOCK`
+columns: 10.5 MiB). `vmem_bytes` is the sum, and `fits` says whether a
+call stays under `VMEM_LIMIT`: what XLA keeps in VMEM across the call
+(the residual stream, the shared expert's output) has to stay there,
+and on a v5e's 128 MiB a call of 52 MiB left the rest of the step as
+it was where one of 64 MiB slowed it by a millisecond a layer. The
+call declares no `cost_estimate`: given one, the compiler planned the
+whole step's VMEM round the call and every layer's softmax fusion fell
+to a third of its window (732 for 557 us each, 2.2 ms a step).
+Parity is pinned by tests/test_pallas.py in interpret mode and the
+compile for a described v5e by tests/test_dsv3_tpu_compile.py.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+BLOCK = 128               # columns of the intermediate width a step
+VMEM_LIMIT = 52 << 20     # the most a call may take of VMEM
+
+
+def vmem_bytes(tokens: int, hidden: int, tile: int) -> int:
+    """What a call holds in VMEM, with room for the compiler's own."""
+    return (4 * tokens * hidden + (2 * 2 + 4 + 4) * tile * hidden
+            + 12 * hidden * BLOCK + (1 << 20))
+
+
+def fits(tokens: int, hidden: int, inter: int, tile: int) -> bool:
+    """Whether `expert_tiles` takes these shapes: whole lane tiles, whole
+    sublane tiles of bf16 rows, and a layer's output that VMEM holds."""
+    return (hidden % 128 == 0 and inter % BLOCK == 0 and tile % 16 == 0
+            and tokens % 8 == 0
+            and vmem_bytes(tokens, hidden, tile) <= VMEM_LIMIT)
+
+
+def _kernel(rows_ref, wts_ref, counts_ref, xs_ref, *rest, held: int,
+            steps: int, tile: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    leaves, out_ref = rest[:3 * held], rest[3 * held]
+    xbuf, gbuf, ubuf, dbuf, acc, out, wsem, xsem, osem = rest[3 * held + 1:]
+
+    def weights(e: int, j, slot):
+        """The three copies of block `j` of expert `e` (static) into
+        half `slot` of the double buffer."""
+        gate, up, down = leaves[3 * e:3 * e + 3]
+        cols = pl.ds(pl.multiple_of(j * BLOCK, BLOCK), BLOCK)
+        return (pltpu.make_async_copy(gate.at[:, cols], gbuf.at[slot],
+                                      wsem.at[slot, 0]),
+                pltpu.make_async_copy(up.at[:, cols], ubuf.at[slot],
+                                      wsem.at[slot, 1]),
+                pltpu.make_async_copy(down.at[cols, :], dbuf.at[slot],
+                                      wsem.at[slot, 2]))
+
+    def fetch(s):
+        """Start step `s`'s weights; which leaves is found at run time,
+        the copies themselves are static."""
+        for e in range(held):
+            @pl.when(s // steps == e)
+            def _(e=e):
+                for copy in weights(e, s % steps, s % 2):
+                    copy.start()
+
+    def tokens(e, half):
+        return pltpu.make_async_copy(
+            xs_ref.at[pl.ds(pl.multiple_of(e * tile, tile), tile)],
+            xbuf.at[half], xsem.at[half])
+
+    for copy in weights(0, 0, 0):
+        copy.start()
+    tokens(0, 0).start()
+    out[...] = jnp.zeros_like(out)
+
+    def step(s, carry):
+        e, j, slot, half = s // steps, s % steps, s % 2, (s // steps) % 2
+
+        @pl.when(s + 1 < held * steps)
+        def _():
+            fetch(s + 1)
+
+        @pl.when(j == 0)
+        def _():
+            tokens(e, half).wait()
+
+            @pl.when(e + 1 < held)
+            def _():
+                tokens(e + 1, 1 - half).start()
+
+        for copy in weights(0, j, slot):
+            copy.wait()
+        x = xbuf[half]
+        g = jnp.dot(x, gbuf[slot], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, ubuf[slot], preferred_element_type=jnp.float32)
+        y = jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), dbuf[slot],
+                    preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _():
+            acc[...] = y
+
+        @pl.when(j > 0)
+        def _():
+            acc[...] += y
+
+        @pl.when(j == steps - 1)
+        def _():
+            def combine(i, carry):
+                at = e * tile + i
+                out[pl.ds(rows_ref[at], 1), :] += (
+                    acc[pl.ds(i, 1), :] * wts_ref[at])
+                return carry
+
+            jax.lax.fori_loop(0, jnp.minimum(counts_ref[e], tile), combine, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, held * steps, step, 0)
+    done = pltpu.make_async_copy(out, out_ref, osem.at[0])
+    done.start()
+    done.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "interpret"))
+def expert_tiles(experts: list, xs: jax.Array, rows: jax.Array,
+                 wts: jax.Array, counts: jax.Array, tokens: int,
+                 interpret: bool = False) -> jax.Array:
+    """`[tokens, hidden]` f32: zero, plus, for each of the `held`
+    `experts` (`gate`, `up` `[hidden, inter]`, `down` `[inter, hidden]`,
+    bf16) and each of the first `min(counts[e], tile)` rows `i` of its
+    tile of `xs` `[held * tile, hidden]` bf16, `(silu(x gate) * (x up))
+    down * wts[e * tile + i]` added to row `rows[e * tile + i]`. Jitted,
+    so that a step's expert layers trace and lower the kernel once
+    between them, not once a layer (a second of set-up each)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    held = len(experts)
+    hidden, inter = experts[0]["gate"].shape
+    tile = xs.shape[0] // held
+    if not fits(tokens, hidden, inter, tile):
+        raise ValueError(f"expert_tiles takes no {tokens} tokens of "
+                         f"{hidden}, tiles of {tile}, experts of {inter}")
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_kernel, held=held, steps=inter // BLOCK,
+                          tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(),
+            in_specs=[hbm] * (1 + 3 * held), out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((2, tile, hidden), xs.dtype),
+                pltpu.VMEM((2, hidden, BLOCK), xs.dtype),
+                pltpu.VMEM((2, hidden, BLOCK), xs.dtype),
+                pltpu.VMEM((2, BLOCK, hidden), xs.dtype),
+                pltpu.VMEM((tile, hidden), jnp.float32),
+                pltpu.VMEM((tokens, hidden), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 3)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=jax.ShapeDtypeStruct((tokens, hidden), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(tokens, hidden, tile)),
+        name="expert_tiles",
+        interpret=interpret,
+    )(rows, wts, counts, xs,
+      *(e[name] for e in experts for name in ("gate", "up", "down")))

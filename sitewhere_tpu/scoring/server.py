@@ -208,7 +208,9 @@ class ScoringSession:
             "moe.expert_max_tokens": lambda: metrics.histogram(
                 "scoring.moe.expert_max_tokens", buckets=octaves).observe,
             "ctx.positions": lambda: metrics.histogram(
-                "scoring.ctx.positions", buckets=octaves).observe}
+                "scoring.ctx.positions", buckets=octaves).observe,
+            "moe.runs_one_tile": lambda: metrics.counter(
+                "scoring.moe.runs_one_tile").inc}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")

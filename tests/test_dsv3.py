@@ -172,6 +172,67 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
     assert (np.asarray(part[:20]) == 0).all() and int(counts.sum()) < seen
 
 
+# the cases `routed`'s grouped pass tells apart: the model's share,
+# tokens, which rows are live, the tile as a function of the busiest
+# run's length, and the held experts' runs that do NOT fit one tile
+ROUTED_CASES = {
+    "an_expert_with_no_token": (dict(first_expert=0), 2, None, None, 0),
+    "a_run_of_exactly_one_tile": (dict(first_expert=0), 40, None,
+                                  lambda busiest: busiest, 0),
+    "a_run_longer_than_one_tile": (dict(first_expert=0), 40, None,
+                                   lambda busiest: busiest - 1, 1),
+    "every_pair_on_a_held_expert": (
+        dict(first_expert=0, n_routed_experts_held=16), 40, None,
+        lambda busiest: busiest // 3, None),
+    "rows_not_live": (dict(first_expert=0), 40, 20, None, 0),
+    "first_expert_above_zero": (dict(first_expert=8), 40, None,
+                                lambda busiest: busiest // 2, None),
+}
+
+
+@pytest.mark.parametrize("case", ROUTED_CASES)
+def test_the_grouped_pass_agrees_with_the_reference(case):
+    """The held experts' part (program) against `reference.expert_layer`
+    less the shared expert, float32, 1e-6; each held expert's count; and
+    the runs the straight-line pass serves whole (`moe.runs_one_tile`)."""
+    from sitewhere_tpu.models.dsv3 import EXPERT_TILE, runs_one_tile
+
+    over, tokens, dead, tile_of, overflowing = ROUTED_CASES[case]
+    mc = {**MC, **over}
+    first, held = mc["first_expert"], mc["n_routed_experts_held"]
+    full = reference.tenant_params(5, 0, {
+        **MC, "first_expert": 0, "n_routed_experts_held": 16})["layer1"]
+    share = {f"e{i}": full["experts"][f"e{first + i}"] for i in range(held)}
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, 64), jnp.float32)
+    live = jnp.ones(tokens, bool).at[:dead or 0].set(False)
+    model = program(**over)
+    idx, w = model.route(full["router"], x)
+    local = np.asarray(idx)[np.asarray(live)].reshape(-1) - first
+    want_counts = np.bincount(local[(local >= 0) & (local < held)],
+                              minlength=held)
+    tile = tile_of(want_counts.max()) if tile_of else EXPERT_TILE
+    part, counts = jax.jit(model.routed, static_argnames="tile")(
+        share, x, idx, w, live, tile=tile)
+    assert (np.asarray(counts) == want_counts).all()
+    want = reference.expert_layer({**full, "experts": share}, x, mc,
+                                  "float32") \
+        - reference._mlp(full["shared"], x, "float32")
+    keep = np.asarray(live)
+    assert np.abs(np.asarray(want - part))[keep].max() < 1e-6
+    assert (np.asarray(part)[~keep] == 0).all()
+    # what the case is there for
+    if case == "an_expert_with_no_token":
+        assert want_counts.min() == 0
+    if case == "every_pair_on_a_held_expert":
+        assert want_counts.sum() == tokens * mc["num_experts_per_tok"]
+    if overflowing is None:
+        overflowing = (want_counts > tile).sum()
+        assert 0 < overflowing
+    else:
+        assert (want_counts > tile).sum() == overflowing
+    assert int(runs_one_tile(counts, tile)) == held - overflowing
+
+
 def test_mtp_forecast_agrees_with_the_reference(params):
     hist, _ = readings()
     model = program()
@@ -316,6 +377,8 @@ def test_session_holds_no_weights_until_bound_and_never_two_sets(
         assert snap["scoring.moe.assignments"].value == 10 * per_step
         assert 0 < snap["scoring.moe.assignments_held"].value < 10 * per_step
         assert snap["scoring.moe.expert_max_tokens"].count == 10
+        # 12 tokens a step: every held expert's run is one tile or less
+        assert snap["scoring.moe.runs_one_tile"].value == 10 * 2 * 4
         assert snap["scoring.ctx.positions"].count == 10
         assert snap["scoring.ctx.positions"]._max == P - 1
         assert snap["scoring.ctx.reseeds"].value == D     # after event 8

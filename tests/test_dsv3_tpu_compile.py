@@ -32,8 +32,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_compiled_step_moves_no_context_leaf(one_chip):
-    from chip_smoke import _table_moves
+@pytest.fixture(scope="module")
+def step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip): compiled once for every test of this file."""
     from sitewhere_tpu.models import build_model
     from sitewhere_tpu.scoring.stream import streaming_step
 
@@ -57,6 +59,13 @@ def test_compiled_step_moves_no_context_leaf(one_chip):
             params, state, dev, v).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
+    return model, state, compiled
+
+
+def test_compiled_step_moves_no_context_leaf(step):
+    from chip_smoke import _table_moves
+
+    model, state, compiled = step
     hlo = compiled.as_text()
     assert _table_moves(hlo, ROWS) == []
     width = model.cfg.entry_width
@@ -69,3 +78,89 @@ def test_compiled_step_moves_no_context_leaf(one_chip):
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes
+
+
+def _computations(hlo: str) -> tuple[dict, str]:
+    """name -> lines of each computation of an HLO module, and the
+    entry's name."""
+    comps, entry, name = {}, None, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
+
+
+_CALLS = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=(%[\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _inside_whiles(comps: dict) -> set:
+    """Computations that run inside some `while`: its body and
+    condition, and whatever those call."""
+    called = {name: {c for one, many in _CALLS.findall(" ".join(lines))
+                     for c in re.findall(r"%[\w.\-]+", one + " " + many)}
+              for name, lines in comps.items()}
+    inside, todo = set(), [
+        c for lines in comps.values() for line in lines
+        if " while(" in line
+        for c in re.findall(r"(?:body|condition)=(%[\w.\-]+)", line)]
+    while todo:
+        c = todo.pop()
+        if c not in inside:
+            inside.add(c)
+            todo.extend(called.get(c, ()))
+    return inside
+
+
+def test_every_expert_leaf_is_read_once_outside_any_loop(step):
+    """The held experts as one grouped pass: each of an expert layer's 48
+    leaves goes to a kernel (or a product) of the entry computation,
+    outside any `while`; the `while`s that remain are the overflow's,
+    one a held expert, under `moe_experts` and behind ONE conditional,
+    and only they read a leaf a second time; no leaf is copied or
+    sliced."""
+    model, _, compiled = step
+    hlo = compiled.as_text()
+    comps, entry = _computations(hlo)
+    inside = _inside_whiles(comps)
+    assert entry not in inside
+    held = model.cfg.experts_held
+    leaves = re.findall(r"(params__layer1____experts____e\d+____"
+                        r"(?:gate|up|down)__[.\d]*): bf16", hlo)
+    assert len(set(leaves)) == 3 * held
+    body = comps[entry]
+    for leaf in set(leaves):
+        uses = [line for line in body if re.search(
+            rf"(?<![\w.])%{re.escape(leaf)}(?![\w.])", line)
+            and " parameter(" not in line]
+        # the kernel (or a product) takes the leaf as it rests, or the
+        # compiler fetches it ahead into fast memory, whole, for the
+        # kernel and the overflow's loop after it; every other use hands
+        # it to that loop
+        reads = [line for line in uses
+                 if "tpu_custom_call" in line or " convolution(" in line
+                 or " dot(" in line or "kind=kOutput" in line]
+        ahead = [line for line in uses
+                 if " slice-start(" in line or " copy-start(" in line]
+        assert len(reads) == 1 or (not reads and ahead), (leaf, uses)
+        assert all("moe_experts" in line for line in reads)
+        assert all(" conditional(" in line or " while(" in line
+                   or " tuple(" in line for line in uses
+                   if line not in reads and line not in ahead), (leaf, uses)
+    kernels = [line for line in body if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and "moe_experts" in kernels[0]
+    whiles = [line for lines in comps.values() for line in lines
+              if " while(" in line]
+    assert len(whiles) <= held
+    assert all("moe_experts" in line for line in whiles)
+    assert len([line for line in body if " conditional(" in line]) == 1
+    moved = [line for line in hlo.splitlines() if re.search(
+        r"= bf16\[(?:7168,2048|2048,7168)\]\S* "
+        r"(?:copy|slice|dynamic-slice)\(", line)]
+    assert moved == []

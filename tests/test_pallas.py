@@ -169,3 +169,49 @@ def test_ring_probe_keeps_compiled_fn(monkeypatch):
     assert not hasattr(fn, "lower")     # AOT Compiled, not a jit wrapper
     assert ring.fused_status == "compiled"
     np.testing.assert_allclose(scores[:8], ref_scores[:8], atol=1e-5)
+
+
+@pytest.mark.parametrize("held, tile", [(1, 64), (2, 32), (3, 32)])
+def test_expert_kernel_matches_the_plain_products_interpret(held, tile):
+    """ops/expert_kernel.py at the published widths (hidden 7168,
+    intermediate 2048 in its sixteen blocks of 128) over one, two and
+    three experts of few rows, against the three products
+    `Dsv3StreamModel._mlp` makes of each and one scatter-add: bf16
+    operands, f32 sums, `silu * up` rounded to bf16 once, the weight
+    applied in f32, a token's experts summed in f32. The down product is
+    summed block by block, so the two differ by the order of a float32
+    sum. A run's rows past its count add nothing, whatever their weight.
+    (Memory a kernel never wrote reads NaN in interpret mode.)"""
+    from sitewhere_tpu.models import build_model
+    from sitewhere_tpu.ops.expert_kernel import expert_tiles, fits
+
+    hidden, inter, tokens = 7168, 2048, 72
+    assert fits(1024, hidden, inter, 128) and not fits(2048, hidden, inter, 128)
+    keys = iter(jax.random.split(jax.random.PRNGKey(4), 3 * held + 3))
+    experts = [{name: (jax.random.normal(next(keys), shape, jnp.float32)
+                       * 0.02).astype(jnp.bfloat16)
+                for name, shape in (("gate", (hidden, inter)),
+                                    ("up", (hidden, inter)),
+                                    ("down", (inter, hidden)))}
+               for _ in range(held)]
+    rng = np.random.default_rng(held)
+    rows = np.concatenate([np.sort(rng.permutation(tokens)[:tile])
+                           for _ in range(held)]).astype(np.int32)
+    counts = np.asarray([tile, 0, 5][:held], np.int32)
+    x = jax.random.normal(next(keys), (tokens, hidden)).astype(jnp.bfloat16)
+    wts = jax.random.uniform(next(keys), (held * tile,))
+    got = jax.jit(lambda ex, xs, rows, wts, counts: expert_tiles(
+        ex, xs, rows, wts, counts, tokens, interpret=True))(
+            experts, x[rows], rows, wts, counts)
+    model = build_model("dsv3-stream", num_hidden_layers=1, mtp_modules=0)
+    real = (np.arange(tile)[None, :] < counts[:, None]).reshape(-1)
+    ys = jnp.concatenate([
+        model._mlp(expert, x[rows[e * tile:(e + 1) * tile]])
+        for e, expert in enumerate(experts)]) * (wts * real)[:, None]
+    want = jnp.zeros((tokens, hidden), jnp.float32).at[rows].add(ys)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    scale = float(jnp.abs(want).max())
+    assert 0.1 < scale < 10
+    assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+    untouched = np.setdiff1d(np.arange(tokens), rows[real])
+    assert (np.asarray(got)[untouched] == 0).all()

@@ -131,7 +131,10 @@ def test_json_decoder_and_failed_decode(run):
             await sources.receiver("json-in").submit(payload)
 
             em = rt.api("event-management").management("acme")
-            await wait_until(lambda: em.telemetry.total_events >= 1)
+            # the location is persisted on a hop of its own, after the
+            # measurement may already count: wait for both
+            await wait_until(lambda: em.telemetry.total_events >= 1
+                             and em.list_locations(8))
             ms = em.list_measurements(7)
             assert [m.value for m in ms] == [33.5]
             locs = em.list_locations(8)
