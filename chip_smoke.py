@@ -55,8 +55,9 @@ import numpy as np
 REPO = os.path.dirname(os.path.abspath(__file__))
 
 WINDOW, HIDDEN = 64, 64      # the repo's full width (BASELINE.json config 2)
-THRESHOLD = 6.0              # alert bar, as bench.py
-ANOMALY_MAGNITUDE = 12.0     # injected spike, as bench.py
+# alert bar and injected spike, as benchmarks/configs/stream-512k.json
+THRESHOLD = 6.0
+ANOMALY_MAGNITUDE = 12.0
 PARITY_ATOL = 3e-2           # kernel vs scan, as tests/test_pallas.py
 GATEWAY = "gw"               # the tenants' TCP receiver
 # the stores are seeded with WINDOW+4 clean ticks a minute apart; the
@@ -261,7 +262,8 @@ def _tenant_sections(devices: int, model: str = "lstm-stream",
             "model_config": model_config or {"window": WINDOW,
                                              "hidden": HIDDEN},
             "threshold": THRESHOLD,
-            # bucket = ring capacity = fleet, as bench.py sizes them
+            # bucket = ring capacity = fleet, as
+            # benchmarks/configs/stream-512k.json sizes them
             "buckets": [devices], "capacity": devices,
             **rule_extra,
         },
@@ -725,6 +727,26 @@ async def _run_phases(expect_platform: str, n_devices: int,
     return results
 
 
+PEAKS = os.path.join(REPO, "benchmarks", "peaks.json")
+
+
+def peak_bf16_flops(device_kind: str):
+    """The chip's peak by `device_kind` as JAX reports it, from the
+    benchmark's table (read only), or None for a device not in it."""
+    with open(PEAKS) as f:
+        entry = json.load(f).get(device_kind)
+    return entry and entry["bf16_flops_per_s"]
+
+
+def require_peak(device_kind: str) -> None:
+    """Stop before any phase on a chip the benchmark has no peak for:
+    its first run there could report no roofline share."""
+    if peak_bf16_flops(device_kind) is None:
+        log(f"device_kind {device_kind!r} is not in benchmarks/peaks.json's "
+            "peak table; a benchmark here would print mfu: null")
+        raise SystemExit(2)
+
+
 def run_smoke(expect_platform: str, sizes: Sizes) -> dict:
     """Run every phase at `sizes`; returns the summary dict (`ok` is the
     verdict). Raises `SystemExit(2)` before any phase when JAX's first
@@ -746,12 +768,7 @@ def run_smoke(expect_platform: str, sizes: Sizes) -> dict:
             f"{platform!r}: not running")
         raise SystemExit(2)
     if platform == "tpu":
-        from bench import peak_bf16_flops
-
-        if peak_bf16_flops(kind) is None:
-            log(f"device_kind {kind!r} is not in bench.py's peak table; a "
-                "benchmark here would print mfu: null")
-            raise SystemExit(2)
+        require_peak(kind)
 
     from sitewhere_tpu.persistence.native import get_lib
 

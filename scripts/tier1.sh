@@ -210,8 +210,7 @@ PY
 # blocks, the ReplayEngine streams them through an actual
 # SharedScoringPool megabatch slot, and the shadow-scoring gate must
 # CATCH a perturbed candidate checkpoint (and promote an equivalent
-# one) — the cold-tier → scoring-plane contract fails here in tier-1,
-# not only in the bench.
+# one) — the cold-tier → scoring-plane contract fails here in tier-1.
 env JAX_PLATFORMS=cpu python - <<'PY' || { echo "replay smoke: FAILED (ingest→compact→replay→gate spine broken)"; exit 1; }
 import asyncio, os, tempfile
 import jax, numpy as np

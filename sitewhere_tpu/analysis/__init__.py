@@ -30,7 +30,7 @@ The engine walks the package once, shares parsed ASTs across checkers,
 emits `path:line: CODE message` plus a JSON report, supports a
 checked-in baseline (`scripts/swxlint-baseline.json`) for grandfathered
 findings, and exits nonzero on new findings. Dependency-free: stdlib
-`ast` only — importable from bench.py and CI without jax.
+`ast` only — importable from the CLI and CI without jax.
 """
 
 from sitewhere_tpu.analysis.engine import (  # noqa: F401

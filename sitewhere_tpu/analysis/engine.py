@@ -659,7 +659,7 @@ def lint_package(root: Optional[Path] = None,
                  baseline_path: Optional[Path] = None,
                  checkers: Optional[list[Checker]] = None) -> Report:
     """Lint the installed package (or `root`) against its baseline —
-    the one-call entry bench.py and the meta-test use."""
+    the one-call entry the meta-test uses."""
     root = Path(root) if root else package_root()
     if baseline_path is None:
         baseline_path = default_baseline_path(root)

@@ -193,9 +193,8 @@ COUNTERS = (
     # broker-side member eviction on death declarations (kernel/bus.py)
     "fleet.members_evicted",
     # self-tuning dispatch (mesh serving, docs/PERFORMANCE.md):
-    # adaptive-megabatch-window and egress-lane tuner decisions
+    # adaptive-megabatch-window tuner decisions
     "scoring.megabatch_window_adjusts",
-    "egress.autotune_adjusts",
     # fleet observability plane (docs/OBSERVABILITY.md): beat snapshots
     # exported onto the instance telemetry topic, records the
     # FleetObserver folded, telemetry-history windows compacted to disk
@@ -233,12 +232,11 @@ GAUGES = (
     "fleet.forecast_horizon_error_ema",
     "fleet.forecast_model_version",
     "fleet.forecast_load_predicted",
-    # mesh-sharded serving + self-tuning dispatch (scoring/pool.py,
-    # kernel/egresslane.py): devices under the stacked dispatch, the
-    # live adaptive megabatch window, active egress lanes
+    # mesh-sharded serving + self-tuning dispatch (scoring/pool.py):
+    # devices under the stacked dispatch, the live adaptive megabatch
+    # window
     "scoring.mesh_devices",
     "scoring.megabatch_window_ms",
-    "egress.autotune_lanes",
     # per-device mesh telemetry (scoring/pool.py mesh_stats): tenant-row
     # occupancy of the stacked dispatch and the LIVE per-device model
     # throughput — the "read it on a real rig" surface, per-pool

@@ -5,7 +5,7 @@ rebuild ships one:
 
   swx run [--config instance.yaml] [--port 8080]   run a full instance
   swx simulate --host H --port P --devices N       stream SWB1 at a gateway
-  swx bench [...]                                  run the benchmark
+  swx bench --workload W --seed N --seconds S      run one cell of BENCHMARK.json
   swx demo                                         run + simulate + score, one process
   swx dlq list|replay --tenant T                   inspect/replay dead letters
   swx quota show|set --tenant T                    flow-control quotas
@@ -1334,7 +1334,10 @@ def main(argv=None) -> int:
     p_demo.add_argument("--seconds", type=float, default=5.0)
     p_demo.add_argument("--port", type=int)
 
-    sub.add_parser("bench", parents=[common], help="run the benchmark (see bench.py flags)")
+    sub.add_parser("bench",
+                   help="run one cell of BENCHMARK.json (arguments go to "
+                        "benchmarks/run.py: --workload --seed --seconds "
+                        "--trace)")
 
     p_replay = sub.add_parser(
         "replay", parents=[common],
@@ -1399,12 +1402,12 @@ def main(argv=None) -> int:
     if args.cmd == "bench":
         import subprocess
 
-        # bench.py sits beside the package, not in the caller's cwd;
-        # this parent never touches JAX (the child holds the chip)
+        # benchmarks/ sits beside the package, not in the caller's cwd;
+        # this parent never touches JAX (the child holds the chip, and
+        # exits 2 where there is none)
         bench = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py")
-        return subprocess.call([sys.executable, bench, *extra,
-                                *(["--force-cpu"] if args.cpu else [])])
+            os.path.abspath(__file__))), "benchmarks", "run.py")
+        return subprocess.call([sys.executable, bench, *extra])
     if args.cmd in ("run", "demo", "train", "fleet-worker", "replay"):
         _init_backend(args.cpu)
     coro = {"run": cmd_run, "simulate": cmd_simulate, "demo": cmd_demo,

@@ -51,7 +51,8 @@ class InstanceSettings:
     # backlog, scoring occupancy, and flow mode every `interval_ms` into
     # a bounded ring of `observe_ring` samples; loop lag past
     # `observe_stall_ms` counts a stall (the PR-6 starved-loop class).
-    # `observe_enabled: false` (bench `--no-observe`) is the A/B lever.
+    # `observe_enabled: false` is the one switch of the whole recorder
+    # (beat, export, fleet merge): fixtures use it to quiet a runtime.
     observe_enabled: bool = True
     observe_interval_ms: float = 250.0
     observe_ring: int = 256
@@ -74,11 +75,6 @@ class InstanceSettings:
     # opts a durable runtime out.
     observe_history: bool = True
     observe_history_window_s: float = 10.0
-    # controller-host lever for the fleet MERGE specifically (the
-    # FleetObserver beside the FleetController): `observe_enabled`
-    # turns the whole recorder off; this turns off only the fleet-wide
-    # fold — bench `--no-fleet-observe` is the fleetobs A/B's off leg
-    fleet_observe: bool = True
     scoring_batch_window_ms: float = 2.0
     scoring_batch_buckets: tuple[int, ...] = (256, 1024, 4096, 16384)
     # cross-tenant megabatched scoring (scoring/pool.py): when enabled,
@@ -143,7 +139,7 @@ class InstanceSettings:
     # not telemetry rollups); `history_block_events` caps events per
     # block flush; `history_compact_interval_s` > 0 runs the compactor
     # on that cadence inside the event-management engine (0 = on-demand:
-    # CLI/REST/bench drive compaction explicitly). Needs a data_dir.
+    # CLI and REST drive compaction explicitly). Needs a data_dir.
     history_window_s: float = 60.0
     history_block_events: int = 65536
     history_compact_interval_s: float = 0.0
@@ -166,28 +162,13 @@ class InstanceSettings:
     flow_defer_at: float = 0.9
     flow_hysteresis: float = 0.8
     flow_dlq_rate_max: float = 50.0   # DLQ events/s mapping to pressure 1.0
-    # egress fast lanes (kernel/egresslane.py): `egress_fused` engages
-    # the fused scored-publish stage (settle tasks enqueue, supervised
-    # shard loops publish + emit alerts off the flush path);
+    # egress fast lanes (kernel/egresslane.py): settle tasks enqueue,
+    # supervised shard loops publish + emit alerts off the flush path.
     # `egress_lanes` is the default shard count for the egress stage AND
     # the per-tenant consumer lanes (fast lane, staged inbound,
     # persister, outbound fan-out) — N loops join one consumer group,
-    # splitting partitions. Tenant `egress: {fused, lanes}` overrides.
-    egress_fused: bool = True
+    # splitting partitions. Tenant `egress: {lanes}` overrides.
     egress_lanes: int = 1
-    # egress lane-count auto-tuner (kernel/egresslane.py): the stage
-    # watches the TelemetryBeat's signals — its own backlog, event-loop
-    # lag, the tenant's overload mode — and floats the ACTIVE shard
-    # count in [1, egress_autotune_max_lanes]: sustained backlog earns
-    # another lane, sustained loop lag (the measured 1-core trade:
-    # extra lanes deepen the XLA dispatch queue) sheds one. Lane
-    # switches apply only while the stage is idle (per-key publish
-    # order holds by construction) and carry hysteresis + cooldown
-    # (test-pinned). Off by default — `egress: {autotune: true}` (or
-    # the bench's `--egress-autotune`) opts in; `egress_lanes` stays
-    # the static default and the tuner's starting point.
-    egress_autotune: bool = False
-    egress_autotune_max_lanes: int = 4
     # fleet control plane (sitewhere_tpu/fleet): `fleet_managed: true`
     # marks a WORKER runtime whose tenant engines are driven by fleet
     # placement records — the TenantEngineManager stands down (it must
@@ -205,17 +186,15 @@ class InstanceSettings:
     # windows, scores them through the shared megabatch pool as the
     # reserved internal tenant-0, and converts forecasts of per-tenant
     # load `fleet_forecast_horizon_s` ahead into scale-up decisions
-    # BEFORE backlog forms (the ~13–19 s JAX spawn/first-compile bill a
-    # reactive spawn pays after the fact). Reactive logic stays the
+    # BEFORE backlog forms (a reactive spawn pays the JAX start and
+    # first compile after the fact). Reactive logic stays the
     # fallback floor: a confidence/staleness gate demotes to
     # pure-reactive whenever the model is cold (no trained version),
     # history is thin (< `min_windows` per tenant), the freshest
     # forecast is stale (> `max_stale_s`), or the realized horizon
-    # error EMA exceeds `error_gate` (relative). `fleet_forecast:
-    # false` (bench `--no-forecast`) is the predictive A/B's off leg —
-    # the planner is then never built and the controller is byte-for-
-    # byte the PR-8 reactive loop.
-    fleet_forecast: bool = True
+    # error EMA exceeds `error_gate` (relative). The planner needs the
+    # durable telemetry history: a controller without a `data_dir`
+    # never builds it and runs the reactive loop alone.
     fleet_forecast_horizon_s: float = 15.0
     fleet_forecast_window: int = 32         # model input steps (ctx+horizon)
     fleet_forecast_interval_s: float = 1.0  # planner sampling cadence
@@ -227,7 +206,7 @@ class InstanceSettings:
     # `fleet_forecast_retrain_s` seconds inside the planner tick
     # (executor-offloaded — the controller loop keeps ticking), audit-
     # logged into the autoscaler decision trail. 0 = on-demand only
-    # (bench setup / runbook `train_from_history`), the PR-15 behavior.
+    # (the runbook's `train_from_history`).
     fleet_forecast_retrain_s: float = 0.0
     # wire data-plane fast path (kernel/wire.py, docs/PERFORMANCE.md):
     # `wire_prefetch` streams record batches broker→consumer under a
@@ -239,7 +218,9 @@ class InstanceSettings:
     # only what is already queued); `wire_inflight_cap` bounds un-acked
     # fire-and-forget ops — past it the client reports `backlogged`
     # and consumer loops pause through the egress commit barrier.
-    # All on by default; bench `--no-wire-fastpath` is the A/B off leg.
+    # All on by default. `wire_prefetch` and `wire_pipeline` are held by
+    # ROADMAP D3: no benchmark cell crosses the wire yet, and
+    # tests/test_wire_prefetch.py runs both legs.
     wire_prefetch: bool = True
     wire_prefetch_credit: int = 256
     wire_pipeline: bool = True
@@ -312,7 +293,13 @@ def load_yaml_config(path: str) -> tuple[InstanceSettings, list[TenantConfig]]:
         raise RuntimeError("pyyaml not available")
     with open(path) as f:
         doc = yaml.safe_load(f) or {}
-    inst = InstanceSettings.from_env(**(doc.get("instance") or {}))
+    instance = doc.get("instance") or {}
+    known = {f.name for f in dataclasses.fields(InstanceSettings)}
+    unknown = sorted(set(instance) - known)
+    if unknown:
+        raise ValueError(f"{path}: unknown instance setting(s): "
+                         f"{', '.join(unknown)}")
+    inst = InstanceSettings.from_env(**instance)
     tenants = []
     for t in doc.get("tenants") or []:
         t = dict(t)

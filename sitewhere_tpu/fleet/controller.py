@@ -129,8 +129,7 @@ class FleetController(LifecycleComponent):
         # runtime's observe lever — `observe_enabled: false` turns the
         # whole recorder off, fleet merge included
         self.observer = None
-        if getattr(settings, "observe_enabled", True) \
-                and getattr(settings, "fleet_observe", True):
+        if getattr(settings, "observe_enabled", True):
             from sitewhere_tpu.fleet.observer import FleetObserver
 
             self.observer = FleetObserver(runtime)
@@ -466,12 +465,10 @@ class FleetController(LifecycleComponent):
 
     def _ensure_planner(self) -> None:
         """Create the predictive planner on first use (fleet/forecast.py):
-        gated on the forecast lever AND the durable telemetry history —
-        without the history there is nothing to train or serve from,
-        and the reactive path alone runs (the fallback floor)."""
+        gated on the durable telemetry history — without it there is
+        nothing to train or serve from, and the reactive path alone
+        runs (the fallback floor)."""
         if self.planner is not None:
-            return
-        if not getattr(self.runtime.settings, "fleet_forecast", True):
             return
         if getattr(self.runtime, "history", None) is None:
             return

@@ -2,8 +2,8 @@
 
 The reactive autoscaler (controller.py, the ADApt replica-prediction
 shape — PAPERS.md, arXiv 2504.03698) acts only AFTER backlog forms, and
-every spawn it orders pays the ~13–19 s JAX startup + first-compile
-reconvergence the fleet bench's kill drill measured. This module closes
+every spawn it orders pays the JAX startup and first compile before it
+takes load. This module closes
 the loop ROADMAP item 2 names: the durable telemetry history
 (`persistence/durable.py TelemetryHistory` — per-tenant lag, egress
 backlog, scoring occupancy, accept rate, per-worker loop lag) becomes
@@ -385,8 +385,6 @@ class PredictivePlanner:
     # -- the planner loop (controller tick) ----------------------------------
 
     async def tick(self) -> None:
-        if not getattr(self.runtime.settings, "fleet_forecast", True):
-            return
         now = time.monotonic()
         if now - self._last_tick < self.interval_s:
             return
@@ -594,8 +592,6 @@ class PredictivePlanner:
         reactive scale-up bar, else None (fall through to reactive).
         Pure read of planner state — safe to call from sync code."""
         del lags  # forecasts already integrate the per-tenant series
-        if not getattr(self.runtime.settings, "fleet_forecast", True):
-            return None
         reason = self.gate()
         self._gate_reason = reason
         if reason is not None:
@@ -650,8 +646,6 @@ class PredictivePlanner:
     def snapshot(self) -> dict:
         now = time.monotonic()
         return {
-            "enabled": bool(getattr(self.runtime.settings,
-                                    "fleet_forecast", True)),
             "serving": self.pool is not None,
             "trained": self._trained,
             "gate": self._gate_reason or "ok",

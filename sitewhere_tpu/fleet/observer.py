@@ -205,7 +205,7 @@ class FleetObserver(LifecycleComponent):
 
     def snapshot(self) -> dict:
         """The fleet observe report (`GET /api/fleet/observe`,
-        `swx top --fleet`, bench `fleet_observe` block)."""
+        `swx top --fleet`)."""
         self._prune()
         now = time.monotonic()
         lags = self._broker_lags()

@@ -57,7 +57,7 @@ def build_runtime(cfg: dict):
         **(cfg.get("settings") or {}))
     # wire data-plane fast path (docs/PERFORMANCE.md): prefetch +
     # pipelined produce ride the same settings overlay as every other
-    # knob, so the bench's A/B off leg is one `settings` key away
+    # knob
     bus = RemoteEventBus(cfg.get("host", "127.0.0.1"), cfg["port"],
                          secret=cfg.get("secret"),
                          prefetch=settings.wire_prefetch,

@@ -7,7 +7,7 @@ come off `EventHistoryStore.read_range` as read-only zero-copy column
 views and go straight into `SharedScoringPool.admit_columns`, so the
 only per-event work left is the scorer's own dispatch. That makes
 replay the first workload whose ceiling is pure scoring dispatch
-(bench.py --replay measures the margin over live saturation).
+(its rate against live saturation: not measured on the chip, PERF.md).
 
 Slot discipline: every replay registers a transient INTERNAL slot named
 `tenant-0.replay:<tenant>` — the reserved-tenant prefix keeps it out of
@@ -188,8 +188,7 @@ class ReplayEngine:
                 # needs — is preserved) and admit each rank round as its
                 # own chunk: pool takes then align with round boundaries
                 # and every dispatch packs a dense, duplicate-free
-                # batch. Measured on the bench rig: ~4x replay
-                # throughput over admitting the raw window blob.
+                # batch.
                 order = np.argsort(dev, kind="stable")
                 sd = dev[order]
                 start = np.flatnonzero(np.r_[True, sd[1:] != sd[:-1]])

@@ -1,13 +1,12 @@
 """Sharded egress fast lanes: the scored-publish sink tail, fused.
 
 PR 4's ingress fusion (kernel/fastlane.py) made the decoded→admit path
-one hop, and the same-day A/B moved the dominant tail to the SINK stage:
-p99 61–82 ms of publish-side stalls on both lanes (docs/PERFORMANCE.md).
-The cause mirrors the ingress story: every scored flush's settle task
-performed its own bus publish AND its anomaly-alert emission inline, so
-the publish tail rode the settle task's scheduling luck on a busy event
-loop — and a stall in the alert path (an event-store hiccup, a slow
-tenant) blocked the scoring flush pipeline itself.
+one hop, which left the SINK stage as the tail. The cause mirrored the
+ingress story: every scored flush's settle task performed its own bus
+publish AND its anomaly-alert emission inline, so the publish tail rode
+the settle task's scheduling luck on a busy event loop — and a stall in
+the alert path (an event-store hiccup, a slow tenant) blocked the
+scoring flush pipeline itself.
 
 This module is the egress half of the fuse-then-shard playbook
 (PAPERS.md: Cloudflow's fuse-don't-hop rewrite; the PMU streaming tier's
@@ -50,10 +49,9 @@ would double-publish the batch. The `egress.publish` chaos site is
 consulted per batch inside the quarantine wrapper, and the shard loops
 carry the same supervisor/restart budget as every service loop.
 
-Lane config, per tenant (overrides `InstanceSettings.egress_*`):
+Lane config, per tenant (overrides `InstanceSettings.egress_lanes`):
 
     egress:
-      fused: true | false   # false = legacy inline sink (the A/B lever)
       lanes: N              # egress shards AND ingress consumer lanes
 
 `lanes` is also the shard count for the PR 4 ingress fast lane and the
@@ -69,7 +67,7 @@ fault site and `egress.*` / `rules.alerts_emitted` metrics resolve
 against `analysis/registry.py` (FLT01/MET01); the shard loop's
 per-batch handling routes failures to the DLQ with provenance (the
 DLQ01 quarantine discipline, applied to an in-memory queue drain).
-See docs/PERFORMANCE.md for the measured before/after.
+docs/PERFORMANCE.md describes the mechanism; rates are PERF.md's.
 """
 
 from __future__ import annotations
@@ -78,7 +76,6 @@ import asyncio
 import logging
 import time
 from collections import deque
-from typing import Optional
 
 from sitewhere_tpu.kernel.bus import (
     EventBus,
@@ -106,8 +103,8 @@ async def deliver_scored(sink, scored, sink_failures, stage_sink,
       loops, and timing the enqueue would record ~0 and hide the tail).
 
     The pool gathers one of these per tenant of a settled megabatch, so
-    a slow legacy-inline sink for one tenant never serializes the other
-    tenants' deliveries behind it."""
+    a slow sink for one tenant never serializes the other tenants'
+    deliveries behind it."""
     t_sink = time.monotonic()
     try:
         await sink(scored)
@@ -118,16 +115,6 @@ async def deliver_scored(sink, scored, sink_failures, stage_sink,
     else:
         if not getattr(sink, "owns_sink_stage", False):
             stage_sink.observe(time.monotonic() - t_sink)
-
-
-def egress_fused(tenant, runtime) -> bool:
-    """Is the fused egress stage enabled for this tenant? Pure function
-    of config (tenant `egress.fused` over the instance default), so the
-    bench lever and tests pin it deterministically."""
-    section = tenant.section("egress")
-    if "fused" in section:
-        return bool(section["fused"])
-    return bool(getattr(runtime.settings, "egress_fused", True))
 
 
 def egress_lanes(tenant, runtime) -> int:
@@ -144,44 +131,19 @@ def egress_lanes(tenant, runtime) -> int:
         return 1
 
 
-def egress_autotune(tenant, runtime) -> bool:
-    """Is the egress lane-count auto-tuner on for this tenant (tenant
-    `egress.autotune` over `InstanceSettings.egress_autotune`)? Pure
-    function of config, like the other lane predicates."""
-    section = tenant.section("egress")
-    if "autotune" in section:
-        return bool(section["autotune"])
-    return bool(getattr(runtime.settings, "egress_autotune", False))
-
-
-def egress_max_lanes(tenant, runtime) -> int:
-    """The auto-tuner's lane ceiling (tenant `egress.max_lanes` over
-    the instance default; never below the configured static lanes)."""
-    section = tenant.section("egress")
-    cap = section.get("max_lanes",
-                      getattr(runtime.settings, "egress_autotune_max_lanes",
-                              4))
-    try:
-        return max(int(cap), egress_lanes(tenant, runtime))
-    except (TypeError, ValueError):
-        return egress_lanes(tenant, runtime)
-
-
 class EgressStage:
     """Per-tenant fused egress: the scoring sink that never suspends.
 
-    The settle path calls the stage like the old inline sink
-    (`await sink(scored)`) — the call enqueues onto a shard keyed by the
-    batch's source and returns; the shard loops do the publishing and
-    the alert emission. `owns_sink_stage` tells the scoring session/pool
-    that THIS stage observes `scoring.stage_sink_s` (submit→published),
-    so the histogram keeps meaning "settled → published" across the
-    inline and fused configurations."""
+    The settle path calls the stage as a sink (`await sink(scored)`) —
+    the call enqueues onto a shard keyed by the batch's source and
+    returns; the shard loops do the publishing and the alert emission.
+    `owns_sink_stage` tells the scoring session/pool that THIS stage
+    observes `scoring.stage_sink_s` (submit→published), so the
+    histogram means "settled → published" and not the enqueue."""
 
     owns_sink_stage = True
 
-    def __init__(self, engine, lanes: int = 1, autotune: bool = False,
-                 max_lanes: Optional[int] = None):
+    def __init__(self, engine, lanes: int = 1):
         self.engine = engine
         self.scored_topic = engine.tenant_topic(TopicNaming.SCORED_EVENTS)
         self.tracer = engine.runtime.tracer
@@ -212,97 +174,11 @@ class EgressStage:
         # submitted == accounted
         self.submitted = 0
         self.accounted = 0
-        # lane auto-tune (the self-tuning half of mesh serving): shards
-        # are built to the CEILING up front — lifecycle children can't
-        # be added under load — and `active` bounds how many submit
-        # routes to. Idle shards cost one parked loop each. The tuner
-        # (autotune_observe, fed by the TelemetryBeat every beat) moves
-        # `active` one lane at a time on sustained signals: backlog per
-        # active lane past half the shard cap earns a lane, event-loop
-        # lag past the stall threshold while the lanes sit near-empty
-        # sheds one (the measured 1-core trade: extra lanes deepen the
-        # XLA dispatch queue — docs/PERFORMANCE.md). A switch APPLIES
-        # only while the stage is idle, so re-keying can never overtake
-        # a shard's backlog and break per-key publish order.
-        n = max(lanes, 1)
-        ceiling = max(max_lanes or n, n) if autotune else n
-        self.shards = [EgressShard(self, i) for i in range(ceiling)]
-        self.active = n
-        self._autotune = bool(autotune)
-        self._pending_active: Optional[int] = None
-        self._up_beats = 0
-        self._down_beats = 0
-        self._last_adjust_t = -1e9
-        self.autotune_adjusts = metrics.counter("egress.autotune_adjusts")
-        # per-tenant suffix (the registry's `:{suffix}` convention):
-        # one stage per tenant writes this gauge, and a shared base
-        # name would be last-writer-wins noise with >1 tenant
-        self.autotune_gauge = metrics.gauge(
-            f"egress.autotune_lanes:{engine.tenant_id}")
-        self.autotune_gauge.set(self.active)
+        self.shards = [EgressShard(self, i) for i in range(max(lanes, 1))]
 
     @property
     def lanes(self) -> int:
         return len(self.shards)
-
-    # the tuner's thresholds: N consecutive beats of one signal (a
-    # single spike never moves a lane) + a wall-clock cooldown between
-    # adjustments; up and down trigger on DISJOINT conditions (high
-    # backlog vs lag-with-idle-lanes), so the tuner converges instead
-    # of oscillating (test-pinned)
-    AUTOTUNE_CONSECUTIVE = 4
-    AUTOTUNE_COOLDOWN_S = 5.0
-
-    def autotune_observe(self, loop_lag_s: float, stall_s: float,
-                         mode: str = "ok") -> None:
-        """One TelemetryBeat observation (kernel/observe.py calls this
-        every beat): fold the beat's signals — this stage's backlog,
-        the event loop's lag, the tenant's overload mode — into the
-        lane tuner."""
-        if not self._autotune:
-            return
-        self._apply_pending()
-        per_lane = self.backlog / max(self.active, 1)
-        want_up = (per_lane > self.MAX_BACKLOG_PER_SHARD / 2
-                   and self.active < len(self.shards))
-        # lanes that are not earning their keep: the loop is lagging
-        # (or the tenant is shedding) while the shard queues sit
-        # near-empty — publish parallelism is not the bottleneck, the
-        # extra loops are just dispatch-queue depth
-        want_down = (self.active > 1
-                     and per_lane < self.MAX_BACKLOG_PER_SHARD / 4
-                     and (loop_lag_s >= stall_s or mode != "ok"))
-        self._up_beats = self._up_beats + 1 if want_up else 0
-        self._down_beats = self._down_beats + 1 if want_down else 0
-        now = time.monotonic()
-        if now - self._last_adjust_t < self.AUTOTUNE_COOLDOWN_S:
-            return
-        if self._up_beats >= self.AUTOTUNE_CONSECUTIVE:
-            self._pending_active = self.active + 1
-        elif self._down_beats >= self.AUTOTUNE_CONSECUTIVE:
-            self._pending_active = self.active - 1
-        else:
-            return
-        self._up_beats = self._down_beats = 0
-        self._last_adjust_t = now
-        self._apply_pending()
-
-    def _apply_pending(self) -> None:
-        """Apply a decided lane switch, but ONLY at an idle instant:
-        every submitted batch is accounted, so no shard holds backlog a
-        re-keyed submission could overtake (per-key publish order is
-        the invariant the sync fast path and the partition hash share).
-        The stage drains its whole backlog per wakeup, so idle instants
-        are frequent even under load; until one arrives the decision
-        stays pending and `submit` retries it."""
-        if self._pending_active is None or not self.idle:
-            return
-        self.active = self._pending_active
-        self._pending_active = None
-        self.autotune_adjusts.inc()
-        self.autotune_gauge.set(self.active)
-        logger.info("egress[%s]: auto-tuned to %d active lane(s) of %d",
-                    self.engine.tenant_id, self.active, len(self.shards))
 
     # unpublished batches per shard before the consumer loops stop
     # consuming (backlogged below): a slow-but-not-failing publish (a
@@ -320,10 +196,7 @@ class EgressStage:
         """Egress backlog at capacity: the consumer loops consult this
         (through the commit barrier) exactly like the scoring sink's
         `backlogged` — stop consuming, keep draining, offsets hold."""
-        # active lanes, not built shards: an auto-tuned stage's idle
-        # ceiling shards can't drain anything, so they must not widen
-        # the backpressure bound either
-        if self.backlog >= self.MAX_BACKLOG_PER_SHARD * max(self.active, 1):
+        if self.backlog >= self.MAX_BACKLOG_PER_SHARD * self.lanes:
             return True
         # wire bus fire-and-forget window full (kernel/wire.py): a
         # stalled broker must pause the consumer loops through this
@@ -342,12 +215,11 @@ class EgressStage:
         self.submit(scored)
 
     def submit(self, scored) -> None:
-        self._apply_pending()  # a decided lane switch lands idle-only
         key = getattr(scored.ctx, "source", None)
-        if key and self.active > 1:
+        if key and self.lanes > 1:
             # THE bus partition hash (kernel/bus.py key_hash): one key,
             # one shard, one partition — per-device publish order holds
-            shard = self.shards[key_hash(key) % self.active]
+            shard = self.shards[key_hash(key) % self.lanes]
         else:
             shard = self.shards[0]
         self.submitted += 1
@@ -521,9 +393,8 @@ class EgressBarrier:
     """Composite commit barrier for `checkpoint_commit`: the scoring
     sink (session or pool slot) AND the egress stage. Offsets may
     commit only once everything dispatched before the snapshot has
-    settled AND its scored output has left the stage — the same
-    "settled AND published" guarantee the inline sink gave, kept intact
-    across the decoupling."""
+    settled AND its scored output has left the stage: "settled AND
+    published", though the settle path no longer awaits the publish."""
 
     __slots__ = ("_sink", "_egress")
 
@@ -563,11 +434,11 @@ class EgressBarrier:
         return self._sink.settled_through
 
 
-def commit_barrier(sink, egress: Optional[EgressStage]):
-    """The object consumer loops hand to `checkpoint_commit`: the raw
-    sink when the egress stage is disabled (legacy inline publish), the
-    composite barrier when it is fused — ONE call site shape for both
-    configurations, in both consumer lanes."""
-    if sink is None or egress is None:
-        return sink
+def commit_barrier(sink, egress: EgressStage):
+    """The object consumer loops hand to `checkpoint_commit`: the
+    composite barrier over the scoring sink and the engine's egress
+    stage, or None for an engine without a model (it has neither) —
+    ONE call site shape in both consumer lanes."""
+    if sink is None:
+        return None
     return EgressBarrier(sink, egress)

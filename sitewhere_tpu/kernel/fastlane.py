@@ -2,8 +2,7 @@
 
 The staged pipeline pays three produce→consume bus hops on the scored
 path (decoded → inbound validate → persist/enrich → scoring admit), and
-BASELINE.md's round-5 analysis pins the admit-stage tail (p50 5.1 ms,
-p99 81.9 ms on the CPU rig) on event-loop scheduling stalls that
+the admit-stage tail comes from event-loop scheduling stalls that
 COMPOUND across those hops — each produce/poll round-trip is another
 chance for a busy loop to stall the woken consumer, and the stalls
 multiply into the tail. The per-batch compute was never the problem.
@@ -58,7 +57,8 @@ Contracts (machine-checked, docs/ANALYSIS.md): the fused loop consults
 the FlowController on its publish path (FLW01), wraps per-record work in
 DLQ quarantine (DLQ01), and its fault site (`fastlane.handle`) and
 metrics (`fastlane.*`) resolve against `analysis/registry.py`
-(FLT01/MET01). See docs/PERFORMANCE.md for the measured before/after.
+(FLT01/MET01). docs/PERFORMANCE.md describes the mechanism; rates
+are PERF.md's.
 """
 
 from __future__ import annotations
@@ -296,7 +296,7 @@ class FastLane(BackgroundTaskComponent):
         # most the unsettled tail, which is the staged lanes' combined
         # at-least-once guarantee
         ckpt: Optional[tuple[int, dict]] = None
-        # composes the fused egress stage into the barrier when enabled
+        # composes the egress stage into the barrier
         # (kernel/egresslane.py): offsets wait for the PUBLISH, exactly
         # like the staged lane's rule processor
         barrier = commit_barrier(sink, engine.egress)

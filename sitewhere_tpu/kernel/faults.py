@@ -1,4 +1,4 @@
-"""Deterministic fault injection for chaos tests and `bench.py --chaos`.
+"""Deterministic fault injection for chaos tests (tests/test_robustness.py).
 
 ADApt-style robustness (PAPERS.md) needs *provable* degradation
 behavior: the supervisor restarts crashed loops, the DLQ quarantines

@@ -27,13 +27,11 @@ metrics registry (so Prometheus exposition rides the existing
 
 Sampling cost is a handful of dict walks over per-tenant engines — no
 locks, no awaits inside the sample — so the beat is safe to leave on in
-production (the same-day A/B `ab_compare.py observe` pins the overhead
-within noise; docs/OBSERVABILITY.md).
+production (its cost on the chip: not measured).
 
 `observe_report()` combines the beat's latest state with the tracer's
 critical-path analysis (kernel/tracing.py) into the one dict served by
-`GET /api/instance/observe`, rendered by `swx top`, and stamped into
-bench artifacts as the `observe` block.
+`GET /api/instance/observe` and rendered by `swx top`.
 
 Fleet observability (docs/OBSERVABILITY.md): when export is on
 (`observe_export`, auto for fleet workers) every beat also PUBLISHES
@@ -225,8 +223,6 @@ class TelemetryBeat(BackgroundTaskComponent):
         lag_max = max(lags.values(), default=0)
         self.lag_gauge.set(lag_max)
         # flow mode + pressure per tenant (the shed ladder's live state)
-        # — sampled BEFORE the engine walk so the egress lane tuner
-        # sees this beat's modes, not the previous beat's
         flow = getattr(runtime, "flow", None)
         modes = flow.modes() if flow is not None else {}
         # egress backlog + scoring occupancy per rule-processing engine
@@ -241,13 +237,6 @@ class TelemetryBeat(BackgroundTaskComponent):
                     egress[tid] = stage.backlog
                     metrics.gauge(f"observe.egress_backlog:{tid}").set(
                         stage.backlog)
-                    # the egress lane auto-tuner's observation hook
-                    # (kernel/egresslane.py): one beat's signals — the
-                    # stage's own backlog, this loop-lag probe, the
-                    # tenant's shed mode — drive the lane count
-                    stage.autotune_observe(
-                        loop_lag_s, self.stall_s,
-                        mode=(modes.get(tid) or {}).get("mode", "ok"))
                 sink = getattr(eng, "session", None) \
                     or getattr(eng, "pool_slot", None)
                 if sink is not None:
@@ -398,8 +387,7 @@ def observe_report(runtime, tenant: Optional[str] = None) -> dict:
     """The flight recorder's one-call report: critical path over sampled
     traces + the telemetry beat's live state (+ fleet placement when
     this process hosts the controller). Served by
-    `GET /api/instance/observe`, rendered by `swx top`, stamped into
-    bench artifacts."""
+    `GET /api/instance/observe`, rendered by `swx top`."""
     beat = getattr(runtime, "beat", None)
     fleet = getattr(runtime, "fleet", None)
     history = getattr(runtime, "history", None)

@@ -482,7 +482,7 @@ class ServiceRuntime(LifecycleComponent):
 
     def install_faults(self, injector: Any) -> Any:
         """Install a FaultInjector on the runtime and its bus (chaos
-        tests / `bench.py --chaos`). Install BEFORE tenants are added:
+        tests, tests/test_robustness.py). Install BEFORE tenants are added:
         engines capture the injector when they build their durable logs
         and scoring sessions. Returns the injector (chainable)."""
         self.faults = injector

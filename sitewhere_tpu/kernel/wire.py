@@ -1167,9 +1167,9 @@ class RemoteEventBus:
     Fast-path levers (InstanceSettings.wire_*): `prefetch` +
     `prefetch_credit` engage the streaming poll path, `pipeline` +
     `linger_ms` the per-tick coalesced writes, `inflight_cap` the
-    fire-and-forget backpressure bound. All on by default; the A/B off
-    leg (`bench.py --no-wire-fastpath`) restores the PR-8
-    request/response plane bit for bit."""
+    fire-and-forget backpressure bound. All on by default; with
+    `prefetch` and `pipeline` off it is the PR-8 request/response
+    plane (tests/test_wire_prefetch.py runs both legs; ROADMAP D3)."""
 
     def __init__(self, host: str, port: int, secret: Optional[str] = None,
                  *, prefetch: bool = True,

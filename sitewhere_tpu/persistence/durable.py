@@ -184,8 +184,7 @@ class DurableEventLog:
     the writer thread encodes (SWB1 / codec) and appends. The queue is
     bounded: if the disk can't keep up, the newest batch is dropped and
     counted (`dropped`) rather than stalling ingest — durability is a
-    best-effort appendix on this rig, never backpressure on the hot
-    path (the artifactual <10 % bench budget; see BASELINE.md)."""
+    best-effort appendix, never backpressure on the hot path."""
 
     def __init__(self, directory: str, segment_bytes: int = 4 << 20,
                  max_segments: int = 64, fsync_interval_s: float = 0.2,

@@ -563,7 +563,7 @@ class RestServer(LifecycleComponent):
         planner = getattr(fleet, "planner", None)
         if planner is None:
             raise HttpError(404, "predictive planner not running "
-                            "(fleet_forecast off or no telemetry history)")
+                            "(no telemetry history: set data_dir)")
         return planner.snapshot()
 
     def _fleet_observer(self):

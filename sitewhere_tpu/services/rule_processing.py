@@ -54,10 +54,7 @@ from sitewhere_tpu.kernel.bus import FencedError, TopicNaming
 from sitewhere_tpu.kernel.egresslane import (
     EgressStage,
     commit_barrier,
-    egress_autotune,
-    egress_fused,
     egress_lanes,
-    egress_max_lanes,
 )
 from sitewhere_tpu.kernel.fastlane import (
     FastLane,
@@ -85,11 +82,10 @@ def megabatch_enabled(tenant, runtime) -> bool:
 
     Pure function of config (tenant `rule-processing: {megabatch:
     {enabled}}` — or a bare bool — over `InstanceSettings
-    .scoring_megabatch`), so the bench lever and tests pin it
-    deterministically, and every engine of one instance reaches the
-    same answer. `shared: true` (config 4) routes to the pool
-    regardless; this predicate is the megabatch opt-in for tenants that
-    would otherwise run dedicated."""
+    .scoring_megabatch`), so tests pin it deterministically, and every
+    engine of one instance reaches the same answer. `shared: true`
+    (config 4) routes to the pool regardless; this predicate is the
+    megabatch opt-in for tenants that would otherwise run dedicated."""
     rp = tenant.section("rule-processing", {"model": "zscore"})
     if not rp.get("model", "zscore"):
         return False  # scoring disabled: nothing to batch
@@ -190,22 +186,18 @@ class RuleProcessingEngine(TenantEngine):
                 self.mesh_spec = {"data": d or None, "model": m or 1}
         self.session: Optional[ScoringSession] = None
         self.pool_slot: Optional[TenantSlot] = None
-        # fused egress stage (kernel/egresslane.py): scored publishes +
-        # alert emission run on supervised shard loops off the flush
-        # path; scored_sink is what every scored batch flows through
-        # (the stage when fused, the legacy inline publish otherwise).
+        # egress stage (kernel/egresslane.py): scored publishes + alert
+        # emission run on supervised shard loops off the flush path. It
+        # IS the scored sink: every scored batch leaves through it; an
+        # engine without a model scores nothing and has none.
         # Declared FIRST so its shard children stop LAST — they must
         # outlive the consumer loops to publish the final settles.
         self.egress: Optional[EgressStage] = None
-        if self.model_name and egress_fused(tenant, self.runtime):
+        if self.model_name:
             self.egress = EgressStage(
-                self, lanes=egress_lanes(tenant, self.runtime),
-                autotune=egress_autotune(tenant, self.runtime),
-                max_lanes=egress_max_lanes(tenant, self.runtime))
+                self, lanes=egress_lanes(tenant, self.runtime))
             for shard in self.egress.shards:
                 self.add_child(shard)
-        self.scored_sink = (self.egress if self.egress is not None
-                            else self._deliver_scored)
         # clean-handoff commit-through (docs/FLEET.md): lane loops
         # cancelled by an engine stop stash their consumers here
         # instead of closing them; _do_stop commits their delivered
@@ -263,12 +255,12 @@ class RuleProcessingEngine(TenantEngine):
                 self.mesh_spec)
             self.pool_slot = pool.register(
                 self.tenant_id, em.telemetry, self.scoring_cfg.threshold,
-                self.scored_sink)
+                self.egress)
         else:
             model = build_model(self.model_name, **self.model_config)
             self.session = ScoringSession(
                 model, em.telemetry, self.runtime.metrics, self.scoring_cfg,
-                sink=self.scored_sink, tracer=self.runtime.tracer,
+                sink=self.egress, tracer=self.runtime.tracer,
                 faults=self.runtime.faults)
 
     async def _do_start(self, monitor) -> None:
@@ -357,32 +349,13 @@ class RuleProcessingEngine(TenantEngine):
         elif shed == "degrade":
             scored = self.degraded_score(batch)
             flow.count_shed(self.tenant_id, "degrade", len(batch))
-            await self.scored_sink(scored)
+            await self.egress(scored)
         else:
             sink.admit(batch)
 
-    async def _deliver_scored(self, scored: ScoredBatch) -> None:
-        """LEGACY inline sink (`egress: {fused: false}`, the A/B
-        baseline): publish scored events + emit anomaly alerts right on
-        the settle path. The fused default routes through the
-        EgressStage instead (kernel/egresslane.py), which publishes and
-        emits alerts on supervised shard loops off the flush path."""
-        t0 = time.monotonic()
-        await self.runtime.bus.produce(
-            self.tenant_topic(TopicNaming.SCORED_EVENTS), scored,
-            key=scored.ctx.source, fence=self.fence_token())
-        # same stage name as the fused EgressStage records: traces stay
-        # comparable across the inline and fused egress configurations
-        self.runtime.tracer.record(
-            scored.ctx.trace_id, "egress.publish", self.tenant_id,
-            t0, time.monotonic() - t0, len(scored))
-        if self.emit_alerts and scored.is_anomaly.any():
-            em = self.runtime.api("event-management").management(self.tenant_id)
-            em.add_alert_batch(anomaly_alerts(scored, self.model_name))
-
     def build_anomaly_alerts(self, scored: ScoredBatch) -> AlertBatch:
         """The egress stage's alert builder (one place owns the
-        model-name attribution for both the inline and fused sinks)."""
+        model-name attribution)."""
         return anomaly_alerts(scored, self.model_name)
 
     # -- extension points --------------------------------------------------
@@ -538,7 +511,7 @@ class RuleProcessor(BackgroundTaskComponent):
         deferred_consumer = None
         # checkpointed commit state: (dispatch_count at snapshot, positions)
         ckpt: Optional[tuple[int, dict]] = None
-        # the commit barrier composes the scoring sink with the fused
+        # the commit barrier composes the scoring sink with the
         # egress stage (kernel/egresslane.py): offsets commit only once
         # settles have PUBLISHED, not merely settled
         barrier = commit_barrier(sink, engine.egress)

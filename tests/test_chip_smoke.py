@@ -1,7 +1,7 @@
 """chip_smoke.py's body, tiny, on the conftest's 8 virtual CPU devices —
 the control flow the chip check runs at full size — plus the bring-up
-contracts that have no chip in them: the compile-cache helper, the
-platform refusals of chip_smoke.py and bench.py.
+contracts that have no chip in them: the compile-cache helper,
+chip_smoke.py's refusals of a platform and of a chip without a peak.
 
 The other two no-fallback contracts are pinned where their subjects
 already had tests: `mesh_from_spec` raising on a spec that does not fit
@@ -10,20 +10,21 @@ fails raising out of the ring (tests/test_pallas.py).
 """
 
 import os
-from types import SimpleNamespace
 
 import jax
 import pytest
 
-import bench
 import chip_smoke
 from sitewhere_tpu.utils.backend import use_compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# a tick every 0.25 s: at 64 devices a tenant's overload bar is two
+# ticks of backlog, so a shorter period turns any hiccup of a loaded
+# test machine into a frame shed at ingress (seen at 0.05 s)
 TINY = chip_smoke.Sizes(
     devices=64, ticks=6, pool_tenants=2, pool_devices=64, kernel_bucket=256,
-    anomaly_rate=0.05, interval_s=0.05, dsv3_devices=32, dsv3_config=dict(
+    anomaly_rate=0.05, interval_s=0.25, dsv3_devices=32, dsv3_config=dict(
         hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
         num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=4,
         q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
@@ -92,14 +93,15 @@ def test_smoke_refuses_the_wrong_platform():
     assert exc.value.code not in (0, None)
 
 
-def test_bench_refuses_cpu_without_force_cpu():
-    with pytest.raises(RuntimeError, match="needs a TPU"):
-        bench._require_tpu(SimpleNamespace(force_cpu=False), "cpu")
-    bench._require_tpu(SimpleNamespace(force_cpu=True), "cpu")
-    bench._require_tpu(SimpleNamespace(force_cpu=False), "tpu")
-    # the peak lookup chip_smoke gates on knows the v5e as JAX names it
-    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
-    assert bench.peak_bf16_flops("cpu") is None
+def test_smoke_reads_the_peak_from_the_benchmarks_table():
+    # the v5e as JAX names it, from benchmarks/peaks.json
+    assert chip_smoke.peak_bf16_flops("TPU v5 lite") == 197e12
+    chip_smoke.require_peak("TPU v5 lite")
+    # a device the table does not know stops the smoke before any phase
+    assert chip_smoke.peak_bf16_flops("cpu") is None
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.require_peak("cpu")
+    assert exc.value.code == 2
 
 
 def test_compile_cache_env_wins(monkeypatch, tmp_path):

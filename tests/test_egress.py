@@ -1,15 +1,13 @@
 """Sharded egress fast lanes (kernel/egresslane.py): ISSUE 6's
 acceptance tests.
 
-- wiring/config: the fused egress stage engages by default, the tenant
-  `egress: {fused, lanes}` section pins it either way, and `lanes`
-  shards BOTH the egress stage and the consumer lanes.
+- wiring/config: every engine with a model builds its egress stage, and
+  the tenant `egress: {lanes}` section shards BOTH the egress stage and
+  the consumer lanes.
 - lane-count equivalence: `lanes=1` vs `lanes=4` runs of the same event
   sequence produce identical scored events, persisted telemetry,
   alerts, and committed offsets — shard count changes concurrency,
   never behavior.
-- egress-fusion equivalence: fused vs legacy-inline sink produce
-  identical outputs (the A/B lever measures speed, not semantics).
 - alert emission off the flush path: counted (`rules.alerts_emitted`),
   and an alert-path failure can never block a scoring flush.
 - chaos: `egress.publish` faults quarantine the scored batch to the
@@ -48,7 +46,7 @@ RULE = {"model": "zscore", "model_config": {"window": 16},
 async def egress_runtime(num_devices=32, fastlane=None, egress=None,
                          faults=None, instance_id="eg"):
     """Full pipeline runtime with tenant 'acme'; `egress` is the tenant
-    `egress:` section ({fused, lanes}), `fastlane` pins the ingress
+    `egress:` section ({lanes}), `fastlane` pins the ingress
     lane via its override (None = auto-detection)."""
     rt = ServiceRuntime(InstanceSettings(instance_id=instance_id))
     for cls in (DeviceManagementService, EventSourcesService,
@@ -138,7 +136,7 @@ async def _drive(rt, n_sim=48, ticks=12, anomaly_rate=0.05):
 
 def test_egress_wiring_and_lane_config(run):
     async def main():
-        # fused by default, 1 lane; session sink IS the stage
+        # 1 lane by default; session sink IS the stage
         async with egress_runtime(instance_id="eg-w1") as rt:
             eng = rt.api("rule-processing").engine("acme")
             assert eng.egress is not None and eng.egress.lanes == 1
@@ -153,12 +151,6 @@ def test_egress_wiring_and_lane_config(run):
             assert len(eng.egress.shards) == 4
             assert len(eng.fastlanes) == 4
             assert len({lane.name for lane in eng.fastlanes}) == 4
-        # fused: false pins the legacy inline sink (the A/B baseline)
-        async with egress_runtime(egress={"fused": False},
-                                  instance_id="eg-wo") as rt:
-            eng = rt.api("rule-processing").engine("acme")
-            assert eng.egress is None
-            assert eng.session.sink == eng._deliver_scored
         # lanes also shard the STAGED lane's consumers
         async with egress_runtime(fastlane=False, egress={"lanes": 3},
                                   instance_id="eg-ws") as rt:
@@ -192,26 +184,6 @@ def test_lane_count_equivalence(run):
             assert scored_4[key] == val, key
         assert alerts_1 == alerts_4 and alerts_1  # anomalies exist
         assert committed_1 == committed_4 > 0
-
-    run(main())
-
-
-def test_egress_fusion_equivalence(run):
-    """Fused egress vs the legacy inline sink: identical outputs (the
-    bench A/B lever changes the mechanism, not the results)."""
-    async def main():
-        async with egress_runtime(egress={"fused": True, "lanes": 2},
-                                  instance_id="eg-on") as rt:
-            fused = await _drive(rt)
-            snap = rt.metrics.snapshot()
-            assert snap.get("egress.publish_failures", 0) == 0
-        async with egress_runtime(egress={"fused": False},
-                                  instance_id="eg-off") as rt:
-            inline = await _drive(rt)
-        assert fused[0] == inline[0]
-        assert fused[1] == inline[1]
-        assert fused[2] == inline[2]
-        assert fused[3] == inline[3]
 
     run(main())
 

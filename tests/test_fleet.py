@@ -12,7 +12,7 @@ supervisor.
 Topology: in-proc — N worker ServiceRuntimes (fleet_managed) share ONE
 EventBus with a driver runtime hosting event-sources and the
 controller. Same protocol, same records, same consumer groups as the
-multi-process deployment (bench.py --workers); only the process
+multi-process deployment (`swx fleet-worker`); only the process
 boundary is collapsed. HERMETIC since the fencing PR: tenant registry
 state is seeded onto the shared bus (registry-state topic,
 services/replication.py) and every worker adopts from bus replay —

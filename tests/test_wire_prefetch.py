@@ -332,7 +332,9 @@ def test_ff_inflight_cap_backpressure_gated_broker(run):
         await wait_until(lambda: bus.end_offsets("t") == [100],
                          timeout=10.0)
         await wait_until(lambda: not remote.backlogged, timeout=5.0)
-        assert client.ff_pending == 0
+        # the signal clears below the cap; the last acks may still be
+        # on their way when it does
+        await wait_until(lambda: client.ff_pending == 0, timeout=5.0)
         await remote.stop()
         await server.stop()
 
@@ -381,7 +383,7 @@ def test_egress_barrier_surfaces_wire_backpressure():
     stage.engine = type("E", (), {"runtime": _Runtime()})()
     stage.submitted = 0
     stage.accounted = 0
-    stage.active = 1
+    stage.shards = [None]
     assert stage.backlogged is True
     _Bus.backlogged = False
     assert stage.backlogged is False
