@@ -128,6 +128,9 @@ COUNTERS = (
     "scoring.sink_failures",
     "scoring.bus_records_lost",
     "scoring.dispatches",
+    # dispatches whose ids arrived ascending, so that no host sort ran
+    # (scoring/stream.py, "Contract with the engines")
+    "scoring.ring.ascending",
     # what a step of a model with routed experts and window leaves
     # returns beside its scores (models/dsv3.py `step_stats`)
     "scoring.moe.assignments_held",

@@ -166,6 +166,10 @@ class LstmAnomalyModel:
         return se.sum() / jnp.maximum(mask.sum(), 1.0)
 
 
+# the per-row scalars of `StreamingLstmModel`'s row leaf, in lane order
+_ROW_SCALARS = ("pred", "mean", "var", "count")
+
+
 class StreamingLstmModel(LstmAnomalyModel):
     """Event-native streaming twin of the windowed LSTM scorer.
 
@@ -189,36 +193,55 @@ class StreamingLstmModel(LstmAnomalyModel):
     `score`/`loss` (whole-window paths: query/REST, training) are
     inherited unchanged — only the resident hot path differs.
 
-    State leaves: `pred`, `mean`, `var` (f32 `[rows]`), `count` (i32
-    `[rows]`) and ONE leaf `hc` for the recurrent state of all layers,
-    f32 `[rows, round_up(2 * hidden * layers, 128)]`, columns in the
-    order `h0 ‖ c0 ‖ h1 ‖ c1 ...` then zero padding that is never read.
-    Whole 128-lane tiles in the minor dimension keep the table row-major
-    at rest, so the ring scatters rows into its donated buffer in place
-    (scoring/stream.py, "Contract with the model").
+    State: ONE leaf `row`, f32 `[rows, k, 128]` with `k * 128 =
+    round_up(2 * hidden * layers + 4, 128)` values a row: `h0 ‖ c0 ‖ h1 ‖
+    c1 ...`, then the four per-row scalars `pred`, `mean`, `var`, `count`
+    (`scalars` names them; `count` is a whole number in a float32 lane,
+    capped at the window and exact far past it), then zero padding that
+    is never read. One leaf is one row gather and one row scatter a step,
+    where each scalar leaf of its own cost a gather and a scatter over
+    the whole fleet. `[rows, k, 128]` and not `[rows, k * 128]` on a
+    reading alone: the same 1 KB a row at rest at the served widths
+    (of which 528 B are used), and a v5e's compiler scatters 16,384 rows
+    into the first in 1.03 ms, into the second in 1.45 (PERF.md section
+    6, PR 31).
     """
 
     name = "lstm-stream"
     streaming = True
 
     def _hc_width(self) -> int:
-        return -(-2 * self.cfg.hidden * self.cfg.layers // 128) * 128
+        """Values of `h` and `c` of all layers: where the scalars start."""
+        return 2 * self.cfg.hidden * self.cfg.layers
 
-    def _hc_join(self, parts: list) -> jax.Array:
-        """`[h0, c0, h1, c1, ...]`, each `[B, hidden]` → `[B, width]`."""
-        pad = self._hc_width() - len(parts) * self.cfg.hidden
+    def _row_tiles(self) -> int:
+        return -(-(self._hc_width() + len(_ROW_SCALARS)) // 128)
+
+    def _row_join(self, parts: list, pred, mean, var, count) -> jax.Array:
+        """`[h0, c0, h1, c1, ...]`, each `[B, hidden]`, and the four
+        scalars `[B]` → `[B, k, 128]`."""
+        parts = parts + [jnp.stack([pred, mean, var, count], axis=-1)]
+        pad = (self._row_tiles() * 128 - self._hc_width()
+               - len(_ROW_SCALARS))
         if pad:
-            parts = parts + [jnp.zeros((parts[0].shape[0], pad), jnp.float32)]
-        return jnp.concatenate(parts, axis=-1)
+            parts.append(jnp.zeros((parts[0].shape[0], pad), jnp.float32))
+        return jnp.concatenate(parts, axis=-1).reshape(
+            -1, self._row_tiles(), 128)
+
+    def scalars(self, row: jax.Array) -> dict:
+        """The per-row scalars of `row` `[..., k, 128]` by name."""
+        flat = row.reshape(row.shape[:-2] + (-1,))
+        at = self._hc_width()
+        return {name: flat[..., at + i]
+                for i, name in enumerate(_ROW_SCALARS)}
 
     def init_state(self, cap: int) -> dict:
         """Zero per-device streaming state for `cap` rows (callers add
         their own scratch row before passing a capacity here)."""
-        return {"pred": jnp.zeros(cap, jnp.float32),
-                "mean": jnp.zeros(cap, jnp.float32),
-                "var": jnp.ones(cap, jnp.float32),
-                "count": jnp.zeros(cap, jnp.int32),
-                "hc": jnp.zeros((cap, self._hc_width()), jnp.float32)}
+        tiles = self._row_tiles()
+        row = jnp.zeros((cap, tiles * 128), jnp.float32).at[
+            :, self._hc_width() + _ROW_SCALARS.index("var")].set(1.0)
+        return {"row": row.reshape(cap, tiles, 128)}
 
     def _cell(self, params: dict, layer: int, x: jax.Array,
               h: jax.Array, c: jax.Array):
@@ -236,15 +259,16 @@ class StreamingLstmModel(LstmAnomalyModel):
     def step_score(self, params: dict, rows: dict, v: jax.Array):
         """Score + advance gathered state rows for one event each.
 
-        rows: state leaves indexed down to the event batch ([B] /
-        [B, width]); v: [B] raw values. Returns (scores [B], new rows)."""
+        rows: the state leaf indexed down to the event batch
+        ([B, k, 128]); v: [B] raw values. Returns (scores [B], new rows)."""
         cfg = self.cfg
         hid = cfg.hidden
-        mean, var, cnt = rows["mean"], rows["var"], rows["count"]
+        pred, mean, var, cnt = self.scalars(rows["row"]).values()
+        row = rows["row"].reshape(v.shape[0], -1)
         sd = jnp.sqrt(var + 1e-6)
         xn = (v - mean) / sd
         enough = cnt >= max(8, cfg.window // 8)
-        score = jnp.clip(jnp.where(enough, jnp.abs(xn - rows["pred"]), 0.0),
+        score = jnp.clip(jnp.where(enough, jnp.abs(xn - pred), 0.0),
                          0.0, cfg.score_clip)
         # capped-count Welford: behaves like the window-W mean/std once
         # count saturates (the streaming analog of _normalize)
@@ -253,19 +277,17 @@ class StreamingLstmModel(LstmAnomalyModel):
         mean1 = mean + delta / cnt1
         var1 = var + ((v - mean1) * delta - var) / cnt1
         x = ((v - mean1) / jnp.sqrt(var1 + 1e-6))[:, None]
-        out = dict(rows)
-        out["mean"], out["var"], out["count"] = mean1, var1, cnt1
-        hc, parts = rows["hc"], []
+        parts = []
         for layer in range(cfg.layers):
             at = 2 * layer * hid
-            h, c = self._cell(params, layer, x, hc[:, at:at + hid],
-                              hc[:, at + hid:at + 2 * hid])
+            h, c = self._cell(params, layer, x, row[:, at:at + hid],
+                              row[:, at + hid:at + 2 * hid])
             parts += [h, c]
             x = h
-        out["hc"] = self._hc_join(parts)
         head = params["head"]
-        out["pred"] = (x @ head["w"] + head["b"])[:, 0]
-        return score, out
+        pred1 = (x @ head["w"] + head["b"])[:, 0]
+        return score, {"row": self._row_join(parts, pred1, mean1, var1,
+                                             cnt1)}
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:
         """Build streaming state for `n` devices by replaying their host
@@ -279,21 +301,17 @@ class StreamingLstmModel(LstmAnomalyModel):
         mean = (x * v).sum(-1) / n
         var = (((x - mean[:, None]) * v) ** 2).sum(-1) / n
         xn = ((x - mean[:, None]) / jnp.sqrt(var + 1e-6)[:, None]) * v
-        state = self.init_state(x.shape[0])
         seq, parts = xn[:, :, None], []
         for layer in range(cfg.layers):
             seq, (h, c) = lstm_scan(params[f"lstm{layer}"], seq,
                                     cfg.compute_dtype)
             seq = seq.astype(cfg.compute_dtype)
             parts += [h, c]
-        state["hc"] = self._hc_join(parts)
         head = params["head"]
         pred = (seq[:, -1, :].astype(jnp.float32) @ head["w"] + head["b"])[:, 0]
-        state["pred"] = pred
-        state["mean"] = mean
-        state["var"] = jnp.maximum(var, 1e-6)
-        state["count"] = jnp.minimum(v.sum(-1).astype(jnp.int32), cfg.window)
-        return state
+        return {"row": self._row_join(
+            parts, pred, mean, jnp.maximum(var, 1e-6),
+            jnp.minimum(v.sum(-1), float(cfg.window)))}
 
     def flops_per_event(self) -> float:
         """One cell step per event (vs a W-1-step rescan)."""

@@ -309,6 +309,8 @@ class SharedScoringPool:
         # stack_rebuilds surfaces capacity growths (each = a recompile
         # round behind the warmup gate)
         self.dispatches = metrics.counter("scoring.dispatches")
+        # dispatches whose every take arrived ascending: no host sort
+        self.ascending = metrics.counter("scoring.ring.ascending")
         self.megabatch_dispatches = metrics.counter(
             "scoring.megabatch_dispatches")
         self.megabatch_tenants = metrics.histogram(
@@ -539,8 +541,7 @@ class SharedScoringPool:
                 # shapes the hot path will hit
                 for b in (self.stack.pad_batch(b0)
                           for b0 in self.cfg.batch_buckets):
-                    dev = np.full((self.ring.t_cap, b), self.ring.device_cap,
-                                  np.int32)
+                    dev = np.tile(self.ring.padding(b), (self.ring.t_cap, 1))
                     v = np.zeros((self.ring.t_cap, b), np.float32)
                     if getattr(self.ring, "sparse", False):
                         out = self.ring.update_and_score(
@@ -898,34 +899,35 @@ class SharedScoringPool:
             self._pending_max = -1
         if not takes:
             return
-        t_cap, d_cap = self.ring.t_cap, self.ring.device_cap
+        t_cap = self.ring.t_cap
 
         # split every tenant's take into occurrence rounds
         # meta: (tid, slot, n, dev, ts, ing, traces, ev_rounds, ctx,
         #        version-at-dispatch)
         metas = []
         round_parts: list[list[tuple[int, np.ndarray, np.ndarray]]] = []
+        ascending = True     # until a take has to be sorted
         for tid, (dev, val, ts, ing, traces, ctx) in takes.items():
             slot = self.stack.slots[tid]
             n = dev.shape[0]
             ev_rounds = []
-            # O(n) duplicate-free fast path before the O(n log n)
-            # unique/argsort split: a strictly-ascending take (the
-            # replay engine's rank-round chunks; near-sequential
-            # simulator ids) needs no occurrence split at all
+            # O(n) fast path before the O(n log n) argsort/unique split:
+            # a strictly-ascending take (the replay engine's rank-round
+            # chunks; a gateway's frame) is one round as it stands. Any
+            # other is sorted, for the streaming ring wants every round
+            # ascending (scoring/stream.py, "Contract with the
+            # engines"): one round still where no id repeats
             if n < 2 or bool((dev[1:] > dev[:-1]).all()):
                 parts = [(dev, val, None)]
             else:
+                ascending = False
                 order = np.argsort(dev, kind="stable")
                 sd, sv = dev[order], val[order]
                 _, start, cnts = np.unique(sd, return_index=True,
                                            return_counts=True)
-                if int(cnts.max()) == 1:
-                    parts = [(dev, val, None)]
-                else:
-                    cum = np.arange(n) - np.repeat(start, cnts)
-                    parts = [(sd[cum == r], sv[cum == r], order[cum == r])
-                             for r in range(int(cum.max()) + 1)]
+                cum = np.arange(n) - np.repeat(start, cnts)
+                parts = [(sd[cum == r], sv[cum == r], order[cum == r])
+                         for r in range(int(cum.max()) + 1)]
             for r, (rdev, rval, rpos) in enumerate(parts):
                 while len(round_parts) <= r:
                     round_parts.append([])
@@ -941,7 +943,7 @@ class SharedScoringPool:
                     "rule-processing.score.enqueue") as enqueue:
                 for parts in round_parts:
                     b = self._bucket_for(max(p[1].shape[0] for p in parts))
-                    dev_in = np.full((t_cap, b), d_cap, np.int32)  # scratch
+                    dev_in = np.tile(self.ring.padding(b), (t_cap, 1))
                     val_in = np.zeros((t_cap, b), np.float32)
                     for slot, rdev, rval in parts:
                         dev_in[slot, :rdev.shape[0]] = rdev
@@ -959,6 +961,8 @@ class SharedScoringPool:
             self._recover_ring()
             return
         self.dispatches.inc(len(dispatches))
+        if ascending:
+            self.ascending.inc()
         self.megabatch_dispatches.inc(len(dispatches))
         self.megabatch_tenants.observe(float(len(metas)))
         self._tune_window(len(metas))

@@ -287,9 +287,14 @@ class StackedDeviceRing:
 
         return jax.jit(jax.vmap(tenant_step), donate_argnums=(1, 2, 3))
 
+    def padding(self, bucket: int) -> np.ndarray:
+        """What a tenant row of `bucket` slots holds where it has no
+        event: the scratch row."""
+        return np.full(bucket, self.device_cap, np.int32)
+
     def _pad(self, dev: np.ndarray, v: np.ndarray) -> tuple:
         """dev/v are already [T_cap, B]; host fills padding with
-        device_cap (the scratch row) before calling. Placement shards
+        `padding` (the scratch row) before calling. Placement shards
         them over the mesh (tenant→model, batch→data) when one exists."""
         return (self._place_in(dev), self._place_in(v))
 

@@ -184,6 +184,8 @@ class ScoringSession:
         # `scoring.dispatches` is the instance-wide dispatch rate in
         # both operating modes.
         self.dispatches = metrics.counter("scoring.dispatches")
+        # takes whose ids arrived ascending: dispatched with no host sort
+        self.ascending = metrics.counter("scoring.ring.ascending")
         # end-to-end latency decomposition (one observation per batch or
         # per flush — negligible overhead, and the p99 stops being a
         # single opaque number):
@@ -536,9 +538,13 @@ class ScoringSession:
         n = dev.shape[0]
         dev = dev.astype(np.int32, copy=False)
         self.ring.ensure_capacity(int(dev.max()))
-        counts = np.unique(dev, return_counts=True)[1]
-        if counts.max() == 1:
+        # the ring wants each round's ids strictly ascending
+        # (scoring/stream.py, "Contract with the engines"): a take that
+        # arrives so (a gateway's frame) is one round as it stands, any
+        # other is sorted, which a take without repeats leaves one round
+        if n < 2 or bool((dev[1:] > dev[:-1]).all()):
             rounds = [(dev, val, None)]  # identity mapping
+            self.ascending.inc()
         else:
             order = np.argsort(dev, kind="stable")
             sd, sv = dev[order], val[order]

@@ -22,6 +22,25 @@ A leaf of two or more dimensions declares a minor dimension of whole
 a `[rows, 64]` leaf rests column-major on a TPU, and each step copied
 both tables to row-major and back round their row scatters, 4.8 of a
 6.2 ms step over 524,289 rows on a v5e (PERF.md section 6, PR 27).
+Per-row scalars of a fleet-sized table belong in the row leaf: a
+one-dimensional leaf costs a gather and a scatter of its own, 0.12 to
+0.39 and 0.08 ms at 16,384 of 524,289 rows (PERF.md section 6, PR 31).
+A row of more than one tile is better declared `[rows, k, 128]` than
+`[rows, k * 128]`: the compiler's row scatter of 16,384 rows took 1.03
+ms into the first and 1.45 into the second (the same bytes at rest).
+
+Contract with the engines: the rows of a step, `dev`, ascend strictly,
+padding included. Padding takes the indices past the table's last row,
+one each (`pad_rows`): the gather clips them onto the scratch row, the
+scatter drops them, so a step writes the rows it was given and no
+other. Every scatter says `unique_indices` (`DISTINCT_ROWS`): an
+engine that names a row twice in one step gets a table that no test of
+the step alone would catch. Both engines sort a take that does not
+ascend and split one with repeats into rounds
+(`ScoringSession._dispatch`, `SharedScoringPool`). That the rows ascend
+is said to the gathers only: told so, a v5e's compiler made the row
+scatter a sixth slower and a context append thirty times (PERF.md
+section 6, PR 31).
 
 A model may declare WINDOW leaves, `windows = {leaf: position leaf}`: a
 bounded window `[rows, positions, width]` a row. The step gathers the
@@ -54,39 +73,52 @@ import numpy as np
 
 from sitewhere_tpu.utils import grow_pow2
 
+# how every scatter of the ring writes: padding lies past the table and
+# is dropped, and no row is named twice ("Contract with the engines")
+DISTINCT_ROWS = dict(mode="drop", unique_indices=True)
+
+
+def pad_rows(scratch: int, n: int) -> np.ndarray:
+    """`n` padding indices for a table whose scratch row is `scratch`:
+    past the last row, ascending, one each."""
+    return np.arange(scratch + 1, scratch + 1 + n, dtype=np.int32)
+
 
 def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     """The ring step's three parts, under the `jax.named_scope`s a
     profile shows them by: rows of `dev` out of the table, one cell step
-    on them, the new rows back (padding lands in the scratch row). A
-    window leaf takes one entry a row, at the row's own position.
-    -> (state, scores, the step's numbers or None)."""
+    on them, the new rows back (padding reads the scratch row and writes
+    nothing). A window leaf takes one entry a row, at the row's own
+    position. -> (state, scores, the step's numbers or None)."""
     windows = getattr(model, "windows", None)
     with jax.named_scope("ring_gather"):
-        rows = jax.tree.map(lambda leaf: leaf[dev], state)
+        rows = jax.tree.map(
+            lambda leaf: leaf.at[dev].get(mode="clip",
+                                          indices_are_sorted=True), state)
     stats = None
     with jax.named_scope("cell_step"):
         if windows is None:
             scores, new_rows = model.step_score(params, rows, v)
         else:
             scores, new_rows, stats = model.step_score(
-                params, rows, v, live=dev != scratch)
+                params, rows, v, live=dev < scratch)
     if windows is None:
         with jax.named_scope("ring_scatter"):
             state = jax.tree.map(
                 lambda leaf, rows_new: leaf.at[dev].set(rows_new,
-                                                        mode="drop"),
+                                                        **DISTINCT_ROWS),
                 state, new_rows)
         return state, scores, stats
     out = {}
     with jax.named_scope("ctx_append"):
         for name, at in windows.items():
             out[name] = state[name].at[dev, rows[at]].set(new_rows[name],
-                                                          mode="drop")
+                                                          **DISTINCT_ROWS)
     with jax.named_scope("ring_scatter"):
         for name, leaf in state.items():
             if name not in windows:
-                out[name] = leaf.at[dev].set(new_rows[name], mode="drop")
+                out[name] = leaf.at[dev].set(new_rows[name],
+                                             **DISTINCT_ROWS)
     return out, scores, stats
 
 
@@ -103,7 +135,7 @@ def streaming_step(model, out_dtype=None) -> Callable:
     upcasts on assignment into its float32 result array."""
 
     def step(params, state, dev, v):
-        # the scratch row is the table's last: padding is sent there
+        # the scratch row is the table's last: padding lies past it
         scratch = jax.tree.leaves(state)[0].shape[0] - 1
         state, scores, stats = _gather_step_scatter(model, params, state,
                                                     dev, v, scratch)
@@ -144,9 +176,9 @@ def streaming_step_sparse(model, k: int,
         state, scores, _ = _gather_step_scatter(model, params, state, dev,
                                                 v, scratch_index)
         with jax.named_scope("sparse_topk"):
-            # scratch-row padding must never report: its state absorbs
-            # arbitrary writes, so its score is garbage by design
-            is_anom = (scores >= threshold) & (dev != scratch_index)
+            # padding must never report: it reads the scratch row, whose
+            # score means nothing
+            is_anom = (scores >= threshold) & (dev < scratch_index)
             n_anom = is_anom.sum().astype(jnp.int32)
             masked = jnp.where(is_anom, scores, -jnp.inf)
             top_scores, top_pos = jax.lax.top_k(masked, k)
@@ -192,7 +224,7 @@ def sparse_take(n_anom, pos, vals,
 
 class StreamingRing:
     """Per-device streaming model state for up to `capacity` devices,
-    plus one scratch row (index `capacity`) that absorbs padding."""
+    plus one scratch row (index `capacity`) that padding reads."""
 
     def __init__(self, model, capacity: int = 1024,
                  initial_floor: int = 1024, score_dtype=None,
@@ -211,11 +243,11 @@ class StreamingRing:
         # closure is new each time), which put seconds of compile on the
         # event loop at each reload and each hot-swap on the chip
         self._warm_state = jax.jit(model.warm_state)
-        # seeded rows into the donated table, any rows: the table is
-        # never copied for a block of them
+        # seeded rows into the donated table, any ascending rows: the
+        # table is never copied for a block of them
         self._put = jax.jit(
             lambda state, seeded, rows: jax.tree.map(
-                lambda leaf, new: leaf.at[rows].set(new, mode="drop"),
+                lambda leaf, new: leaf.at[rows].set(new, **DISTINCT_ROWS),
                 state, seeded), donate_argnums=(0,))
         self.faulted = False
         self.state = jax.device_put(model.init_state(self.capacity + 1))
@@ -256,10 +288,11 @@ class StreamingRing:
 
     def load(self, values: np.ndarray, count: np.ndarray,
              rows: Optional[np.ndarray] = None) -> None:
-        """Seed `rows` (the first `n` where none are named) by replaying
-        host windows (`TelemetryStore.window` layout: chronological,
-        left-padded), `model.seed_rows` of them a call where the model
-        says how many its seeding can take at once."""
+        """Seed `rows` (strictly ascending; the first `n` where none are
+        named) by replaying host windows (`TelemetryStore.window`
+        layout: chronological, left-padded), `model.seed_rows` of them a
+        call where the model says how many its seeding can take at
+        once."""
         n, w = values.shape
         assert w == self.window
         if rows is None:
@@ -278,11 +311,10 @@ class StreamingRing:
             x, ok, at = (values[lo:lo + block], valid[lo:lo + block],
                          rows[lo:lo + block])
             short = block - x.shape[0]
-            if short:            # one compiled shape; padding -> scratch
+            if short:            # one compiled shape; padding is dropped
                 x = np.concatenate([x, np.zeros((short, w), x.dtype)])
                 ok = np.concatenate([ok, np.zeros((short, w), bool)])
-                at = np.concatenate([at, np.full(short, self.capacity,
-                                                 at.dtype)])
+                at = np.concatenate([at, pad_rows(self.capacity, short)])
             seeded = self._warm_state(params, jnp.asarray(x, jnp.float32),
                                       jnp.asarray(ok))
             self.state = self._put(self.state, seeded,
@@ -324,16 +356,17 @@ class StreamingRing:
     def _pad(self, dev: np.ndarray, v: np.ndarray,
              bucket: int) -> tuple[np.ndarray, np.ndarray]:
         n = dev.shape[0]
-        out_dev = np.full(bucket, self.capacity, np.int32)  # scratch row
+        out_dev = np.empty(bucket, np.int32)
         out_v = np.zeros(bucket, np.float32)
         out_dev[:n] = dev
+        out_dev[n:] = pad_rows(self.capacity, bucket - n)
         out_v[:n] = v
         return out_dev, out_v
 
     def update_and_score(self, model, params, dev: np.ndarray,
                          v: np.ndarray, bucket: int) -> jax.Array:
-        """Advance + score one event per row of `dev` (unique ids!);
-        returns `[bucket]` scores on device (async)."""
+        """Advance + score one event per row of `dev` (strictly
+        ascending ids!); returns `[bucket]` scores on device (async)."""
         self._params = params
         if self._positions and dev.size:
             self._reseed_full(dev)
@@ -380,8 +413,8 @@ class StackedStreamingRing:
 
     over the tenant axis, donated in place: every tenant's events cost
     one cell step each (not a W-step window rescan), uploading only the
-    `[T_cap, B]` (device id, value) deltas. Padding lands in each
-    tenant's scratch row `D_cap`.
+    `[T_cap, B]` (device id, value) deltas. Padding reads each
+    tenant's scratch row `D_cap` and writes nothing.
 
     Seeding is per-tenant (`load_tenant`) because streaming state is a
     function of that tenant's WEIGHTS — the caller passes the tenant's
@@ -498,10 +531,16 @@ class StackedStreamingRing:
         return jax.jit(jax.vmap(streaming_step(self.model, self.score_dtype)),
                        donate_argnums=(1,))
 
+    def padding(self, bucket: int) -> np.ndarray:
+        """What a tenant row of `bucket` slots holds where it has no
+        event (`pad_rows`): a take's ids overwrite the head, the tail
+        that is left still ascends past them."""
+        return pad_rows(self.device_cap, bucket)
+
     def update_and_score(self, model, stacked_params, dev: np.ndarray,
                          v: np.ndarray, thresholds=None):
-        """dev: [T_cap, B] int32 (scratch-row-padded, unique ids per
-        tenant row!), v: [T_cap, B] float32 → [T_cap, B] scores on
+        """dev: [T_cap, B] int32 (each tenant row strictly ascending,
+        its tail `pad_rows`), v: [T_cap, B] float32 → [T_cap, B] scores on
         device (async); sparse mode returns per-tenant
         (n_anom[T], positions[T, k], scores[T, k]) and needs
         `thresholds` [T_cap] float32."""

@@ -51,7 +51,7 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert a["alerts"] >= 1 and a["compiles_after_warmup"] == 0
     assert a["feeder_exit"] == 0
     assert a["table_moves"] == []
-    assert sorted(a["state_layouts"]) == ["count", "hc", "mean", "pred", "var"]
+    assert sorted(a["state_layouts"]) == ["row"]
     assert b["ok"] and b["skipped"] == "pallas_ok false on cpu"
     assert c["mesh"] == {"data": 2, "model": 2}
     assert c["scored_per_tenant"] == [want]

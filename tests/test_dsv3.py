@@ -20,7 +20,11 @@ from sitewhere_tpu.models import build_model
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring import server
 from sitewhere_tpu.scoring.server import ScoringConfig, ScoringSession
-from sitewhere_tpu.scoring.stream import StreamingRing, streaming_step
+from sitewhere_tpu.scoring.stream import (
+    StreamingRing,
+    pad_rows,
+    streaming_step,
+)
 
 reference = models.load("dsv3-stream")
 
@@ -266,8 +270,8 @@ def test_window_leaf_is_appended_in_place(params):
     state = jax.tree.map(lambda leaf, rows: leaf.at[5:5 + D].set(rows),
                          state, seeded)
     before = jax.tree.map(np.asarray, state)
-    dev = np.full(16, cap, np.int32)             # padding -> scratch row
-    dev[:D] = np.arange(5, 5 + D)
+    dev = np.concatenate([np.arange(5, 5 + D, dtype=np.int32),
+                          pad_rows(cap, 16 - D)])   # padding: dropped
     v = np.zeros(16, np.float32)
     v[:D] = frames[0]
     compiled = step.lower(params, state, dev, v).compile()
@@ -282,10 +286,16 @@ def test_window_leaf_is_appended_in_place(params):
     for l in range(model.layers):
         changed = np.argwhere((np.asarray(state[f"ctx{l}"])
                                != before[f"ctx{l}"]).any(-1))
-        rows = {(5 + i, W) for i in range(D)} | {(cap, 0)}
-        assert {tuple(rc) for rc in changed} <= rows
-        assert {(5 + i, W) for i in range(D)} <= {tuple(rc) for rc in changed}
+        assert {tuple(rc) for rc in changed} == {(5 + i, W)
+                                                 for i in range(D)}
     assert (np.asarray(state["pos"])[5:5 + D] == W + 1).all()
+    # the padding read the scratch row and wrote it no more than any
+    # other row the step was not given
+    for name, leaf in state.items():
+        if name not in model.windows:
+            kept = np.ones(cap + 1, bool)
+            kept[5:5 + D] = False
+            assert (np.asarray(leaf)[kept] == before[name][kept]).all(), name
     text = compiled.as_text()
     for scope in ("ring_gather", "ctx_append", "ring_scatter", "mla_project",
                   "mla_attend", "moe_route", "moe_experts", "dense_mlp",
@@ -294,20 +304,24 @@ def test_window_leaf_is_appended_in_place(params):
 
 
 def test_lstm_stream_lowers_to_the_same_program_as_before():
-    """The ring's old contract written out (gather whole rows, step,
-    scatter whole rows back) against the code `lstm-stream` now goes
-    through: the same StableHLO, so its compiled step is unchanged."""
+    """The ring's contract for a model without window leaves written out
+    (gather whole rows that ascend, step, write whole distinct rows
+    back) against the code `lstm-stream` goes through beside
+    `dsv3-stream`: the same StableHLO, so the window leaves cost its
+    compiled step nothing."""
     model = build_model("lstm-stream", window=64, hidden=64)
 
     def old_step(params, state, dev, v):
         with jax.named_scope("ring_gather"):
-            rows = jax.tree.map(lambda leaf: leaf[dev], state)
+            rows = jax.tree.map(
+                lambda leaf: leaf.at[dev].get(
+                    mode="clip", indices_are_sorted=True), state)
         with jax.named_scope("cell_step"):
             scores, new_rows = model.step_score(params, rows, v)
         with jax.named_scope("ring_scatter"):
             state = jax.tree.map(
-                lambda leaf, rows_new: leaf.at[dev].set(rows_new,
-                                                        mode="drop"),
+                lambda leaf, rows_new: leaf.at[dev].set(
+                    rows_new, mode="drop", unique_indices=True),
                 state, new_rows)
         return state, scores.astype(jnp.float16)
 

@@ -2,7 +2,9 @@
 and not attached (the TPU's compiler is installed here), at the published
 widths with one leading and one expert layer and a small fleet: the
 compiled step copies and transposes no context leaf, which rests
-row-major in whole lane tiles. Nothing runs, so nothing here is a time.
+row-major in whole lane tiles; and the ring step of `lstm-stream` at
+`stream-512k`'s own size, which moves rows of ONE table. Nothing runs,
+so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
 test that needs it is in this one file (one process loads the TPU's
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 ROWS, BUCKET = 1025, 256
+FLEET_ROWS, FRAME = 524289, 16384       # `stream-512k`: the table, a frame
 
 
 @pytest.fixture(scope="module")
@@ -32,33 +35,42 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def step(one_chip):
-    """(model, state shapes, the ring step compiled for the described
-    chip): compiled once for every test of this file."""
-    from sitewhere_tpu.models import build_model
+def _compile_step(model, rows, bucket, one_chip, out_dtype):
+    """The ring step of `model` over a table of `rows` rows and a bucket
+    of `bucket`, compiled for the described chip: (state shapes, the
+    compiled step)."""
     from sitewhere_tpu.scoring.stream import streaming_step
-
-    model = build_model("dsv3-stream", num_hidden_layers=2,
-                        first_k_dense_replace=1, n_routed_experts_held=16,
-                        vocab_held=16160, mtp_modules=0)
 
     def described(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
     params = described(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    state = described(jax.eval_shape(lambda: model.init_state(ROWS)))
-    dev = jax.ShapeDtypeStruct((BUCKET,), jnp.int32, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((BUCKET,), jnp.float32, sharding=one_chip)
+    state = described(jax.eval_shape(lambda: model.init_state(rows)))
+    dev = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bucket,), jnp.float32, sharding=one_chip)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(streaming_step(model, jnp.float32),
+        compiled = jax.jit(streaming_step(model, out_dtype),
                            donate_argnums=(1,)).lower(
             params, state, dev, v).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
+    return state, compiled
+
+
+@pytest.fixture(scope="module")
+def step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip): compiled once for every test of this file."""
+    from sitewhere_tpu.models import build_model
+
+    model = build_model("dsv3-stream", num_hidden_layers=2,
+                        first_k_dense_replace=1, n_routed_experts_held=16,
+                        vocab_held=16160, mtp_modules=0)
+    state, compiled = _compile_step(model, ROWS, BUCKET, one_chip,
+                                    jnp.float32)
     return model, state, compiled
 
 
@@ -164,3 +176,37 @@ def test_every_expert_leaf_is_read_once_outside_any_loop(step):
         r"= bf16\[(?:7168,2048|2048,7168)\]\S* "
         r"(?:copy|slice|dynamic-slice)\(", line)]
     assert moved == []
+
+
+def test_lstm_stream_step_moves_rows_of_one_table(one_chip):
+    """`lstm-stream` at `stream-512k`'s size: the compiled step takes one
+    sorted gather from ONE table and writes it with one scatter, in
+    place; no leaf of a scalar a row is left, each of which cost a
+    gather and a scatter of its own over the whole fleet (PERF.md
+    section 6, PR 31); the table is neither copied nor transposed and
+    comes back in its own buffer."""
+    from chip_smoke import _table_moves
+    from sitewhere_tpu.models import build_model
+
+    model = build_model("lstm-stream", window=64, hidden=64)
+    state, compiled = _compile_step(model, FLEET_ROWS, FRAME, one_chip,
+                                    jnp.float16)
+    hlo = compiled.as_text()
+    assert _table_moves(hlo, FLEET_ROWS) == []
+    (leaf,) = jax.tree.leaves(state)
+    assert leaf.shape == (FLEET_ROWS, 2, 128)
+    shapes = set(re.findall(rf"\w+\[(?:\d+,)*{FLEET_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == {f"f32[{FLEET_ROWS},2,128]"}, shapes
+    # at rest one row a tile, 1 KB contiguous
+    assert f"f32[{FLEET_ROWS},2,128]{{2,1,0:T(2,128)}} parameter" in hlo
+    lines = hlo.splitlines()
+    gathers = [line for line in lines if " gather(" in line]
+    assert len(gathers) == 1 and f"= f32[{FRAME},2,128]" in gathers[0]
+    assert "indices_are_sorted=true" in gathers[0]
+    scatters = [line for line in lines if " scatter(" in line]
+    assert len(scatters) == 1 and f"f32[{FLEET_ROWS},2,128]" in scatters[0]
+    assert "unique_indices=true" in scatters[0]
+    assert "indices_are_sorted=true" not in scatters[0]
+    assert "tpu_custom_call" not in hlo
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= leaf.size * leaf.dtype.itemsize

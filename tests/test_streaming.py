@@ -22,6 +22,12 @@ from tests.test_pipeline import wait_until
 from tests.test_scoring import _fill_store
 
 
+def _scalar(ring, name):
+    """One of the per-row scalars the model keeps in its row leaf, for
+    every row of `ring` (dedicated `[rows]`, stacked `[tenants, rows]`)."""
+    return np.asarray(ring.model.scalars(ring.state["row"])[name])
+
+
 def _session(store, buckets=(256,), threshold=4.0, window=64):
     s = ScoringSession(
         build_model("lstm-stream", window=window), store, MetricsRegistry(),
@@ -103,7 +109,7 @@ def test_streaming_regrow_preserves_state(run):
         s.ring.ensure_capacity(cap0 + 10)
         assert s.ring.capacity > cap0
         # old rows kept their history count; fresh rows start cold
-        counts = np.asarray(s.ring.state["count"])
+        counts = _scalar(s.ring, "count")
         assert counts[:100].min() >= 8
         assert counts[cap0:cap0 + 5].max() == 0
         # still scores after the regrow
@@ -128,7 +134,7 @@ def test_streaming_fault_recovery_reloads_from_host(run):
         s.ring.faulted = True
         s._recover_ring()
         assert not s.ring.faulted
-        assert np.asarray(s.ring.state["count"])[:50].min() >= 8
+        assert _scalar(s.ring, "count")[:50].min() >= 8
         batch, _ = sim.tick(t=41 * 60.0)
         s.admit(batch)
         scored = await s.flush()
@@ -150,7 +156,7 @@ def test_streaming_swap_params_reseeds_state(run):
         sim = DeviceSimulator(SimConfig(num_devices=50, seed=5), tenant_id="t")
         _fill_store(store, sim, 70)
         s = _session(store)
-        old_pred = np.asarray(s.ring.state["pred"][:50]).copy()
+        old_pred = _scalar(s.ring, "pred")[:50].copy()
         new_params = s.model.init(jax.random.PRNGKey(99))
         s.swap_params(new_params)
         # reference: a session born with the new weights (identical
@@ -160,12 +166,11 @@ def test_streaming_swap_params_reseeds_state(run):
             build_model("lstm-stream", window=64), store, MetricsRegistry(),
             ScoringConfig(buckets=(256,)), params=new_params)
         fresh.warmup()
-        np.testing.assert_allclose(np.asarray(s.ring.state["pred"][:50]),
-                                   np.asarray(fresh.ring.state["pred"][:50]),
+        np.testing.assert_allclose(_scalar(s.ring, "pred")[:50],
+                                   _scalar(fresh.ring, "pred")[:50],
                                    atol=1e-5)
         # and it genuinely changed (old state would have been wrong)
-        assert np.abs(np.asarray(s.ring.state["pred"][:50])
-                      - old_pred).max() > 1e-3
+        assert np.abs(_scalar(s.ring, "pred")[:50] - old_pred).max() > 1e-3
         assert s.version == 1
         s.close()
         fresh.close()
@@ -173,7 +178,7 @@ def test_streaming_swap_params_reseeds_state(run):
     run(main())
 
 
-# -- the state's layout in the table: one leaf `hc` for h and c --------------
+# -- the state's layout in the table: one row leaf for h, c and the scalars ---
 
 WIDTHS = [(16, 1), (64, 1), (64, 2)]     # (hidden, layers)
 W, D, B, TICKS = 16, 40, 24, 24          # window, devices, bucket, ticks
@@ -236,12 +241,13 @@ def _plain_tick(model, params, st, dev, v):
 def test_step_over_hc_matches_a_loop_that_keeps_h_and_c_apart(
         hidden, layers, vmapped, start):
     """24 ticks through the ring's jitted step, cold and seeded, dedicated
-    and under `vmap`, against a plain loop with h and c of each layer in
-    arrays of their own: packing them into `hc` moves no value."""
+    and under `vmap`, against a plain loop with h and c of each layer and
+    every scalar in arrays of their own: packing them into one row leaf
+    moves no value."""
     import jax
     import jax.numpy as jnp
 
-    from sitewhere_tpu.scoring.stream import streaming_step
+    from sitewhere_tpu.scoring.stream import pad_rows, streaming_step
 
     model = build_model("lstm-stream", window=W, hidden=hidden,
                         layers=layers)
@@ -272,10 +278,11 @@ def test_step_over_hc_matches_a_loop_that_keeps_h_and_c_apart(
     else:
         state, stacked = states[0], params[0]
     for _ in range(TICKS):
-        # unique ids a tenant, the tail of the bucket on the scratch row
-        dev = np.full((tenants, B), D, np.int32)
+        # ascending ids a tenant, the tail of the bucket padding: past the
+        # table for the step (dropped), on the scratch row for the loop
+        dev = np.tile(pad_rows(D, B), (tenants, 1))
         for t in range(tenants):
-            dev[t, :B - 3] = rng.permutation(D)[:B - 3]
+            dev[t, :B - 3] = np.sort(rng.permutation(D)[:B - 3])
         v = rng.normal(20.0, 3.0, (tenants, B)).astype(np.float32)
         if vmapped:
             state, got = step(stacked, state, dev, v)
@@ -283,11 +290,29 @@ def test_step_over_hc_matches_a_loop_that_keeps_h_and_c_apart(
             state, got = step(stacked, state, dev[0], v[0])
             got = got[None]
         for t in range(tenants):
-            plains[t], want = plain(params[t], plains[t], dev[t], v[t])
+            plains[t], want = plain(params[t], plains[t],
+                                    np.minimum(dev[t], D), v[t])
             np.testing.assert_allclose(np.asarray(got[t, :B - 3]),
                                        np.asarray(want[:B - 3]),
                                        rtol=1e-6, atol=1e-6)
     assert float(np.asarray(got).max()) > 0.0       # the gate opened
+    # and the table holds what the loop holds, row for row (the scratch
+    # row apart: the loop's padding wrote it, the step's wrote nothing)
+    for t in range(tenants):
+        row = state["row"][t] if vmapped else state["row"]
+        flat = np.asarray(row).reshape(D + 1, -1)
+        pred, mean, var, count, hs, cs = plains[t]
+        for name, want in zip(("pred", "mean", "var", "count"),
+                              (pred, mean, var, count)):
+            np.testing.assert_allclose(
+                np.asarray(model.scalars(row)[name])[:D],
+                np.asarray(want, np.float32)[:D], rtol=1e-6, atol=1e-6)
+        for layer in range(layers):
+            at = 2 * layer * hidden
+            for k, want in enumerate((hs[layer], cs[layer])):
+                np.testing.assert_allclose(
+                    flat[:D, at + k * hidden:at + (k + 1) * hidden],
+                    np.asarray(want)[:D], rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("hidden,layers", WIDTHS)
@@ -307,16 +332,70 @@ def test_state_tree_is_one_shape_and_rests_in_whole_tiles(hidden, layers):
                           jax.ShapeDtypeStruct((D, W), jnp.bool_))
     assert jax.tree.structure(cold) == jax.tree.structure(warm)
     assert jax.tree.leaves(cold) == jax.tree.leaves(warm)
-    assert cold["hc"].shape == (D, -(-2 * hidden * layers // 128) * 128)
-    for leaf in jax.tree.leaves(cold):
-        assert leaf.shape[0] == D
-        assert leaf.ndim == 1 or leaf.shape[-1] % 128 == 0, leaf
+    # one leaf: h and c of every layer, then the four scalars, in whole
+    # 128-lane tiles; a one-dimensional leaf would cost a gather and a
+    # scatter of its own over the whole fleet
+    assert list(cold) == ["row"]
+    assert cold["row"].shape == (
+        D, -(-(2 * hidden * layers + 4) // 128), 128)
+    assert cold["row"].dtype == jnp.float32
+    fresh = model.scalars(model.init_state(3)["row"])
+    assert {k: float(v[0]) for k, v in fresh.items()} == {
+        "pred": 0.0, "mean": 0.0, "var": 1.0, "count": 0.0}
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 3])
+@pytest.mark.parametrize("live", [0, 5, 1024, 1500, 2048])
+def test_distinct_rows_scatter_writes_the_named_rows_only(tiles, live):
+    """What the ring's scatters say (`DISTINCT_ROWS`) over what its
+    engines hand them (ascending rows, then `pad_rows`): the named rows
+    written, every other row and the scratch row bit for bit, alone and
+    under `vmap` (the pool), for no live row, a few, a whole bucket and
+    between, rows of one to three tiles."""
+    import jax
+    import jax.numpy as jnp
+    from sitewhere_tpu.scoring.stream import DISTINCT_ROWS, pad_rows
+
+    rows_n, bucket = 3001, 2048
+    rng = np.random.default_rng(tiles * 7 + live)
+    table = rng.normal(size=(rows_n, tiles, 128)).astype(np.float32)
+    rows = rng.normal(size=(bucket, tiles, 128)).astype(np.float32)
+    dev = np.concatenate([
+        np.sort(rng.permutation(rows_n - 1)[:live]).astype(np.int32),
+        pad_rows(rows_n - 1, bucket - live)])
+    want = table.copy()
+    want[dev[:live]] = rows[:live]
+
+    def put(table, dev, rows):
+        return table.at[dev].set(rows, **DISTINCT_ROWS)
+
+    assert (np.asarray(put(jnp.asarray(table), dev, rows)) == want).all()
+    stacked = jax.vmap(put)(jnp.stack([table, table + 1]),
+                            jnp.stack([dev, dev]), jnp.stack([rows, rows]))
+    assert (np.asarray(stacked[0]) == want).all()
+    want1 = table + 1
+    want1[dev[:live]] = rows[:live]
+    assert (np.asarray(stacked[1]) == want1).all()
+
+
+def _table_ops(text: str, op: str, rows: int) -> list[str]:
+    """The attributes of each `stablehlo.<op>` of a lowered step whose
+    first operand is a table of `rows` rows."""
+    import re
+
+    return [attrs for attrs, operand in re.findall(
+        rf'"stablehlo\.{op}"\([^\n]*? <\{{(.*?)\}}>(?: \(\{{.*?\}}\))? : '
+        r'\(tensor<(\d+)x',
+        text, re.S) if int(operand) == rows]
 
 
 @pytest.mark.parametrize("hidden,layers", WIDTHS)
 def test_lowered_step_scatters_once_a_state_leaf(hidden, layers):
-    """One scatter a leaf, five in all whatever the depth: a leaf added
-    later shows up here, in review."""
+    """One gather and one scatter, of the one leaf, whatever the depth (a
+    leaf added later shows up here, in review). The scatter is told that
+    no row comes twice and NOT that they ascend, the gather that they
+    ascend: what a v5e measured as free, and as a sixth slower (PERF.md
+    section 6, PR 31)."""
     import jax
     import jax.numpy as jnp
 
@@ -328,7 +407,40 @@ def test_lowered_step_scatters_once_a_state_leaf(hidden, layers):
     text = jax.jit(streaming_step(model)).lower(
         model.init(jax.random.PRNGKey(0)), state,
         jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.float32)).as_text()
-    assert text.count('"stablehlo.scatter"(') == len(state) == 5
+    scatters, gathers = (_table_ops(text, op, D + 1)
+                         for op in ("scatter", "gather"))
+    assert len(scatters) == len(gathers) == len(state) == 1
+    assert text.count('"stablehlo.scatter"(') == 1
+    assert all("indices_are_sorted = false" in line
+               and "unique_indices = true" in line for line in scatters)
+    assert all("indices_are_sorted = true" in line for line in gathers)
+
+
+def test_lowered_dsv3_step_scatters_once_a_state_leaf():
+    """`dsv3-stream`'s step: one scatter a leaf, the context appends
+    among them, every one told that no row comes twice and none that
+    they ascend (a context append so told took thirty times as long on
+    a v5e); one sorted gather a leaf (the model's own lookups and
+    scatters, of an embedding or over experts, are not the table's)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sitewhere_tpu.scoring.stream import streaming_step
+    from tests.test_dsv3 import program
+
+    model = program()
+    state = model.init_state(D + 1)
+    assert set(model.windows) < set(state)
+    text = jax.jit(streaming_step(model)).lower(
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)), state,
+        jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.float32)).as_text()
+    scatters = _table_ops(text, "scatter", D + 1)
+    assert len(scatters) == len(state)
+    assert all("indices_are_sorted = false" in line
+               and "unique_indices = true" in line for line in scatters)
+    gathers = _table_ops(text, "gather", D + 1)
+    assert len(gathers) == len(state)
+    assert all("indices_are_sorted = true" in line for line in gathers)
 
 
 # -- pooled streaming (config 4 at streaming speed) -------------------------
@@ -362,7 +474,7 @@ def test_pool_streaming_uses_stacked_streaming_ring(run):
         _make_pool_tenant(pool, "a", 20, 3, delivered)
         assert isinstance(pool.ring, StackedStreamingRing)
         await wait_until(lambda: pool.ready, timeout=60.0)
-        assert np.asarray(pool.ring.state["count"])[0, :20].min() >= 8
+        assert _scalar(pool.ring, "count")[0, :20].min() >= 8
         pool.close()
 
     run(main())
@@ -447,14 +559,14 @@ def test_pool_streaming_swap_params_reseeds_slot(run):
         await wait_until(lambda: pool.ready, timeout=60.0)
         slot_a = pool.stack.slots["a"]
         slot_b = pool.stack.slots["b"]
-        pred_a0 = np.asarray(pool.ring.state["pred"][slot_a, :25]).copy()
-        pred_b0 = np.asarray(pool.ring.state["pred"][slot_b, :25]).copy()
+        pred_a0 = _scalar(pool.ring, "pred")[slot_a, :25].copy()
+        pred_b0 = _scalar(pool.ring, "pred")[slot_b, :25].copy()
 
         new_params = model.init(jax.random.PRNGKey(99))
         version = slots["a"].swap_params(new_params)
         assert version == 1
         # a's state moved to the new weights...
-        pred_a1 = np.asarray(pool.ring.state["pred"][slot_a, :25])
+        pred_a1 = _scalar(pool.ring, "pred")[slot_a, :25]
         assert np.abs(pred_a1 - pred_a0).max() > 1e-3
         # ...and matches a dedicated session born with them
         ref = ScoringSession(
@@ -463,12 +575,150 @@ def test_pool_streaming_swap_params_reseeds_slot(run):
             params=new_params)
         ref.warmup()
         np.testing.assert_allclose(
-            pred_a1, np.asarray(ref.ring.state["pred"][:25]), atol=1e-5)
+            pred_a1, _scalar(ref.ring, "pred")[:25], atol=1e-5)
         # b untouched
         np.testing.assert_allclose(
-            np.asarray(pool.ring.state["pred"][slot_b, :25]), pred_b0,
-            atol=0.0)
+            _scalar(pool.ring, "pred")[slot_b, :25], pred_b0, atol=0.0)
         ref.close()
         pool.close()
+
+    run(main())
+
+
+# -- the ring's contract with the engines: the rows of a step ascend --------
+#
+# The step tells the compiler that its rows ascend and that none comes
+# twice, so a take that arrives shuffled or names a device twice must be
+# put right by the engine BEFORE the ring sees it: handed on as it came it
+# would, on a backend that uses the promise, silently corrupt the table.
+
+FLEET, BUCKET = 48, 32
+
+
+def _take(kind: str) -> np.ndarray:
+    """Device ids of one take, in arrival order."""
+    rng = np.random.default_rng(len(kind))
+    if kind == "ascending":             # fills its bucket: no padding
+        return np.sort(rng.permutation(FLEET)[:BUCKET])
+    if kind == "short":                 # ascending, most of the bucket padding
+        return np.sort(rng.permutation(FLEET)[:5])
+    if kind == "shuffled":              # no id twice, in no order
+        ids = rng.permutation(FLEET)[:BUCKET - 4]
+        assert (ids[1:] < ids[:-1]).any()
+        return ids
+    if kind == "repeats":               # ids up to four times, in no order
+        return rng.integers(0, 9, BUCKET - 4)
+    assert kind == "sorted-repeats"     # in order, but not strictly
+    return np.array([3, 3, 3, 7, 9, 9, 20])
+
+
+def _per_event_loop(model, params, table, dev, v):
+    """One event after another on a host copy of the table, each through
+    `step_score` alone: (the table afterwards, the scores in order)."""
+    import jax
+
+    one = jax.jit(model.step_score)
+    table, scores = table.copy(), []
+    for d, x in zip(dev, v):
+        s, new = one(params, {"row": table[d][None]}, np.float32([x]))
+        table[d] = np.asarray(new["row"])[0]
+        scores.append(float(s[0]))
+    return table, np.array(scores, np.float32)
+
+
+def _batch(dev, v, t):
+    from sitewhere_tpu.domain.batch import BatchContext, MeasurementBatch
+
+    n = dev.shape[0]
+    return MeasurementBatch(BatchContext(tenant_id="a"), dev.astype(np.uint32),
+                            np.zeros(n, np.uint16), v,
+                            np.full(n, t, np.float64))
+
+
+@pytest.mark.parametrize("engine", ["session", "pool"])
+@pytest.mark.parametrize(
+    "kind", ["ascending", "short", "shuffled", "repeats", "sorted-repeats"])
+def test_a_take_in_any_order_scores_as_a_per_event_loop(run, engine, kind):
+    """Scores in arrival order and the final table of a plain per-event
+    loop, whatever order the take arrived in; every row the take did not
+    name, the scratch row and a neighbouring tenant's rows among them,
+    bit for bit as it was; `scoring.ring.ascending` counts the takes that
+    needed no host sort."""
+
+    async def main():
+        model = build_model("lstm-stream", window=64)
+        metrics = MetricsRegistry()
+        dev = _take(kind)
+        v = np.random.default_rng(5).normal(20.0, 3.0, dev.shape[0]).astype(
+            np.float32)
+        store = TelemetryStore(history=128, initial_devices=FLEET)
+        sim = DeviceSimulator(SimConfig(num_devices=FLEET, seed=3),
+                              tenant_id="a")
+        _fill_store(store, sim, 70)
+        if engine == "session":
+            s = ScoringSession(model, store, metrics, ScoringConfig(
+                buckets=(BUCKET,), score_dtype="float32"))
+            s.warmup()
+            params, ring, rows = s.params, s.ring, slice(None)
+        else:
+            pool = SharedScoringPool(model, metrics, PoolConfig(
+                batch_buckets=(BUCKET,), batch_window_ms=1.0,
+                score_dtype="float32"))
+            delivered: dict[str, list] = {}
+            for tid, seed in (("a", 3), ("b", 4)):
+                other = TelemetryStore(history=128, initial_devices=FLEET)
+                _fill_store(other, DeviceSimulator(
+                    SimConfig(num_devices=FLEET, seed=seed), tenant_id=tid), 70)
+
+                async def deliver(scored, tid=tid):
+                    delivered[tid].append(scored)
+
+                delivered[tid] = []
+                pool.register(tid, other if tid == "b" else store, 4.0,
+                              deliver)
+            await wait_until(lambda: pool.ready, timeout=60.0)
+            params, ring = pool.stack.get_params("a"), pool.ring
+            rows = pool.stack.slots["a"]
+        before = np.asarray(ring.state["row"])
+        # what the ring is handed, whatever this backend makes of the
+        # promise: every row of ids strictly ascending, padding included
+        step, handed = ring.update_and_score, []
+
+        def watched(model, params, ids, *rest, **kw):
+            handed.append(np.atleast_2d(ids))
+            return step(model, params, ids, *rest, **kw)
+
+        ring.update_and_score = watched
+        counts = {n: metrics.counter(n).value
+                  for n in ("scoring.ring.ascending", "scoring.dispatches")}
+        if engine == "session":
+            s.admit(_batch(dev, v, 71 * 60.0))
+            scored = await s.flush()
+        else:
+            pool.admit("a", _batch(dev, v, 71 * 60.0))
+            await wait_until(lambda: delivered["a"], timeout=30.0)
+            scored = delivered["a"][0]
+            assert not delivered["b"]
+        after = np.asarray(ring.state["row"])
+        assert handed and all((np.diff(ids, axis=1) > 0).all()
+                              for ids in handed)
+        want, scores = _per_event_loop(model, params, before[rows], dev, v)
+        assert (scored.device_index == dev).all()
+        assert scores.max() > 0.0                 # seeded: the gate is open
+        np.testing.assert_allclose(scored.score, scores, rtol=1e-5, atol=1e-5)
+        named = np.zeros(before[rows].shape[0], bool)
+        named[dev] = True
+        np.testing.assert_allclose(after[rows][named], want[named],
+                                   rtol=1e-5, atol=1e-6)
+        untouched = np.ones(before.shape[:-2], bool)
+        untouched[rows] = ~named                  # a view: the scratch row
+        assert untouched[rows][-1]                # stays among them
+        assert (after[untouched] == before[untouched]).all()
+        rounds = int(np.unique(dev, return_counts=True)[1].max())
+        took = {n: metrics.counter(n).value - c for n, c in counts.items()}
+        assert took == {
+            "scoring.ring.ascending": float(kind in ("ascending", "short")),
+            "scoring.dispatches": float(rounds)}
+        (s if engine == "session" else pool).close()
 
     run(main())
