@@ -132,11 +132,14 @@ COUNTERS = (
     # (scoring/stream.py, "Contract with the engines")
     "scoring.ring.ascending",
     # what a step of a model with routed experts and window leaves
-    # returns beside its scores (models/dsv3.py `step_stats`)
+    # returns beside its scores (`step_stats` of models/dsv3.py and
+    # models/laguna.py): `ctx.wrapped` counts live rows whose append
+    # overwrote an older position of a wrapping window leaf
     "scoring.moe.assignments_held",
     "scoring.moe.assignments",
     "scoring.moe.runs_one_tile",
     "scoring.ctx.reseeds",
+    "scoring.ctx.wrapped",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services
@@ -285,7 +288,10 @@ HISTOGRAMS = (
     "scoring.device_wait_s",
     "scoring.settle_wake_s",
     "scoring.moe.expert_max_tokens",
+    # a step's mean attended length: over the bounded window leaves,
+    # and over those that wrap
     "scoring.ctx.positions",
+    "scoring.ctx.window_positions",
     "scoring.megabatch_tenants_per_dispatch",
     # flight recorder (kernel/observe.py): event-loop lag per beat
     "observe.loop_lag_s",

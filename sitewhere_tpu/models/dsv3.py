@@ -60,22 +60,23 @@ position to, at each row's own `pos`.
 
 from __future__ import annotations
 
-import functools
-import itertools
 import math
 from dataclasses import dataclass, field
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from sitewhere_tpu.ops import expert_kernel
-
-EXPERT_TILE = 128        # rows of one held expert's products at a time
-SEED_TOKENS = 2048       # tokens of one seeding call: its activations
-                         # (under 1 GB at the published widths) fit
-                         # beside the weights and the context
+from sitewhere_tpu.models import seqblocks
+from sitewhere_tpu.models.seqblocks import (  # noqa: F401  (names kept)
+    EXPERT_TILE,
+    SEED_TOKENS,
+    Experts,
+    SeqBlocks,
+    rms as _rms,
+    rope as _rope,
+    runs_one_tile,
+)
 
 
 def _yarn() -> dict:
@@ -152,32 +153,12 @@ class Dsv3Config:
         return -(-self.latent_width // 128) * 128
 
 
-def _rms(x, w, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
 def rope_tables(cfg: Dsv3Config, positions: int) -> tuple:
-    """(cos, sin) `[positions, qk_rope_head_dim // 2]`, YaRN as published:
-    frequencies above the correction range keep theta's, those below are
-    divided by `factor`, a linear ramp between."""
-    rs, dim, base = cfg.rope_scaling, cfg.qk_rope_head_dim, cfg.rope_theta
-    freq = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if rs and rs.get("type") == "yarn":
-        orig = rs["original_max_position_embeddings"]
-
-        def correction(rotations):
-            return dim * math.log(orig / (rotations * 2 * math.pi)) / (
-                2 * math.log(base))
-
-        low = max(math.floor(correction(rs["beta_fast"])), 0)
-        high = min(math.ceil(correction(rs["beta_slow"])), dim - 1)
-        ramp = np.clip((np.arange(dim // 2) - low)
-                       / ((high - low) or 0.001), 0.0, 1.0)
-        freq = freq / rs["factor"] * ramp + freq * (1.0 - ramp)
-    angle = np.arange(positions, dtype=np.float64)[:, None] * freq[None, :]
-    return (np.cos(angle).astype(np.float32),
-            np.sin(angle).astype(np.float32))
+    """(cos, sin) `[positions, qk_rope_head_dim // 2]`, YaRN as published."""
+    rs = cfg.rope_scaling
+    return seqblocks.rope_tables(
+        positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+        rs if rs and rs.get("type") == "yarn" else None)
 
 
 def softmax_scale(cfg: Dsv3Config) -> float:
@@ -189,16 +170,7 @@ def softmax_scale(cfg: Dsv3Config) -> float:
     return scale
 
 
-def _rope(x, cos, sin):
-    """Rotate pairs `(2i, 2i + 1)` of the last axis; `cos`, `sin`
-    broadcast against `x[..., ::2]`."""
-    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
-                     -1).reshape(x.shape)
-
-
-class Dsv3StreamModel:
+class Dsv3StreamModel(SeqBlocks):
     """Functional, like every model here: the instance holds the
     configuration and tables made from it, weights are passed in."""
 
@@ -225,6 +197,11 @@ class Dsv3StreamModel:
             raise ValueError("a context holds fewer positions than the "
                              "window it is seeded from")
         self.cfg = cfg
+        self.experts = Experts(
+            routed=cfg.n_routed_experts, held=cfg.experts_held,
+            first=cfg.first_expert, per_token=cfg.num_experts_per_tok,
+            scale=cfg.routed_scaling_factor, scoring=cfg.scoring_func,
+            groups=cfg.n_group, groups_kept=cfg.topk_group)
         self.layers = cfg.num_hidden_layers
         # state leaves that are windows -> the leaf that holds the
         # position a step appends at (scoring/stream.py)
@@ -290,40 +267,7 @@ class Dsv3StreamModel:
                 "eh_proj": ((2 * h, h), w), "block": self._block_shapes(True)}
         return shapes
 
-    def init(self, rng: jax.Array) -> dict:
-        """Random weights leaf by leaf (normal, std 0.02; norms 1; the
-        router's bias std 0.01), each made in float32 and kept in its own
-        type, so that no second copy of the set is ever alive."""
-        made = itertools.count()
-
-        def build(spec, name=""):
-            if isinstance(spec, dict):
-                return {k: build(v, k) for k, v in spec.items()}
-            shape, dtype = spec
-            if "norm" in name:
-                return jnp.ones(shape, dtype)
-            return _normal(jax.random.fold_in(rng, next(made)), shape, dtype,
-                           0.01 if name == "bias" else 0.02)
-
-        return build(self.param_shapes())
-
     # -- pieces ---------------------------------------------------------------
-
-    def _mm(self, x, w):
-        cdt = self.cfg.compute_dtype
-        return jnp.dot(x.astype(cdt), w.astype(cdt),
-                       preferred_element_type=jnp.float32,
-                       precision=_precision(cdt))
-
-    def _ein(self, spec, a, b):
-        cdt = self.cfg.compute_dtype
-        return jnp.einsum(spec, a.astype(cdt), b.astype(cdt),
-                          preferred_element_type=jnp.float32,
-                          precision=_precision(cdt))
-
-    def _mlp(self, p, x):
-        return self._mm(jax.nn.silu(self._mm(x, p["gate"]))
-                        * self._mm(x, p["up"]), p["down"])
 
     def _project(self, p, h, cos, sin):
         """The MLA projections of normed tokens `h` `[..., hidden]` at
@@ -393,123 +337,6 @@ class Dsv3StreamModel:
         out = self._ein("bhc,chd->bhd", lat, w_v)
         return out.reshape(out.shape[0], -1)
 
-    def route(self, p, x):
-        """Experts and weights of tokens `x` `[T, hidden]` (float32):
-        (`[T, k]` int32, `[T, k]` float32), over ALL routed experts."""
-        c = self.cfg
-        s = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), p["w"].T,
-            precision=jax.lax.Precision.HIGHEST))
-        choice = s + p["bias"]
-        groups = choice.reshape(x.shape[0], c.n_group, -1)
-        group_score = jax.lax.top_k(groups, 2)[0].sum(-1)
-        kept = jax.lax.top_k(group_score, c.topk_group)[1]
-        keep = jnp.zeros((x.shape[0], c.n_group), bool).at[
-            jnp.arange(x.shape[0])[:, None], kept].set(True)
-        masked = jnp.where(jnp.repeat(keep, groups.shape[-1], axis=1),
-                           choice, -jnp.inf)
-        idx = jax.lax.top_k(masked, c.num_experts_per_tok)[1]
-        w = jnp.take_along_axis(s, idx, axis=1)
-        w = w / w.sum(-1, keepdims=True) * c.routed_scaling_factor
-        return idx.astype(jnp.int32), w
-
-    def routed(self, p, x, idx, w, live, tile=EXPERT_TILE):
-        """What the held experts give for tokens `x` `[T, hidden]`:
-        `sum_k w * expert_k(x)` over the chosen experts held here, and
-        each held expert's token count `[held]` (rows not `live` count
-        and compute nothing). The pairs are sorted by expert and the
-        layer is ONE grouped pass over them: the token rows of every
-        held expert's first `tile` pairs are gathered once, laid at
-        `e * tile`, each expert's three products run over its tile with
-        no loop round them (an expert's leaves are read once, one after
-        another), and the weighted rows are summed into the tokens. A
-        run longer than `tile` takes its further tiles in a loop that
-        is entered only where some run is that long: nothing is
-        dropped, no expert has a capacity."""
-        c = self.cfg
-        t, k = idx.shape
-        held = c.experts_held
-        local = idx.reshape(-1) - c.first_expert
-        here = (local >= 0) & (local < held) & jnp.repeat(live, k)
-        group = jnp.where(here, local, held)
-        _, token, weight = jax.lax.sort(
-            (group, jnp.arange(t * k, dtype=jnp.int32) // k, w.reshape(-1)),
-            num_keys=1, is_stable=True)
-        counts = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
-        starts = jnp.cumsum(counts) - counts
-        token = jnp.concatenate([token, jnp.zeros(tile, jnp.int32)])
-        weight = jnp.concatenate([weight, jnp.zeros(tile, jnp.float32)])
-        xc = x.astype(c.compute_dtype)
-        lane = jnp.arange(tile)
-
-        def tile_of(lo, end):
-            """Token rows and weights of the `tile` pairs from `lo` on
-            (weight 0 from `end` on), for one run or `[held]` runs."""
-            at = lo[..., None] + lane
-            return token[at], jnp.where(at < end[..., None], weight[at], 0.0)
-
-        rows, wt = tile_of(starts, starts + counts)
-        out = self._first_tiles([p[f"e{e}"] for e in range(held)], xc,
-                                rows.reshape(-1), wt.reshape(-1), counts)
-
-        def further_tiles(out):
-            for e in range(held):
-                start, end = starts[e], starts[e] + counts[e]
-                expert = p[f"e{e}"]
-
-                def further(i, out, start=start, end=end, expert=expert):
-                    rows, wt = tile_of(start + i * tile, end)
-                    return out.at[rows].add(
-                        self._mlp(expert, xc[rows]) * wt[:, None])
-
-                out = jax.lax.fori_loop(1, (counts[e] + tile - 1) // tile,
-                                        further, out)
-            return out
-
-        return jax.lax.cond((counts > tile).any(), further_tiles,
-                            lambda out: out, out), counts
-
-    def _first_tiles(self, experts, xc, rows, wt, counts):
-        """`sum w * expert(x)` over every held expert's first tile of
-        pairs: `rows`, `wt` `[held * tile]` are the pairs' tokens and
-        weights (0 past a run's end), tile `e` expert `e`'s. ->
-        `[T, hidden]` float32. On a TPU, in bfloat16 and at shapes it
-        takes, one kernel that streams the leaves where they rest and
-        sums in place (ops/expert_kernel.py); elsewhere the same three
-        products and a scatter-add an expert."""
-        t, tile = xc.shape[0], rows.shape[0] // len(experts)
-
-        def plain(experts, xs, rows, wt, counts):
-            # a scatter-add an expert: one of every tile's rows at once
-            # took twice their time on a v5e (PERF.md, PR 29); `counts`
-            # is for the kernel, here `wt` is 0 past a run's end
-            out = jnp.zeros((t, xs.shape[1]), jnp.float32)
-            for e, expert in enumerate(experts):
-                at = slice(e * tile, (e + 1) * tile)
-                out = out.at[rows[at]].add(
-                    self._mlp(expert, xs[at]) * wt[at, None])
-            return out
-
-        hidden, inter = experts[0]["gate"].shape
-        if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
-                or not expert_kernel.fits(t, hidden, inter, tile)):
-            return plain(experts, xc[rows], rows, wt, counts)
-        return jax.lax.platform_dependent(
-            experts, xc[rows], rows, wt, counts, default=plain,
-            tpu=functools.partial(expert_kernel.expert_tiles, tokens=t))
-
-    def _ffn(self, p, x, live):
-        """The block's second half on normed tokens `[T, hidden]`; the
-        held experts' token counts `[held]` where the layer has experts."""
-        if "mlp" in p:
-            with jax.named_scope("dense_mlp"):
-                return self._mlp(p["mlp"], x), None
-        with jax.named_scope("moe_route"):
-            idx, w = self.route(p["router"], x)
-        with jax.named_scope("moe_experts"):
-            routed, counts = self._routed(p["experts"], x, idx, w, live)
-            return self._mlp(p["shared"], x) + routed, counts
-
     def _block_prefill(self, p, x, count, cos, sin):
         """One block over `[n, S, hidden]`; also the layer's context
         entries `[n, S, entry_width]`."""
@@ -540,38 +367,6 @@ class Dsv3StreamModel:
 
     # -- tokens -------------------------------------------------------------
 
-    def _bin(self, xn):
-        v = self.cfg.vocab
-        return jnp.clip(jnp.floor((xn + 8.0) / 16.0 * v), 0,
-                        v - 1).astype(jnp.int32)
-
-    def _window_tokens(self, x, valid):
-        """A stored window `[n, W]` (chronological, left-padded) as
-        tokens with the valid ones first, and the window's statistics:
-        (tokens `[n, W]`, count `[n]`, mean `[n]`, var `[n]`). The
-        statistics are taken value by value in stored order, by the rule
-        an event updates them with: no sum, so no order of summation
-        for another program to disagree about at a bin's edge."""
-        n, w = x.shape
-
-        def take(carry, col):
-            mean, var, cnt = carry
-            v, ok = col
-            cnt1 = jnp.minimum(cnt + 1, w)
-            d = v - mean
-            mean1 = mean + d / cnt1
-            var1 = var + ((v - mean1) * d - var) / cnt1
-            return (jnp.where(ok, mean1, mean), jnp.where(ok, var1, var),
-                    jnp.where(ok, cnt1, cnt)), None
-
-        (mean, var, count), _ = jax.lax.scan(
-            take, (jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32),
-                   jnp.zeros(n, jnp.int32)), (x.T, valid.T))
-        xn = (x - mean[:, None]) / jnp.sqrt(var + 1e-6)[:, None]
-        first = (jnp.arange(w)[None, :] + (w - count)[:, None]) % w
-        return (jnp.take_along_axis(self._bin(xn), first, axis=1), count,
-                mean, var)
-
     def _prefill(self, params, tokens, count):
         """Every block over `[n, S]` tokens: (hidden states before the
         final norm `[n, S, hidden]`, context entries a layer)."""
@@ -585,34 +380,11 @@ class Dsv3StreamModel:
             entries.append(entry)
         return x, entries
 
-    def _logits(self, params, h):
-        with jax.named_scope("lm_head"):
-            return self._mm(_rms(h, params["norm"], self.cfg.rms_norm_eps),
-                            params["head"])
-
-    def _in_blocks(self, fn, *rows):
-        """`fn` over row blocks of `seed_rows`, one after another, so a
-        whole bucket's windows never stand in memory at once."""
-        n, b = rows[0].shape[0], self.seed_rows
-        if n <= b:
-            return fn(*rows)
-        pad = -n % b
-        blocks = [jnp.concatenate([r, jnp.zeros((pad,) + r.shape[1:],
-                                                r.dtype)]).reshape(
-            (-1, b) + r.shape[1:]) for r in rows]
-        out = jax.lax.map(lambda xs: fn(*xs), tuple(blocks))
-        return jax.tree.map(
-            lambda o: o.reshape((-1,) + o.shape[2:])[:n], out)
-
     # -- the model's surfaces -------------------------------------------------
 
     def init_state(self, cap: int) -> dict:
         c = self.cfg
-        state = {"mean": jnp.zeros(cap, jnp.float32),
-                 "var": jnp.ones(cap, jnp.float32),
-                 "count": jnp.zeros(cap, jnp.int32),
-                 "pos": jnp.zeros(cap, jnp.int32),
-                 "hn": jnp.zeros((cap, c.hidden_size), c.compute_dtype)}
+        state = self._row_state(cap)
         for l in range(self.layers):
             state[f"ctx{l}"] = jnp.zeros(
                 (cap, c.context_positions, c.entry_width), c.compute_dtype)
@@ -625,20 +397,8 @@ class Dsv3StreamModel:
         to append at `rows["pos"]`. Also the step's numbers, in
         `step_stats`' order (`live` masks the padding out of them)."""
         c = self.cfg
-        mean, var, cnt, pos = (rows["mean"], rows["var"], rows["count"],
-                               rows["pos"])
-        token = self._bin((v - mean) / jnp.sqrt(var + 1e-6))
-        with jax.named_scope("lm_head"):
-            logits = self._mm(rows["hn"], params["head"])
-            surprisal = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
-                logits, token[:, None], axis=1)[:, 0]
-        score = jnp.clip(jnp.where(cnt >= self._gate, surprisal, 0.0),
-                         0.0, c.score_clip)
-        cnt1 = jnp.minimum(cnt + 1, c.window)
-        delta = v - mean
-        mean1 = mean + delta / cnt1
-        var1 = var + ((v - mean1) * delta - var) / cnt1
-        out = {"mean": mean1, "var": var1, "count": cnt1, "pos": pos + 1}
+        pos = rows["pos"]
+        token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
         held = busiest = one_tile = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
@@ -664,39 +424,10 @@ class Dsv3StreamModel:
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:
         """State of `n` devices after their stored windows (`[n, W]`
         chronological left-padded): the prefill form over each window."""
-        c = self.cfg
-        n, w = x.shape
-        tokens, count, mean, var = self._window_tokens(x, valid)
-        h, entries = self._prefill(params, tokens, count)
-        last = h[jnp.arange(n), jnp.maximum(count - 1, 0)]
-        state = self.init_state(n)
-        state.update(mean=mean, var=jnp.maximum(var, 1e-6),
-                     count=jnp.minimum(count, c.window), pos=count)
-        state["hn"] = jnp.where(
-            (count > 0)[:, None],
-            _rms(last, params["norm"], c.rms_norm_eps), 0.0).astype(
-                c.compute_dtype)
+        state, entries, _ = self._warm(params, x, valid)
         for l, entry in enumerate(entries):
-            state[f"ctx{l}"] = state[f"ctx{l}"].at[:, :w].set(entry)
+            state[f"ctx{l}"] = state[f"ctx{l}"].at[:, :x.shape[1]].set(entry)
         return state
-
-    def score(self, params: dict, x: jax.Array, valid: jax.Array) -> jax.Array:
-        """The newest value's score from a stored window alone (the query
-        path): the surprisal of its bin under the positions before it."""
-        c = self.cfg
-
-        def rows(x, valid):
-            n = x.shape[0]
-            tokens, count, _, _ = self._window_tokens(x, valid)
-            h, _ = self._prefill(params, tokens, count)
-            at = jnp.maximum(count - 1, 1)
-            logits = self._logits(params, h[jnp.arange(n), at - 1])
-            surprisal = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
-                logits, tokens[jnp.arange(n), at][:, None], axis=1)[:, 0]
-            return jnp.clip(jnp.where(count >= self._gate, surprisal, 0.0),
-                            0.0, c.score_clip)
-
-        return self._in_blocks(rows, x, valid)
 
     def forecast_bins(self, params: dict, x: jax.Array, valid: jax.Array):
         """(draft `[n]`, log-probabilities of the bin after it `[n, V]`):
@@ -738,20 +469,3 @@ class Dsv3StreamModel:
         bins = jnp.stack([draft, jnp.argmax(after, -1)], 1)
         xn = (bins + 0.5) * (16.0 / self.cfg.vocab) - 8.0
         return (xn * sd[:, None] + mean[:, None])[..., None]
-
-
-def runs_one_tile(counts, tile=EXPERT_TILE):
-    """Of the held experts' runs `counts` `[held]`, those that
-    `Dsv3StreamModel.routed`'s straight-line pass serves whole (an
-    empty run too); the others enter its overflow loop."""
-    return (counts <= tile).sum()
-
-
-def _precision(cdt):
-    return (jax.lax.Precision.HIGHEST if jnp.dtype(cdt) == jnp.float32
-            else None)
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "dtype", "std"))
-def _normal(key, shape, dtype, std):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)

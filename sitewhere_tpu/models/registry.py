@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Any
 
 from sitewhere_tpu.models.dsv3 import Dsv3Config, Dsv3StreamModel
+from sitewhere_tpu.models.laguna import LagunaConfig, LagunaStreamModel
 from sitewhere_tpu.models.longwin import LongWindowConfig, LongWindowModel
 from sitewhere_tpu.models.lstm import (
     LstmAnomalyModel,
@@ -28,6 +29,9 @@ MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     "lstm-stream": (LstmConfig, StreamingLstmModel),
     # DeepSeek-V3's block (MLA, routed experts) as a streaming scorer
     "dsv3-stream": (Dsv3Config, Dsv3StreamModel),
+    # Laguna-S-2.1's block (windowed and full GQA side by side, gated
+    # heads, softmax-routed experts) as a streaming scorer
+    "laguna-stream": (LagunaConfig, LagunaStreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

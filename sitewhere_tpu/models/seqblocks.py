@@ -1,0 +1,403 @@
+"""What the streaming sequence scorers have in common (`dsv3-stream`,
+models/dsv3.py; `laguna-stream`, models/laguna.py): RMSNorm, rope and
+its YaRN tables, the quantiser that makes a measurement a token, the
+surprisal score with its short-history gate, and the expert layer that
+is told which experts it holds.
+
+A measurement becomes a token by the device's capped running mean and
+variance (the leaves and the update `lstm-stream` has):
+`bin = clip(floor((xn + 8) / 16 * V), 0, V - 1)`; an event's score is
+the surprisal of the bin that arrived under the prediction made at the
+device's previous event, 0 until the device has reported
+`max(8, window // 8)` values, clipped at `score_clip`.
+
+The share of an expert layer held here (`Experts`): the layer routes
+over all `routed` experts, computes every token-expert pair that lands
+on one of the `held` experts from `first` on (no capacity, none
+dropped) and leaves out what the absent experts would add.
+
+A model mixes `SeqBlocks` in, sets `self.cfg` (with `compute_dtype`,
+`window`, `score_clip`, `vocab`, `hidden_size`, `rms_norm_eps`),
+`self.experts` (an `Experts`), `self.seed_rows` and `self._gate`, and
+brings `param_shapes`, `init_state` and `_prefill`. Imports nothing
+beyond JAX at import time: the expert kernel imports Pallas when it is
+first traced.
+"""
+
+from __future__ import annotations
+
+import functools
+import itertools
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from sitewhere_tpu.ops import expert_kernel
+
+EXPERT_TILE = 128        # rows of one held expert's products at a time
+SEED_TOKENS = 2048       # tokens of one seeding call: its activations
+                         # (under 1 GB at the published widths) fit
+                         # beside the weights and the context
+
+
+@dataclass(frozen=True)
+class Experts:
+    """An expert layer's routing rule and the share of it held here."""
+    routed: int              # experts the router scores
+    held: int                # ...of which this chip holds `held`
+    first: int               # ...from expert `first` on
+    per_token: int
+    scale: float             # the kept weights sum to this
+    scoring: str             # "sigmoid" | "softmax" of the router's logits
+    groups: int = 1          # group-limited choice: the experts in
+    groups_kept: int = 1     # `groups`, of which the best are kept
+
+
+def rms(x, w, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def rope_tables(positions: int, dim: int, base: float,
+                yarn: dict | None = None) -> tuple:
+    """(cos, sin) `[positions, dim // 2]` for a rotated width of `dim`;
+    with `yarn` (`factor`, `original_max_position_embeddings`,
+    `beta_fast`, `beta_slow`) as published: frequencies above the
+    correction range keep theta's, those below are divided by `factor`,
+    a linear ramp between."""
+    freq = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn:
+        orig = yarn["original_max_position_embeddings"]
+
+        def correction(rotations):
+            return dim * math.log(orig / (rotations * 2 * math.pi)) / (
+                2 * math.log(base))
+
+        low = max(math.floor(correction(yarn["beta_fast"])), 0)
+        high = min(math.ceil(correction(yarn["beta_slow"])), dim - 1)
+        ramp = np.clip((np.arange(dim // 2) - low)
+                       / ((high - low) or 0.001), 0.0, 1.0)
+        freq = freq / yarn["factor"] * ramp + freq * (1.0 - ramp)
+    angle = np.arange(positions, dtype=np.float64)[:, None] * freq[None, :]
+    return (np.cos(angle).astype(np.float32),
+            np.sin(angle).astype(np.float32))
+
+
+def rope(x, cos, sin):
+    """Rotate pairs `(2i, 2i + 1)` of the last axis; `cos`, `sin`
+    broadcast against `x[..., ::2]`."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     -1).reshape(x.shape)
+
+
+def runs_one_tile(counts, tile=EXPERT_TILE):
+    """Of the held experts' runs `counts` `[held]`, those that
+    `SeqBlocks.routed`'s straight-line pass serves whole (an empty run
+    too); the others enter its overflow loop."""
+    return (counts <= tile).sum()
+
+
+def precision(cdt):
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(cdt) == jnp.float32
+            else None)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "std"))
+def normal(key, shape, dtype, std):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+class SeqBlocks:
+    """The pieces, as methods of the model that mixes them in."""
+
+    def _mm(self, x, w):
+        cdt = self.cfg.compute_dtype
+        return jnp.dot(x.astype(cdt), w.astype(cdt),
+                       preferred_element_type=jnp.float32,
+                       precision=precision(cdt))
+
+    def _ein(self, spec, a, b):
+        cdt = self.cfg.compute_dtype
+        return jnp.einsum(spec, a.astype(cdt), b.astype(cdt),
+                          preferred_element_type=jnp.float32,
+                          precision=precision(cdt))
+
+    def _mlp(self, p, x):
+        return self._mm(jax.nn.silu(self._mm(x, p["gate"]))
+                        * self._mm(x, p["up"]), p["down"])
+
+    def init(self, rng: jax.Array) -> dict:
+        """Random weights leaf by leaf over `param_shapes()` (normal, std
+        0.02; norms 1; a router's bias std 0.01), each made in float32
+        and kept in its own type, so that no second copy of the set is
+        ever alive."""
+        made = itertools.count()
+
+        def build(spec, name=""):
+            if isinstance(spec, dict):
+                return {k: build(v, k) for k, v in spec.items()}
+            shape, dtype = spec
+            if "norm" in name:
+                return jnp.ones(shape, dtype)
+            return normal(jax.random.fold_in(rng, next(made)), shape, dtype,
+                          0.01 if name == "bias" else 0.02)
+
+        return build(self.param_shapes())
+
+    # -- the expert layer ---------------------------------------------------
+
+    def route(self, p, x):
+        """Experts and weights of tokens `x` `[T, hidden]` (float32):
+        (`[T, k]` int32, `[T, k]` float32), over ALL routed experts.
+        `s` = the router's scoring function of `x W^T`; choice scores
+        `s` plus the router's bias where it has one; with groups, a
+        group's score is the sum of its two best and only the kept
+        groups' experts can be chosen; weights the chosen `s` over their
+        sum times `scale`."""
+        ex = self.experts
+        logits = jnp.dot(x.astype(jnp.float32), p["w"].T,
+                         precision=jax.lax.Precision.HIGHEST)
+        s = (jax.nn.sigmoid(logits) if ex.scoring == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+        choice = s + p["bias"] if "bias" in p else s
+        if ex.groups > 1:
+            groups = choice.reshape(x.shape[0], ex.groups, -1)
+            group_score = jax.lax.top_k(groups, 2)[0].sum(-1)
+            kept = jax.lax.top_k(group_score, ex.groups_kept)[1]
+            keep = jnp.zeros((x.shape[0], ex.groups), bool).at[
+                jnp.arange(x.shape[0])[:, None], kept].set(True)
+            choice = jnp.where(jnp.repeat(keep, groups.shape[-1], axis=1),
+                               choice, -jnp.inf)
+        idx = jax.lax.top_k(choice, ex.per_token)[1]
+        w = jnp.take_along_axis(s, idx, axis=1)
+        w = w / w.sum(-1, keepdims=True) * ex.scale
+        return idx.astype(jnp.int32), w
+
+    def routed(self, p, x, idx, w, live, tile=EXPERT_TILE):
+        """What the held experts give for tokens `x` `[T, hidden]`:
+        `sum_k w * expert_k(x)` over the chosen experts held here, and
+        each held expert's token count `[held]` (rows not `live` count
+        and compute nothing). The pairs are sorted by expert and the
+        layer is ONE grouped pass over them: the token rows of every
+        held expert's first `tile` pairs are gathered once, laid at
+        `e * tile`, each expert's three products run over its tile with
+        no loop round them (an expert's leaves are read once, one after
+        another), and the weighted rows are summed into the tokens. A
+        run longer than `tile` takes its further tiles in a loop that
+        is entered only where some run is that long: nothing is
+        dropped, no expert has a capacity."""
+        c = self.cfg
+        t, k = idx.shape
+        held = self.experts.held
+        local = idx.reshape(-1) - self.experts.first
+        here = (local >= 0) & (local < held) & jnp.repeat(live, k)
+        group = jnp.where(here, local, held)
+        _, token, weight = jax.lax.sort(
+            (group, jnp.arange(t * k, dtype=jnp.int32) // k, w.reshape(-1)),
+            num_keys=1, is_stable=True)
+        counts = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+        starts = jnp.cumsum(counts) - counts
+        token = jnp.concatenate([token, jnp.zeros(tile, jnp.int32)])
+        weight = jnp.concatenate([weight, jnp.zeros(tile, jnp.float32)])
+        xc = x.astype(c.compute_dtype)
+        lane = jnp.arange(tile)
+
+        def tile_of(lo, end):
+            """Token rows and weights of the `tile` pairs from `lo` on
+            (weight 0 from `end` on), for one run or `[held]` runs."""
+            at = lo[..., None] + lane
+            return token[at], jnp.where(at < end[..., None], weight[at], 0.0)
+
+        rows, wt = tile_of(starts, starts + counts)
+        out = self._first_tiles([p[f"e{e}"] for e in range(held)], xc,
+                                rows.reshape(-1), wt.reshape(-1), counts)
+
+        def further_tiles(out):
+            for e in range(held):
+                start, end = starts[e], starts[e] + counts[e]
+                expert = p[f"e{e}"]
+
+                def further(i, out, start=start, end=end, expert=expert):
+                    rows, wt = tile_of(start + i * tile, end)
+                    return out.at[rows].add(
+                        self._mlp(expert, xc[rows]) * wt[:, None])
+
+                out = jax.lax.fori_loop(1, (counts[e] + tile - 1) // tile,
+                                        further, out)
+            return out
+
+        return jax.lax.cond((counts > tile).any(), further_tiles,
+                            lambda out: out, out), counts
+
+    def _first_tiles(self, experts, xc, rows, wt, counts):
+        """`sum w * expert(x)` over every held expert's first tile of
+        pairs: `rows`, `wt` `[held * tile]` are the pairs' tokens and
+        weights (0 past a run's end), tile `e` expert `e`'s. ->
+        `[T, hidden]` float32. On a TPU, in bfloat16 and at shapes it
+        takes, one kernel that streams the leaves where they rest and
+        sums in place (ops/expert_kernel.py); elsewhere the same three
+        products and a scatter-add an expert."""
+        t, tile = xc.shape[0], rows.shape[0] // len(experts)
+
+        def plain(experts, xs, rows, wt, counts):
+            # a scatter-add an expert: one of every tile's rows at once
+            # took twice their time on a v5e (PERF.md, PR 29); `counts`
+            # is for the kernel, here `wt` is 0 past a run's end
+            out = jnp.zeros((t, xs.shape[1]), jnp.float32)
+            for e, expert in enumerate(experts):
+                at = slice(e * tile, (e + 1) * tile)
+                out = out.at[rows[at]].add(
+                    self._mlp(expert, xs[at]) * wt[at, None])
+            return out
+
+        hidden, inter = experts[0]["gate"].shape
+        if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
+                or not expert_kernel.fits(t, hidden, inter, tile)):
+            return plain(experts, xc[rows], rows, wt, counts)
+        return jax.lax.platform_dependent(
+            experts, xc[rows], rows, wt, counts, default=plain,
+            tpu=functools.partial(expert_kernel.expert_tiles, tokens=t))
+
+    def _ffn(self, p, x, live):
+        """The block's second half on normed tokens `[T, hidden]`; the
+        held experts' token counts `[held]` where the layer has experts."""
+        if "mlp" in p:
+            with jax.named_scope("dense_mlp"):
+                return self._mlp(p["mlp"], x), None
+        with jax.named_scope("moe_route"):
+            idx, w = self.route(p["router"], x)
+        with jax.named_scope("moe_experts"):
+            routed, counts = self._routed(p["experts"], x, idx, w, live)
+            return self._mlp(p["shared"], x) + routed, counts
+
+    # -- tokens and the score -------------------------------------------------
+
+    def _bin(self, xn):
+        v = self.cfg.vocab
+        return jnp.clip(jnp.floor((xn + 8.0) / 16.0 * v), 0,
+                        v - 1).astype(jnp.int32)
+
+    def _window_tokens(self, x, valid):
+        """A stored window `[n, W]` (chronological, left-padded) as
+        tokens with the valid ones first, and the window's statistics:
+        (tokens `[n, W]`, count `[n]`, mean `[n]`, var `[n]`). The
+        statistics are taken value by value in stored order, by the rule
+        an event updates them with: no sum, so no order of summation
+        for another program to disagree about at a bin's edge."""
+        n, w = x.shape
+
+        def take(carry, col):
+            mean, var, cnt = carry
+            v, ok = col
+            cnt1 = jnp.minimum(cnt + 1, w)
+            d = v - mean
+            mean1 = mean + d / cnt1
+            var1 = var + ((v - mean1) * d - var) / cnt1
+            return (jnp.where(ok, mean1, mean), jnp.where(ok, var1, var),
+                    jnp.where(ok, cnt1, cnt)), None
+
+        (mean, var, count), _ = jax.lax.scan(
+            take, (jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32),
+                   jnp.zeros(n, jnp.int32)), (x.T, valid.T))
+        xn = (x - mean[:, None]) / jnp.sqrt(var + 1e-6)[:, None]
+        first = (jnp.arange(w)[None, :] + (w - count)[:, None]) % w
+        return (jnp.take_along_axis(self._bin(xn), first, axis=1), count,
+                mean, var)
+
+    def _arrive(self, params, rows, v):
+        """An event's first half, from the row's scalars and `hn`: the
+        token of the value that arrived, its score (the surprisal under
+        the head's prediction at the previous event, gated and clipped)
+        and the row's next `mean`, `var`, `count`, `pos`."""
+        c = self.cfg
+        mean, var, cnt, pos = (rows["mean"], rows["var"], rows["count"],
+                               rows["pos"])
+        token = self._bin((v - mean) / jnp.sqrt(var + 1e-6))
+        with jax.named_scope("lm_head"):
+            logits = self._mm(rows["hn"], params["head"])
+            surprisal = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+                logits, token[:, None], axis=1)[:, 0]
+        score = jnp.clip(jnp.where(cnt >= self._gate, surprisal, 0.0),
+                         0.0, c.score_clip)
+        cnt1 = jnp.minimum(cnt + 1, c.window)
+        delta = v - mean
+        mean1 = mean + delta / cnt1
+        var1 = var + ((v - mean1) * delta - var) / cnt1
+        return token, score, {"mean": mean1, "var": var1, "count": cnt1,
+                              "pos": pos + 1}
+
+    def _row_state(self, cap: int) -> dict:
+        """The leaves every such model keeps a row: the running
+        statistics, the position, and what the head needs of the
+        previous event (the final norm's output)."""
+        c = self.cfg
+        return {"mean": jnp.zeros(cap, jnp.float32),
+                "var": jnp.ones(cap, jnp.float32),
+                "count": jnp.zeros(cap, jnp.int32),
+                "pos": jnp.zeros(cap, jnp.int32),
+                "hn": jnp.zeros((cap, c.hidden_size), c.compute_dtype)}
+
+    def _warm(self, params, x, valid):
+        """Seeding's first half, the prefill form over stored windows `[n,
+        W]` (chronological, left-padded): (the state of `n` rows with
+        its window leaves still empty, the prefill's context entries a
+        layer, the values a row holds `[n]`)."""
+        c = self.cfg
+        n = x.shape[0]
+        tokens, count, mean, var = self._window_tokens(x, valid)
+        h, entries = self._prefill(params, tokens, count)
+        last = h[jnp.arange(n), jnp.maximum(count - 1, 0)]
+        state = self.init_state(n)
+        state.update(mean=mean, var=jnp.maximum(var, 1e-6),
+                     count=jnp.minimum(count, c.window), pos=count)
+        state["hn"] = jnp.where(
+            (count > 0)[:, None],
+            rms(last, params["norm"], c.rms_norm_eps), 0.0).astype(
+                c.compute_dtype)
+        return state, entries, count
+
+    def score(self, params: dict, x: jax.Array, valid: jax.Array) -> jax.Array:
+        """The newest value's score from a stored window alone (the query
+        path): the surprisal of its bin under the positions before it."""
+        def rows(x, valid):
+            tokens, count, _, _ = self._window_tokens(x, valid)
+            h, _ = self._prefill(params, tokens, count)
+            return self._window_score(params, h, tokens, count)
+
+        return self._in_blocks(rows, x, valid)
+
+    def _window_score(self, params, h, tokens, count):
+        """The newest value's score from a prefill over its window (the
+        query path): the surprisal of its bin under the positions
+        before it, `h` `[n, S, hidden]` before the final norm."""
+        n = h.shape[0]
+        at = jnp.maximum(count - 1, 1)
+        logits = self._logits(params, h[jnp.arange(n), at - 1])
+        surprisal = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+            logits, tokens[jnp.arange(n), at][:, None], axis=1)[:, 0]
+        return jnp.clip(jnp.where(count >= self._gate, surprisal, 0.0),
+                        0.0, self.cfg.score_clip)
+
+    def _logits(self, params, h):
+        with jax.named_scope("lm_head"):
+            return self._mm(rms(h, params["norm"], self.cfg.rms_norm_eps),
+                            params["head"])
+
+    def _in_blocks(self, fn, *rows):
+        """`fn` over row blocks of `seed_rows`, one after another, so a
+        whole bucket's windows never stand in memory at once."""
+        n, b = rows[0].shape[0], self.seed_rows
+        if n <= b:
+            return fn(*rows)
+        pad = -n % b
+        blocks = [jnp.concatenate([r, jnp.zeros((pad,) + r.shape[1:],
+                                                r.dtype)]).reshape(
+            (-1, b) + r.shape[1:]) for r in rows]
+        out = jax.lax.map(lambda xs: fn(*xs), tuple(blocks))
+        return jax.tree.map(
+            lambda o: o.reshape((-1,) + o.shape[2:])[:n], out)

@@ -1,9 +1,12 @@
 """Pallas TPU kernel: a layer's held experts over their tiles of tokens.
 
 Why this op: a held expert of `dsv3-stream` sees a few dozen tokens a
-step (models/dsv3.py `routed`), so its three products are bound by
-reading the expert's weights (88 MB in bf16 at the published widths,
-0.1 ms at a v5e's 819 GB/s), not by arithmetic. One call takes the
+step, one of `laguna-stream` about ten (models/seqblocks.py `routed`),
+so its three products are bound by reading the expert's weights (88 MB
+in bf16 at DeepSeek-V3's published widths, 0.1 ms at a v5e's 819 GB/s;
+19 MB at Laguna-S-2.1's), not by arithmetic. Every size is read off
+the shapes it is handed: the held experts (16 of 7168 x 2048, 32 of
+3072 x 1024), the tile, the tokens. One call takes the
 whole layer: every expert's leaves stay where they rest in HBM, one
 leaf an expert a projection, and are streamed through VMEM once, in
 blocks of the intermediate width, one expert after another with no gap
@@ -25,14 +28,15 @@ a row added in place, and is written out once at the end. (Measured on
 a v5e, PERF.md PR 29: 118 us an expert, 746 GB/s; the same sum as 16
 XLA scatter-adds of 128 rows cost 33 us each.)
 
-Numbers as the plain path's (`Dsv3StreamModel._mlp`): operands bf16,
+Numbers as the plain path's (`SeqBlocks._mlp`): operands bf16,
 accumulation f32, `silu(g) * u` in f32 and rounded to bf16 once before
 the down product, the pair's weight applied in f32, the sum over a
 token's experts in f32, expert by expert. The down product is summed
 block by block in f32, so the two paths differ by the order of a
 float32 sum.
 
-VMEM: the output (28 MiB for 1,024 tokens at hidden 7168), the token
+VMEM: the output (28 MiB for 1,024 tokens at hidden 7168, 3 MiB for
+256 at 3072), the token
 tile twice, one tile of sums, three weight blocks twice (`BLOCK`
 columns: 10.5 MiB). `vmem_bytes` is the sum, and `fits` says whether a
 call stays under `VMEM_LIMIT`: what XLA keeps in VMEM across the call

@@ -123,24 +123,29 @@ class ScoringSession:
         # crash loses nothing — the supervisor restarts the consuming
         # loop and the still-pending events flush on the next tick
         self.faults = faults
-        # weights of which the device cannot hold two sets (what the code
-        # can see: their bytes against the device's memory) are never
-        # resident twice: the session builds none of its own and holds
-        # none until the first set is bound (`swap_params`, which then
-        # seeds and warms), and a later set takes its predecessor's place
-        # leaf by leaf
+        # weights of which the device cannot hold two sets beside the
+        # ring's table, with a tenth of it left for the programs' scratch
+        # (what the code can see: their bytes against the device's
+        # memory), are never resident twice: the session builds none of
+        # its own and holds none until the first set is bound
+        # (`swap_params`, which then seeds and warms), and a later set
+        # takes its predecessor's place leaf by leaf
+        self.params = None
+        self.ring = self._new_ring(self._fleet_rows())
         limit = device_memory_bytes()
-        self.one_set_only = limit is not None and 2 * sum(
-            x.size * x.dtype.itemsize for x in jax.tree.leaves(
-                jax.eval_shape(model.init, jax.random.PRNGKey(cfg.seed)))
-        ) > limit
+        table = sum(x.nbytes for x in jax.tree.leaves(
+            getattr(self.ring, "state", None)))
+        weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+            jax.eval_shape(model.init, jax.random.PRNGKey(cfg.seed))))
+        self.one_set_only = (limit is not None
+                             and table + 2 * weights > 0.9 * limit)
         if params is None and not self.one_set_only:
             params = model.init(jax.random.PRNGKey(cfg.seed))
-        self.params = None if params is None else jax.device_put(params)
+        if params is not None:
+            self.params = jax.device_put(params)
+            if hasattr(self.ring, "bind_params"):
+                self.ring.bind_params(self.params)
         self.version = 0
-        host = telemetry.channels.get(cfg.mtype)
-        self.ring = self._new_ring(max(
-            cfg.capacity, host.capacity if host else 0, 1024))
         self._fns: dict[int, Callable] = {}   # score_devices query path
         # False while warmup compiles buckets; flushes are held (admission
         # capped) so no live request pays a compile
@@ -212,10 +217,22 @@ class ScoringSession:
             "ctx.positions": lambda: metrics.histogram(
                 "scoring.ctx.positions", buckets=octaves).observe,
             "moe.runs_one_tile": lambda: metrics.counter(
-                "scoring.moe.runs_one_tile").inc}
+                "scoring.moe.runs_one_tile").inc,
+            "ctx.window_positions": lambda: metrics.histogram(
+                "scoring.ctx.window_positions", buckets=octaves).observe,
+            "ctx.wrapped": lambda: metrics.counter(
+                "scoring.ctx.wrapped").inc}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")
+
+    def _fleet_rows(self) -> int:
+        """Rows the ring is asked for: the fleet-size hint, or as far as
+        the host store holds values. (How far past them the table goes
+        is the ring's to say.)"""
+        host = self.telemetry.channels.get(self.cfg.mtype)
+        has = np.flatnonzero(host.count) if host is not None else ()
+        return max(self.cfg.capacity, int(has[-1]) + 1 if len(has) else 0)
 
     def _new_ring(self, capacity: int):
         """Window ring (raw history, per-event window rescore) or
@@ -312,10 +329,13 @@ class ScoringSession:
         if host is None:
             return
         w = self.model.cfg.window
-        devices = np.arange(host.capacity)
+        # every row of the store, as far as the ring goes (or the fleet,
+        # where it has outgrown the ring: the load then grows it)
+        devices = np.arange(min(host.capacity, max(self.ring.capacity,
+                                                   self._fleet_rows())))
         x, _ = host.window(devices, w)
         with self.tracer.span("rule-processing.seed"):
-            self.ring.load(x, np.minimum(host.count, w))
+            self.ring.load(x, np.minimum(host.count[devices], w))
             # a streaming ring seeds on the device: the span ends when
             # that work has (the window ring only uploads)
             jax.block_until_ready(getattr(self.ring, "state", None))

@@ -50,12 +50,19 @@ row's own position)` into the donated buffer: a 1 MB context is read
 whole, as attention must, and 1 KB of it is written. Such a model's
 `step_score` also takes `live` (the rows that are no padding) and may
 return a third value, the numbers of `model.step_stats`, which ride
-home at the end of the score vector. A row whose window is full is
-seeded again before its next event, exactly as at warm-up, from the last
-`window` values the ring was given for it (its own host record of them:
-what the host store holds for the row once it has taken the same
-events, without a race against the persister); `model.seed_rows`, where
-declared, is how many rows one seeding call takes (a prefill's
+home at the end of the score vector. Each window leaf has its own
+bound, its `shape[1]`. One that the model names in `wraps` keeps the
+newest `shape[1]` positions: the entry of position `p` is written at
+`p mod shape[1]` over the oldest, so it never fills. The others are
+bounded: a row is FULL when its position reaches the smallest of their
+bounds, and a full row is seeded again before its next event, exactly
+as at warm-up, from the last `window` values the ring was given for it
+(its own host record of them: what the host store holds for the row
+once it has taken the same events, without a race against the
+persister). `model.cfg.window` may be longer than a wrapping leaf:
+`warm_state` then leaves in it what the steps would have left, the
+last `shape[1]` positions in their wrapped slots. `model.seed_rows`,
+where declared, is how many rows one seeding call takes (a prefill's
 activations have to fit beside the weights).
 
 The host `TelemetryStore` stays the durable copy; `load()` rebuilds
@@ -72,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sitewhere_tpu.utils import grow_pow2
+from sitewhere_tpu.utils.backend import device_memory_bytes
 
 # how every scatter of the ring writes: padding lies past the table and
 # is dropped, and no row is named twice ("Contract with the engines")
@@ -84,6 +92,45 @@ def pad_rows(scratch: int, n: int) -> np.ndarray:
     return np.arange(scratch + 1, scratch + 1 + n, dtype=np.int32)
 
 
+def table_rows(model, asked: int, floor: int) -> int:
+    """Rows of a ring's table for a fleet of `asked` devices: the next
+    power of two from `floor` up (room to grow into, few compiled
+    shapes), unless a table that long would take over half of the
+    device's memory by what `model.init_state`'s rows weigh (a context
+    of megabytes a device); then as many rows as were asked for."""
+    rows = grow_pow2(asked, floor=floor)
+    limit = device_memory_bytes()
+    if limit is None or rows == asked:
+        return rows
+    row = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        jax.eval_shape(lambda: model.init_state(1))))
+    return asked if rows * row > limit // 2 else rows
+
+
+# the most a row may weigh for the compiler of a v5e to gather it as one
+# slice: past 512 KiB it first slices the WHOLE table by lanes, then
+# walks the rows of each piece in a loop (PERF.md section 6, PR 32)
+GATHER_SLICE_BYTES = 1 << 19
+
+
+def _rows(leaf, dev):
+    """Rows `dev` of a table for reading (padding reads the scratch
+    row). A row of `[positions, width]` heavier than one gathered slice
+    may be is taken in blocks of positions, through a view of the table
+    that splits its positions (no bytes move for a view): one gather
+    either way."""
+    row_bytes = leaf[0].size * leaf.dtype.itemsize
+    if leaf.ndim != 3 or row_bytes <= GATHER_SLICE_BYTES:
+        return leaf.at[dev].get(mode="clip", indices_are_sorted=True)
+    rows, positions, width = leaf.shape
+    blocks = next(n for n in range(-(-row_bytes // GATHER_SLICE_BYTES),
+                                   positions + 1) if positions % n == 0)
+    got = leaf.reshape(rows, blocks, positions // blocks, width).at[
+        dev[:, None], jnp.arange(blocks)[None, :]].get(
+            mode="clip", indices_are_sorted=True)
+    return got.reshape(dev.shape[0], positions, width)
+
+
 def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     """The ring step's three parts, under the `jax.named_scope`s a
     profile shows them by: rows of `dev` out of the table, one cell step
@@ -92,9 +139,7 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     position. -> (state, scores, the step's numbers or None)."""
     windows = getattr(model, "windows", None)
     with jax.named_scope("ring_gather"):
-        rows = jax.tree.map(
-            lambda leaf: leaf.at[dev].get(mode="clip",
-                                          indices_are_sorted=True), state)
+        rows = jax.tree.map(lambda leaf: _rows(leaf, dev), state)
     stats = None
     with jax.named_scope("cell_step"):
         if windows is None:
@@ -110,10 +155,14 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
                 state, new_rows)
         return state, scores, stats
     out = {}
+    wraps = getattr(model, "wraps", ())
     with jax.named_scope("ctx_append"):
         for name, at in windows.items():
-            out[name] = state[name].at[dev, rows[at]].set(new_rows[name],
-                                                          **DISTINCT_ROWS)
+            slot = rows[at]
+            if name in wraps:       # the newest positions, in a circle
+                slot = slot % state[name].shape[1]
+            out[name] = state[name].at[dev, slot].set(new_rows[name],
+                                                      **DISTINCT_ROWS)
     with jax.named_scope("ring_scatter"):
         for name, leaf in state.items():
             if name not in windows:
@@ -232,7 +281,7 @@ class StreamingRing:
                  sparse_k: int = 0):
         self.model = model
         self.window = int(model.cfg.window)  # load()-contract width
-        self.capacity = grow_pow2(int(capacity), floor=initial_floor)
+        self.capacity = table_rows(model, int(capacity), initial_floor)
         self.score_dtype = jnp.dtype(score_dtype) if score_dtype else None
         # sparse anomaly readback (streaming_step_sparse): set a
         # threshold to ship only anomalous (position, score) pairs home
@@ -251,15 +300,17 @@ class StreamingRing:
                 state, seeded), donate_argnums=(0,))
         self.faulted = False
         self.state = jax.device_put(model.init_state(self.capacity + 1))
-        # a model with window leaves: how many positions a row's windows
-        # hold, and on the host how many each row has filled (the device's
+        # a model with window leaves: how many positions a row's bounded
+        # windows hold (the smallest bound among those that do not wrap),
+        # and on the host how many each row has filled (the device's
         # `pos` leaf, mirrored so that a full row is known without a
         # read-back) and the last `window` values it was given (a ring of
         # them a row, `_oldest` where the oldest lies): what a full row is
         # seeded again from. `reseeded` counts such rows.
-        windows = getattr(model, "windows", None)
-        self._positions = (self.state[next(iter(windows))].shape[1]
-                           if windows else 0)
+        windows = getattr(model, "windows", None) or {}
+        self._positions = min(
+            (self.state[name].shape[1] for name in windows
+             if name not in getattr(model, "wraps", ())), default=0)
         rows = self.capacity + 1 if windows else 0
         self._filled = np.zeros(rows, np.int32)
         self._recent = np.zeros((rows, self.window), np.float32)

@@ -31,7 +31,18 @@ TINY = chip_smoke.Sizes(
         qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=16, n_group=2,
         topk_group=1, num_experts_per_tok=4, vocab_size=64,
         n_routed_experts_held=4, mtp_modules=0, window=64,
-        context_positions=96))
+        context_positions=96),
+    laguna_devices=32, laguna_config=dict(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, num_hidden_layers=3,
+        num_key_value_heads=2, head_dim=64,
+        num_attention_heads_per_layer=[4, 6, 4], num_experts=16,
+        num_experts_per_tok=4, vocab_size=64, num_experts_held=4,
+        sliding_window=32, window=64, context_positions=96,
+        layer_types=["full_attention", "sliding_attention",
+                     "full_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"],
+        gating_types=["per_head"] * 3))
 
 
 def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
@@ -45,7 +56,7 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert summary["device"] == {"platform": "cpu", "kind": "cpu",
                                  "count": 8}
     assert summary["compile_cache"] == str(tmp_path)
-    a, b, c, d = (summary["phases"][k] for k in "ABCD")
+    a, b, c, d, e = (summary["phases"][k] for k in "ABCDE")
     want = TINY.devices * TINY.ticks
     assert a["sent"] == a["scored"] == a["published"] == want
     assert a["alerts"] >= 1 and a["compiles_after_warmup"] == 0
@@ -62,6 +73,14 @@ def test_smoke_body_tiny_on_cpu(monkeypatch, tmp_path):
     assert isinstance(d["table_moves"], list) and d["assignments_held"] > 0
     assert d["compiles_after_warmup"] == 0 and d["device_bytes"] is None
     assert {"ctx0", "ctx1", "hn", "pos"} <= set(d["state_layouts"])
+    # `laguna-stream` the same way: a windowed and a full context a layer
+    assert e["sent"] == e["scored"] == e["published"] == 32 * TINY.ticks
+    assert isinstance(e["table_moves"], list) and e["assignments_held"] > 0
+    assert e["compiles_after_warmup"] == 0
+    assert {"k0", "v0", "k1", "v1", "k2", "v2", "hn", "pos"} \
+        <= set(e["state_layouts"])
+    assert e["state_layouts"]["k1"].startswith("[1025, 32, 128]")
+    assert e["state_layouts"]["k2"].startswith("[1025, 96, 128]")
 
 
 @pytest.mark.parametrize("line,found", [

@@ -2,9 +2,11 @@
 and not attached (the TPU's compiler is installed here), at the published
 widths with one leading and one expert layer and a small fleet: the
 compiled step copies and transposes no context leaf, which rests
-row-major in whole lane tiles; and the ring step of `lstm-stream` at
-`stream-512k`'s own size, which moves rows of ONE table. Nothing runs,
-so nothing here is a time.
+row-major in whole lane tiles; the ring step of `laguna-stream` at its
+benchmark configuration's own size (five layers, 769 rows of 12 MiB),
+held to the same and to the chip's memory; and the ring step of
+`lstm-stream` at `stream-512k`'s own size, which moves rows of ONE
+table. Nothing runs, so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
 test that needs it is in this one file (one process loads the TPU's
@@ -130,52 +132,141 @@ def _inside_whiles(comps: dict) -> set:
     return inside
 
 
-def test_every_expert_leaf_is_read_once_outside_any_loop(step):
-    """The held experts as one grouped pass: each of an expert layer's 48
-    leaves goes to a kernel (or a product) of the entry computation,
-    outside any `while`; the `while`s that remain are the overflow's,
-    one a held expert, under `moe_experts` and behind ONE conditional,
-    and only they read a leaf a second time; no leaf is copied or
-    sliced."""
-    model, _, compiled = step
-    hlo = compiled.as_text()
+def _expert_leaves_read_once(hlo: str, held: int, layers: list,
+                             hidden: int, inter: int) -> None:
+    """The held experts as one grouped pass a layer: each of an expert
+    layer's `3 * held` leaves goes to a kernel (or a product) of the
+    entry computation, outside any `while`; the `while`s that remain are
+    the overflow's, one a held expert, under `moe_experts` and behind ONE
+    conditional a layer, and only they read a leaf a second time; no leaf
+    is copied or sliced."""
     comps, entry = _computations(hlo)
     inside = _inside_whiles(comps)
     assert entry not in inside
-    held = model.cfg.experts_held
-    leaves = re.findall(r"(params__layer1____experts____e\d+____"
-                        r"(?:gate|up|down)__[.\d]*): bf16", hlo)
-    assert len(set(leaves)) == 3 * held
     body = comps[entry]
-    for leaf in set(leaves):
-        uses = [line for line in body if re.search(
-            rf"(?<![\w.])%{re.escape(leaf)}(?![\w.])", line)
-            and " parameter(" not in line]
-        # the kernel (or a product) takes the leaf as it rests, or the
-        # compiler fetches it ahead into fast memory, whole, for the
-        # kernel and the overflow's loop after it; every other use hands
-        # it to that loop
-        reads = [line for line in uses
-                 if "tpu_custom_call" in line or " convolution(" in line
-                 or " dot(" in line or "kind=kOutput" in line]
-        ahead = [line for line in uses
-                 if " slice-start(" in line or " copy-start(" in line]
-        assert len(reads) == 1 or (not reads and ahead), (leaf, uses)
-        assert all("moe_experts" in line for line in reads)
-        assert all(" conditional(" in line or " while(" in line
-                   or " tuple(" in line for line in uses
-                   if line not in reads and line not in ahead), (leaf, uses)
+    for layer in layers:
+        leaves = re.findall(rf"(params__layer{layer}____experts____e\d+____"
+                            r"(?:gate|up|down)__[.\d]*): bf16", hlo)
+        assert len(set(leaves)) == 3 * held
+        for leaf in set(leaves):
+            uses = [line for line in body if re.search(
+                rf"(?<![\w.])%{re.escape(leaf)}(?![\w.])", line)
+                and " parameter(" not in line]
+            # the kernel (or a product) takes the leaf as it rests, or the
+            # compiler fetches it ahead into fast memory, whole, for the
+            # kernel and the overflow's loop after it; every other use
+            # hands it to that loop
+            reads = [line for line in uses
+                     if "tpu_custom_call" in line or " convolution(" in line
+                     or " dot(" in line or "kind=kOutput" in line]
+            ahead = [line for line in uses
+                     if " slice-start(" in line or " copy-start(" in line]
+            assert len(reads) == 1 or (not reads and ahead), (leaf, uses)
+            assert all("moe_experts" in line for line in reads)
+            assert all(" conditional(" in line or " while(" in line
+                       or " tuple(" in line for line in uses
+                       if line not in reads and line not in ahead), (leaf,
+                                                                     uses)
     kernels = [line for line in body if "tpu_custom_call" in line]
-    assert len(kernels) == 1 and "moe_experts" in kernels[0]
+    assert len(kernels) == len(layers)
+    assert all("moe_experts" in line for line in kernels)
     whiles = [line for lines in comps.values() for line in lines
               if " while(" in line]
-    assert len(whiles) <= held
+    assert len(whiles) <= held * len(layers)
     assert all("moe_experts" in line for line in whiles)
-    assert len([line for line in body if " conditional(" in line]) == 1
+    assert len([line for line in body
+                if " conditional(" in line]) == len(layers)
     moved = [line for line in hlo.splitlines() if re.search(
-        r"= bf16\[(?:7168,2048|2048,7168)\]\S* "
+        rf"= bf16\[(?:{hidden},{inter}|{inter},{hidden})\]\S* "
         r"(?:copy|slice|dynamic-slice)\(", line)]
     assert moved == []
+
+
+def test_every_expert_leaf_is_read_once_outside_any_loop(step):
+    model, _, compiled = step
+    _expert_leaves_read_once(compiled.as_text(), model.cfg.experts_held, [1],
+                             7168, 2048)
+
+
+# -- `laguna-stream` at `laguna-s-2.1-ep8`'s own size -------------------------
+
+LAGUNA_ROWS, LAGUNA_FRAME = 769, 256
+
+
+@pytest.fixture(scope="module")
+def laguna_step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip) at the benchmark configuration's `model_config` as it stands."""
+    import json
+    import os
+
+    from sitewhere_tpu.models import build_model
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "laguna-s-2.1-ep8.json")) as fh:
+        model = build_model("laguna-stream", **json.load(fh)["model_config"])
+    state, compiled = _compile_step(model, LAGUNA_ROWS, LAGUNA_FRAME,
+                                    one_chip, jnp.float32)
+    return model, state, compiled
+
+
+def test_laguna_step_moves_no_context_leaf_and_fits_the_chip(laguna_step):
+    """Ten window leaves, six of 512 positions and four of 768, a
+    position's keys or values 1,024 lanes: none is copied, transposed
+    or sliced, as a table or as the frame's gathered rows; each rests
+    row-major; the donated state comes back in its own buffers; and the
+    step's arguments and scratch fit a v5e's 16 GiB with room."""
+    from chip_smoke import _table_moves
+
+    model, state, compiled = laguna_step
+    hlo = compiled.as_text()
+    assert _table_moves(hlo, LAGUNA_ROWS) == []
+    # nothing of a table's length but the leaves themselves: told to
+    # gather rows of over 512 KiB, the compiler sliced each whole table
+    # by lanes first (`bf16[769,768,384]`) and walked the rows in a loop;
+    # the ring gathers such rows in blocks of positions
+    views = {f"bf16[{LAGUNA_ROWS},2,256,1024]",
+             f"bf16[{LAGUNA_ROWS},3,256,1024]"}
+    shapes = set(re.findall(rf"\w+\[{LAGUNA_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == views | {
+        f"bf16[{LAGUNA_ROWS},512,1024]", f"bf16[{LAGUNA_ROWS},768,1024]",
+        f"bf16[{LAGUNA_ROWS},3072]", f"f32[{LAGUNA_ROWS}]",
+        f"s32[{LAGUNA_ROWS}]"}, shapes
+    # ...a view of a table in blocks of 256 positions is a bitcast of it
+    made = [line for line in hlo.splitlines() if re.match(
+        rf"\s*(?:ROOT )?%\S+ = bf16\[{LAGUNA_ROWS},[23],256,1024\]", line)]
+    assert made and all(" bitcast(" in line or " parameter(" in line
+                        for line in made), made
+    assert "mini-gather" not in hlo
+    whiles = [line for line in hlo.splitlines() if " while(" in line]
+    assert all("moe_experts" in line for line in whiles)
+    for positions, leaves in ((512, 6), (768, 4)):
+        assert sum(leaf.shape == (LAGUNA_ROWS, positions, 1024)
+                   for leaf in state.values()) == leaves
+        layouts = set(re.findall(
+            rf"bf16\[{LAGUNA_ROWS},{positions},1024\]\{{([\d,]+)", hlo))
+        assert layouts == {"2,1,0"}
+    moved = [line for line in hlo.splitlines() if re.search(
+        r"= bf16\[\d+,(?:512|768),1024\]\S* "
+        r"(?:copy|transpose|slice|dynamic-slice)\(", line)]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert state_bytes > 9.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9
+
+
+def test_laguna_expert_leaves_are_read_once_by_one_kernel_a_layer(
+        laguna_step):
+    """The expert kernel at its second width (3072 x 1024, 32 held, 96
+    leaves a layer, four layers): the same grouped pass, from the shapes
+    it is handed."""
+    model, _, compiled = laguna_step
+    _expert_leaves_read_once(compiled.as_text(), model.experts.held,
+                             [1, 2, 3, 4], 3072, 1024)
 
 
 def test_lstm_stream_step_moves_rows_of_one_table(one_chip):

@@ -171,10 +171,14 @@ def test_ring_probe_keeps_compiled_fn(monkeypatch):
     np.testing.assert_allclose(scores[:8], ref_scores[:8], atol=1e-5)
 
 
+@pytest.mark.parametrize("hidden, inter", [(7168, 2048), (3072, 1024)],
+                         ids=["dsv3_widths", "laguna_widths"])
 @pytest.mark.parametrize("held, tile", [(1, 64), (2, 32), (3, 32)])
-def test_expert_kernel_matches_the_plain_products_interpret(held, tile):
-    """ops/expert_kernel.py at the published widths (hidden 7168,
-    intermediate 2048 in its sixteen blocks of 128) over one, two and
+def test_expert_kernel_matches_the_plain_products_interpret(held, tile,
+                                                            hidden, inter):
+    """ops/expert_kernel.py at each served model's published widths
+    (hidden 7168, intermediate 2048 in its sixteen blocks of 128; hidden
+    3072, intermediate 1024 in eight) over one, two and
     three experts of few rows, against the three products
     `Dsv3StreamModel._mlp` makes of each and one scatter-add: bf16
     operands, f32 sums, `silu * up` rounded to bf16 once, the weight
@@ -185,8 +189,10 @@ def test_expert_kernel_matches_the_plain_products_interpret(held, tile):
     from sitewhere_tpu.models import build_model
     from sitewhere_tpu.ops.expert_kernel import expert_tiles, fits
 
-    hidden, inter, tokens = 7168, 2048, 72
-    assert fits(1024, hidden, inter, 128) and not fits(2048, hidden, inter, 128)
+    tokens = 72
+    # a frame of either cell fits, a seeding call's tokens do not
+    assert fits(1024, 7168, 2048, 128) and not fits(2048, 7168, 2048, 128)
+    assert fits(256, 3072, 1024, 128) and not fits(4224, 3072, 1024, 128)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 3 * held + 3))
     experts = [{name: (jax.random.normal(next(keys), shape, jnp.float32)
                        * 0.02).astype(jnp.bfloat16)

@@ -722,3 +722,180 @@ def test_a_take_in_any_order_scores_as_a_per_event_loop(run, engine, kind):
         (s if engine == "session" else pool).close()
 
     run(main())
+
+
+# -- window leaves bounded one by one (a wrapping leaf beside a bounded one) --
+
+
+class _TwoBounds:
+    """The least model with two kinds of window in one row: `near` holds
+    4 positions and wraps, `far` holds 8 and is bounded; an entry is the
+    event's value in every lane, the score the value."""
+
+    streaming = True
+    windows = {"near": "pos", "far": "pos"}
+    step_stats = ()
+    seed_rows = 2
+
+    class cfg:
+        window = 5          # longer than `near`
+
+    def __init__(self, wraps=("near",), near=4, far=8):
+        self.wraps, self.bounds = frozenset(wraps), {"near": near, "far": far}
+
+    def init(self, rng):
+        import jax.numpy as jnp
+
+        return {"one": jnp.ones(())}
+
+    def init_state(self, cap):
+        import jax.numpy as jnp
+
+        return {"pos": jnp.zeros(cap, jnp.int32),
+                **{name: jnp.zeros((cap, n, 128), jnp.float32)
+                   for name, n in self.bounds.items()}}
+
+    def step_score(self, params, rows, v, live):
+        import jax.numpy as jnp
+
+        entry = jnp.broadcast_to(v[:, None], (v.shape[0], 128))
+        return v * params["one"], {"pos": rows["pos"] + 1, "near": entry,
+                                   "far": entry}, None
+
+    def warm_state(self, params, x, valid):
+        """The stored values as positions 0..count-1, where the steps
+        would have left them."""
+        import jax.numpy as jnp
+
+        n, w = x.shape
+        count = valid.sum(1)
+        first = (jnp.arange(w)[None, :] + (w - count)[:, None]) % w
+        vals = jnp.take_along_axis(x, first, axis=1)
+        state = self.init_state(n)
+        state["pos"] = count.astype(jnp.int32)
+        for name, bound in self.bounds.items():
+            for p in range(w):
+                slot = p % bound if name in self.wraps else p
+                state[name] = state[name].at[:, slot].set(jnp.where(
+                    (p < count)[:, None], vals[:, p, None],
+                    state[name][:, slot]))
+        return state
+
+
+def _two_bounds_ring(model, events):
+    """Three rows seeded from values 1..5, then `events` events of
+    values 6, 7, ...: (ring, the values each row holds by leaf)."""
+    import jax
+
+    ring = StreamingRing(model, capacity=3, initial_floor=3)
+    ring.bind_params(model.init(jax.random.PRNGKey(0)))
+    ring.load(np.tile(np.arange(1, 6, dtype=np.float32), (3, 1)),
+              np.full(3, 5))
+    for k in range(events):
+        out = np.asarray(ring.update_and_score(
+            model, ring._params, np.arange(3, dtype=np.int32),
+            np.full(3, 6.0 + k, np.float32), 4))
+        assert (out[:3] == 6.0 + k).all()
+    held = {name: np.asarray(ring.state[name])[:3, :, 0]
+            for name in model.windows}
+    return ring, held
+
+
+@pytest.mark.parametrize("events", [0, 1, 2, 3])
+def test_a_wrapping_leaf_takes_position_p_at_p_mod_its_bound(events):
+    """Value `p + 1` is position `p`: `near` keeps the newest 4 in
+    their wrapped slots, seeded (5 values through 4 slots) and stepped
+    alike; `far` keeps every position where it is; and the bound that
+    says "full" is `far`'s alone."""
+    ring, held = _two_bounds_ring(_TwoBounds(), events)
+    assert ring._positions == 8 and ring.reseeded == 0
+    last = 5 + events                     # positions 0..last-1 are filled
+    want_near = [max(p for p in range(last) if p % 4 == s) + 1
+                 for s in range(4)]
+    assert (held["near"] == want_near).all()
+    assert (held["far"][:, :last] == np.arange(1, last + 1)).all()
+    assert (held["far"][:, last:] == 0).all()
+    assert (np.asarray(ring.state["pos"])[:3] == last).all()
+    assert (ring._filled[:3] == last).all()
+
+
+def test_a_row_is_full_by_its_bounded_leaf_alone_and_then_seeded_again():
+    """Positions 5, 6, 7 fill `far`; the fourth event finds the rows
+    full, seeds them again from their last 5 values (4..8: `near`
+    wraps them as the seeding does) and lands at position 5."""
+    ring, held = _two_bounds_ring(_TwoBounds(), 4)
+    assert ring.reseeded == 3
+    assert (np.asarray(ring.state["pos"])[:3] == 6).all()
+    assert (held["far"][:, :6] == [4, 5, 6, 7, 8, 9]).all()
+    assert (held["near"] == [8, 9, 6, 7]).all()
+
+
+def test_a_row_whose_leaves_all_wrap_is_never_full():
+    ring, held = _two_bounds_ring(_TwoBounds(wraps=("near", "far")), 12)
+    assert ring._positions == 0 and ring.reseeded == 0
+    assert (np.asarray(ring.state["pos"])[:3] == 17).all()
+    assert (held["near"] == [17, 14, 15, 16]).all()
+    assert (held["far"] == [17, 10, 11, 12, 13, 14, 15, 16]).all()
+
+
+def test_without_wraps_the_smallest_bound_is_the_rows():
+    """No leaf wraps (what `dsv3-stream` declares): the smallest bound
+    among the leaves says "full", as the one bound did."""
+    ring, held = _two_bounds_ring(_TwoBounds(wraps=(), near=6), 1)
+    assert ring._positions == 6 and ring.reseeded == 0
+    assert (held["near"] == [1, 2, 3, 4, 5, 6]).all()
+    ring.update_and_score(ring.model, ring._params,
+                          np.arange(3, dtype=np.int32),
+                          np.full(3, 7.0, np.float32), 4)
+    assert ring.reseeded == 3
+    assert (np.asarray(ring.state["near"])[:3, :, 0] == [2, 3, 4, 5, 6, 7]).all()
+
+
+@pytest.mark.parametrize("slice_bytes,blocks", [(1 << 19, 1), (3072, 2),
+                                                (2048, 3), (1024, 6)])
+def test_heavy_rows_are_gathered_in_blocks_of_positions(monkeypatch,
+                                                        slice_bytes, blocks):
+    """A window leaf's rows for reading: whole where a row weighs no more
+    than one gathered slice may (how every leaf was read before), else in
+    the fewest equal blocks of positions that do, through a view of the
+    table; the same rows either way, the scratch row for padding."""
+    import jax
+    import jax.numpy as jnp
+
+    from sitewhere_tpu.scoring import stream
+
+    monkeypatch.setattr(stream, "GATHER_SLICE_BYTES", slice_bytes)
+    table = jax.random.normal(jax.random.PRNGKey(0), (9, 6, 256), jnp.float32)
+    dev = jnp.asarray([1, 4, 5, 9, 10], jnp.int32)     # two of padding
+    text = jax.jit(lambda t, d: stream._rows(t, d)).lower(
+        table, dev).as_text()
+    assert (f"tensor<9x{blocks}x{6 // blocks}x256xf32>" in text) \
+        == (blocks > 1)
+    assert text.count('"stablehlo.gather"(') == 1
+    got = np.asarray(stream._rows(table, dev))
+    assert (got == np.asarray(table)[[1, 4, 5, 8, 8]]).all()
+    # a leaf of one value a row, or of one tile a row, is read as it was
+    flat = jnp.arange(9.0)
+    assert (np.asarray(stream._rows(flat, dev)) == [1, 4, 5, 8, 8]).all()
+
+
+@pytest.mark.parametrize("limit,asked,rows", [
+    (None, 768, 1024),                 # a backend that reports no memory
+    (1 << 40, 768, 1024),              # light rows: the next power of two
+    (12 << 20, 768, 768),              # 1,024 rows would take over half
+    (12 << 20, 512, 512),              # ...under the floor of 1,024 too
+    (1 << 20, 1024, 1024),             # a power of two is what was asked
+], ids=["no_limit", "light_rows", "heavy_rows", "heavy_rows_under_floor",
+        "a_power_of_two"])
+def test_a_table_is_rounded_up_only_while_that_costs_little(
+        monkeypatch, limit, asked, rows):
+    """`table_rows`: rows of 6,148 B (`_TwoBounds`); 1,024 of them are
+    6.3 MB, over half of a device of 12 MiB."""
+    from sitewhere_tpu.scoring import stream
+
+    monkeypatch.setattr(stream, "device_memory_bytes", lambda: limit)
+    got = stream.table_rows(_TwoBounds(), asked, 1024)
+    assert got == rows
+    ring = StreamingRing(_TwoBounds(), capacity=asked)
+    assert ring.capacity == got
+    assert ring.state["far"].shape == (got + 1, 8, 128)
