@@ -72,6 +72,7 @@ TRACE_STAGES: tuple[tuple[str, str], ...] = (
     ("wire.poll", "queue"),                  # broker append → delivery
     ("inbound.enrich", "service"),           # mask validate + split
     ("event-management.persist", "service"), # columnar store scatter
+    ("device-state.merge", "service"),       # enriched batch → dense state
     ("rule-processing.seed", "service"),     # stored windows → ring state
     ("rule-processing.dispatch", "queue"),   # admission → jit dispatch
     ("rule-processing.score", "service"),    # dispatch → scores on host
@@ -154,6 +155,10 @@ COUNTERS = (
     "event_sources.quota_rejected",
     "event_management.enrich_publish_failures",
     "device_state.presence_transitions",
+    # batches the state merger merged, and those whose ids ascended on
+    # one channel, so that neither a sort nor a ufunc.at ran
+    "device_state.merges",
+    "device_state.merges_fast",
     "schedule.jobs_fired",
     "command_delivery.delivered",
     "command_delivery.failed",
@@ -287,6 +292,9 @@ HISTOGRAMS = (
     "scoring.device_enqueue_s",
     "scoring.device_wait_s",
     "scoring.settle_wake_s",
+    # one enriched batch into the dense device state, on the event loop
+    # (services/device_state.py); quarter octaves as the three above
+    "device_state.merge_s",
     "scoring.moe.expert_max_tokens",
     # a step's mean attended length: over the bounded window leaves,
     # and over those that wrap

@@ -30,7 +30,7 @@ from typing import Iterable
 
 from jax.profiler import TraceAnnotation
 
-from sitewhere_tpu.kernel.metrics import MetricsRegistry
+from sitewhere_tpu.kernel.metrics import QUARTER_OCTAVES, MetricsRegistry
 from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.scoring.stream import result_to_host
 
@@ -60,10 +60,6 @@ SETTLE_POOL = ThreadPoolExecutor(max_workers=8, thread_name_prefix="swx-settle",
 # pipeline above
 QUERY_POOL = ThreadPoolExecutor(max_workers=2, thread_name_prefix="swx-query",
                                 initializer=_name_os_thread)
-
-# 10 us to 1 s in quarter octaves: the three parts are read as medians
-# beside each other, and a median off 2x buckets is too coarse to add up
-QUARTER_OCTAVES = [1e-5 * 2 ** (i / 4) for i in range(67)]
 
 
 def to_host(out) -> tuple:
