@@ -141,6 +141,10 @@ COUNTERS = (
     "scoring.moe.runs_one_tile",
     "scoring.ctx.reseeds",
     "scoring.ctx.wrapped",
+    # bytes of the fixed-size state leaves that the dedicated ring's
+    # dispatches rewrote whole: live rows times a row of the leaves that
+    # are no window (scoring/stream.py `rewritten_bytes`)
+    "scoring.state.rewritten_bytes",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services
@@ -300,6 +304,12 @@ HISTOGRAMS = (
     # and over those that wrap
     "scoring.ctx.positions",
     "scoring.ctx.window_positions",
+    # a recurrent matrix state's step (`step_stats` of
+    # models/olmo_hybrid.py): the mean decay it applied, and the largest
+    # magnitude it found in the rows it read, which is what their last
+    # events left (bounded while beta < 2 and ||k|| = 1)
+    "scoring.state.decay",
+    "scoring.state.absmax",
     "scoring.megabatch_tenants_per_dispatch",
     # flight recorder (kernel/observe.py): event-loop lag per beat
     "observe.loop_lag_s",

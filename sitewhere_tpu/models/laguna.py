@@ -232,7 +232,7 @@ class LagunaStreamModel(SeqBlocks):
 
     def _positions(self, layer: int) -> int:
         c = self.cfg
-        return (c.sliding_window if self.kinds[layer] == "sliding_attention"
+        return (c.sliding_window if self._sliding(layer)
                 else c.context_positions)
 
     # -- weights ------------------------------------------------------------
@@ -299,54 +299,23 @@ class LagunaStreamModel(SeqBlocks):
         cdt = self.cfg.compute_dtype
         return k.reshape(k.shape[:-2] + (-1,)).astype(cdt), v.astype(cdt)
 
+    def _sliding(self, layer: int) -> bool:
+        return self.kinds[layer] == "sliding_attention"
+
     def _attend_prefill(self, layer, q, k, v, count):
-        """The prefill form over `[n, S]` tokens: causal softmax (banded
-        on a sliding layer), positions at or past a row's `count` masked
-        out. `k`, `v` `[n, S, kv * d]` as stored. -> `[n, S, n_l, d]`."""
+        """The prefill form over `[n, S]` tokens, banded on a sliding
+        layer (models/seqblocks.py). -> `[n, S, n_l, d]`."""
         c = self.cfg
-        n, s, heads, d = q.shape
-        kv = c.num_key_value_heads
-        logits = self._ein(
-            "nqkgd,nskd->nkgqs", q.reshape(n, s, kv, heads // kv, d),
-            k.reshape(n, s, kv, d)) * self._scale
-        at = jnp.arange(s)
-        seen = at[None, :] <= at[:, None]
-        if self.kinds[layer] == "sliding_attention":
-            seen &= at[:, None] - at[None, :] < c.sliding_window
-        seen = seen[None] \
-            & (at[None, None, :] < jnp.maximum(count, 1)[:, None, None])
-        probs = jax.nn.softmax(
-            jnp.where(seen[:, None, None], logits, -jnp.inf), axis=-1)
-        out = self._ein("nkgqs,nskd->nqkgd", probs, v.reshape(n, s, kv, d))
-        return out.reshape(n, s, heads, d)
+        return self._causal_prefill(
+            q, k, v, count, c.num_key_value_heads,
+            c.sliding_window if self._sliding(layer) else None)
 
     def _attend_decode(self, layer, q, k, v, kctx, vctx, pos):
-        """The decode form for one token a row: `kctx`, `vctx` `[B, P,
-        kv * d]` are the row's stored context, `k`, `v` `[B, kv * d]`
-        its own position's, at `pos` (a sliding layer: at `pos mod P`,
-        and once it has wrapped every slot is inside the window). The
-        query of head `h` is laid in the lanes of its key-value head
-        and zeros elsewhere, so the logits are one product over a
-        position's whole entry, and a head's output is read back from
-        the same lanes of the weighted sum of values. -> `[B, n_l, d]`."""
-        c = self.cfg
-        b, heads, d = q.shape
-        kv, positions = c.num_key_value_heads, kctx.shape[1]
-        rows = jnp.arange(b)
-        slot = (pos % positions if self.kinds[layer] == "sliding_attention"
-                else pos)
-        keys = kctx.at[rows, slot].set(k, mode="drop")
-        vals = vctx.at[rows, slot].set(v, mode="drop")
-        own = jnp.eye(kv, dtype=jnp.float32)[None, :, None, :, None]
-        wide = (q.reshape(b, kv, heads // kv, 1, d) * own).reshape(
-            b, heads, kv * d)
-        logits = self._ein("bhc,bpc->bhp", wide, keys) * self._scale
-        seen = jnp.arange(positions)[None, :] <= pos[:, None]
-        probs = jax.nn.softmax(
-            jnp.where(seen[:, None, :], logits, -jnp.inf), axis=-1)
-        out = self._ein("bhp,bpc->bhc", probs, vals)
-        return (out.reshape(b, kv, heads // kv, kv, d) * own).sum(3).reshape(
-            b, heads, d)
+        """The decode form for one token a row; a sliding layer's
+        context wraps (models/seqblocks.py). -> `[B, n_l, d]`."""
+        return self._decode_rows(q, k, v, kctx, vctx, pos,
+                                 self.cfg.num_key_value_heads,
+                                 self._sliding(layer))
 
     def _attention(self, layer, p, x, at, attend):
         """The block's first half on the residual stream `x` `[...,
@@ -358,8 +327,7 @@ class LagunaStreamModel(SeqBlocks):
             q, k, v = self._project(layer, p, u, at)
             k, v = self._stored(k, v)
         with jax.named_scope(
-                "attn_window" if self.kinds[layer] == "sliding_attention"
-                else "attn_full"):
+                "attn_window" if self._sliding(layer) else "attn_full"):
             a = attend(q, k, v)
         with jax.named_scope("head_gate"):
             a = self._gated(p, u, a)

@@ -17,6 +17,10 @@ from sitewhere_tpu.models.lstm import (
     LstmConfig,
     StreamingLstmModel,
 )
+from sitewhere_tpu.models.olmo_hybrid import (
+    OlmoHybridConfig,
+    OlmoHybridStreamModel,
+)
 from sitewhere_tpu.models.seasonal import (
     SeasonalTrendConfig,
     SeasonalTrendForecaster,
@@ -32,6 +36,9 @@ MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     # Laguna-S-2.1's block (windowed and full GQA side by side, gated
     # heads, softmax-routed experts) as a streaming scorer
     "laguna-stream": (LagunaConfig, LagunaStreamModel),
+    # Olmo-Hybrid-7B's block (gated delta-rule layers with a matrix
+    # state a head, a full-attention layer a period) as a streaming scorer
+    "olmo-hybrid-stream": (OlmoHybridConfig, OlmoHybridStreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

@@ -1,8 +1,10 @@
 """What the streaming sequence scorers have in common (`dsv3-stream`,
-models/dsv3.py; `laguna-stream`, models/laguna.py): RMSNorm, rope and
-its YaRN tables, the quantiser that makes a measurement a token, the
-surprisal score with its short-history gate, and the expert layer that
-is told which experts it holds.
+models/dsv3.py; `laguna-stream`, models/laguna.py; `olmo-hybrid-stream`,
+models/olmo_hybrid.py): RMSNorm, rope and its YaRN tables, the quantiser
+that makes a measurement a token, the surprisal score with its
+short-history gate, attention over stored keys and values in a prefill
+and a decode form, and the expert layer that is told which experts it
+holds.
 
 A measurement becomes a token by the device's capped running mean and
 variance (the leaves and the update `lstm-stream` has):
@@ -18,8 +20,9 @@ dropped) and leaves out what the absent experts would add.
 
 A model mixes `SeqBlocks` in, sets `self.cfg` (with `compute_dtype`,
 `window`, `score_clip`, `vocab`, `hidden_size`, `rms_norm_eps`),
-`self.experts` (an `Experts`), `self.seed_rows` and `self._gate`, and
-brings `param_shapes`, `init_state` and `_prefill`. Imports nothing
+`self.experts` (an `Experts`, where it has an expert layer),
+`self.seed_rows` and `self._gate`, and brings `param_shapes`,
+`init_state` and `_prefill`. Imports nothing
 beyond JAX at import time: the expert kernel imports Pallas when it is
 first traced.
 """
@@ -274,6 +277,58 @@ class SeqBlocks:
         with jax.named_scope("moe_experts"):
             routed, counts = self._routed(p["experts"], x, idx, w, live)
             return self._mlp(p["shared"], x) + routed, counts
+
+    # -- attention over a row's stored keys and values ---------------------------
+
+    def _causal_prefill(self, q, k, v, count, kv: int, band=None):
+        """The prefill form over `[n, S]` tokens: causal softmax, over
+        the newest `band` positions only where a band is given,
+        positions at or past a row's `count` masked out. `q` `[n, S,
+        heads, d]`; `k`, `v` `[n, S, kv * d]` as stored, query head `h`
+        reading key-value head `h // (heads / kv)`. The model sets
+        `self._scale`. -> `[n, S, heads, d]`."""
+        n, s, heads, d = q.shape
+        logits = self._ein(
+            "nqkgd,nskd->nkgqs", q.reshape(n, s, kv, heads // kv, d),
+            k.reshape(n, s, kv, d)) * self._scale
+        at = jnp.arange(s)
+        seen = at[None, :] <= at[:, None]
+        if band is not None:
+            seen &= at[:, None] - at[None, :] < band
+        seen = seen[None] \
+            & (at[None, None, :] < jnp.maximum(count, 1)[:, None, None])
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None, None], logits, -jnp.inf), axis=-1)
+        out = self._ein("nkgqs,nskd->nqkgd", probs, v.reshape(n, s, kv, d))
+        return out.reshape(n, s, heads, d)
+
+    def _decode_rows(self, q, k, v, kctx, vctx, pos, kv: int,
+                     wraps: bool = False):
+        """The decode form for one token a row: `kctx`, `vctx` `[B, P,
+        kv * d]` are the row's stored context, `k`, `v` `[B, kv * d]`
+        its own position's, at `pos` (a context that `wraps`: at `pos
+        mod P`, and once it has wrapped every slot is inside the
+        window). The query of head `h` is laid in the lanes of its
+        key-value head and zeros elsewhere, so the logits are one
+        product over a position's whole entry, and a head's output is
+        read back from the same lanes of the weighted sum of values:
+        the context is read as it rests. -> `[B, heads, d]`."""
+        b, heads, d = q.shape
+        positions = kctx.shape[1]
+        rows = jnp.arange(b)
+        slot = pos % positions if wraps else pos
+        keys = kctx.at[rows, slot].set(k, mode="drop")
+        vals = vctx.at[rows, slot].set(v, mode="drop")
+        own = jnp.eye(kv, dtype=jnp.float32)[None, :, None, :, None]
+        wide = (q.reshape(b, kv, heads // kv, 1, d) * own).reshape(
+            b, heads, kv * d)
+        logits = self._ein("bhc,bpc->bhp", wide, keys) * self._scale
+        seen = jnp.arange(positions)[None, :] <= pos[:, None]
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None, :], logits, -jnp.inf), axis=-1)
+        out = self._ein("bhp,bpc->bhc", probs, vals)
+        return (out.reshape(b, kv, heads // kv, kv, d) * own).sum(3).reshape(
+            b, heads, d)
 
     # -- tokens and the score -------------------------------------------------
 

@@ -221,10 +221,21 @@ class ScoringSession:
             "ctx.window_positions": lambda: metrics.histogram(
                 "scoring.ctx.window_positions", buckets=octaves).observe,
             "ctx.wrapped": lambda: metrics.counter(
-                "scoring.ctx.wrapped").inc}
+                "scoring.ctx.wrapped").inc,
+            # a recurrent matrix state (models/olmo_hybrid.py): the mean
+            # decay a step applied to it, in (0, 1), and the largest
+            # magnitude the step found in the rows it read
+            "state.decay": lambda: metrics.histogram(
+                "scoring.state.decay",
+                buckets=[i / 64 for i in range(1, 65)]).observe,
+            "state.absmax": lambda: metrics.histogram(
+                "scoring.state.absmax",
+                buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")
+        # bytes of fixed-size state leaves the dispatches rewrote whole
+        self.rewritten = metrics.counter("scoring.state.rewritten_bytes")
 
     def _fleet_rows(self) -> int:
         """Rows the ring is asked for: the fleet-size hint, or as far as
@@ -594,6 +605,10 @@ class ScoringSession:
         if reseeded:
             self.reseeds.inc(reseeded)
             self.ring.reseeded = 0
+        rewritten = getattr(self.ring, "rewritten_bytes", 0)
+        if rewritten:
+            self.rewritten.inc(rewritten)
+            self.ring.rewritten_bytes = 0
         return dispatches
 
     async def _settle_and_deliver(self, dispatches, dev, ts,

@@ -65,6 +65,21 @@ last `shape[1]` positions in their wrapped slots. `model.seed_rows`,
 where declared, is how many rows one seeding call takes (a prefill's
 activations have to fit beside the weights).
 
+Such a model's FIXED-SIZE leaves of three or more dimensions (a row
+that is a matrix: a recurrent state of 2.2 MB a layer, rewritten whole
+at every event) reach `step_score` not as gathered rows but IN TURN, as
+a `RowsInTurn` each: `read(after)` gathers the rows once `after` has
+been computed, `write(rows, then)` puts their next values into the
+donated table and returns `then`, and the model returns nothing for the
+leaf. A layer reads its rows when it starts and writes them before the
+next one starts. Handed all rows at once, as the other leaves are, a
+v5e's compiler gathered every layer's rows when the step started and
+kept every layer's new rows until it ended: 4.05 GB of scratch where
+one layer's at a time are 2.01 (three layers of 566 MB a frame; PERF.md
+section 6, PR 35). `ring.rewritten_bytes` counts what the dispatches
+since it was last read rewrote whole: live rows times the bytes of a
+row of the leaves that are no window.
+
 The host `TelemetryStore` stays the durable copy; `load()` rebuilds
 state from it at warmup or after a fault (same recovery story as the
 window ring).
@@ -72,6 +87,7 @@ window ring).
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Optional
 
 import jax
@@ -115,20 +131,45 @@ GATHER_SLICE_BYTES = 1 << 19
 
 def _rows(leaf, dev):
     """Rows `dev` of a table for reading (padding reads the scratch
-    row). A row of `[positions, width]` heavier than one gathered slice
-    may be is taken in blocks of positions, through a view of the table
-    that splits its positions (no bytes move for a view): one gather
-    either way."""
+    row). A row of three or more dimensions that is heavier than one
+    gathered slice may be (a context `[positions, width]`, a matrix
+    state `[groups, keys, lanes]`) is taken in blocks of its leading
+    dimension, through a view of the table that splits it (no bytes
+    move for a view): one gather either way."""
     row_bytes = leaf[0].size * leaf.dtype.itemsize
-    if leaf.ndim != 3 or row_bytes <= GATHER_SLICE_BYTES:
+    if leaf.ndim < 3 or row_bytes <= GATHER_SLICE_BYTES:
         return leaf.at[dev].get(mode="clip", indices_are_sorted=True)
-    rows, positions, width = leaf.shape
+    rows, positions, *width = leaf.shape
     blocks = next(n for n in range(-(-row_bytes // GATHER_SLICE_BYTES),
                                    positions + 1) if positions % n == 0)
-    got = leaf.reshape(rows, blocks, positions // blocks, width).at[
+    got = leaf.reshape(rows, blocks, positions // blocks, *width).at[
         dev[:, None], jnp.arange(blocks)[None, :]].get(
             mode="clip", indices_are_sorted=True)
-    return got.reshape(dev.shape[0], positions, width)
+    return got.reshape(dev.shape[0], positions, *width)
+
+
+class RowsInTurn:
+    """The rows of one fixed-size leaf whose row is a matrix, handed to
+    `step_score` in the table's place ("Contract with the model"): the
+    gather and the scatter are the ring's, WHEN they run is the
+    model's."""
+
+    def __init__(self, table, dev):
+        self.table, self._dev = table, dev
+
+    def read(self, after):
+        """Rows `dev`, gathered once `after` has been computed."""
+        dev, _ = jax.lax.optimization_barrier((self._dev, after))
+        with jax.named_scope("ring_gather"):
+            return _rows(self.table, dev)
+
+    def write(self, rows, then):
+        """The rows' next values into the table. -> `then`, which is
+        there once they are in: read on from what is returned."""
+        with jax.named_scope("ring_scatter"):
+            table = self.table.at[self._dev].set(rows, **DISTINCT_ROWS)
+        self.table, then = jax.lax.optimization_barrier((table, then))
+        return then
 
 
 def _gather_step_scatter(model, params, state, dev, v, scratch=None):
@@ -136,10 +177,17 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     profile shows them by: rows of `dev` out of the table, one cell step
     on them, the new rows back (padding reads the scratch row and writes
     nothing). A window leaf takes one entry a row, at the row's own
-    position. -> (state, scores, the step's numbers or None)."""
+    position; a leaf whose row is a matrix is read and written inside
+    the cell step, in its turn. -> (state, scores, the step's numbers
+    or None)."""
     windows = getattr(model, "windows", None)
     with jax.named_scope("ring_gather"):
-        rows = jax.tree.map(lambda leaf: _rows(leaf, dev), state)
+        if windows is None:
+            rows = jax.tree.map(lambda leaf: _rows(leaf, dev), state)
+        else:
+            rows = {name: RowsInTurn(leaf, dev) if leaf.ndim >= 3
+                    and name not in windows else _rows(leaf, dev)
+                    for name, leaf in state.items()}
     stats = None
     with jax.named_scope("cell_step"):
         if windows is None:
@@ -165,7 +213,9 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
                                                       **DISTINCT_ROWS)
     with jax.named_scope("ring_scatter"):
         for name, leaf in state.items():
-            if name not in windows:
+            if isinstance(rows[name], RowsInTurn):
+                out[name] = rows[name].table
+            elif name not in windows:
                 out[name] = leaf.at[dev].set(new_rows[name],
                                              **DISTINCT_ROWS)
     return out, scores, stats
@@ -316,6 +366,14 @@ class StreamingRing:
         self._recent = np.zeros((rows, self.window), np.float32)
         self._oldest = np.zeros(rows, np.int32)
         self.reseeded = 0
+        # bytes of a row of the leaves that are no window (a step rewrites
+        # them whole), and what the dispatches so far rewrote: the
+        # session's to read and clear
+        fixed = ([x for name, x in self.state.items() if name not in windows]
+                 if windows else jax.tree.leaves(self.state))
+        self.row_bytes = sum(x.dtype.itemsize * math.prod(x.shape[1:])
+                             for x in fixed)
+        self.rewritten_bytes = 0
 
     def ensure_capacity(self, max_index: int) -> None:
         if max_index < self.capacity:
@@ -370,6 +428,11 @@ class StreamingRing:
                                       jnp.asarray(ok))
             self.state = self._put(self.state, seeded,
                                    jnp.asarray(at, jnp.int32))
+            # one block's seeded rows at a time: a call's outputs are
+            # allocated when it is dispatched, and dispatch ran 14 blocks
+            # ahead of a v5e (3.7 GB of seeded rows alive at once beside
+            # a table of 9.8 GB; PERF.md section 6, PR 35)
+            jax.block_until_ready(seeded)
         if self._positions:
             self._filled[rows] = np.minimum(count, w)
             self._recent[rows], self._oldest[rows] = values, 0
@@ -436,6 +499,7 @@ class StreamingRing:
         except Exception:
             self.faulted = True  # donated state is gone; needs load()
             raise
+        self.rewritten_bytes += int(dev.size) * self.row_bytes
         if self._positions:
             self._filled[dev] += 1
             at = self._oldest[dev]

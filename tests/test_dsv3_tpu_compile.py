@@ -4,9 +4,11 @@ widths with one leading and one expert layer and a small fleet: the
 compiled step copies and transposes no context leaf, which rests
 row-major in whole lane tiles; the ring step of `laguna-stream` at its
 benchmark configuration's own size (five layers, 769 rows of 12 MiB),
-held to the same and to the chip's memory; and the ring step of
-`lstm-stream` at `stream-512k`'s own size, which moves rows of ONE
-table. Nothing runs, so nothing here is a time.
+held to the same and to the chip's memory; the ring step of
+`olmo-hybrid-stream` at its configuration's own size (four layers, 769
+rows of 12.75 MB, three matrix states among them), held to the same;
+and the ring step of `lstm-stream` at `stream-512k`'s own size, which
+moves rows of ONE table. Nothing runs, so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
 test that needs it is in this one file (one process loads the TPU's
@@ -301,3 +303,87 @@ def test_lstm_stream_step_moves_rows_of_one_table(one_chip):
     assert "tpu_custom_call" not in hlo
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= leaf.size * leaf.dtype.itemsize
+
+
+# -- `olmo-hybrid-stream` at `olmo-hybrid-7b-pp8`'s own size --------------------
+
+OLMO_ROWS, OLMO_FRAME = 769, 256
+
+
+@pytest.fixture(scope="module")
+def olmo_step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip) at the benchmark configuration's `model_config` as it stands."""
+    import json
+    import os
+
+    from sitewhere_tpu.models import build_model
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "olmo-hybrid-7b-pp8.json")) as fh:
+        model = build_model("olmo-hybrid-stream",
+                            **json.load(fh)["model_config"])
+    state, compiled = _compile_step(model, OLMO_ROWS, OLMO_FRAME, one_chip,
+                                    jnp.float32)
+    return model, state, compiled
+
+
+def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
+        olmo_step):
+    """Three matrix states of 2.2 MB a row (`f32[769, 15, 96, 384]`: two
+    heads of 192 values side by side in three lane tiles), three leaves
+    of conv taps and two context leaves of 2.9 MB a row: none is copied,
+    transposed or sliced, as a table or as the frame's gathered rows;
+    each rests row-major; a heavy row is gathered in blocks through a
+    view and scattered by ONE scatter, no loop; the donated state comes
+    back in its own buffers; and the scratch is one layer's rows, not
+    every layer's (4.05 GB when the step was handed all rows at once)."""
+    from chip_smoke import _table_moves
+
+    model, state, compiled = olmo_step
+    hlo = compiled.as_text()
+    lines = hlo.splitlines()
+    assert _table_moves(hlo, OLMO_ROWS) == []
+    views = {f"f32[{OLMO_ROWS},5,3,96,384]", f"bf16[{OLMO_ROWS},6,64,3840]"}
+    shapes = set(re.findall(rf"\w+\[{OLMO_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == views | {
+        f"f32[{OLMO_ROWS},15,96,384]", f"bf16[{OLMO_ROWS},270,128]",
+        f"bf16[{OLMO_ROWS},384,3840]", f"bf16[{OLMO_ROWS},3840]",
+        f"f32[{OLMO_ROWS}]", f"s32[{OLMO_ROWS}]"}, shapes
+    made = [line for line in lines if re.match(
+        rf"\s*(?:ROOT )?%\S+ = (?:f32\[{OLMO_ROWS},5,3,96,384\]|"
+        rf"bf16\[{OLMO_ROWS},6,64,3840\])", line)]
+    assert made and all(" bitcast(" in line or " parameter(" in line
+                        for line in made), made
+    assert "mini-gather" not in hlo
+    assert not [line for line in lines if " while(" in line]
+    for shape, leaves in ((f"f32[{OLMO_ROWS},15,96,384]", 3),
+                          (f"bf16[{OLMO_ROWS},384,3840]", 2),
+                          (f"bf16[{OLMO_ROWS},270,128]", 3)):
+        dims = tuple(int(d) for d in shape[shape.index("[") + 1:-1].split(","))
+        assert sum(x.shape == dims for x in state.values()) == leaves
+        layouts = set(re.findall(re.escape(shape) + r"\{([\d,]+)", hlo))
+        assert layouts == {",".join(map(str, reversed(range(len(dims)))))}, \
+            shape
+        scatters = [line for line in lines if " scatter(" in line
+                    and f"= {shape}" in line]
+        assert len(scatters) == leaves, shape
+        assert all("unique_indices=true" in line
+                   and "indices_are_sorted=true" not in line
+                   for line in scatters)
+    moved = [line for line in lines if re.search(
+        r"= (?:f32\[\d+,15,96,384\]|f32\[\d+,3,96,384\]|"
+        r"bf16\[\d+,384,3840\]|bf16\[\d+,64,3840\])\S* "
+        r"(?:copy|transpose|slice|dynamic-slice)\(", line)
+        # (inside a scatter's fusion the updates pass a `transpose` that
+        # permutes nothing)
+        and "dimensions={0,1,2,3}" not in line]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes > 9.8e9
+    assert mem.argument_size_in_bytes > 13.0e9
+    assert mem.temp_size_in_bytes < 2.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9

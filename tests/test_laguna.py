@@ -457,14 +457,21 @@ PARENTS_STEPS = {
         "a5310a450bcf90fc7d33ab984b47e7136ea208a53d764d09065701be3766b16e"),
     "lstm-stream": (
         "a2bd1f0a98b51e60dd3cc8f6c6d127a580d5712cec9d5c7608bf3d8c512680c8"),
+    # ...and this model's own at PR 34's tree, the parent of the PR that
+    # moved its two attention forms to models/seqblocks.py and handed the
+    # ring's matrix rows over in turn (`RowsInTurn`)
+    "laguna-stream_float32": (
+        "036399799416ae1ea409f504fde1634f7a6655ce7e10ba0fe11f47a2cdb42575"),
+    "laguna-stream_bfloat16": (
+        "17cfafc294528bf580633f28be64de4c4f4f72027d7f42415257dd83777882bf"),
 }
 
 
 @pytest.mark.parametrize("which", PARENTS_STEPS)
 def test_the_other_models_steps_lower_to_the_parents_text(which):
-    """`dsv3-stream` (tests/test_dsv3.py's size) and `lstm-stream`
-    (`stream-512k`'s widths) declare nothing new and lower to the text
-    they lowered to before."""
+    """`dsv3-stream` (tests/test_dsv3.py's size), `lstm-stream`
+    (`stream-512k`'s widths) and `laguna-stream` (this file's size)
+    declare nothing new and lower to the text they lowered to before."""
     import hashlib
 
     from tests.test_dsv3 import MC as DSV3
@@ -472,6 +479,10 @@ def test_the_other_models_steps_lower_to_the_parents_text(which):
     if which == "lstm-stream":
         text = _lowered_step(build_model("lstm-stream", window=64, hidden=64),
                              1025, 256, jnp.float16)
+    elif which.startswith("laguna"):
+        model = program() if which.endswith("32") else build_model(
+            "laguna-stream", **MC)
+        text = _lowered_step(model, 41, 16, jnp.float32)
     else:
         over = {"compute_dtype": jnp.float32} if which.endswith("32") else {}
         text = _lowered_step(build_model("dsv3-stream", **over, **DSV3), 41,

@@ -899,3 +899,90 @@ def test_a_table_is_rounded_up_only_while_that_costs_little(
     ring = StreamingRing(_TwoBounds(), capacity=asked)
     assert ring.capacity == got
     assert ring.state["far"].shape == (got + 1, 8, 128)
+
+
+# -- fixed-size leaves whose row is a matrix: read and written in turn --------
+
+
+class _MatrixRows:
+    """The least model with a window leaf beside a fixed-size leaf whose
+    row is a matrix `[4, 8, 128]` (16 KiB): an event adds its value to
+    every element of its row's matrix and appends it to its context; the
+    score is the matrix's first element before the event."""
+
+    streaming = True
+    windows = {"far": "pos"}
+    step_stats = ()
+
+    class cfg:
+        window = 3
+
+    def init(self, rng):
+        import jax.numpy as jnp
+
+        return {"one": jnp.ones(())}
+
+    def init_state(self, cap):
+        import jax.numpy as jnp
+
+        return {"pos": jnp.zeros(cap, jnp.int32),
+                "far": jnp.zeros((cap, 8, 128), jnp.float32),
+                "m": jnp.zeros((cap, 4, 8, 128), jnp.float32)}
+
+    def step_score(self, params, rows, v, live):
+        import jax.numpy as jnp
+
+        m = rows["m"].read(v)
+        score = rows["m"].write(m + v[:, None, None, None], m[:, 0, 0, 0])
+        entry = jnp.broadcast_to(v[:, None], (v.shape[0], 128))
+        return score * params["one"], {"pos": rows["pos"] + 1,
+                                       "far": entry}, None
+
+    def warm_state(self, params, x, valid):
+        import jax.numpy as jnp
+
+        state = self.init_state(x.shape[0])
+        count = valid.sum(1)
+        state["pos"] = count.astype(jnp.int32)
+        state["m"] = state["m"] + jnp.where(valid, x, 0).sum(1)[
+            :, None, None, None]
+        return state
+
+
+@pytest.mark.parametrize("slice_bytes", [1 << 19, 4096],
+                         ids=["whole_rows", "rows_in_blocks"])
+def test_matrix_rows_are_written_where_named_and_counted_live(
+        monkeypatch, slice_bytes):
+    """A fixed-size leaf of three or more dimensions reaches the step as
+    a `RowsInTurn`; heavier than one gathered slice its rows are read in
+    blocks of their leading dimension. The rows written are the rows
+    named, padding reads the scratch row and writes nothing, and
+    `rewritten_bytes` counts the live rows of the leaves that are no
+    window."""
+    import jax
+
+    from sitewhere_tpu.scoring import stream
+
+    monkeypatch.setattr(stream, "GATHER_SLICE_BYTES", slice_bytes)
+    model = _MatrixRows()
+    ring = StreamingRing(model, capacity=6, initial_floor=6)
+    ring.bind_params(model.init(jax.random.PRNGKey(0)))
+    assert ring.row_bytes == 4 + 4 * 8 * 128 * 4      # `pos` and `m`
+    ring.load(np.tile(np.float32([1, 2, 3]), (6, 1)), np.full(6, 3))
+    dev = np.asarray([1, 4, 5], np.int32)
+    for k, value in enumerate((10.0, 100.0)):
+        out = np.asarray(ring.update_and_score(
+            model, ring._params, dev, np.full(3, value, np.float32), 4))
+        assert (out[:3] == (6.0, 16.0)[k]).all()
+    m = np.asarray(ring.state["m"])
+    assert (m[[1, 4, 5]] == 116.0).all() and (m[[0, 2, 3]] == 6.0).all()
+    assert (m[6] == 0).all()                           # the scratch row
+    far = np.asarray(ring.state["far"])
+    assert (far[[1, 4, 5], 3:5, 0] == [10.0, 100.0]).all()
+    assert not far[[0, 2, 3, 6]].any()
+    assert ring.rewritten_bytes == 2 * 3 * ring.row_bytes
+    fn = ring._fns[ring.capacity, 4]
+    text = fn.lower(ring._params, ring.state, *ring._pad(
+        dev, np.zeros(3, np.float32), 4)).as_text()
+    assert ("tensor<7x4x1x8x128xf32>" in text) == (slice_bytes == 4096)
+    assert text.count("optimization_barrier") == 2     # a read, a write
