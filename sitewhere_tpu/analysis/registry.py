@@ -145,6 +145,10 @@ COUNTERS = (
     # dispatches rewrote whole: live rows times a row of the leaves that
     # are no window (scoring/stream.py `rewritten_bytes`)
     "scoring.state.rewritten_bytes",
+    # live rows whose matrix state a step updated where it rested in the
+    # ring's table, summed over the step's linear layers: what was
+    # lowered (ops/state_kernel.py), 0 where the plain path runs
+    "scoring.state.in_place_rows",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services

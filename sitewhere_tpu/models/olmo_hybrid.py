@@ -66,6 +66,7 @@ taps as they were.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Any
@@ -74,6 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from sitewhere_tpu.models.seqblocks import SEED_TOKENS, SeqBlocks, rms
+from sitewhere_tpu.ops import state_kernel
 
 _LAYERS = 32              # the published depth: periods of four
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -141,7 +143,8 @@ class OlmoHybridStreamModel(SeqBlocks):
     streaming = True
     # the numbers `step_score` returns beside the scores, by the names
     # the session feeds the metrics registry under (`scoring.<name>`)
-    step_stats = ("ctx.positions", "state.decay", "state.absmax")
+    step_stats = ("ctx.positions", "state.decay", "state.absmax",
+                  "state.in_place")
 
     def __init__(self, cfg: OlmoHybridConfig = OlmoHybridConfig()):
         n = cfg.num_hidden_layers
@@ -264,16 +267,13 @@ class OlmoHybridStreamModel(SeqBlocks):
                 2.0 if c.linear_allow_neg_eigval else 1.0)
             return z, self._mm(x, p["g"]), alpha, beta
 
-    def _gdn_cell(self, p, s, taps, z, alpha, beta):
-        """One position a row: the conv over the row's taps and this
-        position's input `z` `[B, channels]`, then the delta rule on the
-        state `s` `[B, H / g, dk, g * dv]` as it rests; `taps` `[B, (K -
-        1) * channels]`. -> (`o` `[B, H * dv]`, the next state, the next
-        taps, the largest magnitude a row's state held `[B]`)."""
+    def _gdn_conv(self, p, taps, z):
+        """The conv over a row's taps `[B, (K - 1) * channels]` and this
+        position's input `z` `[B, channels]`. -> (`q`, `k` `[B, H, dk]`
+        normed, `v` `[B, H * dv]`, the next taps)."""
         c = self.cfg
         b = z.shape[0]
-        heads, dk, dv = (c.linear_num_value_heads, c.linear_key_head_dim,
-                         c.linear_value_head_dim)
+        heads, dk = c.linear_num_value_heads, c.linear_key_head_dim
         with jax.named_scope("gdn_conv"):
             taps = jnp.concatenate([taps, z], -1)
             wide = taps.astype(jnp.float32)
@@ -288,24 +288,35 @@ class OlmoHybridStreamModel(SeqBlocks):
                 return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True)
                                          + L2_EPS)
 
-            q = unit(y[:, :c.key_width]) * dk ** -0.5
-            k = unit(y[:, c.key_width:2 * c.key_width])
-            v = y[:, 2 * c.key_width:]
+            return (unit(y[:, :c.key_width]) * dk ** -0.5,
+                    unit(y[:, c.key_width:2 * c.key_width]),
+                    y[:, 2 * c.key_width:], taps)
+
+    def _lanes(self, x):
+        """`[B, H, ...]` -> `[B, H / group, ..., group * dv]`: a head's
+        numbers over the head's own lanes (a select by lane, which fuses
+        into whatever reads it)."""
+        group, dv = self._group, self.cfg.linear_value_head_dim
+        lane_head = jnp.arange(group * dv) // dv
+        x = x.reshape((x.shape[0], x.shape[1] // group, group) + x.shape[2:])
+        out = x[:, :, 0, ..., None]
+        for j in range(1, group):
+            out = jnp.where(lane_head >= j, x[:, :, j, ..., None], out)
+        return out
+
+    def _gdn_cell(self, p, s, taps, z, alpha, beta):
+        """One position a row: the conv over the row's taps and this
+        position's input `z` `[B, channels]`, then the delta rule on the
+        state `s` `[B, H / g, dk, g * dv]` as it rests; `taps` `[B, (K -
+        1) * channels]`. -> (`o` `[B, H * dv]`, the next state, the next
+        taps, the largest magnitude a row's state held `[B]`)."""
+        c = self.cfg
+        b = z.shape[0]
+        q, k, v, taps = self._gdn_conv(p, taps, z)
         with jax.named_scope("gdn_state"):
-            group, lane_head = self._group, jnp.arange(self._group * dv) // dv
-
-            def lanes(x):
-                """`[B, H, ...]` -> `[B, H / group, ..., group * dv]`: a
-                head's numbers over the head's own lanes (a select by
-                lane, which fuses into whatever reads it)."""
-                x = x.reshape((b, heads // group, group) + x.shape[2:])
-                out = x[:, :, 0, ..., None]
-                for j in range(1, group):
-                    out = jnp.where(lane_head >= j, x[:, :, j, ..., None], out)
-                return out
-
+            lanes = self._lanes
             kw, decay = lanes(k), lanes(alpha)
-            v = v.reshape(b, heads // group, group * dv)
+            v = v.reshape(b, -1, self._state_shape[-1])
             # S <- alpha S; r = v - S^T k; S <- S + k (beta r)^T; o = S^T q.
             # ONE pass over S as it was found gives S^T k, S^T q and its
             # largest magnitude (three reductions of one read), a second
@@ -320,7 +331,27 @@ class OlmoHybridStreamModel(SeqBlocks):
             s = decay[:, :, None, :] * s + kw * write[:, :, None, :]
         return (o.reshape(b, c.value_width), s, taps,
                 largest.reshape(b, -1).max(1))
-        return o.reshape(b, c.value_width), s, taps
+
+    def _gdn_rows(self, p, table, dev, taps, z, alpha, beta):
+        """`_gdn_cell` on rows `dev` of a layer's state `table`, each
+        read out of the row it rests in and written back into it by one
+        kernel (ops/state_kernel.py): the same lines, the state never
+        gathered. -> (the table, `o`, the next taps, the largest
+        magnitude a row's state held, 0 for padding, and how many live
+        rows were updated where they rested)."""
+        b, groups = z.shape[0], self._state_shape[0]
+        q, k, v, taps = self._gdn_conv(p, taps, z)
+        with jax.named_scope("gdn_state"):
+            # what a row brings beside its state: keys and queries a head
+            # a lane, four vectors over the lanes; nothing of `S`'s shape
+            keys = jnp.stack([k, q], 1).swapaxes(2, 3)
+            vec = jnp.stack([v.reshape(b, groups, -1), self._lanes(alpha),
+                             self._lanes(beta),
+                             self._lanes((k * q).sum(-1))], 1)
+            table, out = state_kernel.update_rows(table, dev, keys, vec)
+        return (table, out[:, :groups].reshape(b, -1), taps,
+                out[:, groups, 0],
+                (dev < table.shape[0] - 1).sum(dtype=jnp.int32))
 
     def _gdn_out(self, p, x, o, gate):
         """Heads `o` `[..., H * dv]` normed one by one, gated, through
@@ -334,12 +365,40 @@ class OlmoHybridStreamModel(SeqBlocks):
             return x + rms(self._mm(y.reshape(o.shape), p["o"]),
                            p["mixer_norm"], c.rms_norm_eps)
 
-    def _linear_decode(self, p, x, s, taps):
-        """-> (x, the row's next `s`, its next taps, `alpha` `[B, H]`,
-        the largest magnitude its `s` held `[B]`)."""
+    def _linear_decode(self, p, x, state, taps):
+        """A linear layer on `x` `[B, hidden]`, one event a row; `state`
+        and `taps` are the layer's two leaves in turn
+        (scoring/stream.py, `RowsInTurn`). The state is updated where it
+        rests on a TPU, where its rows fit the kernel's VMEM
+        (ops/state_kernel.py `fits`); elsewhere its rows are read,
+        stepped by `_gdn_cell` and written whole: one algorithm, and the
+        plain path is the kernel's twin in the tests. -> (x, `alpha`
+        `[B, H]`, the largest magnitude a row's `s` held `[B]`, the rows
+        updated where they rested)."""
         z, gate, alpha, beta = self._gdn_project(p, x)
-        o, s, taps, largest = self._gdn_cell(p, s, taps, z, alpha, beta)
-        return self._gdn_out(p, x, o, gate), s, taps, alpha, largest
+        tapped = taps.read(x).reshape(x.shape[0], -1)
+
+        def plain(table, dev, taps, z, alpha, beta):
+            # the ring's own gather and scatter, as a function of the
+            # table: a turn of `state`'s kind over the table handed in
+            rows = type(state)(table, dev)
+            o, s, taps, held = self._gdn_cell(p, rows.read(z), taps, z,
+                                              alpha, beta)
+            o = rows.write(s, o)
+            return rows.table, o, taps, held, jnp.int32(0)
+
+        def turn(table, dev):
+            args = (table, dev, tapped, z, alpha, beta)
+            if not state_kernel.fits(table.shape, table.dtype):
+                return plain(*args)
+            return jax.lax.platform_dependent(
+                *args, default=plain,
+                tpu=functools.partial(self._gdn_rows, p))
+
+        o, c1, held, in_place = state.update(turn, x)
+        x = taps.write(c1.reshape((-1,) + self._taps_shape),
+                       self._gdn_out(p, x, o, gate))
+        return x, alpha, held, in_place
 
     def _linear_prefill(self, p, x, count):
         """Over `[n, S, hidden]`: the cell scanned over the positions, a
@@ -449,22 +508,20 @@ class OlmoHybridStreamModel(SeqBlocks):
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
         decay, largest = jnp.float32(0), jnp.float32(0)
+        in_place = jnp.int32(0)
         for l in range(self.layers):
             p = params[f"layer{l}"]
             first, second = self._leaves(l)
             if self.kinds[l] == LINEAR:
-                # gathered when the layer starts, back in the table
+                # the layer's rows when it starts, back in the table
                 # before the next one starts: the step holds one layer's
                 # rows at a time
-                state, taps = rows[first], rows[second]
-                x, s, c1, alpha, held = self._linear_decode(
-                    p, x, state.read(x),
-                    taps.read(x).reshape(v.shape[0], -1))
+                x, alpha, held, rested = self._linear_decode(
+                    p, x, rows[first], rows[second])
                 decay += jnp.where(live[:, None], alpha, 0).sum()
                 largest = jnp.maximum(largest,
                                       jnp.where(live, held, 0).max())
-                x = state.write(s, x)
-                x = taps.write(c1.reshape((-1,) + self._taps_shape), x)
+                in_place += rested
             else:
                 x, out[first], out[second] = self._full(
                     p, x, lambda q, k, v, kctx=rows[first],
@@ -478,12 +535,18 @@ class OlmoHybridStreamModel(SeqBlocks):
             jnp.where(live, pos, 0).sum() / n_live,
             decay / (n_live * max(self.kinds.count(LINEAR), 1)
                      * c.linear_num_value_heads),
-            largest])
+            largest, in_place.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:
         """State of `n` devices after their stored windows (`[n, W]`
         chronological left-padded): the prefill form over each window."""
+        if state_kernel.fits((1,) + self._state_shape, jnp.float32):
+            # traced once, when seeding starts: the step's kernel will want
+            # its library, which comes in while the seeding calls wait on
+            # the chip (begun where the model is built, it took the fleet's
+            # registration 1.3 s longer: PERF.md section 6, PR 36)
+            state_kernel.import_ahead()
         state, left, _ = self._warm(params, x, valid)
         w = x.shape[1]
         for l, (first, second) in enumerate(left):

@@ -4,7 +4,13 @@ auto-selection helper; CPU/test runs always take the reference path
 (Pallas interpret mode is exercised by dedicated parity tests).
 `expert_kernel` (a layer's held experts of `dsv3-stream` and of
 `laguna-stream`) is imported by its one caller, models/seqblocks.py,
-which holds its plain twin."""
+which holds its plain twin. `state_kernel` (a linear layer's matrix
+states of `olmo-hybrid-stream`, updated in the rows of the ring's table
+they rest in) is imported by models/olmo_hybrid.py, whose `_gdn_cell`
+is its plain twin: the kernel is handed to `RowsInTurn.update`
+(scoring/stream.py), which promises when it runs; the kernel promises
+that rows the frame does not name, the scratch row among them, come
+back as they were, and that every write has landed when it returns."""
 
 from sitewhere_tpu.ops.lstm_kernel import (  # noqa: F401
     lstm_window_final,

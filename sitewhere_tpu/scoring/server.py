@@ -230,7 +230,11 @@ class ScoringSession:
                 buckets=[i / 64 for i in range(1, 65)]).observe,
             "state.absmax": lambda: metrics.histogram(
                 "scoring.state.absmax",
-                buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe}
+                buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe,
+            # live rows whose matrix state a step's kernel updated where
+            # it rested, over its linear layers; 0 on the plain path
+            "state.in_place": lambda: metrics.counter(
+                "scoring.state.in_place_rows").inc}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")

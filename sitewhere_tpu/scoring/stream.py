@@ -72,11 +72,21 @@ a `RowsInTurn` each: `read(after)` gathers the rows once `after` has
 been computed, `write(rows, then)` puts their next values into the
 donated table and returns `then`, and the model returns nothing for the
 leaf. A layer reads its rows when it starts and writes them before the
-next one starts. Handed all rows at once, as the other leaves are, a
-v5e's compiler gathered every layer's rows when the step started and
-kept every layer's new rows until it ended: 4.05 GB of scratch where
-one layer's at a time are 2.01 (three layers of 566 MB a frame; PERF.md
-section 6, PR 35). `ring.rewritten_bytes` counts what the dispatches
+next one starts. `update(fn, after)` is both at once for a model that
+can update the rows WHERE THEY REST: once `after` has been computed it
+calls `fn(table, dev)` with the whole table and the step's row indices
+(padding included, past the scratch row), takes `(table, *outs)` back,
+keeps that table as the leaf's next value and returns `outs`, which are
+there once the table is written. It promises the order, `fn` promises
+the ring's own contract: rows not named and the scratch row come back as
+they were, and every write has landed when `fn`'s result is read
+(models/olmo_hybrid.py hands it ops/state_kernel.py's kernel on a TPU:
+2.2 MB rows cross HBM twice where gather, cell and scatter moved them
+six times; PERF.md section 6, PR 36). Handed all rows at once, as the
+other leaves are, a v5e's compiler gathered every layer's rows when the
+step started and kept every layer's new rows until it ended: 4.05 GB of
+scratch where one layer's at a time are 2.01 (three layers of 566 MB a
+frame; PERF.md section 6, PR 35). `ring.rewritten_bytes` counts what the dispatches
 since it was last read rewrote whole: live rows times the bytes of a
 row of the leaves that are no window.
 
@@ -170,6 +180,15 @@ class RowsInTurn:
             table = self.table.at[self._dev].set(rows, **DISTINCT_ROWS)
         self.table, then = jax.lax.optimization_barrier((table, then))
         return then
+
+    def update(self, fn, after):
+        """The rows updated where they rest: `fn(table, dev)`, called
+        once `after` has been computed, hands back `(table, *outs)`; the
+        table is kept, and `outs` are there once it is written."""
+        dev, _ = jax.lax.optimization_barrier((self._dev, after))
+        table, *outs = fn(self.table, dev)
+        self.table, outs = jax.lax.optimization_barrier((table, outs))
+        return outs
 
 
 def _gather_step_scatter(model, params, state, dev, v, scratch=None):

@@ -269,6 +269,53 @@ def test_matrix_states_are_written_in_place_in_their_turn():
         assert scope in text, scope
 
 
+def test_a_turn_through_update_is_the_read_cell_and_write_it_replaced(
+        monkeypatch):
+    """`RowsInTurn.update` on the CPU is the plain path: the scores, the
+    step's numbers and every leaf of the next state are bit-equal to the
+    step that read a layer's rows, ran `_gdn_cell` on them and wrote
+    them (the form before the state was updated where it rests), padding
+    and all; and the plain path counts no row as updated in place."""
+    model, params = program(), params_of(MC)
+    hist, frames = readings(W, 2)
+    cap = 11
+    state = model.init_state(cap + 1)
+    seeded = jax.jit(model.warm_state)(params, jnp.asarray(hist),
+                                       jnp.ones((D, W), bool))
+    state = jax.tree.map(lambda leaf, rows: leaf.at[3:3 + D].set(rows),
+                         state, seeded)
+    dev = np.concatenate([np.arange(3, 3 + D, dtype=np.int32),
+                          pad_rows(cap, 8 - D)])
+
+    def two_steps():
+        step, at, out = jax.jit(streaming_step(model)), state, []
+        for k in range(2):
+            v = np.zeros(8, np.float32)
+            v[:D] = frames[k]
+            at, scores = step(params, at, dev, v)
+            out.append(np.asarray(scores))
+        return jax.tree.map(np.asarray, at), out
+
+    got_state, got = two_steps()
+
+    def as_before(self, p, x, state, taps):
+        z, gate, alpha, beta = self._gdn_project(p, x)
+        o, s, c1, held = self._gdn_cell(
+            p, state.read(x), taps.read(x).reshape(x.shape[0], -1), z,
+            alpha, beta)
+        x = state.write(s, self._gdn_out(p, x, o, gate))
+        x = taps.write(c1.reshape((-1,) + self._taps_shape), x)
+        return x, alpha, held, jnp.int32(0)
+
+    monkeypatch.setattr(type(model), "_linear_decode", as_before)
+    want_state, want = two_steps()
+    assert model.step_stats[-1] == "state.in_place"
+    for a, b in zip(got, want):
+        assert (a == b).all() and a[-1] == 0
+    for name, leaf in want_state.items():
+        assert (got_state[name] == leaf).all(), name
+
+
 def test_the_steps_numbers_reach_the_registry_through_a_session(run):
     """A session over the ring: scores against the reference, and on the
     registry the context's positions, the mean decay, the largest
@@ -304,6 +351,8 @@ def test_the_steps_numbers_reach_the_registry_through_a_session(run):
         assert decay.count == 10 and 0.2 < decay.sum / 10 < 1.0
         absmax = snap["scoring.state.absmax"]
         assert absmax.count == 10 and 0 < absmax._max < 1.0
+        # the CPU's step is the plain path: no row updated where it rests
+        assert snap["scoring.state.in_place_rows"].value == 0
         assert snap["scoring.ctx.reseeds"].value == 0
         # 6 live rows of: three states of 32 x 128 float32, three of
         # taps 3 x 256 float32 (the products' type here), hn, 4 scalars

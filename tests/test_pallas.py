@@ -221,3 +221,175 @@ def test_expert_kernel_matches_the_plain_products_interpret(held, tile,
     assert float(jnp.abs(got - want).max()) < 1e-5 * scale
     untouched = np.setdiff1d(np.arange(tokens), rows[real])
     assert (np.asarray(got)[untouched] == 0).all()
+
+
+# -- ops/state_kernel.py: a matrix state updated where it rests ---------------
+
+LINEAR, FULL = "linear_attention", "full_attention"
+# (heads, keys a head, values a head): the published head sizes, rested
+# `[rows, 15, 96, 384]`, and a small shape, four heads to a row of lanes
+STATE_SHAPES = {"published_heads": (30, 96, 192), "small": (8, 16, 32)}
+
+
+def _linear_model(heads, dk, dv, **over):
+    from sitewhere_tpu.models import build_model
+
+    return build_model("olmo-hybrid-stream", **{**dict(
+        compute_dtype=jnp.float32, hidden_size=128, intermediate_size=256,
+        num_hidden_layers=1, layer_types=[LINEAR], num_attention_heads=2,
+        num_key_value_heads=2, vocab_size=64, linear_num_key_heads=heads,
+        linear_num_value_heads=heads, linear_key_head_dim=dk,
+        linear_value_head_dim=dv, window=8, context_positions=16), **over})
+
+
+def _interpreted(monkeypatch):
+    """`update_rows` in interpret mode wherever the model calls it."""
+    import functools
+
+    from sitewhere_tpu.ops import state_kernel
+
+    monkeypatch.setattr(state_kernel, "update_rows", functools.partial(
+        state_kernel.update_rows, interpret=True))
+
+
+def _a_layers_inputs(model, rows, frame, seed):
+    """A state table of `rows` rows and what a frame of `frame` events
+    brings a linear layer: (`p`, table, taps, z, alpha, beta)."""
+    c = model.cfg
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 6))
+    p = {"conv": jax.random.normal(next(keys), (c.linear_conv_kernel_dim,
+                                                c.conv_channels)) * 0.5}
+    table = jax.random.normal(next(keys), (rows,) + model._state_shape) * 0.3
+    taps = jax.random.normal(next(keys), (frame, 3 * c.conv_channels))
+    z = jax.random.normal(next(keys), (frame, c.conv_channels))
+    alpha = jax.random.uniform(next(keys), (frame, c.linear_num_value_heads),
+                               minval=0.2, maxval=1.0)
+    beta = jax.random.uniform(next(keys), (frame, c.linear_num_value_heads),
+                              minval=0.0, maxval=2.0)
+    return p, table, taps, z, alpha, beta
+
+
+@pytest.mark.parametrize("shape", STATE_SHAPES)
+def test_state_kernel_matches_the_cell_and_writes_no_other_row_interpret(
+        shape, monkeypatch):
+    """ops/state_kernel.py against `_gdn_cell` on random states and
+    operands: the next state to 1e-5 of its scale, `o` to 1e-4, the
+    largest magnitude a row held exactly (a maximum has no order); rows
+    the frame does not name are bit-equal after the call, the scratch
+    row among them; padding (`dev` past the scratch row) writes nothing,
+    and its `o` and magnitude read 0; a SECOND dispatch over rows that
+    overlap the first's equals two plain steps."""
+    from sitewhere_tpu.ops import state_kernel
+    from sitewhere_tpu.scoring.stream import pad_rows
+
+    model = _linear_model(*STATE_SHAPES[shape])
+    rows, frame, live = 7, 6, 4
+    p, table, taps, z, alpha, beta = _a_layers_inputs(model, rows, frame, 3)
+    assert state_kernel.fits(table.shape, table.dtype)
+    if shape == "published_heads":
+        assert table.shape[1:] == (15, 96, 384)
+    _interpreted(monkeypatch)
+    scratch = rows - 1
+
+    def plain(table, dev):
+        o, s, _, held = model._gdn_cell(p, table[jnp.minimum(dev, scratch)],
+                                        taps, z, alpha, beta)
+        return table.at[dev].set(s, mode="drop"), o, held
+
+    def kernel(table, dev):
+        table, o, _, held, n = model._gdn_rows(p, table, dev, taps, z,
+                                               alpha, beta)
+        return table, o, held, n
+
+    first = np.concatenate([[0, 2, 3, 5], pad_rows(scratch, frame - live)])
+    second = np.concatenate([[1, 2, 5], pad_rows(scratch, frame - 3)])
+    want, got = table, table
+    for dev, n_live in ((first, live), (second, 3)):
+        dev = jnp.asarray(dev, jnp.int32)
+        before = np.asarray(got)
+        want, o_want, held_want = jax.jit(plain)(want, dev)
+        got, o_got, held_got, n = jax.jit(kernel)(got, dev)
+        assert int(n) == n_live
+        scale = float(jnp.abs(want).max())
+        assert 0.5 < scale < 10
+        assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+        o_scale = float(jnp.abs(o_want[:n_live]).max())
+        assert float(jnp.abs(o_got - o_want)[:n_live].max()) < 1e-4 * o_scale
+        assert (np.asarray(held_got)[:n_live]
+                == np.asarray(held_want)[:n_live]).all()
+        assert not np.asarray(o_got)[n_live:].any()
+        assert not np.asarray(held_got)[n_live:].any()
+        unnamed = np.setdiff1d(np.arange(rows), np.asarray(dev)[:n_live])
+        assert (np.asarray(got)[unnamed] == before[unnamed]).all()
+        named = np.asarray(dev)[:n_live]
+        assert (np.asarray(got)[named] != before[named]).any(axis=(1, 2, 3)
+                                                            ).all()
+
+
+def test_state_kernel_takes_float32_rows_of_whole_tiles_that_vmem_holds():
+    """`fits` reads the leaf's shape and dtype: the published row and the
+    tests' small ones; not a bfloat16 leaf, a row of keys that is no
+    whole sublane tile, lanes that are no whole lane tile, nor a row
+    four of which pass the VMEM the call asks for; and `update_rows`
+    refuses what `fits` does not take."""
+    from sitewhere_tpu.ops import state_kernel
+
+    fits = state_kernel.fits
+    assert fits((769, 15, 96, 384), jnp.float32)
+    assert state_kernel.vmem_bytes((769, 15, 96, 384)) < 10 << 20
+    assert fits((7, 2, 16, 128), jnp.float32)
+    assert not fits((769, 15, 96, 384), jnp.bfloat16)
+    assert not fits((769, 15, 92, 384), jnp.float32)
+    assert not fits((769, 15, 96, 192), jnp.float32)
+    assert not fits((769, 30, 96, 384), jnp.float32)
+    assert not fits((769, 96, 5760), jnp.float32)
+    with pytest.raises(ValueError, match="takes no table"):
+        state_kernel.update_rows(
+            jnp.zeros((3, 1, 12, 128)), jnp.zeros(2, jnp.int32),
+            jnp.zeros((2, 2, 12, 2)), jnp.zeros((2, 4, 1, 128)),
+            interpret=True)
+
+
+@pytest.mark.parametrize("case, heads, in_place", [
+    ("rows_the_kernel_takes", (4, 16, 64), True),
+    ("keys_that_are_no_whole_tile", (16, 12, 32), False)])
+def test_the_step_lowered_for_a_tpu_is_the_plain_step(case, heads, in_place,
+                                                      monkeypatch):
+    """The whole ring step with the TPU's branch taken (the kernel in
+    interpret mode) against the step as the CPU lowers it: scores and
+    every state leaf to float32 round-off, the step's other numbers
+    equal, and `state.in_place` counts the live rows of every linear
+    layer where the branch ran, 0 on the plain path. A leaf `fits` does
+    not take never reaches the choice: the plain path runs on any
+    platform."""
+    from sitewhere_tpu.scoring.stream import pad_rows, streaming_step
+
+    model = _linear_model(*heads, num_hidden_layers=4,
+                          layer_types=[LINEAR] * 3 + [FULL])
+    params = model.init(jax.random.PRNGKey(0))
+    cap, frame, live = 9, 8, 5
+    state = model.init_state(cap + 1)
+    for name in state:
+        if name[0] in "sc":
+            state[name] = jax.random.normal(
+                jax.random.PRNGKey(len(name)), state[name].shape) * 0.2
+    dev = np.concatenate([[0, 1, 4, 6, 8], pad_rows(cap, frame - live)]
+                         ).astype(np.int32)
+    v = np.linspace(-1, 1, frame).astype(np.float32)
+    want_state, want = jax.jit(streaming_step(model))(params, state, dev, v)
+
+    def on_a_tpu(*args, default, tpu):
+        return tpu(*args)
+
+    _interpreted(monkeypatch)
+    monkeypatch.setattr(jax.lax, "platform_dependent", on_a_tpu)
+    got_state, got = jax.jit(streaming_step(model))(params, state, dev, v)
+    stats = len(model.step_stats)
+    assert model.step_stats[-1] == "state.in_place"
+    assert float(want[-1]) == 0
+    assert float(got[-1]) == (3 * live if in_place else 0)
+    np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
+    np.testing.assert_allclose(got[-stats:-1], want[-stats:-1], rtol=1e-6)
+    for name, leaf in want_state.items():
+        np.testing.assert_allclose(got_state[name], leaf, atol=1e-5,
+                                   err_msg=name)
