@@ -116,6 +116,51 @@ def trace_stage_kind(name: str) -> str | None:
     """Registered kind for a trace stage name, or None if unknown."""
     return TRACE_STAGE_KINDS.get(name)
 
+
+# -- loop operators (kernel/tracing.py Tracer.watch_loop) ---------------------
+# The names under which the serving loop's busy seconds are kept, counter
+# `busy.loop.<operator>`. An operator is a name the CODE gives a task,
+# never the fleet: a task's operator is the rightmost `/`-element of its
+# name that, with a trailing `-<digits>` (a shard, a port, a consumer id)
+# dropped, is in this inventory, else `other`. `BackgroundTaskComponent`
+# names its task by the component's path, so a component's own name is
+# its operator; one whose name is the deployment's (a receiver, a
+# snapshotter) or says too little (`loop`) declares `operator` on its
+# class and the path carries it last. A task made by `create_task` is named where it is made; one that
+# asyncio makes (a server's connection handler) names itself at its first
+# line. tests/test_loop_watch.py holds every such name in the tree to
+# this inventory. NOT merged into TRACE_STAGES: an operator is never
+# `record()`ed and has no place in the critical path.
+
+LOOP_OPERATORS = frozenset({
+    # the served path (kernel/fastlane.py, services/, scoring/)
+    "tcp-receiver", "queue-receiver", "mqtt-receiver", "websocket-receiver",
+    "coap-receiver", "amqp-receiver", "stomp-receiver",
+    "fastlane", "fastlane-produce", "inbound-processor", "event-persister",
+    "state-merger", "presence-monitor", "rule-processor",
+    "scoring-settle", "scoring-pool", "scoring-first-weights",
+    "scoring-regrow", "warmup", "egress",
+    # the consumers beside it
+    "outbound-manager", "command-delivery-manager", "registration-manager",
+    "batch-element-processor", "schedule-manager",
+    # the runtime's own
+    "telemetry-beat", "tenant-engine-manager", "supervisor", "respin",
+    "snapshotter", "registry-replicator", "flow-fair-pump", "rest",
+    # the wire bus and the Kafka facade (kernel/wire.py, kernel/kafka*.py)
+    "wire-server", "wire-dispatch", "wire-rx", "wire-push", "wire-drain",
+    "wire-background", "kafka-endpoint", "kafka-background",
+    # the fleet (fleet/): the controller's and the observer's `loop`, the
+    # worker's `control` and `apply`
+    "fleet-controller", "fleet-observer", "fleet-worker-control",
+    "fleet-worker-apply",
+})
+# what is no operator: the seconds of a task no name above fits, and the
+# two shares of a stretch that are no task's
+LOOP_NOT_OPERATORS = frozenset({"other", "callbacks", "unspanned"})
+if LOOP_OPERATORS & LOOP_NOT_OPERATORS:
+    raise ValueError("a loop operator may not be named "
+                     f"{sorted(LOOP_OPERATORS & LOOP_NOT_OPERATORS)}")
+
 # -- metric base names, by kind (kernel/metrics.py registry) ----------------
 # Per-tenant variants use the `:{tenant_id}` suffix on the same base name
 # and share the base's registration.
@@ -191,6 +236,12 @@ COUNTERS = (
     # flight recorder (kernel/observe.py)
     "observe.beats",
     "observe.loop_stalls",
+    # the loop's account (kernel/tracing.py watch_loop): seconds the
+    # serving loop sat in its selector (beside the `busy.loop*` family:
+    # window = loop.select_s + busy.loop), and steps of the stall
+    # threshold or more, each kept by name in the tracer's ring
+    "loop.select_s",
+    "loop.slow_steps",
     # fleet control plane (sitewhere_tpu/fleet)
     "fleet.heartbeats",
     "fleet.rebalances",
@@ -317,13 +368,18 @@ HISTOGRAMS = (
     "scoring.megabatch_tenants_per_dispatch",
     # flight recorder (kernel/observe.py): event-loop lag per beat
     "observe.loop_lag_s",
+    # task steps and runs of callbacks of a millisecond or more on the
+    # serving loop (kernel/tracing.py watch_loop), quarter octaves
+    "loop.long_step_s",
     # fleet: placement-seen → engines-adopted per tenant move
     "fleet.handoff_s",
 )
 
 # f-string metric names whose suffix is computed at runtime
 # (FlowController.count builds f"flow.{name}", Tracer.add_busy builds
-# f"busy.{stage}": seconds a trace stage, or "gc", kept a thread busy);
+# f"busy.{stage}": seconds a trace stage, or "gc", kept a thread busy, and
+# the loop's account "loop", "loop.<operator>", "loop.callbacks",
+# "loop.unspanned");
 # MET01 accepts an f-string whose literal prefix matches one of these
 # exactly.
 DYNAMIC_METRIC_PREFIXES = ("flow.", "busy.")

@@ -520,6 +520,40 @@ def _render_stages(cp: dict) -> list[str]:
     return lines
 
 
+def _render_loop(account: dict) -> list[str]:
+    """The serving loop's account (kernel/tracing.py `watch_loop`): how
+    busy the loop is, who keeps it busy, and the slow steps by name."""
+    busy, idle = account.get("busy_s"), account.get("select_s")
+    lines = []
+    if busy is None or idle is None:
+        lines.append("loop: steps only (this loop has no selector to time)")
+        total = sum(op["busy_s"] for op in account["operators"].values())
+    else:
+        total = busy
+        window = (busy + idle) or 1.0
+        lines.append(
+            f"loop: busy {busy / window:.1%} of {window:.1f}s watched — "
+            f"callbacks {account.get('callbacks_s', 0.0) / window:.1%}, "
+            f"in no span {account.get('unspanned_s', 0.0) / window:.1%}")
+    lines.append(f"  {'operator':<28} {'busy s':>9} {'share':>7} "
+                 f"{'steps':>9}")
+    for name, op in list(account["operators"].items())[:12]:
+        lines.append(f"  {name:<28} {op['busy_s']:>9.3f} "
+                     f"{op['busy_s'] / (total or 1.0):>7.1%} "
+                     f"{op['steps']:>9}")
+    slow = account.get("slow_steps") or []
+    if slow:
+        lines.append(f"  slow steps (>= "
+                     f"{account.get('slow_step_threshold_ms', 0):.0f}ms, "
+                     f"newest last):")
+        for step in slow[-8:]:
+            where = f" in {step['stage']}" if step.get("stage") else ""
+            lines.append(
+                f"    {step['seconds'] * 1e3:>8.1f}ms  "
+                f"{step['operator']:<24} {step.get('task') or '-'}{where}")
+    return lines
+
+
 def render_top(report: dict) -> str:
     """Render one flight-recorder report (`GET /api/instance/observe`)
     as the `swx top` screen. Pure function — tests and --json callers
@@ -559,6 +593,9 @@ def render_top(report: dict) -> str:
     lines.extend(_render_stages(cp))
     if not cp.get("stages"):
         lines.append("  (no sampled spans yet)")
+    if report.get("loop"):
+        lines.append("")
+        lines.extend(_render_loop(report["loop"]))
     last = (beat or {}).get("last") or {}
     if last:
         lags = last.get("consumer_lag") or {}

@@ -579,6 +579,8 @@ class FleetController(LifecycleComponent):
 class _ControllerLoop(BackgroundTaskComponent):
     """The controller's single supervised loop."""
 
+    operator = "fleet-controller"       # its own name is `loop`
+
     def __init__(self, controller: FleetController):
         super().__init__("loop")
         self.controller = controller

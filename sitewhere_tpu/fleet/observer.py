@@ -325,6 +325,8 @@ class FleetObserver(LifecycleComponent):
 class _ObserverLoop(BackgroundTaskComponent):
     """Consume the telemetry topic (one supervised loop)."""
 
+    operator = "fleet-observer"         # its own name is `loop`
+
     def __init__(self, observer: FleetObserver):
         super().__init__("loop")
         self.observer = observer

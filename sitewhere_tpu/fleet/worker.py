@@ -371,6 +371,8 @@ class FleetWorker(LifecycleComponent):
 class _WorkerControlLoop(BackgroundTaskComponent):
     """Consume fleet-control + publish heartbeats (one supervised loop)."""
 
+    operator = "fleet-worker-control"   # its own name is `control`
+
     def __init__(self, worker: FleetWorker):
         super().__init__("control")
         self.worker = worker
@@ -417,6 +419,8 @@ class _WorkerApplyLoop(BackgroundTaskComponent):
     take seconds (engine start = jit warmup), and heartbeats must keep
     flowing through it or the controller would declare this worker dead
     mid-handoff."""
+
+    operator = "fleet-worker-apply"     # its own name is `apply`
 
     def __init__(self, worker: FleetWorker):
         super().__init__("apply")

@@ -158,7 +158,8 @@ async def produce_settled(bus, topic, value, *, key=None, fence=None,
         sent.append(True)
         return await bus.produce(topic, value, key=key, fence=fence)
 
-    task = asyncio.ensure_future(run())
+    task = asyncio.get_running_loop().create_task(
+        run(), name="fastlane-produce")
     try:
         await asyncio.shield(task)
     except asyncio.CancelledError:

@@ -201,7 +201,8 @@ def _spawn_logged(tasks: set, coro) -> "asyncio.Task":
     the event loop does not (an unretained task can be GC'd mid-flight —
     swx lint TSK01), and the `_log_failure` wrapper retrieves the result
     so a failed background op surfaces in the log instead of nowhere."""
-    task = asyncio.get_running_loop().create_task(_log_failure(coro))
+    task = asyncio.get_running_loop().create_task(
+        _log_failure(coro), name="kafka-background")
     tasks.add(task)
     task.add_done_callback(tasks.discard)
     return task

@@ -233,6 +233,8 @@ class KafkaEndpoint:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("kafka-endpoint")
         self._writers.add(writer)
         try:
             while True:

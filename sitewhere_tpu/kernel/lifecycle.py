@@ -301,8 +301,17 @@ class BackgroundTaskComponent(LifecycleComponent):
         self._crash_times.clear()
         self._spawn()
 
+    # The task's operator in the loop's account (kernel/tracing.py
+    # `watch_loop`) is the component's own name; a class whose instances
+    # are named by the deployment (a receiver, a snapshotter), or by a
+    # word that says too little (`loop`), says here which name of
+    # `analysis/registry.py`'s LOOP_OPERATORS it works under.
+    operator: Optional[str] = None
+
     def _spawn(self) -> None:
-        self._task = asyncio.create_task(self._run(), name=self.path)
+        name = self.path if self.operator is None \
+            else f"{self.path}/{self.operator}"
+        self._task = asyncio.create_task(self._run(), name=name)
         self._task.add_done_callback(self._on_task_done)
 
     def _root(self):

@@ -18,7 +18,9 @@ metrics registry (so Prometheus exposition rides the existing
 - **event-loop lag**: the drift between when the beat asked to wake and
   when the loop actually ran it. A loop that stops yielding — the PR-6
   starvation class — shows up within ONE beat as a lag spike; past
-  `observe_stall_ms` it counts `observe.loop_stalls` and logs loudly.
+  `observe_stall_ms` it counts `observe.loop_stalls` and logs loudly,
+  naming the task and operator whose step held the loop (the tracer's
+  slow-step ring, kernel/tracing.py `watch_loop`).
 - **consumer lag** per group (committed offset vs head), via
   `EventBus.group_lags()` — the backlog signal autoscaling needs.
 - **egress shard backlog** and **scoring occupancy** (pending/inflight)
@@ -27,7 +29,10 @@ metrics registry (so Prometheus exposition rides the existing
 
 Sampling cost is a handful of dict walks over per-tenant engines — no
 locks, no awaits inside the sample — so the beat is safe to leave on in
-production (its cost on the chip: not measured).
+production. Its cost on the chip is its own operator's share of the
+serving loop's second, `busy.loop.telemetry-beat`: 0.0009 s/s in both
+`stream-512k` cells, one tenant, four beats a second, so 0.22 ms a beat
+(PERF.md section 5, PR 37).
 
 `observe_report()` combines the beat's latest state with the tracer's
 critical-path analysis (kernel/tracing.py) into the one dict served by
@@ -190,8 +195,9 @@ class TelemetryBeat(BackgroundTaskComponent):
             self.stalls.inc()
             logger.warning(
                 "telemetry-beat: event loop lagged %.1f ms (stall "
-                "threshold %.1f ms) — a consumer loop is not yielding",
-                loop_lag_s * 1e3, self.stall_s * 1e3)
+                "threshold %.1f ms) — %s",
+                loop_lag_s * 1e3, self.stall_s * 1e3,
+                self._who_stalled(loop_lag_s))
         metrics = runtime.metrics
         # consumer lag: committed offset vs head, per group (in-proc bus
         # only; a wire-bus process reads lag on the broker process)
@@ -274,6 +280,26 @@ class TelemetryBeat(BackgroundTaskComponent):
         if self._export_topic is not None:
             self._export(sample)
         return sample
+
+    def _who_stalled(self, loop_lag_s: float) -> str:
+        """The longest slow step of the tracer's ring that ended inside
+        the lag just measured, in words; the old guess where the ring
+        holds none (a loop without the account's seam)."""
+        since = time.monotonic() - loop_lag_s - 1e-3
+        held = [s for s in self.runtime.tracer.slow_steps()
+                if s["t_start"] + s["seconds"] >= since]
+        if not held:
+            return "a consumer loop is not yielding"
+        step = max(held, key=lambda s: s["seconds"])
+        who = (f"task {step['task']} (operator {step['operator']})"
+               if step["task"] else "callbacks that are no task")
+        text = f"{who} held the loop {step['seconds'] * 1e3:.1f} ms"
+        if step["stage"]:
+            text += (f", {step['stage_s'] * 1e3:.1f} ms of it in "
+                     f"{step['stage']}")
+        if step["gc_s"]:
+            text += f", {step['gc_s'] * 1e3:.1f} ms in the collector"
+        return text
 
     def _worker_key(self) -> str:
         """This process's identity on the telemetry topic / in worker-
@@ -385,14 +411,17 @@ class TelemetryBeat(BackgroundTaskComponent):
 
 def observe_report(runtime, tenant: Optional[str] = None) -> dict:
     """The flight recorder's one-call report: critical path over sampled
-    traces + the telemetry beat's live state (+ fleet placement when
-    this process hosts the controller). Served by
+    traces + the loop's account + the telemetry beat's live state (+
+    fleet placement when this process hosts the controller). Served by
     `GET /api/instance/observe`, rendered by `swx top`."""
     beat = getattr(runtime, "beat", None)
     fleet = getattr(runtime, "fleet", None)
     history = getattr(runtime, "history", None)
     return {
         "critical_path": runtime.tracer.critical_path(tenant=tenant),
+        # the serving loop's account: busy and waiting seconds, each
+        # operator's share, the slow-step ring (kernel/tracing.py)
+        "loop": runtime.tracer.loop_report(),
         "beat": beat.snapshot() if beat is not None else None,
         "fleet": fleet.snapshot() if fleet is not None else None,
         # durable telemetry history (persistence/durable.py): series/

@@ -370,7 +370,8 @@ class ServiceRuntime(LifecycleComponent):
         self.metrics = MetricsRegistry()
         from sitewhere_tpu.kernel.tracing import Tracer
         self.tracer = Tracer(sample=settings.trace_sample,
-                             metrics=self.metrics)
+                             metrics=self.metrics,
+                             stall_s=settings.observe_stall_ms / 1e3)
         # `bus` may be a RemoteEventBus (kernel/wire.py): this process
         # then shares one broker's topics with peer processes — the
         # process-split deployment the reference runs as 14 JVMs
@@ -662,8 +663,12 @@ class ServiceRuntime(LifecycleComponent):
     async def _do_start(self, monitor: LifecycleProgressMonitor) -> None:
         # the collector's pauses, as `busy.gc` and on a profiler trace
         self.tracer.watch_gc()
+        # the serving loop's own account: every task step from here on
+        # credited to an operator, the selector's wait counted as idle
+        self.tracer.watch_loop(asyncio.get_running_loop())
 
     async def _do_stop(self, monitor: LifecycleProgressMonitor) -> None:
+        self.tracer.unwatch_loop()
         self.tracer.unwatch_gc()
         eb = getattr(self, "_external_bus", None)
         if eb is not None:

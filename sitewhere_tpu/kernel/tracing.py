@@ -50,19 +50,53 @@ trace running an annotation costs well under a microsecond. A stage
 whose interval holds an `await` keeps `record()`: its wall time is not
 busy time, and an annotation left open across a suspension would cover
 whatever else the loop ran meanwhile.
+
+`Tracer.watch_loop(loop)` is the account of the code that DOES await:
+the serving loop's own second, split with nothing left over,
+
+    window = loop.select_s + busy.loop
+    busy.loop = sum of busy.loop.<operator> + busy.loop.callbacks
+
+A task's step (one `send` or `throw` of its coroutine, synchronous by
+construction) is timed by a wrapper that the loop's task factory puts
+round the coroutine, and its seconds go to the task's operator: the
+rightmost `/`-element of the task's name that, less a trailing
+`-<digits>`, is in `analysis/registry.py`'s `LOOP_OPERATORS`, else
+`other`. The selector's wait is the idle time (`loop.select_s`), timed
+by a proxy round the loop's selector; what a stretch between two waits
+holds beside its steps is `busy.loop.callbacks` (socket reads, timers,
+done-callbacks, tasks older than the watch), and what of the stretch no
+outermost `span()` and no collection covered on the loop's thread is
+`busy.loop.unspanned`: the dark share, as a counter. While a
+`jax.profiler` trace runs each step is also an annotation
+`loop.<operator>` and each wait one named `loop.select`, so the loop
+thread's line is tiled by operators, with the stage spans nested inside
+them, and by waits; the gaps that are left are the callbacks.
+A step or a stretch of callbacks of a millisecond or more goes into the
+histogram `loop.long_step_s`; one of `stall_s` or more is kept, with its
+operator, task and covering stage, in a bounded ring (`slow_steps()`).
 """
 
 from __future__ import annotations
 
+import asyncio
+import collections.abc
 import gc
 import itertools
+import re
+import threading
 import time
 import zlib
 from collections import deque
 from dataclasses import dataclass
 from typing import Iterable, Optional
 
-from sitewhere_tpu.kernel.metrics import Counter, Histogram, MetricsRegistry
+from sitewhere_tpu.kernel.metrics import (
+    QUARTER_OCTAVES,
+    Counter,
+    Histogram,
+    MetricsRegistry,
+)
 
 
 @dataclass(frozen=True, slots=True)
@@ -86,13 +120,14 @@ class _Span:
     `t_end` are its `time.monotonic()` instants; `n_events` may be set
     inside the block (a decode learns its count by decoding)."""
 
-    __slots__ = ("_tracer", "_annotation", "stage", "trace_id", "tenant_id",
-                 "n_events", "t_start", "t_end")
+    __slots__ = ("_tracer", "_annotation", "_watch", "stage", "trace_id",
+                 "tenant_id", "n_events", "t_start", "t_end")
 
     def __init__(self, tracer: "Tracer", stage: str, trace_id: int,
                  tenant_id: str, n_events: int):
         self._tracer = tracer
         self._annotation = tracer._annotate(stage)
+        self._watch = None
         self.stage = stage
         self.trace_id = trace_id
         self.tenant_id = tenant_id
@@ -102,12 +137,316 @@ class _Span:
     def __enter__(self) -> "_Span":
         self._annotation.__enter__()
         self.t_start = time.monotonic()
+        watch = self._tracer._watch
+        if watch is not None and watch.thread == threading.get_ident():
+            self._watch = watch
+            watch.depth += 1
         return self
 
     def __exit__(self, *exc) -> None:
         self.t_end = time.monotonic()
         self._annotation.__exit__(*exc)
+        watch = self._watch
+        if watch is not None:
+            watch.depth -= 1
+            if watch.depth == 0:        # outermost on the loop's thread
+                watch.cover(self.stage, self.t_start,
+                            self.t_end - self.t_start)
         self._tracer._close(self)
+
+
+# -- the loop's account (Tracer.watch_loop) -----------------------------------
+
+_now = time.monotonic
+LONG_STEP_S = 1e-3      # from here a step pays a histogram observation
+OTHER = "other"         # the operator of a task no inventory name fits
+SELECT_SPAN = "loop.select"     # the selector's wait, on a profiler trace
+_SHARD = re.compile(r"-\d+$")
+
+
+def operator_of(task_name: str) -> str:
+    """The operator a task's seconds go to: the rightmost `/`-element of
+    its name that, with a trailing `-<digits>` dropped, is in
+    `LOOP_OPERATORS`, else `other`. Only inventory names come out, so
+    what a tenant, an instance or a model is called never names a
+    counter."""
+    from sitewhere_tpu.analysis.registry import LOOP_OPERATORS
+
+    for element in reversed(task_name.split("/")):
+        element = _SHARD.sub("", element)
+        if element in LOOP_OPERATORS:
+            return element
+    return OTHER
+
+
+class _Operator:
+    """One operator's counter, steps and annotation name."""
+
+    __slots__ = ("name", "busy", "steps", "span_name")
+
+    def __init__(self, name: str, busy: Counter):
+        self.name = name
+        self.busy = busy
+        self.steps = 0
+        self.span_name = f"loop.{name}"
+
+
+class _LoopWatch:
+    """What one `watch_loop` keeps. A stretch runs from one return of the
+    selector's `select` (`t_stretch`) to its next call; `mark` is where
+    the last step in it ended (or the stretch began), so that `now -
+    mark` is a run of callbacks that are no task; `steps_s` and
+    `covered_s` are the stretch's seconds inside steps, and inside
+    outermost spans and collections."""
+
+    __slots__ = ("tracer", "loop", "thread", "live", "factory",
+                 "selector", "operators", "busy", "select_s", "callbacks",
+                 "unspanned", "long_steps", "slow", "t_stretch", "mark",
+                 "steps_s", "covered_s", "depth", "waiting", "gc_waiting_s",
+                 "long_spans", "tracing", "annotation", "by_task_name")
+
+    def __init__(self, tracer: "Tracer", loop):
+        metrics = tracer.metrics
+        self.tracer = tracer
+        self.loop = loop
+        # a step is an annotation `loop.<operator>` only while a profiler
+        # trace runs: it is too frequent to pay a third of a microsecond
+        # for one that nothing records
+        self.annotation = tracer._annotation_class()
+        self.tracing = getattr(self.annotation, "is_enabled", lambda: True)
+        self.thread = threading.get_ident()
+        self.live = True
+        # ours on the loop; `selector` stays None on a loop without the seam
+        self.factory = self.selector = None
+        self.operators: dict[str, _Operator] = {}
+        self.by_task_name: dict[str, _Operator] = {}
+        self.busy = tracer._busy_counter("loop")
+        self.select_s = metrics.counter("loop.select_s")
+        self.callbacks = tracer._busy_counter("loop.callbacks")
+        self.unspanned = tracer._busy_counter("loop.unspanned")
+        self.long_steps = metrics.histogram(
+            "loop.long_step_s",
+            buckets=[b for b in QUARTER_OCTAVES if b > LONG_STEP_S])
+        self.slow = metrics.counter("loop.slow_steps")
+        self.t_stretch = self.mark = _now()
+        self.steps_s = self.covered_s = 0.0
+        self.depth = 0
+        self.waiting = False
+        self.gc_waiting_s = 0.0
+        # outermost spans, and collections, of a millisecond or more on
+        # the loop's thread, newest last: what a slow step names its
+        # stage and its collector's seconds by
+        self.long_spans: deque[tuple] = deque(maxlen=16)
+
+    def operator(self, task) -> _Operator:
+        """The operator of `task` by its name now (the rule is
+        `operator_of`'s)."""
+        task_name = task.get_name() if task is not None else ""
+        operator = self.by_task_name.get(task_name)
+        if operator is None:
+            name = operator_of(task_name)
+            operator = self.operators.get(name)
+            if operator is None:
+                operator = self.operators[name] = _Operator(
+                    name, self.tracer._busy_counter(f"loop.{name}"))
+            if len(self.by_task_name) < 4096:   # names are the fleet's
+                self.by_task_name[task_name] = operator
+        return operator
+
+    def cover(self, stage: str, t_start: float, seconds: float,
+              inside_a_span: bool = False) -> None:
+        """An outermost span or a collection closed on the loop's
+        thread: its seconds are not dark (a collection inside a span is
+        the span's already), and a slow step can name it."""
+        if not inside_a_span:
+            self.covered_s += seconds
+        if seconds >= LONG_STEP_S:
+            self.long_spans.append((t_start, seconds, stage))
+
+    def end_stretch(self, now: float) -> None:
+        """The stretch since the selector last returned is over (it is
+        about to wait again, or the watch ends): its seconds go to
+        `busy.loop`, and what of them was no step, and what nothing
+        covered, to their counters."""
+        if now - self.mark >= LONG_STEP_S:
+            self.long_step(None, self.mark, now - self.mark)
+        stretch = now - self.t_stretch
+        self.busy.value += stretch
+        self.callbacks.value += stretch - self.steps_s
+        self.unspanned.value += stretch - self.covered_s
+        self.steps_s = self.covered_s = 0.0
+        self.t_stretch = self.mark = now
+
+    def long_step(self, operator: Optional[_Operator], t_start: float,
+                  seconds: float) -> None:
+        """A step (or, with no operator, a run of callbacks) of a
+        millisecond or more; from `stall_s` it is kept by name."""
+        self.long_steps.observe(seconds)
+        tracer = self.tracer
+        if seconds < tracer.stall_s:
+            return
+        self.slow.inc()
+        task = asyncio.current_task(self.loop) if operator else None
+        inside = [s for s in self.long_spans if s[0] >= t_start]
+        stage = max((s for s in inside if not s[2].startswith("gc.")),
+                    key=lambda s: s[1], default=None)
+        tracer._slow_steps.append({
+            "operator": operator.name if operator else "callbacks",
+            "task": task.get_name() if task is not None else None,
+            "t_start": t_start,
+            "seconds": seconds,
+            "stage": stage[2] if stage else None,
+            "stage_s": stage[1] if stage else 0.0,
+            "gc_s": sum(s[1] for s in inside if s[2].startswith("gc.")),
+        })
+
+
+class _TaskSteps(collections.abc.Coroutine):
+    """A task's coroutine with each step (one `send` or `throw`) timed.
+    Everything else reads through to the coroutine: `cr_frame`,
+    `cr_code`, `cr_running`, `cr_await`, `__name__`, `__qualname__`, so
+    `Task.get_stack()`, `repr(task)` and a crash log read as before."""
+
+    __slots__ = ("_coro", "_watch", "_operator", "_thrown")
+
+    def __init__(self, coro, watch: _LoopWatch):
+        self._coro = coro
+        self._watch = watch
+        self._operator: Optional[_Operator] = None
+        self._thrown: Optional[tuple] = None
+
+    def __getattr__(self, name: str):
+        return getattr(self._coro, name)
+
+    def __await__(self):
+        return self._coro.__await__()
+
+    def close(self):
+        return self._coro.close()
+
+    def throw(self, *exc):
+        # a step like any other: parked here, thrown in `send`
+        self._thrown = exc
+        return self.send(None)
+
+    def send(self, value):
+        watch = self._watch
+        thrown = self._thrown
+        if not watch.live:
+            if thrown is None:
+                return self._coro.send(value)
+            self._thrown = None
+            return self._coro.throw(*thrown)
+        operator = self._operator
+        first = operator is None
+        if first:
+            # `create_task` names the task after the factory returned, and
+            # a connection handler names itself at its first line: the
+            # name is read again when this first step's seconds are folded
+            operator = watch.operator(asyncio.current_task(watch.loop))
+        annotation = None
+        if watch.tracing():         # a profiler trace is running
+            annotation = watch.annotation(operator.span_name)
+            annotation.__enter__()
+        t0 = _now()
+        if t0 - watch.mark >= LONG_STEP_S and watch.selector is not None:
+            watch.long_step(None, watch.mark, t0 - watch.mark)
+        try:
+            if thrown is None:
+                return self._coro.send(value)
+            self._thrown = None
+            return self._coro.throw(*thrown)
+        finally:
+            t1 = _now()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            if first:
+                operator = self._operator = watch.operator(
+                    asyncio.current_task(watch.loop))
+            if watch.live:      # not the step that ended the watch
+                seconds = t1 - t0
+                operator.busy.value += seconds
+                operator.steps += 1
+                watch.steps_s += seconds
+                watch.mark = t1
+                if seconds >= LONG_STEP_S:
+                    watch.long_step(operator, t0, seconds)
+
+
+class _TaskFactory:
+    """The loop's task factory while it is watched: the task that the
+    factory before it (or `asyncio.Task`) makes, of the wrapped
+    coroutine."""
+
+    def __init__(self, watch: _LoopWatch, before):
+        self.watch = watch
+        self.before = before
+
+    def __call__(self, loop, coro, **kwargs):
+        if self.watch.live:
+            coro = _TaskSteps(coro, self.watch)
+        if self.before is not None:
+            return self.before(loop, coro, **kwargs)
+        return asyncio.Task(coro, loop=loop, **kwargs)
+
+
+class _TimedSelector:
+    """The loop's selector with its wait timed: the loop is idle exactly
+    while it sits in `select`. Everything else is the selector's own."""
+
+    def __init__(self, watch: _LoopWatch, before):
+        self.watch = watch
+        self.before = before
+
+    def __getattr__(self, name: str):
+        return getattr(self.before, name)
+
+    def select(self, timeout=None):
+        watch = self.watch
+        if not watch.live:
+            return self.before.select(timeout)
+        t0 = _now()
+        watch.end_stretch(t0)
+        annotation = None
+        if watch.tracing():         # on a trace the wait has a name too,
+            annotation = watch.annotation(SELECT_SPAN)  # callbacks are
+            annotation.__enter__()                      # the gaps
+        watch.waiting = True
+        try:
+            return self.before.select(timeout)
+        finally:
+            watch.waiting = False
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            t1 = watch.t_stretch = watch.mark = _now()
+            watch.select_s.value += t1 - t0
+            if watch.gc_waiting_s:
+                # a collection that ran on this thread between the two
+                # instants (the selector's own lists) was no wait
+                collected, watch.gc_waiting_s = watch.gc_waiting_s, 0.0
+                watch.select_s.value -= collected
+                watch.busy.value += collected
+                watch.callbacks.value += collected
+
+
+def _selector_of(loop):
+    """The seam where a loop waits: asyncio's selector loops keep their
+    selector as `_selector` and call its `select` once an iteration
+    (tests/test_loop_watch.py pins that to the interpreter in use). No
+    public seam exists on a loop that is already running. None where the
+    loop has none: it is then watched for its steps alone."""
+    selector = getattr(loop, "_selector", None)
+    return selector if callable(getattr(selector, "select", None)) else None
+
+
+def _live_before(link):
+    """What `link` (a factory or a selector of ours) was put round, with
+    dead links of its own kind skipped: two runtimes on one loop may
+    stop in the order they started."""
+    before = link.before
+    while isinstance(before, type(link)) and not before.watch.live:
+        before = before.before
+    return before
 
 
 class Tracer:
@@ -116,15 +455,22 @@ class Tracer:
     the total span budget; each stage's ring gets `stage_capacity`
     (default `capacity // 8`, min 64) so stages evict only their own
     history. `metrics` is the registry the busy counters live in (the
-    runtime's; a tracer built without one keeps its own)."""
+    runtime's; a tracer built without one keeps its own). `watch_loop`
+    adds the serving loop's account (the module's docstring)."""
 
     def __init__(self, capacity: int = 4096, sample: int = 64,
                  stage_capacity: int = 0,
-                 metrics: Optional[MetricsRegistry] = None):
+                 metrics: Optional[MetricsRegistry] = None,
+                 stall_s: float = 0.1):
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._busy: dict[str, Counter] = {}
         self._annotation_cls = None
         self._gc_open: Optional[tuple] = None
+        # the loop's account (watch_loop): a step of `stall_s` or more is
+        # kept by name (the runtime passes its `observe_stall_ms`)
+        self.stall_s = stall_s
+        self._watch: Optional[_LoopWatch] = None
+        self._slow_steps: deque[dict] = deque(maxlen=32)
         self.sample = max(int(sample), 1)
         self.stage_capacity = (max(int(stage_capacity), 1)
                                if stage_capacity
@@ -198,15 +544,18 @@ class Tracer:
         self.record(span.trace_id, span.stage, span.tenant_id,
                     span.t_start, seconds, span.n_events)
 
-    def add_busy(self, stage: str, seconds: float) -> None:
-        """Add to `busy.<stage>`. A plain float add, so from the thread
-        that owns the counter only: a worker thread hands its instants
-        back and the event loop adds them."""
+    def _busy_counter(self, stage: str) -> Counter:
         counter = self._busy.get(stage)
         if counter is None:
             counter = self._busy[stage] = self.metrics.counter(
                 f"busy.{stage}")
-        counter.inc(seconds)
+        return counter
+
+    def add_busy(self, stage: str, seconds: float) -> None:
+        """Add to `busy.<stage>`. A plain float add, so from the thread
+        that owns the counter only: a worker thread hands its instants
+        back and the event loop adds them."""
+        self._busy_counter(stage).inc(seconds)
 
     def watch_gc(self) -> None:
         """Put the collector's pauses on both clocks: each collection is
@@ -230,17 +579,96 @@ class Tracer:
         elif self._gc_open is not None:
             annotation, t0 = self._gc_open
             self._gc_open = None
-            self.add_busy("gc", time.monotonic() - t0)
+            seconds = time.monotonic() - t0
+            self.add_busy("gc", seconds)
             annotation.__exit__(None, None, None)
+            watch = self._watch
+            if watch is not None and watch.thread == threading.get_ident():
+                if watch.waiting:
+                    watch.gc_waiting_s += seconds
+                else:
+                    watch.cover(f"gc.gen{info['generation']}", t0, seconds,
+                                inside_a_span=watch.depth > 0)
+
+    # -- the loop's account ---------------------------------------------------
+
+    def watch_loop(self, loop) -> None:
+        """Account for `loop`'s time from now on (the module's
+        docstring), called on the loop's own thread. Tasks made from here
+        on have their steps timed; where the loop keeps its selector as
+        `_selector` (asyncio's selector loops do) its waits are timed
+        too, and where it does not the steps are still counted and
+        `busy.loop` is not reported. Watching the loop that is watched
+        already changes nothing."""
+        watch = self._watch
+        if watch is not None:
+            if watch.loop is loop:
+                return
+            self.unwatch_loop()
+        watch = self._watch = _LoopWatch(self, loop)
+        watch.factory = _TaskFactory(watch, loop.get_task_factory())
+        loop.set_task_factory(watch.factory)
+        selector = _selector_of(loop)
+        if selector is not None:
+            watch.selector = loop._selector = _TimedSelector(watch, selector)
+
+    def unwatch_loop(self) -> None:
+        """Leave the loop's factory and selector as `watch_loop` found
+        them. Tasks made meanwhile keep their wrappers, which from now
+        on pass straight through."""
+        watch, self._watch = self._watch, None
+        if watch is None:
+            return
+        watch.live = False
+        loop = watch.loop
+        if loop.get_task_factory() is watch.factory:
+            loop.set_task_factory(_live_before(watch.factory))
+        if watch.selector is not None:
+            watch.end_stretch(_now())       # the stretch this call is in
+            if loop._selector is watch.selector:
+                loop._selector = _live_before(watch.selector)
+
+    def slow_steps(self) -> list[dict]:
+        """The steps, and runs of callbacks, of `stall_s` or more that
+        the ring still holds, oldest first: operator, task, start on
+        `time.monotonic()`, seconds, and the stage of the span that
+        covered most of it."""
+        return list(self._slow_steps)
+
+    def loop_report(self) -> Optional[dict]:
+        """The loop's account since `watch_loop`, for `observe_report()`:
+        seconds busy and waiting, each operator's seconds and steps,
+        and the slow-step ring. None while no loop is watched."""
+        watch = self._watch
+        if watch is None:
+            return None
+        def seconds(counter: Counter) -> Optional[float]:
+            # the four that only a timed selector can give
+            return round(counter.value, 6) if watch.selector is not None \
+                else None
+
+        operators = {
+            name: {"busy_s": round(op.busy.value, 6), "steps": op.steps}
+            for name, op in sorted(watch.operators.items(),
+                                   key=lambda kv: -kv[1].busy.value)}
+        return {
+            "busy_s": seconds(watch.busy),
+            "select_s": seconds(watch.select_s),
+            "callbacks_s": seconds(watch.callbacks),
+            "unspanned_s": seconds(watch.unspanned),
+            "operators": operators,
+            "slow_step_threshold_ms": round(self.stall_s * 1e3, 1),
+            "slow_steps": [
+                {**s, "seconds": round(s["seconds"], 6),
+                 "stage_s": round(s["stage_s"], 6),
+                 "gc_s": round(s["gc_s"], 6)} for s in self._slow_steps],
+        }
 
     # -- query surface -----------------------------------------------------
 
     def _all(self) -> Iterable[Span]:
         for ring in self._rings.values():
             yield from ring
-
-    def stages(self) -> list[str]:
-        return sorted(self._rings)
 
     def spans(self, stage: Optional[str] = None,
               tenant: Optional[str] = None,

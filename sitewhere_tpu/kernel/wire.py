@@ -166,6 +166,8 @@ class WireServer:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("wire-server")
         self._conns.add(writer)
         tasks: set[asyncio.Task] = set()
         try:
@@ -181,7 +183,8 @@ class WireServer:
                     raise ValueError(f"frame {length} exceeds max")
                 body = await reader.readexactly(length)
                 task = asyncio.create_task(
-                    self._dispatch(req_id, body, writer))
+                    self._dispatch(req_id, body, writer),
+                    name="wire-dispatch")
                 tasks.add(task)
                 task.add_done_callback(tasks.discard)
         except (asyncio.IncompleteReadError, ConnectionError, ValueError,
@@ -427,7 +430,7 @@ class WireClient:
         if (self._drain_task is None and transport is not None
                 and transport.get_write_buffer_size() > _DRAIN_WATERMARK):
             self._drain_task = asyncio.get_running_loop().create_task(
-                self._drain_once())
+                self._drain_once(), name="wire-drain")
 
     async def _drain_once(self) -> None:
         try:
@@ -637,7 +640,8 @@ class WireClient:
 
     def spawn(self, coro) -> asyncio.Task:
         """Run a fire-and-forget coroutine, retained until done."""
-        task = asyncio.get_running_loop().create_task(coro)
+        task = asyncio.get_running_loop().create_task(
+            coro, name="wire-background")
         self._bg.add(task)
 
         def done(t: asyncio.Task) -> None:

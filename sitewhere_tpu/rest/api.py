@@ -134,6 +134,8 @@ class RestServer(LifecycleComponent):
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("rest")
         self._writers.add(writer)
         try:
             while True:

@@ -986,7 +986,8 @@ class SharedScoringPool:
                 e.inflight += 1
         task = asyncio.get_running_loop().create_task(
             self._settle_and_deliver(dispatches, metas, t0,
-                                     enqueue.t_end, seq))
+                                     enqueue.t_end, seq),
+            name="scoring-settle")
         self._settle_tasks.add(task)
         task.add_done_callback(self._settle_task_done)
 

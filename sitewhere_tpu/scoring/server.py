@@ -760,7 +760,7 @@ class ScoringSession:
             task = loop.create_task(self._settle_and_deliver(
                 dispatches, dev[lo:hi], ts[lo:hi],
                 ingest[lo:hi], ctx, t0, enqueue.t_end, fut, seq,
-                traces if lo == 0 else None))
+                traces if lo == 0 else None), name="scoring-settle")
             self._settle_tasks.add(task)
             task.add_done_callback(self._settle_task_done)
             n_chunks += 1

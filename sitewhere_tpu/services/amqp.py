@@ -417,6 +417,8 @@ class AmqpListener:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("amqp-receiver")
         conn = _Conn(self, reader, writer)
         self._writers.add(writer)
         try:

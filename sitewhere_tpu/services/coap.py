@@ -254,7 +254,7 @@ class CoapListener(asyncio.DatagramProtocol):
             self._reply_con(addr, mid, build_message(
                 TYPE_ACK, CODE_CHANGED, mid, token))
         task = asyncio.get_running_loop().create_task(
-            self._process(payload, addr))
+            self._process(payload, addr), name="coap-receiver")
         self._tasks.add(task)
         task.add_done_callback(self._tasks.discard)
 

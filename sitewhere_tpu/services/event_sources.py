@@ -211,6 +211,8 @@ class QueueEventReceiver(BackgroundTaskComponent):
     (reference analog: an InboundEventReceiver; doubles as the test/bench
     ingress and the simulator's sink)."""
 
+    operator = "queue-receiver"     # `name` is the deployment's
+
     def __init__(self, name: str, engine: "EventSourcesEngine",
                  decoder: EventDecoder, maxsize: int = 1024):
         super().__init__(name)
@@ -259,6 +261,8 @@ class TcpEventReceiver(BackgroundTaskComponent):
     """Length-prefixed frames over TCP (u32 length + SWB1 body) — the
     gateway ingestion protocol (reference analog: the socket receiver)."""
 
+    operator = "tcp-receiver"     # `name` is the deployment's
+
     MAX_FRAME = 16 * 1024 * 1024  # hostile length prefixes can't buffer GiBs
 
     def __init__(self, name: str, engine: "EventSourcesEngine",
@@ -278,6 +282,8 @@ class TcpEventReceiver(BackgroundTaskComponent):
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("tcp-receiver")
         self._conns.add(writer)
         try:
             while True:
@@ -327,6 +333,8 @@ class MqttEventReceiver(BackgroundTaskComponent):
     to its OWN command topic `<command_topic_prefix><client_id>`;
     filters reaching into the command space any other way (wildcards
     included) get SUBACK failure 0x80. Non-command topics stay open."""
+
+    operator = "mqtt-receiver"     # `name` is the deployment's
 
     def __init__(self, name: str, engine: "EventSourcesEngine",
                  decoder: EventDecoder, host: str = "127.0.0.1",
@@ -419,6 +427,8 @@ class WebSocketEventReceiver(BackgroundTaskComponent):
     unauthenticated peer must never occupy one — same trust model the
     MQTT endpoint enforces at CONNECT."""
 
+    operator = "websocket-receiver"     # `name` is the deployment's
+
     def __init__(self, name: str, engine: "EventSourcesEngine",
                  decoder: EventDecoder, host: str = "127.0.0.1",
                  port: int = 0, tokens: Optional[dict] = None):
@@ -466,6 +476,8 @@ class CoapEventReceiver(BackgroundTaskComponent):
     coap://host:port/<path> over UDP; CON requests are ACKed and
     deduplicated, malformed datagrams are counted and dropped
     (services/coap.py)."""
+
+    operator = "coap-receiver"     # `name` is the deployment's
 
     def __init__(self, name: str, engine: "EventSourcesEngine",
                  decoder: EventDecoder, host: str = "127.0.0.1",
@@ -576,6 +588,8 @@ class AmqpEventReceiver(_BrokerEventReceiver):
     the batch source. `users: {username: password}` enables PLAIN auth
     (unauthenticated connections are refused with 403)."""
 
+    operator = "amqp-receiver"     # `name` is the deployment's
+
     LISTENER = staticmethod(_amqp_listener)
 
 
@@ -586,6 +600,8 @@ class StompEventReceiver(_BrokerEventReceiver):
     destination header becomes the batch source; `receipt` headers are
     honored (at-least-once handshake). `users: {login: passcode}`
     enables auth."""
+
+    operator = "stomp-receiver"     # `name` is the deployment's
 
     LISTENER = staticmethod(_stomp_listener)
 

@@ -194,6 +194,8 @@ class MqttListener:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("mqtt-receiver")
         session: Optional[MqttSession] = None
         self._conns.add(writer)
         try:

@@ -22,6 +22,8 @@ from sitewhere_tpu.persistence.durable import save_snapshot
 
 
 class StoreSnapshotter(BackgroundTaskComponent):
+    operator = "snapshotter"    # the loop's account: `name` is the caller's
+
     def __init__(self, name: str, path: str,
                  epoch_fn: Callable[[], int],
                  collect_fn: Callable[[], dict],

@@ -165,6 +165,8 @@ class StompListener:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("stomp-receiver")
         self._writers.add(writer)
         user = ""
         try:

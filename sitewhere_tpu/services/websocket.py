@@ -207,6 +207,8 @@ class WebSocketListener:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        # asyncio made this task: its operator in the loop's account
+        asyncio.current_task().set_name("websocket-receiver")
         self._conns.add(writer)
         session: Optional[WsSession] = None
         try:
