@@ -194,6 +194,11 @@ COUNTERS = (
     # ring's table, summed over the step's linear layers: what was
     # lowered (ops/state_kernel.py), 0 where the plain path runs
     "scoring.state.in_place_rows",
+    # live rows whose stored context (a window leaf's row of keys and of
+    # values) a step read where it rested in the ring's table, summed
+    # over the step's layers: what was lowered (ops/context_kernel.py),
+    # 0 where the plain path gathers the rows
+    "scoring.ctx.at_rest_rows",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services

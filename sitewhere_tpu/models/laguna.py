@@ -165,7 +165,7 @@ class LagunaStreamModel(SeqBlocks):
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
                   "moe.runs_one_tile", "ctx.window_positions",
-                  "ctx.wrapped")
+                  "ctx.wrapped", "ctx.at_rest")
 
     def __init__(self, cfg: LagunaConfig = LagunaConfig()):
         n = cfg.num_hidden_layers
@@ -211,6 +211,8 @@ class LagunaStreamModel(SeqBlocks):
         self.wraps = frozenset(
             f"{kv}{l}" for l in range(n) for kv in "kv"
             if self.kinds[l] == "sliding_attention")
+        # ...and every one is read where it rests, in its layer's turn
+        self.at_rest = tuple(self.windows)
         # rows one seeding call takes (StreamingRing.load blocks by it)
         self.seed_rows = max(1, SEED_TOKENS // cfg.window)
         self._gate = max(8, cfg.window // 8)
@@ -311,11 +313,12 @@ class LagunaStreamModel(SeqBlocks):
             c.sliding_window if self._sliding(layer) else None)
 
     def _attend_decode(self, layer, q, k, v, kctx, vctx, pos):
-        """The decode form for one token a row; a sliding layer's
-        context wraps (models/seqblocks.py). -> `[B, n_l, d]`."""
-        return self._decode_rows(q, k, v, kctx, vctx, pos,
-                                 self.cfg.num_key_value_heads,
-                                 self._sliding(layer))
+        """The decode form for one token a row over the layer's two
+        window leaves where they rest; a sliding layer's context wraps
+        (models/seqblocks.py). -> `[B, n_l, d]`."""
+        return self._decode_at_rest(q, k, v, kctx, vctx, pos,
+                                    self.cfg.num_key_value_heads,
+                                    self._sliding(layer))
 
     def _attention(self, layer, p, x, at, attend):
         """The block's first half on the residual stream `x` `[...,
@@ -352,12 +355,12 @@ class LagunaStreamModel(SeqBlocks):
 
     def _block_decode(self, layer, p, x, kctx, vctx, pos, live):
         c = self.cfg
-        x, k, v = self._attention(
+        x, _, _ = self._attention(
             layer, p, x, jnp.minimum(pos, c.context_positions - 1),
             lambda q, k, v: self._attend_decode(layer, q, k, v, kctx, vctx,
                                                 pos))
         y, counts = self._ffn(p, rms(x, p["mlp_norm"], c.rms_norm_eps), live)
-        return x + y, k, v, counts
+        return x + y, counts
 
     def _prefill(self, params, tokens, count):
         """Every block over `[n, S]` tokens: (hidden states before the
@@ -383,19 +386,21 @@ class LagunaStreamModel(SeqBlocks):
     def step_score(self, params: dict, rows: dict, v: jax.Array,
                    live: jax.Array):
         """One event a row: the score of the bin that arrived, then the
-        row's next state. For a window leaf the new row is the ONE entry
-        to append at `rows["pos"]` (the ring wraps it where the leaf
-        does). Also the step's numbers, in `step_stats`' order (`live`
-        masks the padding out of them)."""
+        row's next state. The window leaves come as `ContextAtRest`s
+        (scoring/stream.py): a layer appends its ONE entry a row and
+        reads the table behind it in its turn, and nothing is returned
+        for them. Also the step's numbers, in `step_stats`' order
+        (`live` masks the padding out of them)."""
         c = self.cfg
         pos = rows["pos"]
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
-        held = busiest = one_tile = jnp.zeros((), jnp.int32)
+        held = busiest = one_tile = at_rest = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
-            x, out[f"k{l}"], out[f"v{l}"], counts = self._block_decode(
+            x, counts = self._block_decode(
                 l, params[f"layer{l}"], x, rows[f"k{l}"], rows[f"v{l}"], pos,
                 live)
+            at_rest += rows[f"k{l}"].read_rows
             if counts is not None:
                 held += counts.sum()
                 busiest = jnp.maximum(busiest, counts.max())
@@ -422,7 +427,7 @@ class LagunaStreamModel(SeqBlocks):
             busiest.astype(jnp.float32),
             mean_live(pos),
             one_tile.astype(jnp.float32),
-            attended, wrapped])
+            attended, wrapped, at_rest.astype(jnp.float32)])
         return score, out, stats
 
     def _seeded(self, name, leaf, entry, count):

@@ -144,7 +144,7 @@ class OlmoHybridStreamModel(SeqBlocks):
     # the numbers `step_score` returns beside the scores, by the names
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("ctx.positions", "state.decay", "state.absmax",
-                  "state.in_place")
+                  "state.in_place", "ctx.at_rest")
 
     def __init__(self, cfg: OlmoHybridConfig = OlmoHybridConfig()):
         n = cfg.num_hidden_layers
@@ -192,6 +192,8 @@ class OlmoHybridStreamModel(SeqBlocks):
         # layers' leaves are rows, rewritten whole
         self.windows = {f"{kv}{l}": "pos" for l in range(n)
                         if self.kinds[l] == FULL for kv in "kv"}
+        # ...each read where it rests, in its layer's turn
+        self.at_rest = tuple(self.windows)
         # rows one seeding call takes (StreamingRing.load blocks by it)
         self.seed_rows = max(1, SEED_TOKENS // cfg.window)
         self._gate = max(8, cfg.window // 8)
@@ -496,9 +498,10 @@ class OlmoHybridStreamModel(SeqBlocks):
         """One event a row: the score of the bin that arrived, then the
         row's next state. A linear layer's `s` and `c` come in turn
         (scoring/stream.py, `RowsInTurn`): read when the layer starts,
-        written whole before the next one starts, nothing returned for
-        them; for a full layer's window leaves the ONE entry to append
-        at `rows["pos"]`. Also the step's numbers, in `step_stats`'
+        written whole before the next one starts; a full layer's window
+        leaves as `ContextAtRest`s: the layer appends its ONE entry a
+        row and reads the table behind it (`_decode_at_rest`); nothing
+        is returned for either. Also the step's numbers, in `step_stats`'
         order (`live` masks the padding out of them): the mean position,
         the mean of `alpha` over live rows, heads and linear layers, and
         the largest `|S|` found in the live rows' states, which is what
@@ -508,7 +511,7 @@ class OlmoHybridStreamModel(SeqBlocks):
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
         decay, largest = jnp.float32(0), jnp.float32(0)
-        in_place = jnp.int32(0)
+        in_place = at_rest = jnp.int32(0)
         for l in range(self.layers):
             p = params[f"layer{l}"]
             first, second = self._leaves(l)
@@ -523,10 +526,11 @@ class OlmoHybridStreamModel(SeqBlocks):
                                       jnp.where(live, held, 0).max())
                 in_place += rested
             else:
-                x, out[first], out[second] = self._full(
+                x, _, _ = self._full(
                     p, x, lambda q, k, v, kctx=rows[first],
-                    vctx=rows[second]: self._decode_rows(
+                    vctx=rows[second]: self._decode_at_rest(
                         q, k, v, kctx, vctx, pos, c.num_key_value_heads))
+                at_rest += rows[first].read_rows
             x = self._mlp_half(p, x)
         out["hn"] = rms(x, params["norm"], c.rms_norm_eps).astype(
             c.compute_dtype)
@@ -535,7 +539,8 @@ class OlmoHybridStreamModel(SeqBlocks):
             jnp.where(live, pos, 0).sum() / n_live,
             decay / (n_live * max(self.kinds.count(LINEAR), 1)
                      * c.linear_num_value_heads),
-            largest, in_place.astype(jnp.float32)])
+            largest, in_place.astype(jnp.float32),
+            at_rest.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sitewhere_tpu.ops import expert_kernel
+from sitewhere_tpu.ops import context_kernel, expert_kernel
 
 EXPERT_TILE = 128        # rows of one held expert's products at a time
 SEED_TOKENS = 2048       # tokens of one seeding call: its activations
@@ -329,6 +329,49 @@ class SeqBlocks:
         out = self._ein("bhp,bpc->bhc", probs, vals)
         return (out.reshape(b, kv, heads // kv, kv, d) * own).sum(3).reshape(
             b, heads, d)
+
+    def _decode_at_rest(self, q, k, v, kctx, vctx, pos, kv: int,
+                        wraps: bool = False):
+        """`_decode_rows` over a context that stays in the ring's table:
+        `kctx`, `vctx` are the layer's two window leaves as the ring
+        hands them over (scoring/stream.py, `ContextAtRest`). On a TPU,
+        in bfloat16 and at shapes it takes, the position's own entry is
+        appended first and ONE kernel reads each row's keys and values
+        where they then rest (ops/context_kernel.py): the same lines, no
+        gathered copy. Elsewhere the rows are gathered, `_decode_rows`
+        reads them and the entries are appended: one algorithm, and the
+        plain path is the kernel's twin in the tests. `kctx.read_rows`
+        is left saying how many live rows were read at rest. -> `[B,
+        heads, d]`."""
+        dev, slot = kctx.dev, kctx.slot
+
+        def handles(ktab, vtab):
+            return type(kctx)(ktab, dev, slot), type(vctx)(vtab, dev, slot)
+
+        def plain(ktab, vtab, q, k, v):
+            keys, vals = handles(ktab, vtab)
+            out = self._decode_rows(q, k, v, keys.rows(), vals.rows(), pos,
+                                    kv, wraps)
+            return keys.append(k), vals.append(v), out, jnp.int32(0)
+
+        def rested(ktab, vtab, q, k, v):
+            keys, vals = handles(ktab, vtab)
+            ktab, vtab = keys.append(k), vals.append(v)
+            out = context_kernel.context_rows(ktab, vtab, dev, pos, q,
+                                              kv=kv, scale=self._scale)
+            return (ktab, vtab, out,
+                    (dev < ktab.shape[0] - 1).sum(dtype=jnp.int32))
+
+        args = (kctx.table, vctx.table, q, k, v)
+        if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
+                or not context_kernel.fits(kctx.table.shape,
+                                           kctx.table.dtype, q.shape[1], kv)):
+            took = plain(*args)
+        else:
+            took = jax.lax.platform_dependent(*args, default=plain,
+                                              tpu=rested)
+        kctx.table, vctx.table, out, kctx.read_rows = took
+        return out
 
     # -- tokens and the score -------------------------------------------------
 

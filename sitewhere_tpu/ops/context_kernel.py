@@ -1,0 +1,171 @@
+"""Pallas TPU kernel: a layer's attention over each device's stored
+context, read where it rests in the ring's table.
+
+Why this op: a device's context of `laguna-stream` is 1.5 MB of keys and
+as much of values a full layer (768 positions of 1,024 bfloat16), 1 MB a
+sliding one; of `olmo-hybrid-stream` 2.95 MB each (384 of 3,840). A
+frame names 256 rows, and the equations read a row once. As XLA's
+gather, the decode form's write of the position's own entry into the
+gathered copy and its second reading, a row crossed HBM three times:
+ten gathers were 9.85 ms of Laguna's 25.1 ms step on a v5e before the
+model had read a byte, two were 4.6 of Olmo's 19.8 (PERF.md section 6,
+PR 38). Here the two tables are the kernel's inputs as they are, the
+frame's row indices and positions are prefetched scalars that the block
+index is read from, and the pipeline brings row `dev[i + 1]`'s keys and
+values into VMEM while row `dev[i]` computes. Nothing of shape `[frame,
+positions, width]` exists. The kernel only reads: the position's own
+entry is in the table already, appended by the ring before the call
+(scoring/stream.py `ContextAtRest`), and no output aliases a table.
+
+    a row, `q` `[heads, d]`, keys and values `[P, kv * d]` as stored:
+        wide   = q, head h over the lanes of key-value head h // g
+        logits = wide K^T * scale           [heads, P]   f32
+        probs  = softmax(logits where p <= pos)
+        out    = bf16(probs) V              [heads, kv * d] f32
+        o_h    = out's lanes of key-value head h // g
+
+which are `SeqBlocks._decode_rows`' lines (models/seqblocks.py):
+bfloat16 operands, float32 accumulation, softmax in float32; only the
+order of a float32 sum may differ. A leaf that wraps and has wrapped has
+nothing to mask: `p <= pos` says both. The block-diagonal `wide` costs
+`kv` times the needed FLOPs and no more time: either operand order, each
+byte of the context enters the MXU once, which is what bounds the
+products (1.5 TB/s over four MXUs against HBM's 819 GB/s).
+
+Padding (`dev >= scratch`, the table's last row) is clipped onto the
+scratch row; its output is 0 and it writes nothing. Heads are padded to
+whole `(16, 128)` tiles of `probs`; the padded rows' lanes are all zero
+and are cut off again.
+
+VMEM: a row's keys and values twice each (`vmem_bytes`: 6.3 MB of
+blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's) and
+the row's small operands. `fits` says whether a call stays under
+`VMEM_LIMIT`; a leaf that does not fit, or is not bfloat16 in whole
+tiles, takes the model's plain path. No `cost_estimate`
+(ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
+in interpret mode and the compile for a described v5e by
+tests/test_dsv3_tpu_compile.py.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+VMEM_LIMIT = 20 << 20     # the most a call may take of VMEM
+HEAD_TILE = 16            # query rows come in whole bfloat16 tiles
+
+
+def _padded(heads: int) -> int:
+    return -(-heads // HEAD_TILE) * HEAD_TILE
+
+
+def vmem_bytes(shape: tuple, heads: int, kv: int) -> int:
+    """What a call over two tables of `shape` holds in VMEM: four blocks
+    of a row, the row's own operands (the wide query, the wide output,
+    logits and weights, `q` and `o` twice), and room for the
+    compiler's own."""
+    positions, width = shape[1:]
+    hp = _padded(heads)
+    small = hp * (6 * width + 12 * positions + 16 * width // kv)
+    return 4 * 2 * positions * width + small + (2 << 20)
+
+
+def fits(shape: tuple, dtype, heads: int, kv: int) -> bool:
+    """Whether `context_rows` takes two tables of `shape` and `dtype`
+    for `heads` query heads over `kv` key-value heads: a row `[positions,
+    kv * d]` of bfloat16 in whole `(16, 128)` tiles, a key-value head
+    whole lane tiles, four rows of which VMEM holds."""
+    return (len(shape) == 3 and jnp.dtype(dtype) == jnp.bfloat16
+            and shape[1] % 16 == 0 and shape[2] % kv == 0
+            and (shape[2] // kv) % 128 == 0 and heads % kv == 0
+            and vmem_bytes(shape, heads, kv) <= VMEM_LIMIT)
+
+
+def _kernel(dev_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, *, scratch: int,
+            kv: int, group: int, scale: float):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    hp, d = q_ref.shape[1:]
+    positions = k_ref.shape[1]
+    live = dev_ref[i] < scratch
+
+    @pl.when(live)
+    def _():
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, d), 0)
+        # a query row's own key-value head: rows `j * group ...`
+        mine = [(row >= j * group) & (row < (j + 1) * group)
+                for j in range(kv)]
+        q = q_ref[0]
+        wide = jnp.concatenate(
+            [jnp.where(m, q, 0.0) for m in mine], axis=1).astype(k_ref.dtype)
+        logits = jax.lax.dot_general(
+            wide, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        seen = jax.lax.broadcasted_iota(
+            jnp.int32, (hp, positions), 1) <= pos_ref[i]
+        logits = jnp.where(seen, logits, -jnp.inf)
+        e = jnp.exp(logits - jnp.max(logits, axis=1, keepdims=True))
+        probs = e / jnp.sum(e, axis=1, keepdims=True)
+        out = jnp.dot(probs.astype(v_ref.dtype), v_ref[0],
+                      preferred_element_type=jnp.float32)
+        o = jnp.zeros((hp, d), jnp.float32)
+        for j, m in enumerate(mine):
+            o = jnp.where(m, out[:, j * d:(j + 1) * d], o)
+        o_ref[0] = o
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("kv", "scale", "interpret"))
+def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
+                 pos: jax.Array, q: jax.Array, *, kv: int, scale: float,
+                 interpret: bool = False):
+    """Attention of one token a row over rows `dev` `[B]` (ascending
+    strictly, padding past the scratch row, which is the tables' last)
+    of `keys` and `values` `[rows, P, kv * d]` bfloat16, read where they
+    rest: the row's own entry is in them. `pos` `[B]`: positions `p <=
+    pos` are attended to; `q` `[B, heads, d]` float32, head `h` reading
+    key-value head `h // (heads / kv)`. -> `[B, heads, d]` float32, a
+    padding row's 0. Jitted, so that a step's layers of one shape trace
+    and lower the kernel once between them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    frame, heads, d = q.shape
+    if (keys.shape != values.shape or keys.dtype != values.dtype
+            or not fits(keys.shape, keys.dtype, heads, kv)
+            or keys.shape[2] != kv * d):
+        raise ValueError(f"context_rows takes no tables {keys.dtype}"
+                         f"{list(keys.shape)} for {heads} heads on {kv}")
+    rows, positions, width = keys.shape
+    scratch = rows - 1
+    hp = _padded(heads)
+    q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, hp - heads), (0, 0)))
+
+    def row(i, dev, pos):
+        return (jnp.minimum(dev[i], scratch), 0, 0)
+
+    def own(i, dev, pos):
+        return (i, 0, 0)
+
+    context = pl.BlockSpec((1, positions, width), row)
+    out = pl.pallas_call(
+        functools.partial(_kernel, scratch=scratch, kv=kv,
+                          group=heads // kv, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(frame,),
+            in_specs=[pl.BlockSpec((1, hp, d), own), context, context],
+            out_specs=pl.BlockSpec((1, hp, d), own)),
+        out_shape=jax.ShapeDtypeStruct((frame, hp, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(keys.shape, heads, kv)),
+        name="context_rows",
+        interpret=interpret,
+    )(dev, pos, q, keys, values)
+    return out[:, :heads]

@@ -234,7 +234,11 @@ class ScoringSession:
             # live rows whose matrix state a step's kernel updated where
             # it rested, over its linear layers; 0 on the plain path
             "state.in_place": lambda: metrics.counter(
-                "scoring.state.in_place_rows").inc}
+                "scoring.state.in_place_rows").inc,
+            # live rows whose stored context a step's kernel read where
+            # it rested, over its layers; 0 on the plain path
+            "ctx.at_rest": lambda: metrics.counter(
+                "scoring.ctx.at_rest_rows").inc}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")

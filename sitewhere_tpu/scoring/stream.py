@@ -90,6 +90,22 @@ frame; PERF.md section 6, PR 35). `ring.rewritten_bytes` counts what the dispatc
 since it was last read rewrote whole: live rows times the bytes of a
 row of the leaves that are no window.
 
+A WINDOW leaf that its model can read where it rests (the model names
+it in `at_rest`) reaches `step_score` as a `ContextAtRest`, the window
+leaves' sibling of `RowsInTurn`: it holds the table, the step's row
+indices and the slot each row's entry goes to (the position, or the
+position `mod shape[1]` where the leaf wraps). `append(entry)` writes
+the position's own entry into the donated table, as `ctx_append` does
+for the other window leaves when the step ends, and hands back the table
+as it then rests; `rows()` is the gather of the other path. The model
+appends in the layer's turn and reads the table behind the append (a
+data dependence: models/seqblocks.py hands it to ops/context_kernel.py's
+kernel on a TPU, which reads each row once where XLA's gather, the
+decode form's own write and its second reading moved a row of megabytes
+three times; PERF.md section 6, PR 38), returns nothing for the leaf,
+and the ring takes the handle's table as the leaf's next value. What
+reads the table promises to write nothing.
+
 The host `TelemetryStore` stays the durable copy; `load()` rebuilds
 state from it at warmup or after a fault (same recovery story as the
 window ring).
@@ -158,6 +174,12 @@ def _rows(leaf, dev):
     return got.reshape(dev.shape[0], positions, *width)
 
 
+def _slot(leaf, pos, wraps: bool):
+    """Where a window leaf takes the entry of position `pos`: a leaf
+    that wraps keeps the newest positions, in a circle."""
+    return pos % leaf.shape[1] if wraps else pos
+
+
 class RowsInTurn:
     """The rows of one fixed-size leaf whose row is a matrix, handed to
     `step_score` in the table's place ("Contract with the model"): the
@@ -191,6 +213,32 @@ class RowsInTurn:
         return outs
 
 
+class ContextAtRest:
+    """One window leaf that its model reads where it rests, handed to
+    `step_score` in the gathered rows' place ("Contract with the
+    model"): the append is the ring's, WHEN it runs and what reads the
+    table behind it are the model's. `read_rows` is what the model's
+    last reading took where they rested: live rows, 0 from the plain
+    path."""
+
+    def __init__(self, table, dev, slot):
+        self.table, self.dev, self.slot = table, dev, slot
+        self.read_rows = 0
+
+    def rows(self):
+        """Rows `dev` gathered out of the table as it rests."""
+        with jax.named_scope("ring_gather"):
+            return _rows(self.table, self.dev)
+
+    def append(self, entry):
+        """The position's own `entry` `[B, width]` at `(row, slot)`, in
+        place. -> the table as it then rests."""
+        with jax.named_scope("ctx_append"):
+            self.table = self.table.at[self.dev, self.slot].set(
+                entry, **DISTINCT_ROWS)
+        return self.table
+
+
 def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     """The ring step's three parts, under the `jax.named_scope`s a
     profile shows them by: rows of `dev` out of the table, one cell step
@@ -200,13 +248,19 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
     the cell step, in its turn. -> (state, scores, the step's numbers
     or None)."""
     windows = getattr(model, "windows", None)
+    wraps = getattr(model, "wraps", ())
+    at_rest = getattr(model, "at_rest", ())
     with jax.named_scope("ring_gather"):
         if windows is None:
             rows = jax.tree.map(lambda leaf: _rows(leaf, dev), state)
         else:
             rows = {name: RowsInTurn(leaf, dev) if leaf.ndim >= 3
                     and name not in windows else _rows(leaf, dev)
-                    for name, leaf in state.items()}
+                    for name, leaf in state.items() if name not in at_rest}
+            for name in at_rest:
+                rows[name] = ContextAtRest(
+                    state[name], dev, _slot(state[name], rows[windows[name]],
+                                            name in wraps))
     stats = None
     with jax.named_scope("cell_step"):
         if windows is None:
@@ -221,15 +275,13 @@ def _gather_step_scatter(model, params, state, dev, v, scratch=None):
                                                         **DISTINCT_ROWS),
                 state, new_rows)
         return state, scores, stats
-    out = {}
-    wraps = getattr(model, "wraps", ())
+    out = {name: rows[name].table for name in at_rest}
     with jax.named_scope("ctx_append"):
         for name, at in windows.items():
-            slot = rows[at]
-            if name in wraps:       # the newest positions, in a circle
-                slot = slot % state[name].shape[1]
-            out[name] = state[name].at[dev, slot].set(new_rows[name],
-                                                      **DISTINCT_ROWS)
+            if name not in at_rest:
+                out[name] = state[name].at[
+                    dev, _slot(state[name], rows[at], name in wraps)].set(
+                        new_rows[name], **DISTINCT_ROWS)
     with jax.named_scope("ring_scatter"):
         for name, leaf in state.items():
             if isinstance(rows[name], RowsInTurn):

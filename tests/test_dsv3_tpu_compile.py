@@ -6,8 +6,9 @@ row-major in whole lane tiles; the ring step of `laguna-stream` at its
 benchmark configuration's own size (five layers, 769 rows of 12 MiB),
 held to the same and to the chip's memory; the ring step of
 `olmo-hybrid-stream` at its configuration's own size (four layers, 769
-rows of 12.75 MB, three matrix states among them), held to the same;
-and the ring step of `lstm-stream` at `stream-512k`'s own size, which
+rows of 12.75 MB, three matrix states among them), held to the same
+(both read their contexts where they rest: one `context_rows` kernel a
+layer, no gathered rows); and the ring step of `lstm-stream` at `stream-512k`'s own size, which
 moves rows of ONE table. Nothing runs, so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
@@ -169,7 +170,9 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
                        or " tuple(" in line for line in uses
                        if line not in reads and line not in ahead), (leaf,
                                                                      uses)
-    kernels = [line for line in body if "tpu_custom_call" in line]
+    # (a step's other kernels, `context_rows`, are attention's)
+    kernels = [line for line in body if "tpu_custom_call" in line
+               and "context_rows" not in line]
     assert len(kernels) == len(layers)
     assert all("moe_experts" in line for line in kernels)
     whiles = [line for lines in comps.values() for line in lines
@@ -213,52 +216,85 @@ def laguna_step(one_chip):
     return model, state, compiled
 
 
+def _context_kernels(lines, frame, heads, table):
+    """The `context_rows` calls among `lines` that read two tables of
+    shape `table`: each hands back `[frame, heads, 128]` float32 and
+    nothing else, so no table is its output or aliased to one."""
+    calls = [line for line in lines if "tpu_custom_call" in line
+             and "context_rows" in line and line.count(table) == 2]
+    assert all(re.search(rf"= f32\[{frame},{heads},128\]\S* custom-call\(",
+                         line) and "output_to_operand_aliasing" not in line
+               for line in calls), calls
+    return calls
+
+
 def test_laguna_step_moves_no_context_leaf_and_fits_the_chip(laguna_step):
     """Ten window leaves, six of 512 positions and four of 768, a
-    position's keys or values 1,024 lanes: none is copied, transposed
-    or sliced, as a table or as the frame's gathered rows; each rests
-    row-major; the donated state comes back in its own buffers; and the
-    step's arguments and scratch fit a v5e's 16 GiB with room."""
+    position's keys or values 1,024 lanes: none is copied, transposed,
+    sliced or GATHERED, as a table or as the frame's rows. Each layer's
+    attention is ONE kernel that takes the layer's two tables as they
+    rest behind the append of the position's own entries
+    (ops/context_kernel.py): nothing of `[256, 768, 1024]` or `[256,
+    512, 1024]`, nor of their blocked views, exists in the step; each
+    table rests row-major; the donated state comes back in its own
+    buffers; and the step's arguments and scratch fit a v5e's 16 GiB
+    with room."""
     from chip_smoke import _table_moves
 
     model, state, compiled = laguna_step
     hlo = compiled.as_text()
+    lines = hlo.splitlines()
     assert _table_moves(hlo, LAGUNA_ROWS) == []
-    # nothing of a table's length but the leaves themselves: told to
-    # gather rows of over 512 KiB, the compiler sliced each whole table
-    # by lanes first (`bf16[769,768,384]`) and walked the rows in a loop;
-    # the ring gathers such rows in blocks of positions
-    views = {f"bf16[{LAGUNA_ROWS},2,256,1024]",
-             f"bf16[{LAGUNA_ROWS},3,256,1024]"}
+    # nothing of a table's length but the leaves themselves: no view of
+    # a table in blocks of positions, which the gathers went through
     shapes = set(re.findall(rf"\w+\[{LAGUNA_ROWS}(?:,\d+)*\]", hlo))
-    assert shapes == views | {
+    assert shapes == {
         f"bf16[{LAGUNA_ROWS},512,1024]", f"bf16[{LAGUNA_ROWS},768,1024]",
         f"bf16[{LAGUNA_ROWS},3072]", f"f32[{LAGUNA_ROWS}]",
         f"s32[{LAGUNA_ROWS}]"}, shapes
-    # ...a view of a table in blocks of 256 positions is a bitcast of it
-    made = [line for line in hlo.splitlines() if re.match(
-        rf"\s*(?:ROOT )?%\S+ = bf16\[{LAGUNA_ROWS},[23],256,1024\]", line)]
-    assert made and all(" bitcast(" in line or " parameter(" in line
-                        for line in made), made
+    # ...and nothing of a frame's gathered contexts
+    assert not re.search(rf"\[{LAGUNA_FRAME},(?:\d+,)?(?:768|512|256),1024\]",
+                         hlo)
     assert "mini-gather" not in hlo
-    whiles = [line for line in hlo.splitlines() if " while(" in line]
+    whiles = [line for line in lines if " while(" in line]
     assert all("moe_experts" in line for line in whiles)
+    # one kernel a layer: two over the full layers' tables with 48 heads,
+    # three over the sliding layers' with 72 (80 rows: whole tiles)
+    full = _context_kernels(lines, LAGUNA_FRAME, 48,
+                            f"bf16[{LAGUNA_ROWS},768,1024]")
+    sliding = _context_kernels(lines, LAGUNA_FRAME, 80,
+                               f"bf16[{LAGUNA_ROWS},512,1024]")
+    assert (len(full), len(sliding)) == (2, 3)
+    assert all("attn_full" in line for line in full)
+    assert all("attn_window" in line for line in sliding)
+    assert len([line for line in lines if "tpu_custom_call" in line
+                and "context_rows" in line]) == model.layers == 5
     for positions, leaves in ((512, 6), (768, 4)):
+        table = f"bf16[{LAGUNA_ROWS},{positions},1024]"
         assert sum(leaf.shape == (LAGUNA_ROWS, positions, 1024)
                    for leaf in state.values()) == leaves
-        layouts = set(re.findall(
-            rf"bf16\[{LAGUNA_ROWS},{positions},1024\]\{{([\d,]+)", hlo))
+        layouts = set(re.findall(re.escape(table) + r"\{([\d,]+)", hlo))
         assert layouts == {"2,1,0"}
-    moved = [line for line in hlo.splitlines() if re.search(
-        r"= bf16\[\d+,(?:512|768),1024\]\S* "
-        r"(?:copy|transpose|slice|dynamic-slice)\(", line)]
+        # a table is written by the append of a row's one entry and by
+        # nothing else
+        scatters = [line for line in lines if " scatter(" in line
+                    and f"= {table}" in line]
+        assert len(scatters) == leaves
+        assert all("unique_indices=true" in line for line in scatters)
+    moved = [line for line in lines if re.search(
+        r"= bf16\[\d+,(?:512|768|256),1024\]\S* "
+        r"(?:copy|transpose|slice|dynamic-slice|gather)\(", line)]
     assert moved == []
     mem = compiled.memory_analysis()
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes
     assert state_bytes > 9.6e9
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9
+    # the scratch was the gathered contexts': `temp_size_in_bytes` read
+    # 620,203,520 on PR 37's tree and reads 100,768,768 here (PERF.md
+    # section 6, PR 38)
+    assert mem.temp_size_in_bytes < 0.15e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.7e9
 
 
 def test_laguna_expert_leaves_are_read_once_by_one_kernel_a_layer(
@@ -338,10 +374,13 @@ def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
     each rests row-major. A matrix state is never gathered or scattered
     at all: ONE kernel a linear layer takes the table and hands it back
     aliased (ops/state_kernel.py), and nothing of a frame's rows of it,
-    `f32[256, 15, 96, 384]`, exists in the step. The taps' and contexts'
-    heavy rows are gathered in blocks through a view and scattered by
-    ONE scatter, no loop; the donated state comes back in its own
-    buffers."""
+    `f32[256, 15, 96, 384]`, exists in the step. Nor is a context: the
+    full layer's attention is ONE kernel that takes the two tables as
+    they rest behind the append of the position's own entries
+    (ops/context_kernel.py), and nothing of `[256, 384, 3840]`, nor of
+    its blocked view, exists. The taps' rows are gathered and scattered
+    by ONE gather and ONE scatter a leaf, no loop; the donated state
+    comes back in its own buffers."""
     from chip_smoke import _table_moves
 
     model, state, compiled = olmo_step
@@ -349,22 +388,24 @@ def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
     lines = hlo.splitlines()
     assert _table_moves(hlo, OLMO_ROWS) == []
     table = f"f32[{OLMO_ROWS},15,96,384]"
-    views = {f"bf16[{OLMO_ROWS},6,64,3840]"}
+    context = f"bf16[{OLMO_ROWS},384,3840]"
     shapes = set(re.findall(rf"\w+\[{OLMO_ROWS}(?:,\d+)*\]", hlo))
-    assert shapes == views | {
-        table, f"bf16[{OLMO_ROWS},270,128]",
-        f"bf16[{OLMO_ROWS},384,3840]", f"bf16[{OLMO_ROWS},3840]",
-        f"f32[{OLMO_ROWS}]", f"s32[{OLMO_ROWS}]"}, shapes
-    made = [line for line in lines if re.match(
-        rf"\s*(?:ROOT )?%\S+ = bf16\[{OLMO_ROWS},6,64,3840\]", line)]
-    assert made and all(" bitcast(" in line or " parameter(" in line
-                        for line in made), made
+    assert shapes == {
+        table, f"bf16[{OLMO_ROWS},270,128]", context,
+        f"bf16[{OLMO_ROWS},3840]", f"f32[{OLMO_ROWS}]",
+        f"s32[{OLMO_ROWS}]"}, shapes
+    assert not re.search(rf"\[{OLMO_FRAME},(?:\d+,)?(?:384|64),3840\]", hlo)
     assert "mini-gather" not in hlo
     assert not [line for line in lines if " while(" in line]
-    # the three kernels: each takes a state table and returns it in the
-    # same buffer; a table is a parameter, a kernel's first result or
-    # the step's result, and nothing else
-    kernels = [line for line in lines if "tpu_custom_call" in line]
+    # the full layer's one kernel reads both context tables and returns
+    # 30 heads' outputs (32 rows: whole tiles)
+    (attends,) = _context_kernels(lines, OLMO_FRAME, 32, context)
+    assert "attn_full" in attends
+    # the three kernels of the linear layers: each takes a state table
+    # and returns it in the same buffer; a table is a parameter, a
+    # kernel's first result or the step's result, and nothing else
+    kernels = [line for line in lines if "tpu_custom_call" in line
+               and line != attends]
     assert len(kernels) == 3
     assert all(re.search(rf"= \({re.escape(table)}\S*, f32\[{OLMO_FRAME},16,"
                          rf"384\]", line)
@@ -399,19 +440,16 @@ def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
         # (inside a scatter's fusion the updates pass a `transpose` that
         # permutes nothing)
         and "dimensions={0,1,2,3}" not in line
-        and not re.search(r"= bf16\[\d+,(?:384|64),3840\]\S* "
-                          r"(?:gather|scatter)\(", line)]
+        # (a context table is written by the append of a row's one entry)
+        and not re.search(rf"= {re.escape(context)}\S* scatter\(", line)]
     assert moved == []
     mem = compiled.memory_analysis()
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes > 9.8e9
     assert mem.argument_size_in_bytes > 13.0e9
-    # the scratch's peak is the full layer's two gathered contexts (1.51
-    # GB), as it was when a linear layer's rows were gathered too (a
-    # layer's 566 MB of rows and 566 of next state lay under it): the
-    # compiler's buffer assignment reads 1.59 GB for 1.61 (PERF.md
-    # section 6, PR 36). `temp_size_in_bytes` counts more than that
-    # allocation, what is not known: it reads 2.35 GB for 2.06
-    assert mem.temp_size_in_bytes < 2.4e9
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.4e9
+    # the scratch's peak was the full layer's two gathered contexts (1.51
+    # GB; PERF.md section 6, PR 36): `temp_size_in_bytes` read
+    # 2,352,224,256 on PR 37's tree and reads 79,385,088 here (PR 38)
+    assert mem.temp_size_in_bytes < 0.12e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.2e9

@@ -26,6 +26,7 @@ from sitewhere_tpu.models import build_model
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.server import ScoringConfig, ScoringSession
 from sitewhere_tpu.scoring.stream import (
+    ContextAtRest,
     StreamingRing,
     pad_rows,
     streaming_step,
@@ -294,9 +295,18 @@ def test_decode_form_and_gate_against_a_few_lines(layer, heads):
     x = jax.random.normal(jax.random.PRNGKey(1), (b, 64), jnp.float32)
     kctx, vctx = (jax.random.normal(jax.random.PRNGKey(s), (b, slots, 2 * d),
                                     jnp.float32) for s in (2, 3))
+    # the two window leaves as the ring hands them over: a table of the
+    # five rows, each row's slot
+    at = pos % slots if sliding else pos
+    keys, vals = (ContextAtRest(table, jnp.arange(b), at)
+                  for table in (kctx, vctx))
     got, k_new, v_new = model._attention(
         layer, p, x, pos, lambda q, k, v: model._attend_decode(
-            layer, q, k, v, kctx, vctx, pos))
+            layer, q, k, v, keys, vals, pos))
+    for handle, table, entry in ((keys, kctx, k_new), (vals, vctx, v_new)):
+        assert (np.asarray(handle.table) == np.asarray(
+            table.at[jnp.arange(b), at].set(entry))).all()
+    assert int(keys.read_rows) == 0         # the plain path gathers
     u = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
     q, k, v = model._project(layer, p, u, pos)
     assert (np.asarray(k_new) == np.asarray(k).reshape(b, -1)).all()
@@ -402,6 +412,8 @@ def test_the_steps_new_numbers_reach_the_registry_through_a_session(run):
         assert snap["scoring.moe.assignments"].value == 14 * per_step
         assert 0 < snap["scoring.moe.assignments_held"].value < 14 * per_step
         assert snap["scoring.moe.runs_one_tile"].value == 14 * 2 * 4
+        # the CPU's step is the plain path: no context read where it rests
+        assert snap["scoring.ctx.at_rest_rows"].value == 0
         s.close()
 
     run(main())
@@ -409,7 +421,8 @@ def test_the_steps_new_numbers_reach_the_registry_through_a_session(run):
 
 def test_a_model_without_wrapping_leaves_reports_no_window():
     """`layer_types` all full: no leaf wraps, the ring's bound is the
-    contexts', and the two new numbers stay 0."""
+    contexts', and the wrapping leaves' two numbers stay 0 (as does
+    the count of rows read at rest: the CPU's step gathers)."""
     over = dict(layer_types=["full_attention"] * 3)
     model = program(**over)
     assert not model.wraps and len(model.windows) == 6
@@ -422,7 +435,9 @@ def test_a_model_without_wrapping_leaves_reports_no_window():
     ring.load(hist, np.full(D, W))
     out = np.asarray(ring.update_and_score(
         model, params, np.arange(D, dtype=np.int32), frames[0], 8))
-    assert (out[-2:] == 0).all() and out[-4] == W
+    assert model.step_stats[-3:] == ("ctx.window_positions", "ctx.wrapped",
+                                     "ctx.at_rest")
+    assert (out[-3:] == 0).all() and out[-5] == W
 
 
 def test_configuration_the_model_cannot_compute_is_refused():
@@ -457,13 +472,18 @@ PARENTS_STEPS = {
         "a5310a450bcf90fc7d33ab984b47e7136ea208a53d764d09065701be3766b16e"),
     "lstm-stream": (
         "a2bd1f0a98b51e60dd3cc8f6c6d127a580d5712cec9d5c7608bf3d8c512680c8"),
-    # ...and this model's own at PR 34's tree, the parent of the PR that
-    # moved its two attention forms to models/seqblocks.py and handed the
-    # ring's matrix rows over in turn (`RowsInTurn`)
+    # ...and this model's own at PR 38's tree, recorded anew there (its
+    # CPU lowering held PR 34's text until then): PR 38 handed its window
+    # leaves over at rest (`ContextAtRest`), so a layer's rows are
+    # gathered and its entries appended in the layer's turn, not when the
+    # step starts and ends, and the step returns one more number,
+    # `ctx.at_rest`. The same lines on the same operands, in another
+    # place of the text; the three above are of models that declare no
+    # `at_rest` and did not move
     "laguna-stream_float32": (
-        "036399799416ae1ea409f504fde1634f7a6655ce7e10ba0fe11f47a2cdb42575"),
+        "6ae8bb8579a735d91f7f2c77e7de16d250221f9bd64e2dbade883f9db7322d55"),
     "laguna-stream_bfloat16": (
-        "17cfafc294528bf580633f28be64de4c4f4f72027d7f42415257dd83777882bf"),
+        "53e2ddba8b56fe2f98fdb0f249207f31bcee0154ae83590cfa46acd4a921a222"),
 }
 
 

@@ -385,11 +385,216 @@ def test_the_step_lowered_for_a_tpu_is_the_plain_step(case, heads, in_place,
     monkeypatch.setattr(jax.lax, "platform_dependent", on_a_tpu)
     got_state, got = jax.jit(streaming_step(model))(params, state, dev, v)
     stats = len(model.step_stats)
-    assert model.step_stats[-1] == "state.in_place"
-    assert float(want[-1]) == 0
-    assert float(got[-1]) == (3 * live if in_place else 0)
+    # (the full layer's context is float32 here, heads of 64: no leaf
+    # for ops/context_kernel.py, whose count stays 0 on both sides)
+    assert model.step_stats[-2:] == ("state.in_place", "ctx.at_rest")
+    assert float(want[-2]) == 0 and float(want[-1]) == float(got[-1]) == 0
+    assert float(got[-2]) == (3 * live if in_place else 0)
     np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
-    np.testing.assert_allclose(got[-stats:-1], want[-stats:-1], rtol=1e-6)
+    np.testing.assert_allclose(got[-stats:-2], want[-stats:-2], rtol=1e-6)
     for name, leaf in want_state.items():
         np.testing.assert_allclose(got_state[name], leaf, atol=1e-5,
                                    err_msg=name)
+
+
+# -- ops/context_kernel.py: a stored context read where it rests ---------------
+
+# (positions, key-value heads, query heads, wraps, each live row's position,
+# padding rows): a head is 128 wide, as both served models' are
+CONTEXT_CASES = {
+    # a full layer's leaf, grouped heads: the first position, the middle,
+    # the last the leaf holds
+    "bounded_grouped_heads": (32, 2, 6, False, [0, 13, 31], 1),
+    # a sliding layer's: before it has wrapped, the position that fills
+    # it, the first that overwrites, and far round the circle
+    "wrapping_before_and_after": (32, 2, 4, True, [5, 31, 32, 77], 2),
+    # `olmo-hybrid-stream`'s: a query head a key-value head, no group
+    "a_head_a_key_value_head": (48, 3, 3, False, [0, 20, 47], 0),
+    "a_frame_of_one_live_row": (32, 2, 4, False, [9], 3),
+}
+
+
+def _blocks():
+    """`SeqBlocks` with bfloat16 products and nothing else: what
+    `_decode_at_rest` and `_decode_rows` read of a model."""
+    from sitewhere_tpu.models.seqblocks import SeqBlocks
+
+    class Blocks(SeqBlocks):
+        class cfg:
+            compute_dtype = jnp.bfloat16
+        _scale = 128 ** -0.5
+
+    return Blocks()
+
+
+def _context_interpreted(monkeypatch):
+    """`context_rows` in interpret mode wherever a model calls it, and
+    the TPU's branch taken wherever the code asks the platform."""
+    import functools
+
+    from sitewhere_tpu.ops import context_kernel
+
+    monkeypatch.setattr(context_kernel, "context_rows", functools.partial(
+        context_kernel.context_rows, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, default, tpu: tpu(*args))
+
+
+@pytest.mark.parametrize("case", CONTEXT_CASES)
+def test_context_kernel_matches_the_decode_form_and_writes_nothing_interpret(
+        case, monkeypatch):
+    """ops/context_kernel.py through `SeqBlocks._decode_at_rest` against
+    the plain path (the ring's gather, `_decode_rows`, the append): the
+    heads' outputs to float32 round-off (bfloat16 operands and float32
+    sums on both sides: only the order of a sum differs), a padding
+    row's 0; both tables bit-equal to the plain path's, which differ
+    from what they were by the appended entries alone, the scratch row
+    untouched; and the kernel's branch counts the live rows it read
+    where the plain one counts 0."""
+    from sitewhere_tpu.ops import context_kernel
+    from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
+
+    positions, kv, heads, wraps, at, padding = CONTEXT_CASES[case]
+    d, rows, live = 128, 11, len(at)
+    scratch = rows - 1
+    keys = iter(jax.random.split(jax.random.PRNGKey(positions + heads), 5))
+    tables = [jax.random.normal(next(keys), (rows, positions, kv * d)
+                                ).astype(jnp.bfloat16) for _ in range(2)]
+    assert context_kernel.fits(tables[0].shape, tables[0].dtype, heads, kv)
+    frame = live + padding
+    q = jax.random.normal(next(keys), (frame, heads, d)) * 2.0
+    k, v = (jax.random.normal(next(keys), (frame, kv * d)).astype(
+        jnp.bfloat16) for _ in range(2))
+    dev = jnp.asarray(np.concatenate([
+        np.sort(np.random.default_rng(live).permutation(scratch)[:live]),
+        pad_rows(scratch, padding)]), jnp.int32)
+    # padding reads the scratch row's position, 0
+    pos = jnp.asarray(at + [0] * padding, jnp.int32)
+    slot = pos % positions if wraps else pos
+    blocks = _blocks()
+
+    def attend(ktab, vtab):
+        kctx, vctx = (ContextAtRest(t, dev, slot) for t in (ktab, vtab))
+        out = blocks._decode_at_rest(q, k, v, kctx, vctx, pos, kv, wraps)
+        return out, kctx.table, vctx.table, kctx.read_rows
+
+    # (a jit of its own each: the second trace takes the other branch)
+    want, *want_tables, plain_rows = jax.jit(
+        lambda ktab, vtab: attend(ktab, vtab))(*tables)
+    _context_interpreted(monkeypatch)
+    got, *got_tables, read_rows = jax.jit(
+        lambda ktab, vtab: attend(ktab, vtab))(*tables)
+    assert int(plain_rows) == 0 and int(read_rows) == live
+    scale = float(jnp.abs(want[:live]).max())
+    assert 0.3 < scale < 10
+    assert float(jnp.abs(got - want)[:live].max()) < 2e-6 * scale
+    assert not np.asarray(got)[live:].any()
+    for was, plain, rested, entry in zip(tables, want_tables, got_tables,
+                                         (k, v)):
+        assert (np.asarray(rested) == np.asarray(plain)).all()
+        appended = np.asarray(was.at[dev[:live], slot[:live]].set(
+            entry[:live]))
+        assert (np.asarray(rested) == appended).all()
+        assert (np.asarray(rested)[scratch] == np.asarray(was)[scratch]).all()
+
+
+def test_context_kernel_takes_bfloat16_rows_of_whole_tiles_that_vmem_holds():
+    """`fits` reads the leaf's shape and dtype and the heads: the three
+    served leaves; not a float32 leaf, positions that are no whole
+    sublane tile, a key-value head that is no whole lane tile, query
+    heads that are no whole groups, nor a row four of which pass the
+    VMEM a call may ask for; and `context_rows` refuses what `fits`
+    does not take."""
+    from sitewhere_tpu.ops import context_kernel
+
+    fits = context_kernel.fits
+    for shape, heads, kv in (((769, 768, 1024), 48, 8),
+                             ((769, 512, 1024), 72, 8),
+                             ((769, 384, 3840), 30, 30)):
+        assert fits(shape, jnp.bfloat16, heads, kv)
+        assert context_kernel.vmem_bytes(shape, heads, kv) < 16 << 20
+        assert not fits(shape, jnp.float32, heads, kv)
+    assert fits((7, 32, 256), jnp.bfloat16, 4, 2)
+    assert not fits((7, 40, 256), jnp.bfloat16, 4, 2)
+    assert not fits((7, 32, 128), jnp.bfloat16, 4, 2)     # heads of 64
+    assert not fits((7, 32, 192), jnp.bfloat16, 4, 2)
+    assert not fits((7, 32, 256), jnp.bfloat16, 3, 2)
+    assert not fits((769, 4096, 1024), jnp.bfloat16, 48, 8)
+    assert not fits((769, 768, 8, 128), jnp.bfloat16, 48, 8)
+    with pytest.raises(ValueError, match="takes no tables"):
+        context_kernel.context_rows(
+            jnp.zeros((3, 32, 256)), jnp.zeros((3, 32, 256)),
+            jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
+            jnp.zeros((2, 4, 128)), kv=2, scale=1.0, interpret=True)
+
+
+def _laguna_of_128_wide_heads():
+    from sitewhere_tpu.models import build_model
+
+    return build_model(
+        "laguna-stream", hidden_size=128, intermediate_size=128,
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=128, num_experts=8, num_experts_per_tok=2, vocab_size=64,
+        num_experts_held=4, sliding_window=16, window=20,
+        context_positions=48, mlp_only_layers=[0],
+        layer_types=["full_attention", "sliding_attention",
+                     "full_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"],
+        gating_types=["per_head"] * 3,
+        num_attention_heads_per_layer=[4, 6, 4]), 3
+
+
+def _olmo_of_128_wide_heads():
+    model = _linear_model(4, 16, 64, compute_dtype=jnp.bfloat16,
+                          hidden_size=256, num_hidden_layers=2,
+                          layer_types=[LINEAR, FULL], context_positions=32)
+    return model, 1
+
+
+@pytest.mark.parametrize("build", [_laguna_of_128_wide_heads,
+                                   _olmo_of_128_wide_heads],
+                         ids=["laguna-stream", "olmo-hybrid-stream"])
+def test_the_step_that_reads_contexts_at_rest_is_the_plain_step(
+        build, monkeypatch):
+    """The whole ring step of both models that read a context at rest,
+    with the TPU's branch taken (the kernels in interpret mode) against
+    the step as the CPU lowers it, in bfloat16 at heads of 128: scores,
+    every state leaf and the step's other numbers agree (both sides make
+    the same bfloat16 products and sum them in float32, in another
+    order), `ctx.at_rest` counts the live rows of every layer that
+    attends over a stored context where the branch ran and 0 on the
+    plain path, and the context tables are bit-equal but for what a
+    differing sum's rounding put into a later layer's appended entry."""
+    from sitewhere_tpu.scoring.stream import pad_rows, streaming_step
+
+    model, layers = build()
+    params = model.init(jax.random.PRNGKey(0))
+    cap, frame, live = 9, 8, 5
+    hist = np.random.default_rng(0).normal(size=(cap, model.cfg.window)
+                                           ).astype(np.float32)
+    seeded = jax.jit(model.warm_state)(params, jnp.asarray(hist),
+                                       jnp.ones(hist.shape, bool))
+    state = jax.tree.map(lambda leaf, rows: leaf.at[:cap].set(rows),
+                         model.init_state(cap + 1), seeded)
+    dev = np.concatenate([[0, 1, 4, 6, 8], pad_rows(cap, frame - live)]
+                         ).astype(np.int32)
+    v = np.linspace(-1, 1, frame).astype(np.float32)
+    at = model.step_stats.index("ctx.at_rest")
+    stats = len(model.step_stats)
+    want_state, want = jax.jit(streaming_step(model))(params, state, dev, v)
+    _interpreted(monkeypatch)
+    _context_interpreted(monkeypatch)
+    got_state, got = jax.jit(streaming_step(model))(params, state, dev, v)
+    assert float(want[frame + at]) == 0
+    assert float(got[frame + at]) == layers * live
+    assert float(jnp.abs(want[:live]).max()) > 1.0
+    np.testing.assert_allclose(got[:live], want[:live], atol=2e-2)
+    others = [i for i in range(stats) if i != at
+              and model.step_stats[i] != "state.in_place"]
+    np.testing.assert_allclose(np.asarray(got[frame:])[others],
+                               np.asarray(want[frame:])[others], rtol=1e-2)
+    for name, leaf in want_state.items():
+        np.testing.assert_allclose(
+            np.asarray(got_state[name], np.float32),
+            np.asarray(leaf, np.float32), atol=2e-2, err_msg=name)
