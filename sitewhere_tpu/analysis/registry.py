@@ -184,6 +184,10 @@ COUNTERS = (
     "scoring.moe.assignments_held",
     "scoring.moe.assignments",
     "scoring.moe.runs_one_tile",
+    # bytes of held experts' leaves a dispatch's step streams: expert
+    # layers x held experts x an expert's three leaves, off the model's
+    # `param_shapes` (scoring/server.py), whatever a frame routes
+    "scoring.moe.weight_bytes",
     "scoring.ctx.reseeds",
     "scoring.ctx.wrapped",
     # bytes of the fixed-size state leaves that the dedicated ring's

@@ -11,6 +11,7 @@ from typing import Any
 
 from sitewhere_tpu.models.dsv3 import Dsv3Config, Dsv3StreamModel
 from sitewhere_tpu.models.laguna import LagunaConfig, LagunaStreamModel
+from sitewhere_tpu.models.lfm2 import Lfm2Config, Lfm2StreamModel
 from sitewhere_tpu.models.longwin import LongWindowConfig, LongWindowModel
 from sitewhere_tpu.models.lstm import (
     LstmAnomalyModel,
@@ -39,6 +40,10 @@ MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     # Olmo-Hybrid-7B's block (gated delta-rule layers with a matrix
     # state a head, a full-attention layer a period) as a streaming scorer
     "olmo-hybrid-stream": (OlmoHybridConfig, OlmoHybridStreamModel),
+    # LFM2-24B-A2B's block (gated short convolutions three to one with
+    # grouped-query attention, two dense layers, then sigmoid-routed
+    # experts with none shared) as a streaming scorer
+    "lfm2-stream": (Lfm2Config, Lfm2StreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

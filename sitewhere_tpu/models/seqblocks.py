@@ -1,6 +1,7 @@
 """What the streaming sequence scorers have in common (`dsv3-stream`,
 models/dsv3.py; `laguna-stream`, models/laguna.py; `olmo-hybrid-stream`,
-models/olmo_hybrid.py): RMSNorm, rope and its YaRN tables, the quantiser
+models/olmo_hybrid.py; `lfm2-stream`, models/lfm2.py): RMSNorm, rope in
+its two pairings and its YaRN tables, the quantiser
 that makes a measurement a token, the surprisal score with its
 short-history gate, attention over stored keys and values in a prefill
 and a decode form, and the expert layer that is told which experts it
@@ -57,6 +58,8 @@ class Experts:
     scoring: str             # "sigmoid" | "softmax" of the router's logits
     groups: int = 1          # group-limited choice: the experts in
     groups_kept: int = 1     # `groups`, of which the best are kept
+    normed: bool = True      # the kept weights are divided by their sum
+    sum_eps: float = 0.0     # ...plus this, where the published rule adds it
 
 
 def rms(x, w, eps):
@@ -96,6 +99,16 @@ def rope(x, cos, sin):
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      -1).reshape(x.shape)
+
+
+def rope_halves(x, cos, sin):
+    """Rotate pairs `(i, i + d / 2)` of the last axis, `d` its width:
+    the half-split pairing; `cos`, `sin` broadcast against `x[..., :d /
+    2]`. The same turn as `rope`'s under a permutation of the columns
+    of the projections that make `x`."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
 def runs_one_tile(counts, tile=EXPERT_TILE):
@@ -161,7 +174,8 @@ class SeqBlocks:
         `s` plus the router's bias where it has one; with groups, a
         group's score is the sum of its two best and only the kept
         groups' experts can be chosen; weights the chosen `s` over their
-        sum times `scale`."""
+        sum (plus `sum_eps`; as they are where not `normed`) times
+        `scale`."""
         ex = self.experts
         logits = jnp.dot(x.astype(jnp.float32), p["w"].T,
                          precision=jax.lax.Precision.HIGHEST)
@@ -178,7 +192,12 @@ class SeqBlocks:
                                choice, -jnp.inf)
         idx = jax.lax.top_k(choice, ex.per_token)[1]
         w = jnp.take_along_axis(s, idx, axis=1)
-        w = w / w.sum(-1, keepdims=True) * ex.scale
+        if not ex.normed:
+            return idx.astype(jnp.int32), w * ex.scale
+        total = w.sum(-1, keepdims=True)
+        if ex.sum_eps:
+            total = total + ex.sum_eps
+        w = w / total * ex.scale
         return idx.astype(jnp.int32), w
 
     def routed(self, p, x, idx, w, live, tile=EXPERT_TILE):
@@ -190,10 +209,13 @@ class SeqBlocks:
         held expert's first `tile` pairs are gathered once, laid at
         `e * tile`, each expert's three products run over its tile with
         no loop round them (an expert's leaves are read once, one after
-        another), and the weighted rows are summed into the tokens. A
-        run longer than `tile` takes its further tiles in a loop that
-        is entered only where some run is that long: nothing is
-        dropped, no expert has a capacity."""
+        another), and the weighted rows are summed into the tokens.
+        Runs longer than `tile` take their further tiles in further
+        passes of the same kind, tile `n` of every held expert at once
+        (an expert whose run has ended adds nothing), in ONE loop that
+        runs while some run goes on: nothing is dropped, no expert has
+        a capacity. (One loop an expert under a branch, 64 a layer, was
+        four fifths of a step's compile: PERF.md section 6, PR 39.)"""
         c = self.cfg
         t, k = idx.shape
         held = self.experts.held
@@ -209,38 +231,32 @@ class SeqBlocks:
         weight = jnp.concatenate([weight, jnp.zeros(tile, jnp.float32)])
         xc = x.astype(c.compute_dtype)
         lane = jnp.arange(tile)
+        experts = [p[f"e{e}"] for e in range(held)]
 
-        def tile_of(lo, end):
-            """Token rows and weights of the `tile` pairs from `lo` on
-            (weight 0 from `end` on), for one run or `[held]` runs."""
-            at = lo[..., None] + lane
-            return token[at], jnp.where(at < end[..., None], weight[at], 0.0)
+        def tiles(n, left):
+            """`_first_tiles` over tile `n` of every run, of which
+            `left` `[held]` pairs are real: the pairs' token rows and
+            their weights, 0 from a run's end on (an ended run's tile
+            lies in the padding)."""
+            at = jnp.minimum(starts + n * tile, t * k)[:, None] + lane
+            wt = jnp.where(at < (starts + counts)[:, None], weight[at], 0.0)
+            return self._first_tiles(experts, xc, token[at].reshape(-1),
+                                     wt.reshape(-1), left)
 
-        rows, wt = tile_of(starts, starts + counts)
-        out = self._first_tiles([p[f"e{e}"] for e in range(held)], xc,
-                                rows.reshape(-1), wt.reshape(-1), counts)
+        def further(at):
+            n, out = at
+            return n + 1, out + tiles(n, jnp.clip(counts - n * tile, 0, tile))
 
-        def further_tiles(out):
-            for e in range(held):
-                start, end = starts[e], starts[e] + counts[e]
-                expert = p[f"e{e}"]
-
-                def further(i, out, start=start, end=end, expert=expert):
-                    rows, wt = tile_of(start + i * tile, end)
-                    return out.at[rows].add(
-                        self._mlp(expert, xc[rows]) * wt[:, None])
-
-                out = jax.lax.fori_loop(1, (counts[e] + tile - 1) // tile,
-                                        further, out)
-            return out
-
-        return jax.lax.cond((counts > tile).any(), further_tiles,
-                            lambda out: out, out), counts
+        _, out = jax.lax.while_loop(
+            lambda at: (counts > at[0] * tile).any(), further,
+            (jnp.int32(1), tiles(0, counts)))
+        return out, counts
 
     def _first_tiles(self, experts, xc, rows, wt, counts):
-        """`sum w * expert(x)` over every held expert's first tile of
-        pairs: `rows`, `wt` `[held * tile]` are the pairs' tokens and
-        weights (0 past a run's end), tile `e` expert `e`'s. ->
+        """`sum w * expert(x)` over one tile of pairs a held expert (its
+        first, or a further one): `rows`, `wt` `[held * tile]` are the
+        pairs' tokens and weights (0 past a run's end), tile `e` expert
+        `e`'s, `counts` `[held]` how many of a tile's pairs are real. ->
         `[T, hidden]` float32. On a TPU, in bfloat16 and at shapes it
         takes, one kernel that streams the leaves where they rest and
         sums in place (ops/expert_kernel.py); elsewhere the same three
@@ -268,7 +284,8 @@ class SeqBlocks:
 
     def _ffn(self, p, x, live):
         """The block's second half on normed tokens `[T, hidden]`; the
-        held experts' token counts `[held]` where the layer has experts."""
+        held experts' token counts `[held]` where the layer has experts
+        (a shared expert is added where the layer has one)."""
         if "mlp" in p:
             with jax.named_scope("dense_mlp"):
                 return self._mlp(p["mlp"], x), None
@@ -276,6 +293,8 @@ class SeqBlocks:
             idx, w = self.route(p["router"], x)
         with jax.named_scope("moe_experts"):
             routed, counts = self._routed(p["experts"], x, idx, w, live)
+            if "shared" not in p:
+                return routed, counts
             return self._mlp(p["shared"], x) + routed, counts
 
     # -- attention over a row's stored keys and values ---------------------------
@@ -417,7 +436,7 @@ class SeqBlocks:
                                rows["pos"])
         token = self._bin((v - mean) / jnp.sqrt(var + 1e-6))
         with jax.named_scope("lm_head"):
-            logits = self._mm(rows["hn"], params["head"])
+            logits = self._mm(rows["hn"], self._head(params))
             surprisal = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
                 logits, token[:, None], axis=1)[:, 0]
         score = jnp.clip(jnp.where(cnt >= self._gate, surprisal, 0.0),
@@ -481,10 +500,17 @@ class SeqBlocks:
         return jnp.clip(jnp.where(count >= self._gate, surprisal, 0.0),
                         0.0, self.cfg.score_clip)
 
+    def _head(self, params):
+        """The head's matrix `[hidden, vocab]`: its own leaf, or the
+        embedding's where the two are tied (a product that contracts
+        over the embedding's minor dimension: nothing is transposed at
+        rest)."""
+        return params["head"] if "head" in params else params["embed"].T
+
     def _logits(self, params, h):
         with jax.named_scope("lm_head"):
             return self._mm(rms(h, params["norm"], self.cfg.rms_norm_eps),
-                            params["head"])
+                            self._head(params))
 
     def _in_blocks(self, fn, *rows):
         """`fn` over row blocks of `seed_rows`, one after another, so a

@@ -2,8 +2,9 @@
 table. Each kernel ships with a pure-jax reference path and an
 auto-selection helper; CPU/test runs always take the reference path
 (Pallas interpret mode is exercised by dedicated parity tests).
-`expert_kernel` (a layer's held experts of `dsv3-stream` and of
-`laguna-stream`) is imported by its one caller, models/seqblocks.py,
+`expert_kernel` (a layer's held experts of `dsv3-stream`, of
+`laguna-stream` and of `lfm2-stream`) is imported by its one caller,
+models/seqblocks.py,
 which holds its plain twin. `state_kernel` (a linear layer's matrix
 states of `olmo-hybrid-stream`, updated in the rows of the ring's table
 they rest in) is imported by models/olmo_hybrid.py, whose `_gdn_cell`
@@ -16,7 +17,9 @@ back as they were, and that every write has landed when it returns.
 the rows of the ring's table they rest in) is imported by
 models/seqblocks.py, whose `_decode_rows` is its plain twin: the ring
 hands the two tables over as `ContextAtRest`s (scoring/stream.py), the
-position's own entries are appended first, and the kernel only reads."""
+position's own entries are appended first, and the kernel only reads
+(`lfm2-stream`'s key-value heads of 64 are half a lane tile: `fits`
+refuses them and its attention takes the plain twin)."""
 
 from sitewhere_tpu.ops.lstm_kernel import (  # noqa: F401
     lstm_window_final,

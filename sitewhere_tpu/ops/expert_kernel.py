@@ -26,7 +26,12 @@ is fetched during expert `e`. The layer's output `[tokens, hidden]`
 lives in VMEM for the whole call, so the sum over a token's experts is
 a row added in place, and is written out once at the end. (Measured on
 a v5e, PERF.md PR 29: 118 us an expert, 746 GB/s; the same sum as 16
-XLA scatter-adds of 128 rows cost 33 us each.)
+XLA scatter-adds of 128 rows cost 33 us each. At 64 held of 2048 x
+1536, `lfm2-stream`'s, a call reaches 400 to 425 GB/s, 44 to 48 us an
+expert, where it reaches 671 at 32 of 3072 x 1024, the same bytes an
+expert: `fetch` is a chain of one `pl.when` a held expert at every
+step, and an expert adds its rows into the output one by one; PERF.md
+PR 39, ROADMAP S17.)
 
 Numbers as the plain path's (`SeqBlocks._mlp`): operands bf16,
 accumulation f32, `silu(g) * u` in f32 and rounded to bf16 once before

@@ -102,6 +102,18 @@ class ScoringConfig:
         return self.backlog_cap or 4 * self.buckets[-1]
 
 
+def _held_expert_bytes(model) -> int:
+    """Bytes of the held experts' leaves over a model's expert layers
+    (`param_shapes()`: a layer's `experts`, a leaf a projection an
+    expert), which a step streams once whatever a frame routes; 0 for a
+    model without them."""
+    shapes = getattr(model, "param_shapes", dict)()
+    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for block in shapes.values() if isinstance(block, dict)
+               for expert in block.get("experts", {}).values()
+               for shape, dtype in expert.values())
+
+
 class ScoringSession:
     """One tenant's scorer: model + device-resident params & history ring
     + bucketed compiled functions + admission queue."""
@@ -244,6 +256,10 @@ class ScoringSession:
         self.reseeds = metrics.counter("scoring.ctx.reseeds")
         # bytes of fixed-size state leaves the dispatches rewrote whole
         self.rewritten = metrics.counter("scoring.state.rewritten_bytes")
+        # bytes of held experts' leaves a dispatch's step streams: every
+        # expert layer's, read off the checkpoint's layout (no device work)
+        self.expert_weights = metrics.counter("scoring.moe.weight_bytes")
+        self._expert_bytes = _held_expert_bytes(model)
 
     def _fleet_rows(self) -> int:
         """Rows the ring is asked for: the fleet-size hint, or as far as
@@ -607,6 +623,8 @@ class ScoringSession:
                 arr.copy_to_host_async()
             self.batch_size_hist.observe(float(rdev.shape[0]))
             self.dispatches.inc()
+            if self._expert_bytes:
+                self.expert_weights.inc(self._expert_bytes)
             dispatches.append((scores_dev, rdev.shape[0], rpos))
         # rows whose windows were full and were seeded again on the way
         reseeded = getattr(self.ring, "reseeded", 0)

@@ -8,7 +8,10 @@ held to the same and to the chip's memory; the ring step of
 `olmo-hybrid-stream` at its configuration's own size (four layers, 769
 rows of 12.75 MB, three matrix states among them), held to the same
 (both read their contexts where they rest: one `context_rows` kernel a
-layer, no gathered rows); and the ring step of `lstm-stream` at `stream-512k`'s own size, which
+layer, no gathered rows); the ring step of `lfm2-stream` at its
+configuration's own size (eight layers, 2,561 rows, six expert layers of
+64 held experts: 1,152 expert leaves, none copied; its contexts on the
+plain path); and the ring step of `lstm-stream` at `stream-512k`'s own size, which
 moves rows of ONE table. Nothing runs, so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
@@ -140,21 +143,26 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
     """The held experts as one grouped pass a layer: each of an expert
     layer's `3 * held` leaves goes to a kernel (or a product) of the
     entry computation, outside any `while`; the `while`s that remain are
-    the overflow's, one a held expert, under `moe_experts` and behind ONE
-    conditional a layer, and only they read a leaf a second time; no leaf
-    is copied or sliced."""
+    the overflow's, ONE a layer (further passes of the same kind, tile
+    `n` of every held expert at once), under `moe_experts`, and only they
+    read a leaf a second time; no leaf is copied or sliced."""
     comps, entry = _computations(hlo)
     inside = _inside_whiles(comps)
     assert entry not in inside
     body = comps[entry]
+    # the entry's lines by the names they hold, whole (1,152 leaves at 64
+    # held in six layers: one pass over the text, not one a leaf)
+    named = {}
+    for line in body:
+        if " parameter(" not in line:
+            for name in set(re.findall(r"%([\w.]+)", line)):
+                named.setdefault(name, []).append(line)
     for layer in layers:
         leaves = re.findall(rf"(params__layer{layer}____experts____e\d+____"
                             r"(?:gate|up|down)__[.\d]*): bf16", hlo)
         assert len(set(leaves)) == 3 * held
         for leaf in set(leaves):
-            uses = [line for line in body if re.search(
-                rf"(?<![\w.])%{re.escape(leaf)}(?![\w.])", line)
-                and " parameter(" not in line]
+            uses = named.get(leaf, [])
             # the kernel (or a product) takes the leaf as it rests, or the
             # compiler fetches it ahead into fast memory, whole, for the
             # kernel and the overflow's loop after it; every other use
@@ -177,10 +185,8 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
     assert all("moe_experts" in line for line in kernels)
     whiles = [line for lines in comps.values() for line in lines
               if " while(" in line]
-    assert len(whiles) <= held * len(layers)
+    assert len(whiles) == len(layers)
     assert all("moe_experts" in line for line in whiles)
-    assert len([line for line in body
-                if " conditional(" in line]) == len(layers)
     moved = [line for line in hlo.splitlines() if re.search(
         rf"= bf16\[(?:{hidden},{inter}|{inter},{hidden})\]\S* "
         r"(?:copy|slice|dynamic-slice)\(", line)]
@@ -453,3 +459,99 @@ def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
     # 2,352,224,256 on PR 37's tree and reads 79,385,088 here (PR 38)
     assert mem.temp_size_in_bytes < 0.12e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.2e9
+
+
+# -- `lfm2-stream` at `lfm2-24b-a2b-pp5`'s own size -----------------------------
+
+LFM2_ROWS, LFM2_FRAME = 2561, 512
+
+
+@pytest.fixture(scope="module")
+def lfm2_step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip) at the benchmark configuration's `model_config` as it stands."""
+    import json
+    import os
+
+    from sitewhere_tpu.models import build_model
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "lfm2-24b-a2b-pp5.json")) as fh:
+        model = build_model("lfm2-stream", **json.load(fh)["model_config"])
+    state, compiled = _compile_step(model, LFM2_ROWS, LFM2_FRAME, one_chip,
+                                    jnp.float32)
+    return model, state, compiled
+
+
+def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
+    """Six expert layers of 64 held experts of 2048 x 1536: ONE
+    `expert_tiles` call a layer takes the layer's 192 leaves as they
+    rest, outside any loop (the six `while`s are the overflow's, one a
+    layer where there were 64: four fifths of this step's compile), and
+    no leaf is copied or sliced. Six conv
+    tables of 8 KB a row are gathered once and scattered once each, not
+    copied, sliced or transposed; the tied embedding is gathered from
+    and contracted over as it rests. The two attention layers' four
+    context tables take the PLAIN path: a key-value head of 64 is half a
+    lane tile, which `ops/context_kernel.py` `fits` refuses, so each
+    table's rows are gathered (268 MB) and the decode form reads the
+    copy. The perf_opt issue that ROADMAP's Queue S names ("attention
+    over a stored context whose key-value head is half a lane tile")
+    lifts that pin and turns the four gathers below into two kernels."""
+    from chip_smoke import _table_moves
+    from sitewhere_tpu.ops import context_kernel, expert_kernel
+
+    model, state, compiled = lfm2_step
+    hlo = compiled.as_text()
+    lines = hlo.splitlines()
+    assert expert_kernel.fits(LFM2_FRAME, 2048, 1536, 128)
+    assert not context_kernel.fits((LFM2_ROWS, 512, 512), jnp.bfloat16, 32, 8)
+    assert _table_moves(hlo, LFM2_ROWS) == []
+    conv, context = f"bf16[{LFM2_ROWS},32,128]", f"bf16[{LFM2_ROWS},512,512]"
+    shapes = set(re.findall(rf"\w+\[{LFM2_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == {conv, context, f"bf16[{LFM2_ROWS},2048]",
+                      f"f32[{LFM2_ROWS}]", f"s32[{LFM2_ROWS}]"}, shapes
+    _expert_leaves_read_once(hlo, model.experts.held, [2, 3, 4, 5, 6, 7],
+                             2048, 1536)
+    assert model.experts.held == 64
+    # a layer's call and the one inside its overflow's loop: no other kernel
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    assert len(kernels) == 2 * 6 and all("expert_tiles" in line
+                                         for line in kernels)
+    assert len([line for line in lines if " while(" in line]) == 6
+    for shape, leaves in ((conv, 6), (context, 4)):
+        dims = tuple(int(d) for d in shape[shape.index("[") + 1:-1].split(","))
+        assert sum(x.shape == dims for x in state.values()) == leaves
+        layouts = set(re.findall(re.escape(shape) + r"\{([\d,]+)", hlo))
+        assert layouts == {"2,1,0"}, shape
+        scatters = [line for line in lines if " scatter(" in line
+                    and f"= {shape}" in line]
+        assert len(scatters) == leaves, shape
+        assert all("unique_indices=true" in line
+                   and "indices_are_sorted=true" not in line
+                   for line in scatters)
+    # the plain path: a layer's two tables gathered whole, in one slice a
+    # row (512 KiB, the most `scoring/stream.py` `_rows` takes unblocked)
+    gathers = [line for line in lines if re.search(
+        rf"= bf16\[{LFM2_FRAME},512,512\]\S* gather\(", line)]
+    assert len(gathers) == 4 and all("slice_sizes={1,512,512}" in line
+                                     for line in gathers)
+    assert len([line for line in lines if re.search(
+        rf"= bf16\[{LFM2_FRAME},32,128\]\S* gather\(", line)]) == 6
+    moved = [line for line in lines if re.search(
+        r"= (?:bf16\[\d+,32,128\]|bf16\[65536,2048\]|bf16\[2048,65536\])"
+        r"\S* (?:copy|transpose|slice|dynamic-slice)\(", line)
+        # (round a gather or inside a scatter's fusion the rows pass a
+        # `transpose` that permutes nothing)
+        and "dimensions={0,1,2}" not in line]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes > 5.5e9
+    # 8.05 GB of weights and 5.51 of state; the scratch is the two
+    # attention layers' gathered contexts (1,088,491,520 under PR 39)
+    assert 13.5e9 < mem.argument_size_in_bytes < 13.6e9
+    assert mem.temp_size_in_bytes < 1.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.8e9

@@ -461,29 +461,29 @@ def _lowered_step(model, rows, bucket, out_dtype):
         jax.ShapeDtypeStruct((bucket,), jnp.float32)).as_text()
 
 
-# sha256 of the lowered ring step (StableHLO text) at PR 31's tree, the
-# parent of the PR that moved `dsv3-stream`'s blocks to models/seqblocks.py,
-# bounded the ring's window leaves one by one and gathered heavy rows in
-# blocks: the same function, there, on the same arguments
+# sha256 of the lowered ring step (StableHLO text): `lstm-stream`'s at PR
+# 31's tree, the same function, there, on the same arguments: it has
+# held since. The four of the models with held experts were recorded
+# anew at PR 39's tree, which made `SeqBlocks.routed`'s overflow ONE loop
+# of grouped passes (tile `n` of every held expert at once) where it was
+# one loop a held expert under a branch: 64 a layer in the model that PR
+# brought, four fifths of its step's compile. The first tiles' lines are
+# as they were; the loop behind them is what moved, and no frame of any
+# cell enters it (`expert_one_tile_runs_per_step` reads every run). Until
+# then `dsv3-stream`'s two held PR 31's text and `laguna-stream`'s PR
+# 38's (its window leaves handed over at rest, `ContextAtRest`).
+# `olmo-hybrid-stream`'s two are tests/test_lfm2.py's and did not move
 PARENTS_STEPS = {
     "dsv3-stream_float32": (
-        "f56cf595fa807f887a47506464731371528c2a1ce288263d0a04cce6ccb85128"),
+        "022c936805dbb620e8df2e58e317cfb2d1a76127535dc126521e129acbe8d5bf"),
     "dsv3-stream_bfloat16": (
-        "a5310a450bcf90fc7d33ab984b47e7136ea208a53d764d09065701be3766b16e"),
+        "0af314c18e2ee482ac06fd479edc8ce700824d54f17212c0d867da19910f99c9"),
     "lstm-stream": (
         "a2bd1f0a98b51e60dd3cc8f6c6d127a580d5712cec9d5c7608bf3d8c512680c8"),
-    # ...and this model's own at PR 38's tree, recorded anew there (its
-    # CPU lowering held PR 34's text until then): PR 38 handed its window
-    # leaves over at rest (`ContextAtRest`), so a layer's rows are
-    # gathered and its entries appended in the layer's turn, not when the
-    # step starts and ends, and the step returns one more number,
-    # `ctx.at_rest`. The same lines on the same operands, in another
-    # place of the text; the three above are of models that declare no
-    # `at_rest` and did not move
     "laguna-stream_float32": (
-        "6ae8bb8579a735d91f7f2c77e7de16d250221f9bd64e2dbade883f9db7322d55"),
+        "a3e0acb35c313639e52e10adb33b038d3729fa309a895d8917ef62378aad220a"),
     "laguna-stream_bfloat16": (
-        "53e2ddba8b56fe2f98fdb0f249207f31bcee0154ae83590cfa46acd4a921a222"),
+        "157b00bda287cf92bf8c2d83aa012012038564f70ac18f099afb2314394cfc61"),
 }
 
 
