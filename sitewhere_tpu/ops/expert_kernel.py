@@ -6,7 +6,7 @@ so its three products are bound by reading the expert's weights (88 MB
 in bf16 at DeepSeek-V3's published widths, 0.1 ms at a v5e's 819 GB/s;
 19 MB at Laguna-S-2.1's), not by arithmetic. Every size is read off
 the shapes it is handed: the held experts (16 of 7168 x 2048, 32 of
-3072 x 1024), the tile, the tokens. One call takes the
+3072 x 1024, 64 of 2048 x 1536), the tile, the tokens. One call takes the
 whole layer: every expert's leaves stay where they rest in HBM, one
 leaf an expert a projection, and are streamed through VMEM once, in
 blocks of the intermediate width, one expert after another with no gap
@@ -26,12 +26,17 @@ is fetched during expert `e`. The layer's output `[tokens, hidden]`
 lives in VMEM for the whole call, so the sum over a token's experts is
 a row added in place, and is written out once at the end. (Measured on
 a v5e, PERF.md PR 29: 118 us an expert, 746 GB/s; the same sum as 16
-XLA scatter-adds of 128 rows cost 33 us each. At 64 held of 2048 x
-1536, `lfm2-stream`'s, a call reaches 400 to 425 GB/s, 44 to 48 us an
-expert, where it reaches 671 at 32 of 3072 x 1024, the same bytes an
-expert: `fetch` is a chain of one `pl.when` a held expert at every
-step, and an expert adds its rows into the output one by one; PERF.md
-PR 39, ROADMAP S17.)
+XLA scatter-adds of 128 rows cost 33 us each.)
+
+Which leaves step `s + 1` copies from is known only at run time, and
+each copy names its leaf statically: `fetch` finds the expert with
+`pick`, a tree of two-way branches over `[0, held)` halved at each
+level, so a step tests the expert's index `ceil(log2 held)` times (6 at
+64 held, none at 1). A chain of one `pl.when` a held expert tested it
+64 times a step at 64 held of 2048 x 1536, `lfm2-stream`'s, where a
+step's copies take 1.9 us: the kernel alone took 3.08 ms a call there,
+392 GB/s, and takes 1.68 with the tree, 717 GB/s; at 32 of 3072 x
+1024, Laguna's, 0.895 and 0.881 ms (PERF.md PR 40, ROADMAP S17).
 
 Numbers as the plain path's (`SeqBlocks._mlp`): operands bf16,
 accumulation f32, `silu(g) * u` in f32 and rounded to bf16 once before
@@ -80,6 +85,19 @@ def fits(tokens: int, hidden: int, inter: int, tile: int) -> bool:
             and vmem_bytes(tokens, hidden, tile) <= VMEM_LIMIT)
 
 
+def pick(e, lo: int, hi: int, start) -> None:
+    """`start(k)` for the one static `k` of `[lo, hi)` that the traced `e`
+    equals: halve the range at each of `ceil(log2(hi - lo))` two-way
+    branches, so that a step tests `e` that many times, not once a held
+    expert."""
+    if hi - lo == 1:
+        start(lo)
+        return
+    mid = (lo + hi) // 2
+    jax.lax.cond(e < mid, lambda: pick(e, lo, mid, start),
+                 lambda: pick(e, mid, hi, start))
+
+
 def _kernel(rows_ref, wts_ref, counts_ref, xs_ref, *rest, held: int,
             steps: int, tile: int):
     from jax.experimental import pallas as pl
@@ -103,11 +121,13 @@ def _kernel(rows_ref, wts_ref, counts_ref, xs_ref, *rest, held: int,
     def fetch(s):
         """Start step `s`'s weights; which leaves is found at run time,
         the copies themselves are static."""
-        for e in range(held):
-            @pl.when(s // steps == e)
-            def _(e=e):
-                for copy in weights(e, s % steps, s % 2):
-                    copy.start()
+        j, slot = s % steps, s % 2
+
+        def start(e: int):
+            for copy in weights(e, j, slot):
+                copy.start()
+
+        pick(s // steps, 0, held, start)
 
     def tokens(e, half):
         return pltpu.make_async_copy(

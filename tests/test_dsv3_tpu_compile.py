@@ -12,7 +12,10 @@ layer, no gathered rows); the ring step of `lfm2-stream` at its
 configuration's own size (eight layers, 2,561 rows, six expert layers of
 64 held experts: 1,152 expert leaves, none copied; its contexts on the
 plain path); and the ring step of `lstm-stream` at `stream-512k`'s own size, which
-moves rows of ONE table. Nothing runs, so nothing here is a time.
+moves rows of ONE table. In the three steps with held experts each
+`expert_tiles` kernel's Mosaic module is read back: its step picks the
+next weight block in a tree of branches, not one a held expert. Nothing
+runs, so nothing here is a time.
 
 The topology is described inside a fixture, never at import, and every
 test that needs it is in this one file (one process loads the TPU's
@@ -193,10 +196,77 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
     assert moved == []
 
 
+def _mosaic_modules(calls: list) -> list:
+    """The Mosaic module of each distinct kernel among the HLO lines
+    `calls`, parsed back from its custom call's serialized body."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+
+    ctx = mlir.JaxIrContext()
+    ctx.append_dialect_registry(mlir.upstream_dialects)
+    ctx.load_all_available_dialects()
+    tpu.register_dialect(ctx)
+    bodies = set()
+    for line in calls:
+        at = line.index("backend_config=") + len("backend_config=")
+        config, _ = json.JSONDecoder().raw_decode(line[at:])
+        bodies.add(config["custom_call_config"]["body"])
+    modules = []
+    with ctx:
+        ctx.allow_unregistered_dialects = True
+        for body in sorted(bodies):
+            module = ir.Module.parse(base64.b64decode(body))
+            PassManager.parse(
+                "builtin.module(mosaic-serde{serialize=false})").run(
+                    module.operation)
+            modules.append(module)
+    return modules
+
+
+def _branches(block) -> tuple[int, int]:
+    """How deep `scf.if`s nest in `block`, and how many of them one pass
+    through it tests at most (the larger branch of each counted)."""
+    deep = tested = 0
+    for op in block.operations:
+        if op.operation.name == "scf.if":
+            inner = [_branches(b) for r in op.regions for b in r.blocks]
+            deep = max(deep, 1 + max(d for d, _ in inner))
+            tested += 1 + max(t for _, t in inner)
+    return deep, tested
+
+
+def _expert_kernels(lines: list, held: int, calls: int) -> None:
+    """`calls` kernels named `expert_tiles` (a layer's and the one in its
+    overflow's loop), and in each the step that picks the next weight
+    block out of `held` experts' leaves does so in a tree: its
+    conditionals nest at most `ceil(log2 held) + 2` deep, and a step
+    tests at most `ceil(log2 held) + 6` of them (the tree's and the
+    step's own six: the fetch's guard, the token tile's two, the sum's
+    three). The chain before it tested one a held expert at every step,
+    70 at 64 held (PERF.md section 6, PR 40)."""
+    kernels = [line for line in lines if "tpu_custom_call" in line
+               and "expert_tiles" in line]
+    assert len(kernels) == calls
+    levels = (held - 1).bit_length()
+    for module in _mosaic_modules(kernels):
+        func = module.body.operations[0]
+        loops = [op for op in func.regions[0].blocks[0].operations
+                 if op.operation.name == "scf.for"]
+        assert len(loops) == 1
+        deep, tested = _branches(loops[0].regions[0].blocks[0])
+        assert deep <= levels + 2 and tested <= levels + 6, (deep, tested)
+
+
 def test_every_expert_leaf_is_read_once_outside_any_loop(step):
     model, _, compiled = step
-    _expert_leaves_read_once(compiled.as_text(), model.cfg.experts_held, [1],
-                             7168, 2048)
+    hlo = compiled.as_text()
+    _expert_leaves_read_once(hlo, model.cfg.experts_held, [1], 7168, 2048)
+    _expert_kernels(hlo.splitlines(), model.cfg.experts_held, 2)
 
 
 # -- `laguna-stream` at `laguna-s-2.1-ep8`'s own size -------------------------
@@ -309,8 +379,10 @@ def test_laguna_expert_leaves_are_read_once_by_one_kernel_a_layer(
     leaves a layer, four layers): the same grouped pass, from the shapes
     it is handed."""
     model, _, compiled = laguna_step
-    _expert_leaves_read_once(compiled.as_text(), model.experts.held,
-                             [1, 2, 3, 4], 3072, 1024)
+    hlo = compiled.as_text()
+    _expert_leaves_read_once(hlo, model.experts.held, [1, 2, 3, 4], 3072,
+                             1024)
+    _expert_kernels(hlo.splitlines(), model.experts.held, 8)
 
 
 def test_lstm_stream_step_moves_rows_of_one_table(one_chip):
@@ -519,6 +591,7 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     kernels = [line for line in lines if "tpu_custom_call" in line]
     assert len(kernels) == 2 * 6 and all("expert_tiles" in line
                                          for line in kernels)
+    _expert_kernels(lines, model.experts.held, 2 * 6)
     assert len([line for line in lines if " while(" in line]) == 6
     for shape, leaves in ((conv, 6), (context, 4)):
         dims = tuple(int(d) for d in shape[shape.index("[") + 1:-1].split(","))

@@ -8,6 +8,8 @@ Mosaic's own compile and on-chip parity are checked by chip_smoke.py
 phase B.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -171,28 +173,46 @@ def test_ring_probe_keeps_compiled_fn(monkeypatch):
     np.testing.assert_allclose(scores[:8], ref_scores[:8], atol=1e-5)
 
 
-@pytest.mark.parametrize("hidden, inter", [(7168, 2048), (3072, 1024)],
-                         ids=["dsv3_widths", "laguna_widths"])
-@pytest.mark.parametrize("held, tile", [(1, 64), (2, 32), (3, 32)])
-def test_expert_kernel_matches_the_plain_products_interpret(held, tile,
-                                                            hidden, inter):
+def _chain(e, lo, hi, start):
+    """The parent's choice of the next weight block (PR 39's kernel): one
+    `pl.when` a held expert at every step."""
+    from jax.experimental import pallas as pl
+
+    for k in range(lo, hi):
+        pl.when(e == k)(lambda k=k: start(k))
+
+
+@pytest.mark.parametrize("hidden, inter",
+                         [(7168, 2048), (3072, 1024), (2048, 1536)],
+                         ids=["dsv3_widths", "laguna_widths", "lfm2_widths"])
+@pytest.mark.parametrize("held, tile",
+                         [(1, 64), (2, 32), (3, 32), (5, 16), (16, 16)])
+def test_expert_kernel_matches_the_plain_products_interpret(
+        monkeypatch, held, tile, hidden, inter):
     """ops/expert_kernel.py at each served model's published widths
     (hidden 7168, intermediate 2048 in its sixteen blocks of 128; hidden
-    3072, intermediate 1024 in eight) over one, two and
-    three experts of few rows, against the three products
-    `Dsv3StreamModel._mlp` makes of each and one scatter-add: bf16
-    operands, f32 sums, `silu * up` rounded to bf16 once, the weight
-    applied in f32, a token's experts summed in f32. The down product is
-    summed block by block, so the two differ by the order of a float32
-    sum. A run's rows past its count add nothing, whatever their weight.
-    (Memory a kernel never wrote reads NaN in interpret mode.)"""
+    3072, intermediate 1024 in eight; hidden 2048, intermediate 1536 in
+    twelve) over one to sixteen experts of few rows, against the three
+    products `Dsv3StreamModel._mlp` makes of each and one scatter-add:
+    bf16 operands, f32 sums, `silu * up` rounded to bf16 once, the
+    weight applied in f32, a token's experts summed in f32. The down
+    product is summed block by block, so the two differ by the order of
+    a float32 sum. A run's rows past its count add nothing, whatever
+    their weight: every case sees a full tile, an empty run and a
+    partial one. Five experts make a branch tree that is not a power of
+    two, sixteen one of four levels; whatever the tree, the output is
+    BITWISE the parent's, whose chain tested every expert at every step
+    (the same blocks in the same order). (Memory a kernel never wrote
+    reads NaN in interpret mode.)"""
     from sitewhere_tpu.models import build_model
+    from sitewhere_tpu.ops import expert_kernel
     from sitewhere_tpu.ops.expert_kernel import expert_tiles, fits
 
     tokens = 72
-    # a frame of either cell fits, a seeding call's tokens do not
+    # a frame of any cell fits, a seeding call's tokens do not
     assert fits(1024, 7168, 2048, 128) and not fits(2048, 7168, 2048, 128)
     assert fits(256, 3072, 1024, 128) and not fits(4224, 3072, 1024, 128)
+    assert fits(512, 2048, 1536, 128)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 3 * held + 3))
     experts = [{name: (jax.random.normal(next(keys), shape, jnp.float32)
                        * 0.02).astype(jnp.bfloat16)
@@ -203,24 +223,33 @@ def test_expert_kernel_matches_the_plain_products_interpret(held, tile,
     rng = np.random.default_rng(held)
     rows = np.concatenate([np.sort(rng.permutation(tokens)[:tile])
                            for _ in range(held)]).astype(np.int32)
-    counts = np.asarray([tile, 0, 5][:held], np.int32)
     x = jax.random.normal(next(keys), (tokens, hidden)).astype(jnp.bfloat16)
     wts = jax.random.uniform(next(keys), (held * tile,))
-    got = jax.jit(lambda ex, xs, rows, wts, counts: expert_tiles(
-        ex, xs, rows, wts, counts, tokens, interpret=True))(
-            experts, x[rows], rows, wts, counts)
     model = build_model("dsv3-stream", num_hidden_layers=1, mtp_modules=0)
-    real = (np.arange(tile)[None, :] < counts[:, None]).reshape(-1)
-    ys = jnp.concatenate([
-        model._mlp(expert, x[rows[e * tile:(e + 1) * tile]])
-        for e, expert in enumerate(experts)]) * (wts * real)[:, None]
-    want = jnp.zeros((tokens, hidden), jnp.float32).at[rows].add(ys)
-    assert got.shape == want.shape and got.dtype == jnp.float32
-    scale = float(jnp.abs(want).max())
-    assert 0.1 < scale < 10
-    assert float(jnp.abs(got - want).max()) < 1e-5 * scale
-    untouched = np.setdiff1d(np.arange(tokens), rows[real])
-    assert (np.asarray(got)[untouched] == 0).all()
+    # a full tile, an empty run, a partial one: in turn where fewer than
+    # three experts are held
+    runs = [np.resize(np.roll([tile, 0, 5], shift), held).astype(np.int32)
+            for shift in range(3 if held < 3 else 1)]
+    trees = [expert_tiles(experts, x[rows], rows, wts, counts, tokens,
+                          interpret=True) for counts in runs]
+    monkeypatch.setattr(expert_kernel, "pick", _chain)
+    chain = jax.jit(functools.partial(expert_tiles.__wrapped__,
+                                      tokens=tokens, interpret=True))
+    parents = [chain(experts, x[rows], rows, wts, counts) for counts in runs]
+    for counts, got, parent in zip(runs, trees, parents):
+        assert (np.asarray(parent).view(np.uint32)
+                == np.asarray(got).view(np.uint32)).all()
+        real = (np.arange(tile)[None, :] < counts[:, None]).reshape(-1)
+        ys = jnp.concatenate([
+            model._mlp(expert, x[rows[e * tile:(e + 1) * tile]])
+            for e, expert in enumerate(experts)]) * (wts * real)[:, None]
+        want = jnp.zeros((tokens, hidden), jnp.float32).at[rows].add(ys)
+        assert got.shape == want.shape and got.dtype == jnp.float32
+        scale = float(jnp.abs(want).max())
+        assert 0.1 < scale < 10 or not counts.any()
+        assert float(jnp.abs(got - want).max()) <= 1e-5 * scale
+        untouched = np.setdiff1d(np.arange(tokens), rows[real])
+        assert (np.asarray(got)[untouched] == 0).all()
 
 
 # -- ops/state_kernel.py: a matrix state updated where it rests ---------------
