@@ -203,6 +203,12 @@ COUNTERS = (
     # over the step's layers: what was lowered (ops/context_kernel.py),
     # 0 where the plain path gathers the rows
     "scoring.ctx.at_rest_rows",
+    # a looped model's step (models/ouro.py `step_stats`): the bytes of
+    # layer weights its passes stream (passes x layers x a layer's, from
+    # shapes) and of keys and values its equations read ((pos + 1) x
+    # contexts x an entry of keys and values, over live rows)
+    "scoring.loop.weight_bytes",
+    "scoring.ctx.attended_bytes",
     "scoring.megabatch_dispatches",
     "scoring.stack_rebuilds",
     # pipeline services

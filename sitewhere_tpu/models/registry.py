@@ -22,6 +22,7 @@ from sitewhere_tpu.models.olmo_hybrid import (
     OlmoHybridConfig,
     OlmoHybridStreamModel,
 )
+from sitewhere_tpu.models.ouro import OuroConfig, OuroStreamModel
 from sitewhere_tpu.models.seasonal import (
     SeasonalTrendConfig,
     SeasonalTrendForecaster,
@@ -44,6 +45,10 @@ MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     # grouped-query attention, two dense layers, then sigmoid-routed
     # experts with none shared) as a streaming scorer
     "lfm2-stream": (Lfm2Config, Lfm2StreamModel),
+    # Ouro-2.6B's looped stack (the same layers run several passes an
+    # event, each pass over its own key-value contexts) as a streaming
+    # scorer
+    "ouro-stream": (OuroConfig, OuroStreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

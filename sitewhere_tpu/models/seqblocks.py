@@ -1,6 +1,7 @@
 """What the streaming sequence scorers have in common (`dsv3-stream`,
 models/dsv3.py; `laguna-stream`, models/laguna.py; `olmo-hybrid-stream`,
-models/olmo_hybrid.py; `lfm2-stream`, models/lfm2.py): RMSNorm, rope in
+models/olmo_hybrid.py; `lfm2-stream`, models/lfm2.py; `ouro-stream`,
+models/ouro.py): RMSNorm, rope in
 its two pairings and its YaRN tables, the quantiser
 that makes a measurement a token, the surprisal score with its
 short-history gate, attention over stored keys and values in a prefill

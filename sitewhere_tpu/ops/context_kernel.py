@@ -15,7 +15,8 @@ index is read from, and the pipeline brings row `dev[i + 1]`'s keys and
 values into VMEM while row `dev[i]` computes. Nothing of shape `[frame,
 positions, width]` exists. The kernel only reads: the position's own
 entry is in the table already, appended by the ring before the call
-(scoring/stream.py `ContextAtRest`), and no output aliases a table.
+(scoring/stream.py `ContextAtRest`), or handed beside it (below), and no
+output aliases a table.
 
     a row, `q` `[heads, d]`, keys and values `[P, kv * d]` as stored:
         wide   = q, head h over the lanes of key-value head h // g
@@ -37,11 +38,22 @@ scratch row; its output is 0 and it writes nothing. Heads are padded to
 whole `(16, 128)` tiles of `probs`; the padded rows' lanes are all zero
 and are cut off again.
 
+A table whose row holds several contexts side by side, each a block of
+`kv * d` lanes (`ouro-stream`'s one key table and one value table, a
+block for each pass and layer: models/ouro.py), is read at the block
+`block` names, one more prefetched scalar of the index map; a table of
+one block a row is read as it was, by the same lines. Such a model
+writes a position's 48 entries at once when its step ends (one append
+an entry, each XLA's loop of row updates, was 6.6 of an 18.3 ms step on
+a v5e; PERF.md section 6, PR 41), so it hands the kernel the own entry
+`own` beside the table: the table's slot at `pos` is masked, and the
+entry's logit and value take its place in the same softmax.
+
 VMEM: a row's keys and values twice each (`vmem_bytes`: 6.3 MB of
-blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's) and
-the row's small operands. `fits` says whether a call stays under
-`VMEM_LIMIT`; a leaf that does not fit, or is not bfloat16 in whole
-tiles, takes the model's plain path. No `cost_estimate`
+blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's, 9.7
+one of Ouro's 48 blocks) and the row's small operands. `fits` says
+whether a call stays under `VMEM_LIMIT`; a leaf that does not fit, or
+is not bfloat16 in whole tiles, takes the model's plain path. No `cost_estimate`
 (ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
 in interpret mode and the compile for a described v5e by
 tests/test_dsv3_tpu_compile.py.
@@ -62,32 +74,45 @@ def _padded(heads: int) -> int:
     return -(-heads // HEAD_TILE) * HEAD_TILE
 
 
-def vmem_bytes(shape: tuple, heads: int, kv: int) -> int:
-    """What a call over two tables of `shape` holds in VMEM: four blocks
-    of a row, the row's own operands (the wide query, the wide output,
-    logits and weights, `q` and `o` twice), and room for the
-    compiler's own."""
-    positions, width = shape[1:]
+def vmem_bytes(shape: tuple, heads: int, kv: int,
+               width: int | None = None) -> int:
+    """What a call over two tables of `shape` holds in VMEM, reading
+    contexts of `width` lanes (a row's whole width where none is given):
+    four blocks of a row, the row's own operands (the wide query, the
+    wide output, logits and weights, `q` and `o` twice), and room for
+    the compiler's own."""
+    positions, width = shape[1], width or shape[2]
     hp = _padded(heads)
     small = hp * (6 * width + 12 * positions + 16 * width // kv)
     return 4 * 2 * positions * width + small + (2 << 20)
 
 
-def fits(shape: tuple, dtype, heads: int, kv: int) -> bool:
+def fits(shape: tuple, dtype, heads: int, kv: int,
+         width: int | None = None) -> bool:
     """Whether `context_rows` takes two tables of `shape` and `dtype`
-    for `heads` query heads over `kv` key-value heads: a row `[positions,
-    kv * d]` of bfloat16 in whole `(16, 128)` tiles, a key-value head
-    whole lane tiles, four rows of which VMEM holds."""
-    return (len(shape) == 3 and jnp.dtype(dtype) == jnp.bfloat16
-            and shape[1] % 16 == 0 and shape[2] % kv == 0
-            and (shape[2] // kv) % 128 == 0 and heads % kv == 0
-            and vmem_bytes(shape, heads, kv) <= VMEM_LIMIT)
+    for `heads` query heads over `kv` key-value heads, a context being
+    `width` lanes of a row (all of it where none is given): a context
+    `[positions, kv * d]` of bfloat16 in whole `(16, 128)` tiles, a
+    key-value head whole lane tiles, a row whole contexts, four contexts
+    of which VMEM holds."""
+    if len(shape) != 3:
+        return False
+    width = width or shape[2]
+    return (jnp.dtype(dtype) == jnp.bfloat16
+            and shape[1] % 16 == 0 and shape[2] % width == 0
+            and width % kv == 0 and (width // kv) % 128 == 0
+            and heads % kv == 0
+            and vmem_bytes(shape, heads, kv, width) <= VMEM_LIMIT)
 
 
-def _kernel(dev_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, *, scratch: int,
-            kv: int, group: int, scale: float):
+def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
+            scale: float, own: bool):
     from jax.experimental import pallas as pl
 
+    if own:
+        q_ref, k_ref, v_ref, ko_ref, vo_ref, o_ref = refs[-6:]
+    else:
+        q_ref, k_ref, v_ref, o_ref = refs[-4:]   # (after a block's scalar)
     i = pl.program_id(0)
     hp, d = q_ref.shape[1:]
     positions = k_ref.shape[1]
@@ -105,13 +130,28 @@ def _kernel(dev_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, *, scratch: int,
         logits = jax.lax.dot_general(
             wide, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        seen = jax.lax.broadcasted_iota(
-            jnp.int32, (hp, positions), 1) <= pos_ref[i]
+        at = jax.lax.broadcasted_iota(jnp.int32, (hp, positions), 1)
+        # where the own entry comes apart, the table's slot for it is stale
+        seen = at < pos_ref[i] if own else at <= pos_ref[i]
         logits = jnp.where(seen, logits, -jnp.inf)
-        e = jnp.exp(logits - jnp.max(logits, axis=1, keepdims=True))
-        probs = e / jnp.sum(e, axis=1, keepdims=True)
+        top = jnp.max(logits, axis=1, keepdims=True)
+        if own:
+            # the position's own logit: exact products summed in float32
+            own_logit = jnp.sum(
+                wide.astype(jnp.float32) * ko_ref[0].astype(jnp.float32),
+                axis=1, keepdims=True) * scale
+            top = jnp.maximum(top, own_logit)
+            e_own = jnp.exp(own_logit - top)
+        e = jnp.exp(logits - top)
+        total = jnp.sum(e, axis=1, keepdims=True)
+        if own:
+            total = total + e_own
+        probs = e / total
         out = jnp.dot(probs.astype(v_ref.dtype), v_ref[0],
                       preferred_element_type=jnp.float32)
+        if own:
+            out = out + (e_own / total).astype(v_ref.dtype).astype(
+                jnp.float32) * vo_ref[0].astype(jnp.float32)
         o = jnp.zeros((hp, d), jnp.float32)
         for j, m in enumerate(mine):
             o = jnp.where(m, out[:, j * d:(j + 1) * d], o)
@@ -124,48 +164,63 @@ def _kernel(dev_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, *, scratch: int,
 
 @functools.partial(jax.jit, static_argnames=("kv", "scale", "interpret"))
 def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
-                 pos: jax.Array, q: jax.Array, *, kv: int, scale: float,
-                 interpret: bool = False):
+                 pos: jax.Array, q: jax.Array, block=None, own=None, *,
+                 kv: int, scale: float, interpret: bool = False):
     """Attention of one token a row over rows `dev` `[B]` (ascending
     strictly, padding past the scratch row, which is the tables' last)
     of `keys` and `values` `[rows, P, kv * d]` bfloat16, read where they
     rest: the row's own entry is in them. `pos` `[B]`: positions `p <=
     pos` are attended to; `q` `[B, heads, d]` float32, head `h` reading
-    key-value head `h // (heads / kv)`. -> `[B, heads, d]` float32, a
+    key-value head `h // (heads / kv)`. A row wider than `kv * d` holds
+    contexts side by side, and `block` (an int32 scalar, traced or not)
+    is the one read: lanes `[block * kv * d, (block + 1) * kv * d)`.
+    Where `own` `(k, v)` `[B, kv * d]` is given, the position's own entry
+    is not in the tables yet: positions `p < pos` are read from them and
+    the entry takes the place of `pos`. -> `[B, heads, d]` float32, a
     padding row's 0. Jitted, so that a step's layers of one shape trace
     and lower the kernel once between them."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     frame, heads, d = q.shape
+    width = kv * d
     if (keys.shape != values.shape or keys.dtype != values.dtype
-            or not fits(keys.shape, keys.dtype, heads, kv)
-            or keys.shape[2] != kv * d):
+            or not fits(keys.shape, keys.dtype, heads, kv, width)
+            or (block is None) != (keys.shape[2] == width)):
         raise ValueError(f"context_rows takes no tables {keys.dtype}"
                          f"{list(keys.shape)} for {heads} heads on {kv}")
-    rows, positions, width = keys.shape
+    rows, positions = keys.shape[:2]
     scratch = rows - 1
     hp = _padded(heads)
     q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, hp - heads), (0, 0)))
+    scalars = (dev, pos) if block is None else (
+        dev, pos, jnp.asarray(block, jnp.int32).reshape(1))
 
-    def row(i, dev, pos):
-        return (jnp.minimum(dev[i], scratch), 0, 0)
+    def row(i, dev, pos, *block):
+        return (jnp.minimum(dev[i], scratch), 0, block[0][0] if block else 0)
 
-    def own(i, dev, pos):
+    def frame_row(i, *_):
         return (i, 0, 0)
 
     context = pl.BlockSpec((1, positions, width), row)
+    in_specs = [pl.BlockSpec((1, hp, d), frame_row), context, context]
+    operands = (q, keys, values)
+    if own is not None:
+        in_specs += [pl.BlockSpec((1, 1, width), frame_row)] * 2
+        operands += tuple(e.astype(keys.dtype).reshape(frame, 1, width)
+                          for e in own)
     out = pl.pallas_call(
         functools.partial(_kernel, scratch=scratch, kv=kv,
-                          group=heads // kv, scale=scale),
+                          group=heads // kv, scale=scale,
+                          own=own is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(frame,),
-            in_specs=[pl.BlockSpec((1, hp, d), own), context, context],
-            out_specs=pl.BlockSpec((1, hp, d), own)),
+            num_scalar_prefetch=len(scalars), grid=(frame,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hp, d), frame_row)),
         out_shape=jax.ShapeDtypeStruct((frame, hp, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_bytes(keys.shape, heads, kv)),
+            vmem_limit_bytes=vmem_bytes(keys.shape, heads, kv, width)),
         name="context_rows",
         interpret=interpret,
-    )(dev, pos, q, keys, values)
+    )(*scalars, *operands)
     return out[:, :heads]

@@ -250,7 +250,15 @@ class ScoringSession:
             # live rows whose stored context a step's kernel read where
             # it rested, over its layers; 0 on the plain path
             "ctx.at_rest": lambda: metrics.counter(
-                "scoring.ctx.at_rest_rows").inc}
+                "scoring.ctx.at_rest_rows").inc,
+            # a looped model (models/ouro.py): bytes of layer weights
+            # its passes stream a step, passes x layers x a layer's, from
+            # shapes; and the bytes of keys and values its equations
+            # read, over live rows and every (pass, layer) context
+            "loop.weight_bytes": lambda: metrics.counter(
+                "scoring.loop.weight_bytes").inc,
+            "ctx.attended_bytes": lambda: metrics.counter(
+                "scoring.ctx.attended_bytes").inc}
         self._step_stats = [feeds[name]()
                             for name in getattr(model, "step_stats", ())]
         self.reseeds = metrics.counter("scoring.ctx.reseeds")

@@ -104,7 +104,11 @@ kernel on a TPU, which reads each row once where XLA's gather, the
 decode form's own write and its second reading moved a row of megabytes
 three times; PERF.md section 6, PR 38), returns nothing for the leaf,
 and the ring takes the handle's table as the leaf's next value. What
-reads the table promises to write nothing.
+reads the table promises to write nothing. A leaf whose row holds
+several contexts side by side in blocks of lanes (one for each pass and
+layer of a looped model, models/ouro.py) is gathered a block at a time,
+`rows(block, width)`, and its model appends a position's whole line of
+blocks once, when the step's last block is known.
 
 The host `TelemetryStore` stays the durable copy; `load()` rebuilds
 state from it at warmup or after a fault (same recovery story as the
@@ -225,10 +229,16 @@ class ContextAtRest:
         self.table, self.dev, self.slot = table, dev, slot
         self.read_rows = 0
 
-    def rows(self):
-        """Rows `dev` gathered out of the table as it rests."""
+    def rows(self, block=None, width=None):
+        """Rows `dev` gathered out of the table as it rests; of a table
+        whose row holds contexts of `width` lanes side by side, the
+        lanes of block `block` (an int32 scalar, traced or not)."""
         with jax.named_scope("ring_gather"):
-            return _rows(self.table, self.dev)
+            rows = _rows(self.table, self.dev)
+            if block is None:
+                return rows
+            return jax.lax.dynamic_slice_in_dim(rows, block * width, width,
+                                                axis=2)
 
     def append(self, entry):
         """The position's own `entry` `[B, width]` at `(row, slot)`, in

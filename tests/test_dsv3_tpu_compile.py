@@ -628,3 +628,110 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     assert 13.5e9 < mem.argument_size_in_bytes < 13.6e9
     assert mem.temp_size_in_bytes < 1.2e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.8e9
+
+
+# -- `ouro-stream` at `ouro-2.6b-pp4`'s own size --------------------------------
+
+OURO_ROWS, OURO_FRAME = 65, 16
+
+
+@pytest.fixture(scope="module")
+def ouro_step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip) at the benchmark configuration's `model_config` as it stands."""
+    import json
+    import os
+
+    from sitewhere_tpu.models import build_model
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "ouro-2.6b-pp4.json")) as fh:
+        model = build_model("ouro-stream", **json.load(fh)["model_config"])
+    state, compiled = _compile_step(model, OURO_ROWS, OURO_FRAME, one_chip,
+                                    jnp.float32)
+    return model, state, compiled
+
+
+def test_ouro_step_is_one_pass_body_that_moves_no_table_and_no_weight(
+        ouro_step):
+    """Four passes of twelve layers are ONE compiled layer body: the
+    passes a `while`, the layers a `while` inside it over weights stacked
+    `[12, ...]`, and in it ONE `context_rows` call site that reads a
+    2,048-lane block of the two tables `[65, 448, 98304]` as they rest,
+    the position's own entry beside them (each of the 48 contexts a
+    block; the step appends a row's 48 entries as one line when the
+    passes end, in place). No table is copied, transposed, sliced or
+    gathered, nothing
+    of a frame's gathered contexts exists, and each rests row-major. No
+    slice of a stacked weight is made before its product: it is read in
+    the product's own fusion, so the passes stream each weight once a
+    pass and no more. The donated state comes back in its own buffers;
+    arguments and scratch fit the chip."""
+    from chip_smoke import _table_moves
+
+    model, state, compiled = ouro_step
+    hlo = compiled.as_text()
+    lines = hlo.splitlines()
+    assert _table_moves(hlo, OURO_ROWS) == []
+    table = f"bf16[{OURO_ROWS},448,98304]"
+    shapes = set(re.findall(rf"\w+\[{OURO_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == {table, f"bf16[{OURO_ROWS},2048]", f"f32[{OURO_ROWS}]",
+                      f"s32[{OURO_ROWS}]"}, shapes
+    assert not re.search(rf"\[{OURO_FRAME},448,(?:2048|98304)\]", hlo)
+    assert "mini-gather" not in hlo
+    (call,) = _context_kernels(lines, OURO_FRAME, 16, table)
+    assert "attn_full" in call and "loop_pass" in call
+    assert len([line for line in lines if "tpu_custom_call" in line]) == 1
+    whiles = [line for line in lines if " while(" in line]
+    assert len(whiles) == 4
+    # the passes, the layers inside them, and the two appends' loops of
+    # row updates, once a step: a row's 48 entries go in as one line
+    # (an append an entry, 96 such loops, was 6.6 of an 18.3 ms step)
+    appends = [line for line in whiles if "ctx_append" in line]
+    assert len(appends) == 2 and not any("loop_pass" in line
+                                         for line in appends)
+    layouts = set(re.findall(re.escape(table) + r"\{([\d,]+)", hlo))
+    assert layouts == {"2,1,0"}
+    # a table is a parameter, a loop's carry, or the append's update of
+    # a row's block in place, and nothing else
+    made = {re.search(rf"= {re.escape(table)}\S* ([\w-]+)\(", line).group(1)
+            for line in lines if re.search(rf"= {re.escape(table)}\S* ", line)}
+    assert made <= {"parameter", "get-tuple-element", "dynamic-update-slice",
+                    "while", "tuple", "bitcast"}, made
+    # the stacked weights: each a parameter of the step and of the
+    # loops, and read by a product's fusion at the layer's index
+    comps, entry = _computations(hlo)
+    fused = {c for lines_ in comps.values() for line in lines_
+             if " fusion(" in line
+             for c in re.findall(r"calls=(%[\w.\-]+)", line)}
+    weights = r"bf16\[(?:1,)?(?:2048,2048|2048,5632|5632,2048)\]"
+    outside = [line for name, body in comps.items() if name not in fused
+               for line in body]
+    # nothing of a layer's weight is made outside a product's fusion
+    assert not [line for line in outside
+                if re.search(rf"= {weights}\S* ", line)]
+    stacked = r"bf16\[12,(?:2048,2048|2048,5632|5632,2048)\]"
+    names = {m.group(1) for line in outside
+             for m in [re.match(rf"\s*(?:ROOT )?(%\S+) = {stacked}", line)]
+             if m}
+    uses = [line for line in outside if " = " in line and any(
+        re.search(re.escape(n) + r"[,)]", line.split(" = ", 1)[1])
+        for n in names)]
+    # a product's fusion reads it at the layer's index (the MLP's gate
+    # and up in one), and the rest hands it on
+    reads = [line for line in uses if " fusion(" in line]
+    assert len(reads) >= 6 and all("kind=kOutput" in line
+                                   for line in reads), reads
+    assert all(re.search(r" (?:tuple|while|get-tuple-element|bitcast)\(",
+                         line) for line in uses if line not in reads), uses
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes > 11.4e9
+    # 11.45 GB of contexts and 1.636 GB of weights; the scratch is a
+    # layer's activations and the logits (1,776,128 here; 203,360,768
+    # while the rotary turn reshaped q and k into heads and the compiler
+    # transposed their stacked projections to make that a view)
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.2e9
+    assert mem.temp_size_in_bytes < 0.02e9

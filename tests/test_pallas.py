@@ -527,6 +527,64 @@ def test_context_kernel_matches_the_decode_form_and_writes_nothing_interpret(
         assert (np.asarray(rested)[scratch] == np.asarray(was)[scratch]).all()
 
 
+# (positions, key-value heads, query heads, contexts a row, the one read,
+# each live row's position, padding rows): `ouro-stream`'s tables, a
+# (pass, layer) a block of lanes, the position's own entry beside them
+BLOCK_CASES = {
+    "the_third_of_four_a_head_a_key_value_head": (32, 2, 2, 4, 2,
+                                                  [0, 13, 31], 1),
+    "the_last_of_three_grouped_heads": (48, 2, 4, 3, 2, [7, 47], 2),
+    # positions that are no whole lane tile of `probs` (448 is 3.5)
+    "positions_of_half_a_lane_tile": (64 + 16, 2, 2, 2, 1, [0, 70, 79], 1),
+}
+
+
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_context_kernel_reads_a_block_beside_the_own_entry_interpret(case):
+    """`context_rows` over one block of lanes of a row of contexts, the
+    position's own entry handed beside the tables, whose slot at `pos`
+    holds something stale: against `_decode_rows` over the gathered
+    block with the entry laid in, to float32 round-off; a padding row's
+    0; another block answers otherwise; no table is written."""
+    from sitewhere_tpu.ops import context_kernel
+    from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
+
+    positions, kv, heads, blocks, block, at, padding = BLOCK_CASES[case]
+    d, rows, live = 128, 11, len(at)
+    width = kv * d
+    scratch = rows - 1
+    keys = iter(jax.random.split(jax.random.PRNGKey(positions + blocks), 5))
+    tables = [jax.random.normal(next(keys), (rows, positions, blocks * width)
+                                ).astype(jnp.bfloat16) for _ in range(2)]
+    assert context_kernel.fits(tables[0].shape, tables[0].dtype, heads, kv,
+                               width)
+    frame = live + padding
+    q = jax.random.normal(next(keys), (frame, heads, d)) * 2.0
+    k, v = (jax.random.normal(next(keys), (frame, width)).astype(
+        jnp.bfloat16) for _ in range(2))
+    dev = jnp.asarray(np.concatenate([
+        np.sort(np.random.default_rng(live).permutation(scratch)[:live]),
+        pad_rows(scratch, padding)]), jnp.int32)
+    pos = jnp.asarray(at + [0] * padding, jnp.int32)
+    blocks_ = _blocks()
+
+    def plain(block):
+        ktab, vtab = (ContextAtRest(t, dev, pos) for t in tables)
+        return blocks_._decode_rows(q, k, v, ktab.rows(block, width),
+                                    vtab.rows(block, width), pos, kv)
+
+    want = jax.jit(plain)(block)
+    got = context_kernel.context_rows(*tables, dev, pos, q, block, (k, v),
+                                      kv=kv, scale=128 ** -0.5,
+                                      interpret=True)
+    scale = float(jnp.abs(want[:live]).max())
+    assert 0.3 < scale < 10
+    assert float(jnp.abs(got - want)[:live].max()) < 2e-6 * scale
+    assert not np.asarray(got)[live:].any()
+    other = jax.jit(plain)(block - 1)
+    assert float(jnp.abs(other - want)[:live].max()) > 0.1 * scale
+
+
 def test_context_kernel_takes_bfloat16_rows_of_whole_tiles_that_vmem_holds():
     """`fits` reads the leaf's shape and dtype and the heads: the three
     served leaves; not a float32 leaf, positions that are no whole
@@ -581,12 +639,25 @@ def _olmo_of_128_wide_heads():
     return model, 1
 
 
+def _ouro_of_128_wide_heads():
+    from sitewhere_tpu.models import build_model
+
+    model = build_model(
+        "ouro-stream", hidden_size=256, intermediate_size=256,
+        num_hidden_layers=2, layer_types=["full_attention"] * 2,
+        num_attention_heads=2, num_key_value_heads=2, head_dim=128,
+        vocab_size=64, total_ut_steps=3, window=12, context_positions=32)
+    return model, model.slots
+
+
 @pytest.mark.parametrize("build", [_laguna_of_128_wide_heads,
-                                   _olmo_of_128_wide_heads],
-                         ids=["laguna-stream", "olmo-hybrid-stream"])
+                                   _olmo_of_128_wide_heads,
+                                   _ouro_of_128_wide_heads],
+                         ids=["laguna-stream", "olmo-hybrid-stream",
+                              "ouro-stream"])
 def test_the_step_that_reads_contexts_at_rest_is_the_plain_step(
         build, monkeypatch):
-    """The whole ring step of both models that read a context at rest,
+    """The whole ring step of the models that read a context at rest,
     with the TPU's branch taken (the kernels in interpret mode) against
     the step as the CPU lowers it, in bfloat16 at heads of 128: scores,
     every state leaf and the step's other numbers agree (both sides make
