@@ -54,8 +54,9 @@ layer's `c<l>` `[rows, (K - 1) * hidden / 128, 128]`, the taps' last `K
 - 1` inputs, oldest first, in whole lane tiles (handed over in turn, a
 `RowsInTurn`); an attention layer's `k<l>`, `v<l>` `[rows,
 context_positions, kv * d]`, the only window leaves, bounded, and read
-where they rest where `ops/context_kernel.py` takes their shape
-(`at_rest`). Rope is applied before an entry is stored.
+where they rest (`at_rest`): `ops/context_kernel.py` takes a key-value
+head of 64, half a lane tile, two to a tile. Rope is applied before an
+entry is stored.
 
 Two forms of the same numbers. The decode form, one event a row, reads
 the two inputs before it as they rest and a context as it rests. The

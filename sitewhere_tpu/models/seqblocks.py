@@ -355,10 +355,11 @@ class SeqBlocks:
         """`_decode_rows` over a context that stays in the ring's table:
         `kctx`, `vctx` are the layer's two window leaves as the ring
         hands them over (scoring/stream.py, `ContextAtRest`). On a TPU,
-        in bfloat16 and at shapes it takes, the position's own entry is
-        appended first and ONE kernel reads each row's keys and values
-        where they then rest (ops/context_kernel.py): the same lines, no
-        gathered copy. Elsewhere the rows are gathered, `_decode_rows`
+        in bfloat16 and at shapes it takes (a key-value head of whole
+        lane tiles, or of 64 lanes, two to a tile), the position's own
+        entry is appended first and ONE kernel reads each row's keys and
+        values where they then rest (ops/context_kernel.py): the same
+        lines, no gathered copy. Elsewhere the rows are gathered, `_decode_rows`
         reads them and the entries are appended: one algorithm, and the
         plain path is the kernel's twin in the tests. `kctx.read_rows`
         is left saying how many live rows were read at rest. -> `[B,
@@ -383,9 +384,10 @@ class SeqBlocks:
                     (dev < ktab.shape[0] - 1).sum(dtype=jnp.int32))
 
         args = (kctx.table, vctx.table, q, k, v)
+        leaf = (kctx.table.shape, kctx.table.dtype, q.shape[1], kv)
         if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
-                or not context_kernel.fits(kctx.table.shape,
-                                           kctx.table.dtype, q.shape[1], kv)):
+                or not (context_kernel.fits(*leaf)
+                        or context_kernel.fits_paired(*leaf))):
             took = plain(*args)
         else:
             took = jax.lax.platform_dependent(*args, default=plain,

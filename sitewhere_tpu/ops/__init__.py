@@ -12,14 +12,14 @@ is its plain twin: the kernel is handed to `RowsInTurn.update`
 (scoring/stream.py), which promises when it runs; the kernel promises
 that rows the frame does not name, the scratch row among them, come
 back as they were, and that every write has landed when it returns.
-`context_kernel` (a layer's attention of `laguna-stream` and of
-`olmo-hybrid-stream` over each device's stored keys and values, read in
-the rows of the ring's table they rest in) is imported by
-models/seqblocks.py, whose `_decode_rows` is its plain twin: the ring
-hands the two tables over as `ContextAtRest`s (scoring/stream.py), the
-position's own entries are appended first, and the kernel only reads
-(`lfm2-stream`'s key-value heads of 64 are half a lane tile: `fits`
-refuses them and its attention takes the plain twin)."""
+`context_kernel` (a layer's attention of `laguna-stream`, of
+`olmo-hybrid-stream` and of `lfm2-stream` over each device's stored keys
+and values, read in the rows of the ring's table they rest in; a
+key-value head of 64, half a lane tile, is read two to a tile) is
+imported by models/seqblocks.py, whose `_decode_rows` is its plain twin:
+the ring hands the two tables over as `ContextAtRest`s
+(scoring/stream.py), the position's own entries are appended first, and
+the kernel only reads."""
 
 from sitewhere_tpu.ops.lstm_kernel import (  # noqa: F401
     lstm_window_final,

@@ -38,6 +38,20 @@ scratch row; its output is 0 and it writes nothing. Heads are padded to
 whole `(16, 128)` tiles of `probs`; the padded rows' lanes are all zero
 and are cut off again.
 
+A key-value head of 64 lanes (`lfm2-stream`'s 8, under 32 query heads)
+is half a lane tile, so a head's piece of `wide` or of `out` would be a
+half-tile lane slice. Such heads are read two to a tile, by masks: `q`
+comes in twice side by side, a whole tile (XLA lays it so before the
+call: 8 MB at a frame of 512 and 32 heads), `wide` is that tile repeated
+over the context's lane tiles and kept where a lane's head is the row's
+(`_own_lanes`), and a head's output is `out` kept by the same mask, its
+lane tiles summed and then a tile's two halves (a lane roll), each sum
+of one term and zeros; the call hands back the tile and XLA keeps its
+first 64 lanes. A `wide` built by XLA whole and an `out` folded by XLA
+after the call would write and read 16 MB (bfloat16) and 33 MB (float32)
+a layer there. The shape alone chooses: a head of whole lane tiles runs
+the lines above and no mask.
+
 A table whose row holds several contexts side by side, each a block of
 `kv * d` lanes (`ouro-stream`'s one key table and one value table, a
 block for each pass and layer: models/ouro.py), is read at the block
@@ -51,10 +65,11 @@ entry's logit and value take its place in the same softmax.
 
 VMEM: a row's keys and values twice each (`vmem_bytes`: 6.3 MB of
 blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's, 9.7
-one of Ouro's 48 blocks) and the row's small operands. `fits` says
-whether a call stays under `VMEM_LIMIT`; a leaf that does not fit, or
-is not bfloat16 in whole tiles, takes the model's plain path. No `cost_estimate`
-(ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
+one of Ouro's 48 blocks, 2.1 LFM2's) and the row's small operands.
+`fits` (heads of whole lane tiles) and `fits_paired` (heads of half of
+one) say whether a call stays under `VMEM_LIMIT`; a leaf that neither
+takes, or that is not bfloat16 in whole tiles, takes the model's plain
+path. No `cost_estimate` (ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
 in interpret mode and the compile for a described v5e by
 tests/test_dsv3_tpu_compile.py.
 """
@@ -68,6 +83,8 @@ import jax.numpy as jnp
 
 VMEM_LIMIT = 20 << 20     # the most a call may take of VMEM
 HEAD_TILE = 16            # query rows come in whole bfloat16 tiles
+LANES = 128               # a lane tile
+HALF = LANES // 2         # a key-value head of half a lane tile
 
 
 def _padded(heads: int) -> int:
@@ -79,35 +96,59 @@ def vmem_bytes(shape: tuple, heads: int, kv: int,
     """What a call over two tables of `shape` holds in VMEM, reading
     contexts of `width` lanes (a row's whole width where none is given):
     four blocks of a row, the row's own operands (the wide query, the
-    wide output, logits and weights, `q` and `o` twice), and room for
-    the compiler's own."""
+    wide output, logits and weights, `q` and `o` twice, a lane tile
+    each at the least), and room for the compiler's own."""
     positions, width = shape[1], width or shape[2]
     hp = _padded(heads)
-    small = hp * (6 * width + 12 * positions + 16 * width // kv)
+    small = hp * (6 * width + 12 * positions + 16 * max(width // kv, LANES))
     return 4 * 2 * positions * width + small + (2 << 20)
 
 
-def fits(shape: tuple, dtype, heads: int, kv: int,
-         width: int | None = None) -> bool:
-    """Whether `context_rows` takes two tables of `shape` and `dtype`
-    for `heads` query heads over `kv` key-value heads, a context being
-    `width` lanes of a row (all of it where none is given): a context
-    `[positions, kv * d]` of bfloat16 in whole `(16, 128)` tiles, a
-    key-value head whole lane tiles, a row whole contexts, four contexts
-    of which VMEM holds."""
+def _takes(shape: tuple, dtype, heads: int, kv: int, width: int | None,
+           head) -> bool:
     if len(shape) != 3:
         return False
     width = width or shape[2]
     return (jnp.dtype(dtype) == jnp.bfloat16
             and shape[1] % 16 == 0 and shape[2] % width == 0
-            and width % kv == 0 and (width // kv) % 128 == 0
+            and width % LANES == 0 and width % kv == 0 and head(width // kv)
             and heads % kv == 0
             and vmem_bytes(shape, heads, kv, width) <= VMEM_LIMIT)
+
+
+def fits(shape: tuple, dtype, heads: int, kv: int,
+         width: int | None = None) -> bool:
+    """Whether `context_rows` takes two tables of `shape` and `dtype`
+    for `heads` query heads over `kv` key-value heads of whole lane
+    tiles, a context being `width` lanes of a row (all of it where none
+    is given): a context `[positions, kv * d]` of bfloat16 in whole
+    `(16, 128)` tiles, a row whole contexts, four contexts of which VMEM
+    holds."""
+    return _takes(shape, dtype, heads, kv, width, lambda d: d % LANES == 0)
+
+
+def fits_paired(shape: tuple, dtype, heads: int, kv: int,
+                width: int | None = None) -> bool:
+    """As `fits`, for key-value heads of half a lane tile, which
+    `context_rows` reads two to a tile: a context is still whole lane
+    tiles."""
+    return _takes(shape, dtype, heads, kv, width, lambda d: d == HALF)
+
+
+def _own_lanes(hp: int, width: int, kv: int, group: int) -> jax.Array:
+    """`[hp, width]`: whether a lane is of the key-value head that query
+    row `r` reads, `r // group`, where a head is `HALF` lanes."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return (sum((row >= j * group).astype(jnp.int32) for j in range(1, kv))
+            == sum((lane >= j * HALF).astype(jnp.int32)
+                   for j in range(1, kv)))
 
 
 def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
             scale: float, own: bool):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if own:
         q_ref, k_ref, v_ref, ko_ref, vo_ref, o_ref = refs[-6:]
@@ -115,18 +156,27 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
         q_ref, k_ref, v_ref, o_ref = refs[-4:]   # (after a block's scalar)
     i = pl.program_id(0)
     hp, d = q_ref.shape[1:]
-    positions = k_ref.shape[1]
+    positions, width = k_ref.shape[1:]
+    # a key-value head of half a lane tile: `q` comes twice side by side
+    half = width // kv == HALF
     live = dev_ref[i] < scratch
 
     @pl.when(live)
     def _():
-        row = jax.lax.broadcasted_iota(jnp.int32, (hp, d), 0)
-        # a query row's own key-value head: rows `j * group ...`
-        mine = [(row >= j * group) & (row < (j + 1) * group)
-                for j in range(kv)]
-        q = q_ref[0]
-        wide = jnp.concatenate(
-            [jnp.where(m, q, 0.0) for m in mine], axis=1).astype(k_ref.dtype)
+        if half:
+            mine = _own_lanes(hp, width, kv, group)
+            wide = jnp.where(
+                mine, jnp.concatenate([q_ref[0]] * (width // LANES), axis=1),
+                0.0).astype(k_ref.dtype)
+        else:
+            row = jax.lax.broadcasted_iota(jnp.int32, (hp, d), 0)
+            # a query row's own key-value head: rows `j * group ...`
+            mine = [(row >= j * group) & (row < (j + 1) * group)
+                    for j in range(kv)]
+            q = q_ref[0]
+            wide = jnp.concatenate(
+                [jnp.where(m, q, 0.0) for m in mine],
+                axis=1).astype(k_ref.dtype)
         logits = jax.lax.dot_general(
             wide, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -152,10 +202,19 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
         if own:
             out = out + (e_own / total).astype(v_ref.dtype).astype(
                 jnp.float32) * vo_ref[0].astype(jnp.float32)
-        o = jnp.zeros((hp, d), jnp.float32)
-        for j, m in enumerate(mine):
-            o = jnp.where(m, out[:, j * d:(j + 1) * d], o)
-        o_ref[0] = o
+        if half:
+            # the row's own lanes, the lane tiles summed, then a tile's
+            # two halves: one term of each sum is the head's, the others 0
+            out = jnp.where(mine, out, 0.0)
+            o = out[:, :LANES]
+            for t in range(LANES, width, LANES):
+                o = o + out[:, t:t + LANES]
+            o_ref[0] = o + pltpu.roll(o, HALF, 1)
+        else:
+            o = jnp.zeros((hp, d), jnp.float32)
+            for j, m in enumerate(mine):
+                o = jnp.where(m, out[:, j * d:(j + 1) * d], o)
+            o_ref[0] = o
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -185,7 +244,8 @@ def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
     frame, heads, d = q.shape
     width = kv * d
     if (keys.shape != values.shape or keys.dtype != values.dtype
-            or not fits(keys.shape, keys.dtype, heads, kv, width)
+            or not (fits(keys.shape, keys.dtype, heads, kv, width)
+                    or fits_paired(keys.shape, keys.dtype, heads, kv, width))
             or (block is None) != (keys.shape[2] == width)):
         raise ValueError(f"context_rows takes no tables {keys.dtype}"
                          f"{list(keys.shape)} for {heads} heads on {kv}")
@@ -193,6 +253,10 @@ def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
     scratch = rows - 1
     hp = _padded(heads)
     q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, hp - heads), (0, 0)))
+    if d == HALF:
+        # heads of half a lane tile: the query and the output a whole one
+        q = jnp.concatenate([q, q], axis=2)
+    lanes = q.shape[2]
     scalars = (dev, pos) if block is None else (
         dev, pos, jnp.asarray(block, jnp.int32).reshape(1))
 
@@ -203,7 +267,7 @@ def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
         return (i, 0, 0)
 
     context = pl.BlockSpec((1, positions, width), row)
-    in_specs = [pl.BlockSpec((1, hp, d), frame_row), context, context]
+    in_specs = [pl.BlockSpec((1, hp, lanes), frame_row), context, context]
     operands = (q, keys, values)
     if own is not None:
         in_specs += [pl.BlockSpec((1, 1, width), frame_row)] * 2
@@ -216,11 +280,11 @@ def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(frame,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hp, d), frame_row)),
-        out_shape=jax.ShapeDtypeStruct((frame, hp, d), jnp.float32),
+            out_specs=pl.BlockSpec((1, hp, lanes), frame_row)),
+        out_shape=jax.ShapeDtypeStruct((frame, hp, lanes), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_bytes(keys.shape, heads, kv, width)),
         name="context_rows",
         interpret=interpret,
     )(*scalars, *operands)
-    return out[:, :heads]
+    return out[:, :heads, :d]

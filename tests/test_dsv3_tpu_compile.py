@@ -10,9 +10,10 @@ rows of 12.75 MB, three matrix states among them), held to the same
 (both read their contexts where they rest: one `context_rows` kernel a
 layer, no gathered rows); the ring step of `lfm2-stream` at its
 configuration's own size (eight layers, 2,561 rows, six expert layers of
-64 held experts: 1,152 expert leaves, none copied; its contexts on the
-plain path); and the ring step of `lstm-stream` at `stream-512k`'s own size, which
-moves rows of ONE table. In the three steps with held experts each
+64 held experts: 1,152 expert leaves, none copied; its contexts read
+where they rest, key-value heads of 64 two to a lane tile); and the ring
+step of `lstm-stream` at `stream-512k`'s own size, which moves rows of
+ONE table. In the three steps with held experts each
 `expert_tiles` kernel's Mosaic module is read back: its step picks the
 next weight block in a tree of branches, not one a held expert. Nothing
 runs, so nothing here is a time.
@@ -565,12 +566,12 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     tables of 8 KB a row are gathered once and scattered once each, not
     copied, sliced or transposed; the tied embedding is gathered from
     and contracted over as it rests. The two attention layers' four
-    context tables take the PLAIN path: a key-value head of 64 is half a
-    lane tile, which `ops/context_kernel.py` `fits` refuses, so each
-    table's rows are gathered (268 MB) and the decode form reads the
-    copy. The perf_opt issue that ROADMAP's Queue S names ("attention
-    over a stored context whose key-value head is half a lane tile")
-    lifts that pin and turns the four gathers below into two kernels."""
+    context tables are read where they rest: a key-value head of 64 is
+    half a lane tile, which `ops/context_kernel.py` takes two to a tile,
+    so each layer's attention is ONE `context_rows` call over its two
+    tables behind the append of the position's own entries, and no
+    table's rows are gathered (the four gathers of 268 MB each were
+    3.28 ms of a 20.1 ms step on a v5e; PERF.md section 6)."""
     from chip_smoke import _table_moves
     from sitewhere_tpu.ops import context_kernel, expert_kernel
 
@@ -578,7 +579,8 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     hlo = compiled.as_text()
     lines = hlo.splitlines()
     assert expert_kernel.fits(LFM2_FRAME, 2048, 1536, 128)
-    assert not context_kernel.fits((LFM2_ROWS, 512, 512), jnp.bfloat16, 32, 8)
+    assert context_kernel.fits_paired((LFM2_ROWS, 512, 512), jnp.bfloat16,
+                                      32, 8)
     assert _table_moves(hlo, LFM2_ROWS) == []
     conv, context = f"bf16[{LFM2_ROWS},32,128]", f"bf16[{LFM2_ROWS},512,512]"
     shapes = set(re.findall(rf"\w+\[{LFM2_ROWS}(?:,\d+)*\]", hlo))
@@ -587,8 +589,13 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     _expert_leaves_read_once(hlo, model.experts.held, [2, 3, 4, 5, 6, 7],
                              2048, 1536)
     assert model.experts.held == 64
-    # a layer's call and the one inside its overflow's loop: no other kernel
-    kernels = [line for line in lines if "tpu_custom_call" in line]
+    # an expert layer's call and the one inside its overflow's loop, and
+    # an attention layer's one call over its two tables (32 heads of 64
+    # handed back in whole lane tiles): no other kernel
+    attends = _context_kernels(lines, LFM2_FRAME, 32, context)
+    assert len(attends) == 2 and all("attn_full" in line for line in attends)
+    kernels = [line for line in lines if "tpu_custom_call" in line
+               and line not in attends]
     assert len(kernels) == 2 * 6 and all("expert_tiles" in line
                                          for line in kernels)
     _expert_kernels(lines, model.experts.held, 2 * 6)
@@ -604,12 +611,10 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
         assert all("unique_indices=true" in line
                    and "indices_are_sorted=true" not in line
                    for line in scatters)
-    # the plain path: a layer's two tables gathered whole, in one slice a
-    # row (512 KiB, the most `scoring/stream.py` `_rows` takes unblocked)
-    gathers = [line for line in lines if re.search(
-        rf"= bf16\[{LFM2_FRAME},512,512\]\S* gather\(", line)]
-    assert len(gathers) == 4 and all("slice_sizes={1,512,512}" in line
-                                     for line in gathers)
+    # nothing of a frame's gathered contexts: no table's rows are gathered
+    assert not re.search(rf"\[{LFM2_FRAME},(?:\d+,)?512,512\]", hlo)
+    assert not [line for line in lines if " gather(" in line
+                and context in line]
     assert len([line for line in lines if re.search(
         rf"= bf16\[{LFM2_FRAME},32,128\]\S* gather\(", line)]) == 6
     moved = [line for line in lines if re.search(
@@ -623,10 +628,11 @@ def test_lfm2_step_streams_every_expert_once_and_moves_no_table(lfm2_step):
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes > 5.5e9
-    # 8.05 GB of weights and 5.51 of state; the scratch is the two
-    # attention layers' gathered contexts (1,088,491,520 under PR 39)
+    # 8.05 GB of weights and 5.51 of state; the scratch was the two
+    # attention layers' gathered contexts: `temp_size_in_bytes` read
+    # 1,088,491,520 while they were gathered and reads 162,520,064 here
     assert 13.5e9 < mem.argument_size_in_bytes < 13.6e9
-    assert mem.temp_size_in_bytes < 1.2e9
+    assert mem.temp_size_in_bytes < 0.2e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.8e9
 
 

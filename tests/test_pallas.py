@@ -428,19 +428,36 @@ def test_the_step_lowered_for_a_tpu_is_the_plain_step(case, heads, in_place,
 
 # -- ops/context_kernel.py: a stored context read where it rests ---------------
 
-# (positions, key-value heads, query heads, wraps, each live row's position,
-# padding rows): a head is 128 wide, as both served models' are
+# (head width, positions, key-value heads, query heads, wraps, each live
+# row's position, padding rows): heads of 128, as three served models'
+# are, and of 64, two to a lane tile, as `lfm2-stream`'s are
 CONTEXT_CASES = {
     # a full layer's leaf, grouped heads: the first position, the middle,
     # the last the leaf holds
-    "bounded_grouped_heads": (32, 2, 6, False, [0, 13, 31], 1),
+    "bounded_grouped_heads": (128, 32, 2, 6, False, [0, 13, 31], 1),
     # a sliding layer's: before it has wrapped, the position that fills
     # it, the first that overwrites, and far round the circle
-    "wrapping_before_and_after": (32, 2, 4, True, [5, 31, 32, 77], 2),
+    "wrapping_before_and_after": (128, 32, 2, 4, True, [5, 31, 32, 77], 2),
     # `olmo-hybrid-stream`'s: a query head a key-value head, no group
-    "a_head_a_key_value_head": (48, 3, 3, False, [0, 20, 47], 0),
-    "a_frame_of_one_live_row": (32, 2, 4, False, [9], 3),
+    "a_head_a_key_value_head": (128, 48, 3, 3, False, [0, 20, 47], 0),
+    "a_frame_of_one_live_row": (128, 32, 2, 4, False, [9], 3),
+    # heads of half a lane tile: grouped, behind padding rows
+    "half_tile_heads_grouped": (64, 32, 2, 6, False, [0, 13, 31], 2),
+    # `lfm2-stream`'s own grouping, 32 query heads on 8
+    "half_tile_heads_32_on_8": (64, 32, 8, 32, False, [1, 16, 30], 1),
+    # a wrapping context of such heads, before and after it has wrapped
+    "half_tile_heads_wrapping": (64, 32, 2, 4, True, [5, 31, 32, 77], 1),
 }
+
+
+def _context_takes(d, *leaf):
+    """Whether `context_rows` takes the leaf by the one predicate of its
+    head width `d`, and the other refuses it."""
+    from sitewhere_tpu.ops import context_kernel
+
+    whole = context_kernel.fits(*leaf)
+    paired = context_kernel.fits_paired(*leaf)
+    return (paired and not whole) if d == 64 else (whole and not paired)
 
 
 def _blocks():
@@ -483,13 +500,13 @@ def test_context_kernel_matches_the_decode_form_and_writes_nothing_interpret(
     from sitewhere_tpu.ops import context_kernel
     from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
 
-    positions, kv, heads, wraps, at, padding = CONTEXT_CASES[case]
-    d, rows, live = 128, 11, len(at)
+    d, positions, kv, heads, wraps, at, padding = CONTEXT_CASES[case]
+    rows, live = 11, len(at)
     scratch = rows - 1
     keys = iter(jax.random.split(jax.random.PRNGKey(positions + heads), 5))
     tables = [jax.random.normal(next(keys), (rows, positions, kv * d)
                                 ).astype(jnp.bfloat16) for _ in range(2)]
-    assert context_kernel.fits(tables[0].shape, tables[0].dtype, heads, kv)
+    assert _context_takes(d, tables[0].shape, tables[0].dtype, heads, kv)
     frame = live + padding
     q = jax.random.normal(next(keys), (frame, heads, d)) * 2.0
     k, v = (jax.random.normal(next(keys), (frame, kv * d)).astype(
@@ -527,15 +544,20 @@ def test_context_kernel_matches_the_decode_form_and_writes_nothing_interpret(
         assert (np.asarray(rested)[scratch] == np.asarray(was)[scratch]).all()
 
 
-# (positions, key-value heads, query heads, contexts a row, the one read,
-# each live row's position, padding rows): `ouro-stream`'s tables, a
-# (pass, layer) a block of lanes, the position's own entry beside them
+# (head width, positions, key-value heads, query heads, contexts a row,
+# the one read, each live row's position, padding rows): `ouro-stream`'s
+# tables, a (pass, layer) a block of lanes, the position's own entry
+# beside them
 BLOCK_CASES = {
-    "the_third_of_four_a_head_a_key_value_head": (32, 2, 2, 4, 2,
+    "the_third_of_four_a_head_a_key_value_head": (128, 32, 2, 2, 4, 2,
                                                   [0, 13, 31], 1),
-    "the_last_of_three_grouped_heads": (48, 2, 4, 3, 2, [7, 47], 2),
+    "the_last_of_three_grouped_heads": (128, 48, 2, 4, 3, 2, [7, 47], 2),
     # positions that are no whole lane tile of `probs` (448 is 3.5)
-    "positions_of_half_a_lane_tile": (64 + 16, 2, 2, 2, 1, [0, 70, 79], 1),
+    "positions_of_half_a_lane_tile": (128, 64 + 16, 2, 2, 2, 1, [0, 70, 79],
+                                      1),
+    # heads of half a lane tile, grouped: the second block of three
+    "the_second_of_three_half_tile_heads": (64, 32, 4, 8, 3, 1,
+                                            [0, 20, 31], 1),
 }
 
 
@@ -549,15 +571,15 @@ def test_context_kernel_reads_a_block_beside_the_own_entry_interpret(case):
     from sitewhere_tpu.ops import context_kernel
     from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
 
-    positions, kv, heads, blocks, block, at, padding = BLOCK_CASES[case]
-    d, rows, live = 128, 11, len(at)
+    d, positions, kv, heads, blocks, block, at, padding = BLOCK_CASES[case]
+    rows, live = 11, len(at)
     width = kv * d
     scratch = rows - 1
     keys = iter(jax.random.split(jax.random.PRNGKey(positions + blocks), 5))
     tables = [jax.random.normal(next(keys), (rows, positions, blocks * width)
                                 ).astype(jnp.bfloat16) for _ in range(2)]
-    assert context_kernel.fits(tables[0].shape, tables[0].dtype, heads, kv,
-                               width)
+    assert _context_takes(d, tables[0].shape, tables[0].dtype, heads, kv,
+                          width)
     frame = live + padding
     q = jax.random.normal(next(keys), (frame, heads, d)) * 2.0
     k, v = (jax.random.normal(next(keys), (frame, width)).astype(
@@ -586,28 +608,44 @@ def test_context_kernel_reads_a_block_beside_the_own_entry_interpret(case):
 
 
 def test_context_kernel_takes_bfloat16_rows_of_whole_tiles_that_vmem_holds():
-    """`fits` reads the leaf's shape and dtype and the heads: the three
-    served leaves; not a float32 leaf, positions that are no whole
-    sublane tile, a key-value head that is no whole lane tile, query
-    heads that are no whole groups, nor a row four of which pass the
-    VMEM a call may ask for; and `context_rows` refuses what `fits`
-    does not take."""
+    """`fits` (heads of whole lane tiles) and `fits_paired` (heads of
+    half of one) read the leaf's shape and dtype and the heads: the four
+    served leaves, each by one of the two; not a float32 leaf, positions
+    that are no whole sublane tile, a context that is no whole lane
+    tiles, a key-value head that is neither whole lane tiles nor half of
+    one, query heads that are no whole groups, nor a row four of which
+    pass the VMEM a call may ask for; and `context_rows` refuses what
+    neither takes."""
     from sitewhere_tpu.ops import context_kernel
 
-    fits = context_kernel.fits
+    fits, paired = context_kernel.fits, context_kernel.fits_paired
+
+    def takes(*leaf):
+        return fits(*leaf) or paired(*leaf)
+
     for shape, heads, kv in (((769, 768, 1024), 48, 8),
                              ((769, 512, 1024), 72, 8),
-                             ((769, 384, 3840), 30, 30)):
-        assert fits(shape, jnp.bfloat16, heads, kv)
+                             ((769, 384, 3840), 30, 30),
+                             ((2561, 512, 512), 32, 8)):
+        assert takes(shape, jnp.bfloat16, heads, kv)
         assert context_kernel.vmem_bytes(shape, heads, kv) < 16 << 20
-        assert not fits(shape, jnp.float32, heads, kv)
+        assert not takes(shape, jnp.float32, heads, kv)
+    # `lfm2-stream`'s: heads of 64, about 4.5 MB of VMEM, paired alone
+    assert 4.5e6 < context_kernel.vmem_bytes((2561, 512, 512), 32, 8) < 4.6e6
+    assert paired((2561, 512, 512), jnp.bfloat16, 32, 8)
+    assert not fits((2561, 512, 512), jnp.bfloat16, 32, 8)
     assert fits((7, 32, 256), jnp.bfloat16, 4, 2)
-    assert not fits((7, 40, 256), jnp.bfloat16, 4, 2)
-    assert not fits((7, 32, 128), jnp.bfloat16, 4, 2)     # heads of 64
-    assert not fits((7, 32, 192), jnp.bfloat16, 4, 2)
-    assert not fits((7, 32, 256), jnp.bfloat16, 3, 2)
-    assert not fits((769, 4096, 1024), jnp.bfloat16, 48, 8)
-    assert not fits((769, 768, 8, 128), jnp.bfloat16, 48, 8)
+    assert not paired((7, 32, 256), jnp.bfloat16, 4, 2)
+    assert paired((7, 32, 128), jnp.bfloat16, 4, 2)       # heads of 64
+    assert not fits((7, 32, 128), jnp.bfloat16, 4, 2)
+    assert not takes((7, 40, 256), jnp.bfloat16, 4, 2)
+    assert not takes((7, 32, 192), jnp.bfloat16, 6, 3)    # 1.5 lane tiles
+    assert not takes((7, 32, 192), jnp.bfloat16, 4, 2)    # heads of 96
+    assert not takes((7, 32, 384), jnp.bfloat16, 4, 2)    # heads of 192
+    assert not takes((7, 32, 64), jnp.bfloat16, 4, 2)     # heads of 32
+    assert not takes((7, 32, 256), jnp.bfloat16, 3, 2)
+    assert not takes((769, 4096, 1024), jnp.bfloat16, 48, 8)
+    assert not takes((769, 768, 8, 128), jnp.bfloat16, 48, 8)
     with pytest.raises(ValueError, match="takes no tables"):
         context_kernel.context_rows(
             jnp.zeros((3, 32, 256)), jnp.zeros((3, 32, 256)),
@@ -650,16 +688,31 @@ def _ouro_of_128_wide_heads():
     return model, model.slots
 
 
+def _lfm2_of_64_wide_heads():
+    from sitewhere_tpu.models import build_model
+
+    conv, full = "conv", "full_attention"
+    return build_model(
+        "lfm2-stream", hidden_size=256, intermediate_size=256,
+        moe_intermediate_size=64, num_attention_heads=4,
+        num_key_value_heads=2, vocab_size=64, num_experts=8,
+        num_experts_per_tok=2, num_hidden_layers=4, num_dense_layers=1,
+        layer_types=[conv, full, conv, full], window=16,
+        context_positions=32), 2
+
+
 @pytest.mark.parametrize("build", [_laguna_of_128_wide_heads,
                                    _olmo_of_128_wide_heads,
-                                   _ouro_of_128_wide_heads],
+                                   _ouro_of_128_wide_heads,
+                                   _lfm2_of_64_wide_heads],
                          ids=["laguna-stream", "olmo-hybrid-stream",
-                              "ouro-stream"])
+                              "ouro-stream", "lfm2-stream"])
 def test_the_step_that_reads_contexts_at_rest_is_the_plain_step(
         build, monkeypatch):
     """The whole ring step of the models that read a context at rest,
     with the TPU's branch taken (the kernels in interpret mode) against
-    the step as the CPU lowers it, in bfloat16 at heads of 128: scores,
+    the step as the CPU lowers it, in bfloat16 at heads of 128 (and of
+    64, two to a lane tile, in `lfm2-stream`'s): scores,
     every state leaf and the step's other numbers agree (both sides make
     the same bfloat16 products and sum them in float32, in another
     order), `ctx.at_rest` counts the live rows of every layer that
