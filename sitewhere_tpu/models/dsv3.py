@@ -54,8 +54,16 @@ State leaves (scoring/stream.py, "Contract with the model"): `mean`,
 head needs of the previous event (the final norm's output); and one
 WINDOW leaf a layer, `ctx<l>` `[rows, context_positions, entry_width]`
 (`kv_lora_rank + qk_rope_head_dim` values and zeros up to whole lane
-tiles), which the step reads for the batch's rows and appends one
-position to, at each row's own `pos`.
+tiles), which the step appends one position to, at each row's own `pos`,
+and reads for the batch's rows. Each is handed over where it rests
+(`at_rest`: scoring/stream.py, `ContextAtRest`), in its layer's turn:
+the layer appends its entries, then on a TPU ONE kernel a layer reads
+each row's latents in the table as both keys and values
+(ops/context_kernel.py's one-table form: a row's 246 KB read once, where
+XLA's gather, the decode form's own write into the gathered copy and its
+second reading moved them three times); elsewhere, and at shapes the
+kernel does not take, the rows are gathered and `_attend_decode` reads
+them.
 """
 
 from __future__ import annotations
@@ -77,6 +85,7 @@ from sitewhere_tpu.models.seqblocks import (  # noqa: F401  (names kept)
     rope as _rope,
     runs_one_tile,
 )
+from sitewhere_tpu.ops import context_kernel
 
 
 def _yarn() -> dict:
@@ -180,7 +189,7 @@ class Dsv3StreamModel(SeqBlocks):
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
-                  "moe.runs_one_tile")
+                  "moe.runs_one_tile", "ctx.at_rest")
 
     def __init__(self, cfg: Dsv3Config = Dsv3Config()):
         for key, want in (("scoring_func", "sigmoid"), ("hidden_act", "silu"),
@@ -206,6 +215,8 @@ class Dsv3StreamModel(SeqBlocks):
         # state leaves that are windows -> the leaf that holds the
         # position a step appends at (scoring/stream.py)
         self.windows = {f"ctx{l}": "pos" for l in range(self.layers)}
+        # ...each handed over where it rests, in its layer's turn
+        self.at_rest = tuple(self.windows)
         # rows one seeding call takes (StreamingRing.load blocks by it)
         self.seed_rows = max(1, SEED_TOKENS // cfg.window)
         self._cos, self._sin = rope_tables(cfg, cfg.context_positions)
@@ -316,26 +327,80 @@ class Dsv3StreamModel(SeqBlocks):
         out = self._ein("nhqk,nkhd->nqhd", probs, v)
         return out.reshape(out.shape[:2] + (-1,))
 
+    def _latent_query(self, p, q_nope, q_rope):
+        """The decode form's query `[B, heads, entry_width]`: W_kvb's key
+        half folded into `q_nope`, then `q_rope`, then zeros, as an entry
+        rests."""
+        c = self.cfg
+        w_k, _ = self._kv_b(p)
+        return jnp.concatenate([
+            self._ein("bhd,chd->bhc", q_nope, w_k), q_rope,
+            jnp.zeros(q_rope.shape[:-1] + (c.entry_width - c.latent_width,),
+                      jnp.float32)], -1)
+
+    def _latent_out(self, p, lat):
+        """The weighted sum of latents `[B, heads, kv_lora_rank]` through
+        W_kvb's value half. -> `[B, heads * v]`."""
+        _, w_v = self._kv_b(p)
+        out = self._ein("bhc,chd->bhd", lat, w_v)
+        return out.reshape(out.shape[0], -1)
+
     def _attend_decode(self, p, q_nope, q_rope, entry, ctx, pos):
         """The decode form for one token a row: `ctx` `[B, P, latent]` is
         the row's stored context, `entry` its own position's, at `pos`.
         W_kvb's key half goes into the query, its value half comes after
         the weighted sum of latents. -> `[B, heads * v]`."""
         c = self.cfg
-        w_k, w_v = self._kv_b(p)
         rows = jnp.arange(ctx.shape[0])
         keys = ctx.at[rows, pos].set(entry, mode="drop")
-        q = jnp.concatenate([
-            self._ein("bhd,chd->bhc", q_nope, w_k), q_rope,
-            jnp.zeros(q_rope.shape[:-1] + (c.entry_width - c.latent_width,),
-                      jnp.float32)], -1)
+        q = self._latent_query(p, q_nope, q_rope)
         logits = self._ein("bhc,bpc->bhp", q, keys) * self._scale
         seen = jnp.arange(ctx.shape[1])[None, :] <= pos[:, None]
         probs = jax.nn.softmax(
             jnp.where(seen[:, None, :], logits, -jnp.inf), axis=-1)
         lat = self._ein("bhp,bpc->bhc", probs, keys[..., :c.kv_lora_rank])
-        out = self._ein("bhc,chd->bhd", lat, w_v)
-        return out.reshape(out.shape[0], -1)
+        return self._latent_out(p, lat)
+
+    def _attend_at_rest(self, p, q_nope, q_rope, entry, ctx, pos):
+        """`_attend_decode` over a context that stays in the ring's table:
+        `ctx` is the layer's window leaf as the ring hands it over
+        (scoring/stream.py, `ContextAtRest`). On a TPU, in bfloat16 and
+        at shapes it takes, the position's own entry is appended first
+        and ONE kernel reads each row's latents where they then rest, as
+        keys and as values (ops/context_kernel.py, its one-table form):
+        the same lines, no gathered copy. Elsewhere the rows are
+        gathered, `_attend_decode` reads them and the entries are
+        appended. `ctx.read_rows` is left saying how many live rows were
+        read at rest. -> `[B, heads * v]`."""
+        c = self.cfg
+        dev, slot = ctx.dev, ctx.slot
+
+        def plain(table, q_nope, q_rope, entry):
+            rest = type(ctx)(table, dev, slot)
+            out = self._attend_decode(p, q_nope, q_rope, entry, rest.rows(),
+                                      pos)
+            return rest.append(entry), out, jnp.int32(0)
+
+        def rested(table, q_nope, q_rope, entry):
+            table = type(ctx)(table, dev, slot).append(entry)
+            q = self._latent_query(p, q_nope, q_rope).astype(c.compute_dtype)
+            lat = context_kernel.context_rows(
+                table, None, dev, pos, q, scale=self._scale,
+                value_width=c.kv_lora_rank)
+            return (table, self._latent_out(p, lat),
+                    (dev < table.shape[0] - 1).sum(dtype=jnp.int32))
+
+        args = (ctx.table, q_nope, q_rope, entry)
+        if (jnp.dtype(c.compute_dtype) != jnp.bfloat16
+                or not context_kernel.fits_latent(
+                    ctx.table.shape, ctx.table.dtype, c.num_attention_heads,
+                    c.kv_lora_rank)):
+            took = plain(*args)
+        else:
+            took = jax.lax.platform_dependent(*args, default=plain,
+                                              tpu=rested)
+        ctx.table, out, ctx.read_rows = took
+        return out
 
     def _block_prefill(self, p, x, count, cos, sin):
         """One block over `[n, S, hidden]`; also the layer's context
@@ -360,10 +425,10 @@ class Dsv3StreamModel(SeqBlocks):
             q_nope, q_rope, entry = self._project(
                 p, _rms(x, p["attn_norm"], c.rms_norm_eps), cos, sin)
         with jax.named_scope("mla_attend"):
-            x = x + self._mm(self._attend_decode(
+            x = x + self._mm(self._attend_at_rest(
                 p, q_nope, q_rope, entry, ctx, pos), p["o"])
         y, counts = self._ffn(p, _rms(x, p["mlp_norm"], c.rms_norm_eps), live)
-        return x + y, entry, counts
+        return x + y, counts
 
     # -- tokens -------------------------------------------------------------
 
@@ -393,18 +458,20 @@ class Dsv3StreamModel(SeqBlocks):
     def step_score(self, params: dict, rows: dict, v: jax.Array,
                    live: jax.Array):
         """One event a row: the score of the bin that arrived, then the
-        row's next state. For a window leaf the new row is the ONE entry
-        to append at `rows["pos"]`. Also the step's numbers, in
-        `step_stats`' order (`live` masks the padding out of them)."""
+        row's next state. The window leaves come as `ContextAtRest`s: a
+        layer appends its ONE entry a row at `rows["pos"]` and reads the
+        table behind it (`_attend_at_rest`); nothing is returned for
+        them. Also the step's numbers, in `step_stats`' order (`live`
+        masks the padding out of them)."""
         c = self.cfg
         pos = rows["pos"]
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
-        held = busiest = one_tile = jnp.zeros((), jnp.int32)
+        held = busiest = one_tile = at_rest = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
-            x, entry, counts = self._block_decode(
+            x, counts = self._block_decode(
                 params[f"layer{l}"], x, rows[f"ctx{l}"], pos, live)
-            out[f"ctx{l}"] = entry
+            at_rest += rows[f"ctx{l}"].read_rows
             if counts is not None:
                 held += counts.sum()
                 busiest = jnp.maximum(busiest, counts.max())
@@ -418,7 +485,8 @@ class Dsv3StreamModel(SeqBlocks):
             (n_live * (c.num_experts_per_tok * n_moe)).astype(jnp.float32),
             busiest.astype(jnp.float32),
             jnp.where(live, pos, 0).sum() / jnp.maximum(n_live, 1),
-            one_tile.astype(jnp.float32)])
+            one_tile.astype(jnp.float32),
+            at_rest.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:

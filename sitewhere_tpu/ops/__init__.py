@@ -19,7 +19,9 @@ key-value head of 64, half a lane tile, is read two to a tile) is
 imported by models/seqblocks.py, whose `_decode_rows` is its plain twin:
 the ring hands the two tables over as `ContextAtRest`s
 (scoring/stream.py), the position's own entries are appended first, and
-the kernel only reads."""
+the kernel only reads. Its one-table form reads `dsv3-stream`'s latent
+context, one table that is both keys and values, for
+models/dsv3.py, whose `_attend_decode` is that form's plain twin."""
 
 from sitewhere_tpu.ops.lstm_kernel import (  # noqa: F401
     lstm_window_final,

@@ -63,13 +63,43 @@ a v5e; PERF.md section 6, PR 41), so it hands the kernel the own entry
 `own` beside the table: the table's slot at `pos` is masked, and the
 entry's logit and value take its place in the same softmax.
 
+ONE table that is both keys and values (`dsv3-stream`'s latent
+context, models/dsv3.py: a position's `c_kv ‖ k_rope ‖ 0`, 512 + 64
+values in 640 lanes, which every one of 128 query heads reads whole) is
+the call's other form, chosen by what it is handed: no `values`, and a
+value width `value_width`. A row's table is read once and serves both
+products:
+
+    a row, `q` `[heads, W]` in the table's dtype, the table `C` `[P, W]`:
+        logits = q C^T * scale              [heads, P]   f32
+        probs  = softmax(logits where p <= pos)
+        lat    = bf16(probs) C[:, :value_width]   f32 sums, handed back
+                                                   in the table's dtype
+
+which are `Dsv3StreamModel._attend_decode`'s lines, whose next product
+casts `lat` to bfloat16 as this form hands it back. Handed the table
+twice, as keys and as values, the two-table form would read each row
+twice (503 MB a layer where 252 is the row once) and write a float32
+output of the whole width (335 MB a layer) for XLA to cut. `q` comes in
+bfloat16, the dtype the product reads it in (a float32 `q` is 168 MB a
+layer more to read). Padding is clipped onto the scratch row and
+handed back 0, as above. A row is 546 KB of DMA (its context 246 KB, `q`
+164 KB, the output 131 KB), about what a grid step costs of its own, so
+a grid step takes `LATENT_ROWS` rows of the frame, each a block of its
+own whose index is read from the prefetched row indices: at a frame of
+1,024 rows of `[192, 640]` and 128 heads a call took 1.08 ms on a v5e at
+one row a step, 0.88 at four, 0.85 at eight and at sixteen (553 MB:
+654 GB/s).
+
 VMEM: a row's keys and values twice each (`vmem_bytes`: 6.3 MB of
 blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's, 9.7
-one of Ouro's 48 blocks, 2.1 LFM2's) and the row's small operands.
-`fits` (heads of whole lane tiles) and `fits_paired` (heads of half of
-one) say whether a call stays under `VMEM_LIMIT`; a leaf that neither
-takes, or that is not bfloat16 in whole tiles, takes the model's plain
-path. No `cost_estimate` (ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
+one of Ouro's 48 blocks, 2.1 LFM2's) and the row's small operands; the
+one-table form's rows, `q` and output twice each (`latent_vmem_bytes`).
+`fits` (heads of whole lane tiles), `fits_paired` (heads of half of
+one) and `fits_latent` (one table) say whether a call stays under
+`VMEM_LIMIT`; a leaf that none takes, or that is not bfloat16 in whole
+tiles, takes the model's plain path. No `cost_estimate`
+(ops/expert_kernel.py on why). Parity is pinned by tests/test_pallas.py
 in interpret mode and the compile for a described v5e by
 tests/test_dsv3_tpu_compile.py.
 """
@@ -77,6 +107,7 @@ tests/test_dsv3_tpu_compile.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +116,7 @@ VMEM_LIMIT = 20 << 20     # the most a call may take of VMEM
 HEAD_TILE = 16            # query rows come in whole bfloat16 tiles
 LANES = 128               # a lane tile
 HALF = LANES // 2         # a key-value head of half a lane tile
+LATENT_ROWS = 8           # rows a grid step of the one-table form
 
 
 def _padded(heads: int) -> int:
@@ -133,6 +165,29 @@ def fits_paired(shape: tuple, dtype, heads: int, kv: int,
     `context_rows` reads two to a tile: a context is still whole lane
     tiles."""
     return _takes(shape, dtype, heads, kv, width, lambda d: d == HALF)
+
+
+def latent_vmem_bytes(shape: tuple, heads: int, value_width: int) -> int:
+    """What a one-table call over a table of `shape` holds in VMEM: a
+    grid step's `LATENT_ROWS` rows, their `q` and their output twice each,
+    a row's float32 logits, weights and sums, and room for the
+    compiler's own."""
+    positions, width = shape[1:]
+    hp = _padded(heads)
+    blocks = 2 * 2 * (positions * width + hp * width + hp * value_width)
+    return LATENT_ROWS * blocks + hp * (12 * positions + 4 * value_width) \
+        + (2 << 20)
+
+
+def fits_latent(shape: tuple, dtype, heads: int, value_width: int) -> bool:
+    """Whether `context_rows` takes ONE table of `shape` and `dtype` as
+    both keys and values for `heads` query heads that each read a row's
+    whole width, the values its first `value_width` lanes: bfloat16 in
+    whole `(16, 128)` tiles, a grid step's rows of which VMEM holds."""
+    return (len(shape) == 3 and jnp.dtype(dtype) == jnp.bfloat16
+            and shape[1] % 16 == 0 and shape[2] % LANES == 0
+            and value_width % LANES == 0 and 0 < value_width <= shape[2]
+            and latent_vmem_bytes(shape, heads, value_width) <= VMEM_LIMIT)
 
 
 def _own_lanes(hp: int, width: int, kv: int, group: int) -> jax.Array:
@@ -221,10 +276,87 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("kv", "scale", "interpret"))
-def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
+def _latent_kernel(dev_ref, pos_ref, q_ref, *refs, scratch: int,
+                   scale: float):
+    """A grid step's rows of the one-table form: `refs` are the table's
+    rows, one block each, then the output."""
+    from jax.experimental import pallas as pl
+
+    *rows, o_ref = refs
+    step = pl.program_id(0) * len(rows)
+    hp, values = o_ref.shape[1:]
+    for j, c_ref in enumerate(rows):
+        live = dev_ref[step + j] < scratch
+
+        @pl.when(live)
+        def _():
+            logits = jax.lax.dot_general(
+                q_ref[j], c_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            at = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            logits = jnp.where(at <= pos_ref[step + j], logits, -jnp.inf)
+            e = jnp.exp(logits - jnp.max(logits, axis=1, keepdims=True))
+            probs = (e / jnp.sum(e, axis=1, keepdims=True)).astype(
+                c_ref.dtype)
+            o_ref[j] = jnp.dot(probs, c_ref[0, :, :values],
+                               preferred_element_type=jnp.float32
+                               ).astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[j] = jnp.zeros((hp, values), o_ref.dtype)
+
+
+def _latent_rows(table, dev, pos, q, *, value_width: int, scale: float,
+                 interpret: bool):
+    """`context_rows`' one-table form (its docstring)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    frame, heads, width = q.shape
+    if width != table.shape[-1] or not fits_latent(
+            table.shape, table.dtype, heads, value_width):
+        raise ValueError(f"context_rows takes no table {table.dtype}"
+                         f"{list(table.shape)} for {heads} heads reading "
+                         f"{width} lanes, {value_width} of values")
+    rows, positions = table.shape[:2]
+    scratch = rows - 1
+    hp = _padded(heads)
+    q = jnp.pad(q.astype(table.dtype), ((0, 0), (0, hp - heads), (0, 0)))
+    # a grid step's rows: the frame's next `per` rows, each a block of its
+    # own whose index is read from the prefetched row indices
+    per = math.gcd(frame, LATENT_ROWS)
+
+    def row(j, i, dev, pos):
+        return (jnp.minimum(dev[i * per + j], scratch), 0, 0)
+
+    def frame_rows(i, *_):
+        return (i, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scratch=scratch, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(frame // per,),
+            in_specs=[pl.BlockSpec((per, hp, width), frame_rows)] + [
+                pl.BlockSpec((1, positions, width),
+                             functools.partial(row, j)) for j in range(per)],
+            out_specs=pl.BlockSpec((per, hp, value_width), frame_rows)),
+        out_shape=jax.ShapeDtypeStruct((frame, hp, value_width),
+                                       table.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(
+            latent_vmem_bytes(table.shape, heads, value_width))),
+        name="context_rows",
+        interpret=interpret,
+    )(dev, pos, q, *[table] * per)
+    return out[:, :heads]
+
+
+@functools.partial(jax.jit, static_argnames=("kv", "scale", "interpret",
+                                             "value_width"))
+def context_rows(keys: jax.Array, values: jax.Array | None, dev: jax.Array,
                  pos: jax.Array, q: jax.Array, block=None, own=None, *,
-                 kv: int, scale: float, interpret: bool = False):
+                 kv: int = 1, scale: float, interpret: bool = False,
+                 value_width: int | None = None):
     """Attention of one token a row over rows `dev` `[B]` (ascending
     strictly, padding past the scratch row, which is the tables' last)
     of `keys` and `values` `[rows, P, kv * d]` bfloat16, read where they
@@ -237,10 +369,23 @@ def context_rows(keys: jax.Array, values: jax.Array, dev: jax.Array,
     is not in the tables yet: positions `p < pos` are read from them and
     the entry takes the place of `pos`. -> `[B, heads, d]` float32, a
     padding row's 0. Jitted, so that a step's layers of one shape trace
-    and lower the kernel once between them."""
+    and lower the kernel once between them.
+
+    Handed no `values` and a `value_width`, ONE table `keys` `[rows, P,
+    W]` is both keys and values (`fits_latent`): `q` `[B, heads, W]`, each
+    head reading a row's whole width, and the values are its first
+    `value_width` lanes; positions `p <= pos` are attended to, the
+    row's own entry in the table. -> `[B, heads, value_width]` in the
+    table's dtype, float32 sums rounded once, a padding row's 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if values is None:
+        if block is not None or own is not None or kv != 1:
+            raise ValueError("context_rows reads one table whole, with "
+                             "no block, own entry or key-value heads")
+        return _latent_rows(keys, dev, pos, q, value_width=value_width,
+                            scale=scale, interpret=interpret)
     frame, heads, d = q.shape
     width = kv * d
     if (keys.shape != values.shape or keys.dtype != values.dtype

@@ -21,6 +21,7 @@ from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring import server
 from sitewhere_tpu.scoring.server import ScoringConfig, ScoringSession
 from sitewhere_tpu.scoring.stream import (
+    ContextAtRest,
     StreamingRing,
     pad_rows,
     streaming_step,
@@ -45,8 +46,8 @@ ROUND_OFF = 5e-6          # float32 round-off on scores of about 4
 
 
 def program(**over):
-    return build_model("dsv3-stream", compute_dtype=jnp.float32,
-                       **{**MC, **over})
+    return build_model("dsv3-stream", **{"compute_dtype": jnp.float32,
+                                         **MC, **over})
 
 
 @pytest.fixture(scope="module")
@@ -124,9 +125,17 @@ def test_decode_form_agrees_with_prefill_form(params):
     model = program()
     ok = jnp.ones((D, W), bool)
     state = jax.jit(model.warm_state)(params, jnp.asarray(hist[:, -W:]), ok)
-    _, rows, _ = jax.jit(model.step_score)(params, state,
-                                           jnp.asarray(frames[0]),
-                                           jnp.ones(D, bool))
+
+    def step(params, state, v):
+        # the window leaves as the ring hands them over: the table, the
+        # rows, each row's slot
+        rows = {name: ContextAtRest(leaf, jnp.arange(D), state["pos"])
+                if name in model.at_rest else leaf
+                for name, leaf in state.items()}
+        _, out, _ = model.step_score(params, rows, v, jnp.ones(D, bool))
+        return out, {name: rows[name].table for name in model.at_rest}
+
+    rows, tables = jax.jit(step)(params, state, jnp.asarray(frames[0]))
     # the same W + 1 tokens, all through the prefill form
     tokens, count, _, _ = model._window_tokens(jnp.asarray(hist[:, -W:]), ok)
     mean, var = state["mean"], state["var"]
@@ -134,7 +143,8 @@ def test_decode_form_agrees_with_prefill_form(params):
     longer = jnp.concatenate([tokens, new[:, None]], 1)
     h, entries = jax.jit(model._prefill)(params, longer, count + 1)
     for l, entry in enumerate(entries):
-        assert np.abs(np.asarray(rows[f"ctx{l}"] - entry[:, W])).max() < 2e-6
+        assert np.abs(np.asarray(tables[f"ctx{l}"][:, W]
+                                 - entry[:, W])).max() < 2e-6
         assert np.abs(np.asarray(state[f"ctx{l}"][:, :W]
                                  - entry[:, :W])).max() < 2e-6
     from sitewhere_tpu.models.dsv3 import _rms
@@ -301,6 +311,74 @@ def test_window_leaf_is_appended_in_place(params):
                   "mla_attend", "moe_route", "moe_experts", "dense_mlp",
                   "lm_head"):
         assert scope in text, scope
+
+
+def _parents_step(model):
+    """The ring step as it was before the window leaves were handed over
+    where they rest, written out from the model's pieces: every leaf's
+    rows gathered when the step starts, each layer's attention
+    `_attend_decode` over its gathered context, every layer's entry
+    appended when the step ends, the other leaves scattered back."""
+    from sitewhere_tpu.models.dsv3 import _rms
+    from sitewhere_tpu.scoring.stream import DISTINCT_ROWS, _rows
+
+    c = model.cfg
+
+    def step(params, state, dev, v):
+        live = dev < state["pos"].shape[0] - 1
+        rows = {name: _rows(leaf, dev) for name, leaf in state.items()}
+        pos = rows["pos"]
+        token, score, out = model._arrive(params, rows, v)
+        x = params["embed"][token].astype(jnp.float32)
+        at = jnp.minimum(pos, c.context_positions - 1)
+        cos, sin = jnp.asarray(model._cos)[at], jnp.asarray(model._sin)[at]
+        for l in range(model.layers):
+            p = params[f"layer{l}"]
+            q_nope, q_rope, out[f"ctx{l}"] = model._project(
+                p, _rms(x, p["attn_norm"], c.rms_norm_eps), cos, sin)
+            x = x + model._mm(model._attend_decode(
+                p, q_nope, q_rope, out[f"ctx{l}"], rows[f"ctx{l}"], pos),
+                p["o"])
+            y, _ = model._ffn(p, _rms(x, p["mlp_norm"], c.rms_norm_eps), live)
+            x = x + y
+        out["hn"] = _rms(x, params["norm"], c.rms_norm_eps).astype(
+            c.compute_dtype)
+        return {name: leaf.at[(dev, pos) if name in model.windows else dev]
+                .set(out[name], **DISTINCT_ROWS)
+                for name, leaf in state.items()}, score
+
+    return step
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_plain_path_is_the_parents_step_bit_for_bit(params, dtype):
+    """The window leaves handed over where they rest, on the CPU's path
+    (the rows gathered in the layer's turn, `_attend_decode`, the append):
+    the parent's scores and next state to the bit, in float32 and in
+    bfloat16 products, with padding in the frame; `ctx.at_rest` 0."""
+    hist, frames = readings(ticks=3)
+    model = program(compute_dtype=getattr(jnp, dtype))
+    cap = 20
+    seeded = jax.jit(model.warm_state)(params, jnp.asarray(hist[:, -W:]),
+                                       jnp.ones((D, W), bool))
+    state = jax.tree.map(lambda leaf, rows: leaf.at[3:3 + D].set(rows),
+                         model.init_state(cap + 1), seeded)
+    dev = np.concatenate([np.arange(3, 3 + D, dtype=np.int32),
+                          pad_rows(cap, 16 - D)])
+    step, parents = jax.jit(streaming_step(model)), jax.jit(
+        _parents_step(model))
+    want_state = got_state = state
+    for frame in frames:
+        v = np.zeros(16, np.float32)
+        v[:D] = frame
+        got_state, got = step(params, got_state, dev, v)
+        want_state, want = parents(params, want_state, dev, v)
+        assert (np.asarray(got[:16]) == np.asarray(want)).all()
+        assert model.step_stats[-1] == "ctx.at_rest" and float(got[-1]) == 0
+        for name, leaf in want_state.items():
+            assert (np.asarray(got_state[name]) == np.asarray(leaf)).all(), \
+                name
+    assert np.abs(np.asarray(want[:D])).max() > 1.0
 
 
 def test_lstm_stream_lowers_to_the_same_program_as_before():

@@ -2,7 +2,10 @@
 and not attached (the TPU's compiler is installed here), at the published
 widths with one leading and one expert layer and a small fleet: the
 compiled step copies and transposes no context leaf, which rests
-row-major in whole lane tiles; the ring step of `laguna-stream` at its
+row-major in whole lane tiles, and reads it where it rests (one
+`context_rows` call a layer over the table as both keys and values
+behind the append of the position's own entries, no gathered rows); the
+ring step of `laguna-stream` at its
 benchmark configuration's own size (five layers, 769 rows of 12 MiB),
 held to the same and to the chip's memory; the ring step of
 `olmo-hybrid-stream` at its configuration's own size (four layers, 769
@@ -87,21 +90,57 @@ def step(one_chip):
 
 
 def test_compiled_step_moves_no_context_leaf(step):
+    """The latent context (`bf16[1025, 192, 640]` a layer: 576 values in
+    640 lanes) is neither copied nor transposed, and is not gathered at
+    all: each layer's attention is ONE `context_rows` call that takes the
+    layer's table, as keys and as values, behind the append of the
+    position's own entries (ops/context_kernel.py's one-table form), and
+    hands back the weighted latents `[256, 128, 512]` in bfloat16; a
+    table is a parameter or the append's in-place scatter, nothing of a
+    frame's gathered rows `[256, 192, 640]` exists, and the donated state
+    comes back in its own buffers."""
     from chip_smoke import _table_moves
+    from sitewhere_tpu.ops import context_kernel
 
     model, state, compiled = step
     hlo = compiled.as_text()
+    lines = hlo.splitlines()
     assert _table_moves(hlo, ROWS) == []
     width = model.cfg.entry_width
     assert width == 640
-    layouts = set(re.findall(
-        rf"bf16\[{ROWS},192,{width}\]\{{([\d,]+)", hlo))
+    table = f"bf16[{ROWS},192,{width}]"
+    assert context_kernel.fits_latent((ROWS, 192, width), jnp.bfloat16, 128,
+                                      512)
+    layouts = set(re.findall(re.escape(table) + r"\{([\d,]+)", hlo))
     assert layouts == {"2,1,0"}
+    calls = [line for line in lines if "tpu_custom_call" in line
+             and "context_rows" in line]
+    assert len(calls) == model.layers == 2
+    # (the table is handed over once for each row of a grid step, the
+    # same buffer each time)
+    assert all(line.count(table) == context_kernel.LATENT_ROWS and re.search(
+        rf"= bf16\[{BUCKET},128,512\]\S* custom-call\(", line)
+        and "output_to_operand_aliasing" not in line
+        and "mla_attend" in line for line in calls), calls
+    # what makes a value of a table's or a frame's rows' shape: the
+    # parameter, the append (a scatter, fused in place) and nothing else
+    made = {m for line in lines for m in re.findall(
+        r"= bf16\[\d+,192,640\]\S* ([\w-]+)\(", line)}
+    assert made == {"parameter", "scatter", "fusion"}, made
+    assert all("ctx_append" in line for line in lines if re.search(
+        r"= bf16\[\d+,192,640\]\S* (?:fusion|scatter)\(", line))
+    assert not re.search(rf"\[{BUCKET},192,640\]", hlo)
+    assert not [line for line in lines if " gather(" in line
+                and table in line]
     mem = compiled.memory_analysis()
     # the donated state comes back in its own buffers
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(state))
     assert mem.alias_size_in_bytes >= state_bytes
+    # the scratch was the gathered contexts': `temp_size_in_bytes` read
+    # 122,687,488 while each layer's rows were gathered, and reads
+    # 51,384,832 here
+    assert mem.temp_size_in_bytes < 0.07e9
 
 
 def _computations(hlo: str) -> tuple[dict, str]:

@@ -447,6 +447,8 @@ CONTEXT_CASES = {
     "half_tile_heads_32_on_8": (64, 32, 8, 32, False, [1, 16, 30], 1),
     # a wrapping context of such heads, before and after it has wrapped
     "half_tile_heads_wrapping": (64, 32, 2, 4, True, [5, 31, 32, 77], 1),
+    # `laguna-stream`'s full layer at its own widths, 48 query heads on 8
+    "laguna_48_heads_on_8": (128, 32, 8, 48, False, [0, 17, 31], 1),
 }
 
 
@@ -653,6 +655,126 @@ def test_context_kernel_takes_bfloat16_rows_of_whole_tiles_that_vmem_holds():
             jnp.zeros((2, 4, 128)), kv=2, scale=1.0, interpret=True)
 
 
+# (each live row's position, padding rows): `dsv3-stream`'s latent
+# context at the published widths, ONE table of 192 positions a row, 512
+# lanes of values then 64 of the rope part then zeros, which every one of
+# 128 query heads reads whole
+LATENT_CASES = {
+    # a frame of eight: one grid step of `LATENT_ROWS`, padding among them
+    "the_first_a_middle_and_the_last_position": ([0, 95, 191], 5),
+    "a_frame_of_one_live_row": ([140], 3),
+}
+
+
+def _published_mla():
+    from sitewhere_tpu.models import build_model
+
+    return build_model("dsv3-stream", num_hidden_layers=1, mtp_modules=0)
+
+
+@pytest.mark.parametrize("case", LATENT_CASES)
+def test_the_latent_context_is_read_once_where_it_rests_interpret(
+        case, monkeypatch):
+    """ops/context_kernel.py's one-table form through
+    `Dsv3StreamModel._attend_at_rest` against the plain path (the ring's
+    gather, `_attend_decode`, the append) at the published widths: the
+    heads' outputs to the order of float32 sums (bfloat16 operands on
+    both sides, the weighted latents rounded to bfloat16 once on both
+    sides, where the next product casts them), a padding row's 0, at one
+    grid step of eight rows and at a frame of four; the table bit-equal
+    to the plain path's, which differs from what it was by the appended
+    entries alone, the scratch row untouched; and the kernel's branch
+    counts the live rows it read where the plain one counts 0."""
+    from sitewhere_tpu.ops import context_kernel
+    from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
+
+    model = _published_mla()
+    c = model.cfg
+    assert (c.num_attention_heads, c.kv_lora_rank, c.entry_width) == (
+        128, 512, 640)
+    at, padding = LATENT_CASES[case]
+    rows, live = 7, len(at)
+    scratch = rows - 1
+    frame = live + padding
+    keys = iter(jax.random.split(jax.random.PRNGKey(live), 6))
+    stored = jnp.arange(c.entry_width) < c.latent_width
+    table = jnp.where(stored, jax.random.normal(
+        next(keys), (rows, c.context_positions, c.entry_width)),
+        0.0).astype(jnp.bfloat16)
+    q_nope = jax.random.normal(next(keys), (frame, 128, 128))
+    q_rope = jax.random.normal(next(keys), (frame, 128, 64))
+    entry = jnp.where(stored, jax.random.normal(
+        next(keys), (frame, c.entry_width)), 0.0).astype(jnp.bfloat16)
+    p = {"kv_b": (0.05 * jax.random.normal(
+        next(keys), (512, 128 * 256))).astype(jnp.bfloat16)}
+    dev = jnp.asarray(np.concatenate([
+        np.sort(np.random.default_rng(live).permutation(scratch)[:live]),
+        pad_rows(scratch, padding)]), jnp.int32)
+    pos = jnp.asarray(at + [0] * padding, jnp.int32)
+    assert context_kernel.fits_latent(table.shape, table.dtype, 128, 512)
+
+    def attend(table):
+        ctx = ContextAtRest(table, dev, pos)
+        out = model._attend_at_rest(p, q_nope, q_rope, entry, ctx, pos)
+        return out, ctx.table, ctx.read_rows
+
+    # (a jit of its own each: the second trace takes the other branch)
+    want, want_table, plain_rows = jax.jit(lambda t: attend(t))(table)
+    _context_interpreted(monkeypatch)
+    got, got_table, read_rows = jax.jit(lambda t: attend(t))(table)
+    assert int(plain_rows) == 0 and int(read_rows) == live
+    scale = float(jnp.abs(want[:live]).max())
+    assert 0.3 < scale < 10
+    # a float32 sum in another order may round a weighted latent to the
+    # neighbouring bfloat16 (2^-8 of it, times a value weight of about
+    # 0.05): 6.4e-5 of the scale read here, at eight rows a grid step
+    assert float(jnp.abs(got - want)[:live].max()) < 2e-3 * scale
+    assert not np.asarray(got)[live:].any()
+    assert (np.asarray(got_table) == np.asarray(want_table)).all()
+    appended = np.asarray(table.at[dev[:live], pos[:live]].set(entry[:live]))
+    assert (np.asarray(got_table) == appended).all()
+    assert (np.asarray(got_table)[scratch] == np.asarray(table)[scratch]).all()
+
+
+def test_the_one_table_form_takes_bfloat16_tables_of_whole_tiles():
+    """`fits_latent` reads the table's shape and dtype, the heads and the
+    value width: `deepseek-v3-ep16`'s five tables (4,097 rows) in about
+    11.3 MB of VMEM, eight rows a grid step; not a float32 table,
+    positions that are no whole sublane tile, a row or a value width that
+    is no whole lane tiles, values wider than the row, nor a row whose
+    blocks VMEM cannot hold; and `context_rows` refuses a table it does
+    not take, or one handed with a block, an own entry or key-value
+    heads."""
+    from sitewhere_tpu.ops import context_kernel
+
+    fits = context_kernel.fits_latent
+    assert fits((4097, 192, 640), jnp.bfloat16, 128, 512)
+    assert context_kernel.LATENT_ROWS == 8
+    assert 11.0e6 < context_kernel.latent_vmem_bytes((4097, 192, 640), 128,
+                                                     512) < 11.6e6
+    assert not fits((4097, 192, 640), jnp.float32, 128, 512)
+    assert not fits((4097, 200, 640), jnp.bfloat16, 128, 512)
+    assert not fits((4097, 192, 576), jnp.bfloat16, 128, 512)
+    assert not fits((4097, 192, 640), jnp.bfloat16, 128, 576)
+    assert not fits((4097, 192, 640), jnp.bfloat16, 128, 768)
+    assert not fits((4097, 8192, 640), jnp.bfloat16, 128, 512)
+    assert not fits((4097, 192, 5, 128), jnp.bfloat16, 128, 512)
+    table = jnp.zeros((3, 32, 256), jnp.bfloat16)
+    dev, pos = jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32)
+    with pytest.raises(ValueError, match="takes no table"):
+        context_kernel.context_rows(table.astype(jnp.float32), None, dev,
+                                    pos, jnp.zeros((2, 4, 256)), scale=1.0,
+                                    value_width=128, interpret=True)
+    with pytest.raises(ValueError, match="takes no table"):
+        context_kernel.context_rows(table, None, dev, pos,
+                                    jnp.zeros((2, 4, 128)), scale=1.0,
+                                    value_width=128, interpret=True)
+    with pytest.raises(ValueError, match="reads one table whole"):
+        context_kernel.context_rows(table, None, dev, pos,
+                                    jnp.zeros((2, 4, 256)), 0, scale=1.0,
+                                    value_width=128, interpret=True)
+
+
 def _laguna_of_128_wide_heads():
     from sitewhere_tpu.models import build_model
 
@@ -701,18 +823,33 @@ def _lfm2_of_64_wide_heads():
         context_positions=32), 2
 
 
+def _dsv3_of_a_128_wide_latent():
+    from sitewhere_tpu.models import build_model
+
+    return build_model(
+        "dsv3-stream", hidden_size=256, intermediate_size=256,
+        moe_intermediate_size=64, num_hidden_layers=2,
+        first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=64,
+        kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=32,
+        v_head_dim=32, n_routed_experts=8, n_group=2, topk_group=1,
+        num_experts_per_tok=2, vocab_size=64, mtp_modules=0, window=16,
+        context_positions=32), 2
+
+
 @pytest.mark.parametrize("build", [_laguna_of_128_wide_heads,
                                    _olmo_of_128_wide_heads,
                                    _ouro_of_128_wide_heads,
-                                   _lfm2_of_64_wide_heads],
+                                   _lfm2_of_64_wide_heads,
+                                   _dsv3_of_a_128_wide_latent],
                          ids=["laguna-stream", "olmo-hybrid-stream",
-                              "ouro-stream", "lfm2-stream"])
+                              "ouro-stream", "lfm2-stream", "dsv3-stream"])
 def test_the_step_that_reads_contexts_at_rest_is_the_plain_step(
         build, monkeypatch):
     """The whole ring step of the models that read a context at rest,
     with the TPU's branch taken (the kernels in interpret mode) against
     the step as the CPU lowers it, in bfloat16 at heads of 128 (and of
-    64, two to a lane tile, in `lfm2-stream`'s): scores,
+    64, two to a lane tile, in `lfm2-stream`'s; one latent table of 256
+    lanes that is both keys and values, in `dsv3-stream`'s): scores,
     every state leaf and the step's other numbers agree (both sides make
     the same bfloat16 products and sum them in float32, in another
     order), `ctx.at_rest` counts the live rows of every layer that
