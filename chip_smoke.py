@@ -394,14 +394,14 @@ async def phase_server(ph: Phase, expect_platform: str,
         consumer = rt.bus.subscribe(
             rt.naming.tenant_topic(tid, "scored-events"), group="chip-smoke")
         seen = {"events": 0, "versions": set()}
-        scored0 = session.latency.count
+        scored0 = session.flights.latency.count
         warm_mark = ph.compiles()
         proc = await _feed(rt, [tid], devices, sizes)
         want = devices * sizes.ticks
 
         def progress() -> int:
             _drain_topic(consumer, seen)
-            return min(session.latency.count - scored0, seen["events"])
+            return min(session.flights.latency.count - scored0, seen["events"])
 
         try:
             await _wait_stream(progress, want, proc)
@@ -411,7 +411,7 @@ async def phase_server(ph: Phase, expect_platform: str,
         await asyncio.sleep(0.25)       # anything late would be a duplicate
         _drain_topic(consumer, seen)
         consumer.close()
-        scored = session.latency.count - scored0
+        scored = session.flights.latency.count - scored0
         alerts = len(rt.api("event-management").management(tid).alerts)
         ph.out.update(sent=sent, scored=scored, published=seen["events"],
                       alerts=alerts, feeder_exit=rc,
@@ -670,14 +670,14 @@ async def phase_context(ph: Phase, expect_platform: str, sizes: Sizes,
         consumer = rt.bus.subscribe(
             rt.naming.tenant_topic(tid, "scored-events"), group="chip-smoke")
         seen = {"events": 0, "versions": set()}
-        scored0 = session.latency.count
+        scored0 = session.flights.latency.count
         warm_mark = ph.compiles()
         proc = await _feed(rt, [tid], devices, sizes)
         want = devices * sizes.ticks
 
         def progress() -> int:
             _drain_topic(consumer, seen)
-            return min(session.latency.count - scored0, seen["events"])
+            return min(session.flights.latency.count - scored0, seen["events"])
 
         try:
             await _wait_stream(progress, want, proc)
@@ -687,7 +687,7 @@ async def phase_context(ph: Phase, expect_platform: str, sizes: Sizes,
         await asyncio.sleep(0.25)
         _drain_topic(consumer, seen)
         consumer.close()
-        scored = session.latency.count - scored0
+        scored = session.flights.latency.count - scored0
         held = int(rt.metrics.counter("scoring.moe.assignments_held").value)
         stats = jax.local_devices()[0].memory_stats() or {}
         ph.out.update(sent=sent, scored=scored, published=seen["events"],

@@ -48,6 +48,7 @@ TRACE_MODULES = frozenset({
     "sitewhere_tpu/kernel/dlq.py",
     "sitewhere_tpu/scoring/server.py",
     "sitewhere_tpu/scoring/pool.py",
+    "sitewhere_tpu/scoring/settle.py",
     "sitewhere_tpu/rest/api.py",
     # fleet observability: the beat's telemetry export publishes on the
     # same path it records its fleet.telemetry span

@@ -186,7 +186,7 @@ COUNTERS = (
     "scoring.moe.runs_one_tile",
     # bytes of held experts' leaves a dispatch's step streams: expert
     # layers x held experts x an expert's three leaves, off the model's
-    # `param_shapes` (scoring/server.py), whatever a frame routes
+    # `param_shapes` (models/seqblocks.py), whatever a frame routes
     "scoring.moe.weight_bytes",
     "scoring.ctx.reseeds",
     "scoring.ctx.wrapped",

@@ -90,9 +90,8 @@ logger = logging.getLogger(__name__)
 
 async def deliver_scored(sink, scored, sink_failures, stage_sink,
                          label: str = "") -> None:
-    """One settled `ScoredBatch` into a scoring sink, under the ONE
-    delivery contract every settle path shares (the dedicated session's
-    per-flush settle AND the pool's per-tenant megabatch fan-out):
+    """One settled `ScoredBatch` into a scoring sink (the settle both
+    scoring engines share, scoring/settle.py):
 
     - a sink failure is counted (`scoring.sink_failures`) and isolated —
       it can never kill the settle task or, in a megabatch, another
@@ -102,9 +101,9 @@ async def deliver_scored(sink, scored, sink_failures, stage_sink,
       the fused EgressStage observes submit → PUBLISHED on its shard
       loops, and timing the enqueue would record ~0 and hide the tail).
 
-    The pool gathers one of these per tenant of a settled megabatch, so
-    a slow sink for one tenant never serializes the other tenants'
-    deliveries behind it."""
+    A settle gathers one of these per tenant of its dispatch, so a slow
+    sink for one tenant never serializes the other tenants' deliveries
+    behind it."""
     t_sink = time.monotonic()
     try:
         await sink(scored)

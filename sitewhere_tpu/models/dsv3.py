@@ -190,6 +190,7 @@ class Dsv3StreamModel(SeqBlocks):
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
                   "moe.runs_one_tile", "ctx.at_rest")
+    stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats)
 
     def __init__(self, cfg: Dsv3Config = Dsv3Config()):
         for key, want in (("scoring_func", "sigmoid"), ("hidden_act", "silu"),

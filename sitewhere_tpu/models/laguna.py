@@ -166,6 +166,7 @@ class LagunaStreamModel(SeqBlocks):
                   "moe.expert_max_tokens", "ctx.positions",
                   "moe.runs_one_tile", "ctx.window_positions",
                   "ctx.wrapped", "ctx.at_rest")
+    stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats)
 
     def __init__(self, cfg: LagunaConfig = LagunaConfig()):
         n = cfg.num_hidden_layers

@@ -165,6 +165,7 @@ class Lfm2StreamModel(SeqBlocks):
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
                   "moe.runs_one_tile", "ctx.at_rest")
+    stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats)
 
     def __init__(self, cfg: Lfm2Config = Lfm2Config()):
         n = cfg.num_hidden_layers

@@ -145,6 +145,7 @@ class OlmoHybridStreamModel(SeqBlocks):
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("ctx.positions", "state.decay", "state.absmax",
                   "state.in_place", "ctx.at_rest")
+    stat_families = (SeqBlocks.context_stats, SeqBlocks.state_stats)
 
     def __init__(self, cfg: OlmoHybridConfig = OlmoHybridConfig()):
         n = cfg.num_hidden_layers

@@ -143,6 +143,7 @@ class OuroStreamModel(SeqBlocks):
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("ctx.positions", "ctx.at_rest", "loop.weight_bytes",
                   "ctx.attended_bytes")
+    stat_families = (SeqBlocks.context_stats, SeqBlocks.loop_stats)
 
     def __init__(self, cfg: OuroConfig = OuroConfig()):
         n = cfg.num_hidden_layers

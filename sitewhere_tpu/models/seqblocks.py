@@ -23,8 +23,8 @@ dropped) and leaves out what the absent experts would add.
 A model mixes `SeqBlocks` in, sets `self.cfg` (with `compute_dtype`,
 `window`, `score_clip`, `vocab`, `hidden_size`, `rms_norm_eps`),
 `self.experts` (an `Experts`, where it has an expert layer),
-`self.seed_rows` and `self._gate`, and brings `param_shapes`,
-`init_state` and `_prefill`. Imports nothing
+`self.seed_rows` and `self._gate`, `step_stats` and `stat_families`, and
+brings `param_shapes`, `init_state` and `_prefill`. Imports nothing
 beyond JAX at import time: the expert kernel imports Pallas when it is
 first traced.
 """
@@ -61,6 +61,18 @@ class Experts:
     groups_kept: int = 1     # `groups`, of which the best are kept
     normed: bool = True      # the kept weights are divided by their sum
     sum_eps: float = 0.0     # ...plus this, where the published rule adds it
+
+
+def held_expert_bytes(model) -> int:
+    """Bytes of the held experts' leaves over a model's expert layers
+    (`param_shapes()`: a layer's `experts`, a leaf a projection an
+    expert), which a step streams once whatever a frame routes; 0 for a
+    model without them."""
+    shapes = getattr(model, "param_shapes", dict)()
+    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for block in shapes.values() if isinstance(block, dict)
+               for expert in block.get("experts", {}).values()
+               for shape, dtype in expert.values())
 
 
 def rms(x, w, eps):
@@ -119,6 +131,9 @@ def runs_one_tile(counts, tile=EXPERT_TILE):
     return (counts <= tile).sum()
 
 
+OCTAVES = [2.0 ** (i / 4) for i in range(53)]      # 1 to 8,192
+
+
 def precision(cdt):
     return (jax.lax.Precision.HIGHEST if jnp.dtype(cdt) == jnp.float32
             else None)
@@ -131,6 +146,83 @@ def normal(key, shape, dtype, std):
 
 class SeqBlocks:
     """The pieces, as methods of the model that mixes them in."""
+
+    # -- what a step says of itself: the numbers it returns beside the
+    # scores (`step_stats`), each declared with its metric's full name,
+    # kind and buckets by one of the families a model names
+    # (`stat_families`); a family maps a number to what registers its
+    # metric and returns the feed
+
+    step_stats: tuple = ()
+    stat_families: tuple = ()
+
+    def stat_feeds(self, metrics) -> tuple[list, list]:
+        """The serving engine's feeds, registered on `metrics`: one a
+        number of `step_stats`, in its order, and those called once a
+        dispatch (the bytes of held experts' leaves a step streams)."""
+        declared = {}
+        for family in self.stat_families:
+            declared.update(family(metrics))
+        per_step = [declared[name]() for name in self.step_stats]
+        held = held_expert_bytes(self)
+        return per_step, [functools.partial(metrics.counter(
+            "scoring.moe.weight_bytes").inc, held)] if held else []
+
+    @staticmethod
+    def expert_stats(metrics) -> dict:
+        """An expert layer's (`Experts`): pairs held, pairs routed, the
+        most tokens one held expert took, runs served in one tile."""
+        return {
+            "moe.assignments_held": lambda: metrics.counter(
+                "scoring.moe.assignments_held").inc,
+            "moe.assignments": lambda: metrics.counter(
+                "scoring.moe.assignments").inc,
+            "moe.expert_max_tokens": lambda: metrics.histogram(
+                "scoring.moe.expert_max_tokens", buckets=OCTAVES).observe,
+            "moe.runs_one_tile": lambda: metrics.counter(
+                "scoring.moe.runs_one_tile").inc}
+
+    @staticmethod
+    def context_stats(metrics) -> dict:
+        """Attention over stored contexts: the mean attended length over
+        the bounded window leaves and over those that wrap, live rows
+        whose append overwrote a wrapping leaf's oldest position, live
+        rows whose context the step's kernel read where it rested (0 on
+        the plain path), bytes of keys and values a looped model read."""
+        return {
+            "ctx.positions": lambda: metrics.histogram(
+                "scoring.ctx.positions", buckets=OCTAVES).observe,
+            "ctx.window_positions": lambda: metrics.histogram(
+                "scoring.ctx.window_positions", buckets=OCTAVES).observe,
+            "ctx.wrapped": lambda: metrics.counter(
+                "scoring.ctx.wrapped").inc,
+            "ctx.at_rest": lambda: metrics.counter(
+                "scoring.ctx.at_rest_rows").inc,
+            "ctx.attended_bytes": lambda: metrics.counter(
+                "scoring.ctx.attended_bytes").inc}
+
+    @staticmethod
+    def state_stats(metrics) -> dict:
+        """A recurrent matrix state's (models/olmo_hybrid.py): the mean
+        decay a step applied, in (0, 1), the largest magnitude in the
+        rows it read, and live rows whose state its kernel updated where
+        it rested (0 on the plain path)."""
+        return {
+            "state.decay": lambda: metrics.histogram(
+                "scoring.state.decay",
+                buckets=[i / 64 for i in range(1, 65)]).observe,
+            "state.absmax": lambda: metrics.histogram(
+                "scoring.state.absmax",
+                buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe,
+            "state.in_place": lambda: metrics.counter(
+                "scoring.state.in_place_rows").inc}
+
+    @staticmethod
+    def loop_stats(metrics) -> dict:
+        """A looped model's (models/ouro.py): bytes of layer weights its
+        passes stream a step, passes x layers x a layer's, from shapes."""
+        return {"loop.weight_bytes": lambda: metrics.counter(
+            "scoring.loop.weight_bytes").inc}
 
     def _mm(self, x, w):
         cdt = self.cfg.compute_dtype

@@ -14,10 +14,9 @@ operating point [SURVEY.md §7 hard part b]:
   with one admission deadline drains them together;
 - each flush uploads only `[T_cap, B]` (device id, value) deltas, runs
   ONE vmapped append+gather+score call, and settles the result off-loop
-  (the same pipelined-settle design as the dedicated session: host
-  syncs are round-trip-priced, so they run in threads and never block
-  dispatch), then fans results back out to each tenant's deliver
-  callback.
+  through the host side both engines share (scoring/settle.py: the
+  occurrence split, the settle threads, score placement, the flight
+  book), then fans results back out to each tenant's deliver callback.
 
 The pool is keyed by (model name, model config): tenants selecting the
 same architecture share a stack regardless of their thresholds (applied
@@ -41,16 +40,16 @@ tenant register/unregister replace the stacked pytree (never modify it
 snapshots per-tenant versions at dispatch, so an in-flight megabatch
 never observes a torn stack and every settled batch is attributed to
 the weights that scored it (`TenantStack.fence` counts the mutations
-the fence tests pin). The
-settled result fans back out through the per-slot deliver path
-(`kernel/egresslane.deliver_scored`, concurrently per tenant), so
-at-least-once commit discipline, alert emission, and the fused egress
-stage are untouched by the aggregation upstream.
+the fence tests pin). The settled result fans back out to every
+tenant's deliver callback concurrently, so at-least-once commit
+discipline, alert emission, and the fused egress stage are untouched by
+the aggregation upstream.
 """
 
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import time
 from dataclasses import dataclass, field
@@ -59,13 +58,19 @@ from typing import Awaitable, Callable, Optional
 import numpy as np
 
 from sitewhere_tpu.domain.batch import BatchContext, MeasurementBatch, ScoredBatch
-from sitewhere_tpu.kernel.egresslane import deliver_scored
 from sitewhere_tpu.kernel.metrics import MetricsRegistry
 from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.parallel.tenant_stack import TenantStack
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.ring import StackedDeviceRing
-from sitewhere_tpu.scoring.settle import SETTLE_POOL, DeviceStage, to_host
+from sitewhere_tpu.scoring.settle import (
+    SETTLE_POOL,
+    Flights,
+    booked,
+    bucket_for,
+    merged_take,
+    occurrence_rounds,
+)
 from sitewhere_tpu.utils.retry import retry_backoff
 
 logger = logging.getLogger(__name__)
@@ -155,15 +160,7 @@ class TenantSlot:
     def __init__(self, pool: "SharedScoringPool", tenant_id: str):
         self.pool = pool
         self.tenant_id = tenant_id
-        self.scored_meter = pool.scored_meter
-        self.latency = pool.latency
-        # stage decomposition is POOL-wide (all tenants share one flusher
-        # and one histogram set), exposed per-slot so pooled and
-        # dedicated sinks present the same surface to the bench
-        self.stage_admit = pool.stage_admit
-        self.stage_batch = pool.stage_batch
-        self.stage_device = pool.stage_device
-        self.stage_sink = pool.stage_sink
+        self.flights = pool.flights
 
     @property
     def ready(self) -> bool:
@@ -202,17 +199,9 @@ class TenantSlot:
         entry = self.pool.tenants.get(self.tenant_id)
         return entry.inflight if entry is not None else 0
 
-    @property
-    def dispatch_count(self) -> int:
-        return self.pool.dispatch_count
-
-    @property
-    def settled_count(self) -> int:
-        return self.pool.settled_count
-
-    @property
-    def settled_through(self) -> int:
-        return self.pool.settled_through
+    dispatch_count = booked("dispatch_count")
+    settled_count = booked("settled_count")
+    settled_through = booked("settled_through")
 
     @property
     def idle(self) -> bool:
@@ -257,6 +246,12 @@ class SharedScoringPool:
     """One stack + one ring + one flusher for every tenant of one model
     architecture."""
 
+    # the flight book's counts, which the consumer's commit barrier reads
+    inflight = booked("inflight")
+    dispatch_count = booked("dispatch_count")
+    settled_count = booked("settled_count")
+    settled_through = booked("settled_through")
+
     def __init__(self, model, metrics: MetricsRegistry,
                  cfg: PoolConfig = PoolConfig(), mesh=None, tracer=None,
                  faults=None):
@@ -280,37 +275,19 @@ class SharedScoringPool:
         # newest warm-up failure, None once a pass succeeds (see
         # ScoringSession.warmup_error)
         self.warmup_error: Optional[Exception] = None
-        self.inflight = 0
-        self.dispatch_count = 0
-        self.settled_count = 0
-        self._outstanding: set[int] = set()   # dispatched, not yet settled
-        # strong refs to in-flight settle tasks: the loop keeps only
-        # weak ones, and a GC'd settle leaves `inflight`/`_outstanding`
-        # permanently stuck — the megabatch round never completes again
-        self._settle_tasks: set = set()
+        self.flights = Flights(metrics, self.tracer)
         self._pending_max = -1     # highest device index waiting
         self._wake = asyncio.Event()
         self._deadline: Optional[float] = None
         self._flusher: Optional[asyncio.Task] = None
         self._warmup: Optional[asyncio.Task] = None
         self._warmed_key: tuple = ()
-        self.scored_meter = metrics.meter("scoring.events_scored")
-        self.latency = metrics.histogram("scoring.e2e_latency_s")
-        self.anomalies = metrics.counter("scoring.anomalies_detected")
-        self.anomaly_overflow = metrics.counter("scoring.anomaly_overflow")
         self.flush_rounds = metrics.counter("scoring.pool_flush_rounds")
-        self.dropped = metrics.counter("scoring.admissions_dropped")
-        self.sink_failures = metrics.counter("scoring.sink_failures")
-        # megabatch observability: `scoring.dispatches` is the SAME
-        # registry counter the dedicated session incs (instance-wide jit
-        # dispatch rate, the A/B's denominator); megabatch_dispatches
-        # counts only stacked dispatches; tenants_per_dispatch shows how
-        # much cross-tenant aggregation each flush round achieved;
+        # megabatch observability: megabatch_dispatches counts only
+        # stacked dispatches; tenants_per_dispatch shows how much
+        # cross-tenant aggregation each flush round achieved;
         # stack_rebuilds surfaces capacity growths (each = a recompile
         # round behind the warmup gate)
-        self.dispatches = metrics.counter("scoring.dispatches")
-        # dispatches whose every take arrived ascending: no host sort
-        self.ascending = metrics.counter("scoring.ring.ascending")
         self.megabatch_dispatches = metrics.counter(
             "scoring.megabatch_dispatches")
         self.megabatch_tenants = metrics.histogram(
@@ -318,19 +295,10 @@ class SharedScoringPool:
             buckets=[1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0])
         self.stack_rebuilds = metrics.counter("scoring.stack_rebuilds")
         self._rebuilds_seen = 0
-        # latency decomposition, pool-wide (same stage semantics as
-        # ScoringSession: admit → batch → device → sink, the device
-        # stage in the same three parts)
-        self.stage_admit = metrics.histogram("scoring.stage_admit_s")
-        self.stage_batch = metrics.histogram("scoring.stage_batch_s")
-        self.device_stage = DeviceStage(metrics, self.tracer)
-        self.stage_device = self.device_stage.total
-        self.stage_sink = metrics.histogram("scoring.stage_sink_s")
         # mesh-sharded serving observability: how many devices the
         # stacked dispatch actually spans (0 = single-device), plus the
         # adaptive-window state — the live close deadline and how many
-        # times the tuner moved it (the A/B artifact's auto-tuner
-        # decision count)
+        # times the tuner moved it
         # per-pool suffix (one pool per model architecture; a shared
         # base name would be last-writer-wins with several pools)
         self.mesh_gauge = metrics.gauge(
@@ -364,10 +332,6 @@ class SharedScoringPool:
         self._packed_sum = 0.0
         self._rounds_since_adjust = 0
 
-    @property
-    def settled_through(self) -> int:
-        """Commit barrier: every dispatch with seq < this has settled."""
-        return min(self._outstanding) if self._outstanding else self.dispatch_count
 
     # -- per-device mesh telemetry ------------------------------------------
 
@@ -502,7 +466,7 @@ class SharedScoringPool:
             self.ring.clear_tenant(slot)
         self.stack.remove_tenant(tenant_id)
         if entry is not None and entry.pending_n:
-            self.dropped.inc(entry.pending_n)
+            self.flights.dropped.inc(entry.pending_n)
 
     def _ensure_started(self) -> None:
         if self._flusher is None or self._flusher.done():
@@ -573,17 +537,7 @@ class SharedScoringPool:
 
     def admit(self, tenant_id: str, batch: MeasurementBatch) -> None:
         entry = self.tenants[tenant_id]
-        if self.faults is not None:
-            # sync check (admit has no loop to block): a raised fault
-            # propagates to the admitting consumer's per-record
-            # quarantine — the record dead-letters with provenance and
-            # nothing was taken yet, so nothing is lost
-            self.faults.check("scoring.megabatch")
-            if self.mesh is not None:
-                # the mesh-sharded dispatch's own chaos seam: same
-                # quarantine contract, armed only when scoring actually
-                # rides a device mesh
-                self.faults.check("scoring.mesh")
+        self._check_faults()
         mask = batch.mtype == self.cfg.mtype
         if mask.all():
             dev, val, ts = batch.device_index, batch.value, batch.ts
@@ -592,8 +546,8 @@ class SharedScoringPool:
                             batch.ts[mask])
         if dev.shape[0] == 0:
             return
-        now = time.monotonic()
-        self.stage_admit.observe(now - batch.ctx.ingest_monotonic)
+        self.flights.stage_admit.observe(
+            time.monotonic() - batch.ctx.ingest_monotonic)
         if self.cfg.window_auto and not entry.internal:
             # window tuner: live CUSTOMER traffic (guarded — with the
             # tuner off _tune_window never reaches its periodic clear,
@@ -601,16 +555,7 @@ class SharedScoringPool:
             # internal slots like tenant-0 admit on their own cadence
             # and must not count as aggregatable load)
             self._tuner_tenants.add(tenant_id)
-        ingest = np.full(dev.shape[0], batch.ctx.ingest_monotonic)
-        entry.pending.append((dev, val, ts, ingest, batch.ctx, now))
-        entry.pending_n += dev.shape[0]
-        if dev.shape[0]:
-            self._pending_max = max(self._pending_max, int(dev.max()))
-        if self._deadline is None:
-            # the LIVE window (adaptive when cfg.window_auto): the
-            # tuner floats it above the configured floor, never below
-            self._deadline = time.monotonic() + self._window_s
-        self._wake.set()
+        self._queue(entry, dev, val, ts, batch.ctx)
 
     def admit_columns(self, tenant_id: str, device_index: np.ndarray,
                       value: np.ndarray, ts: np.ndarray,
@@ -625,22 +570,36 @@ class SharedScoringPool:
         (replay slots register internal, like tenant-0). Internal-only
         contract: live ingress keeps going through admit()."""
         entry = self.tenants[tenant_id]
+        self._check_faults()
+        if device_index.shape[0]:
+            self._queue(entry, device_index, value, ts, ctx)
+
+    def _check_faults(self) -> None:
         if self.faults is not None:
-            # same chaos seams as admit(): a raised fault surfaces in
-            # the replay driver before the block is taken
+            # sync check (admit has no loop to block): a raised fault
+            # propagates to the admitting consumer's per-record
+            # quarantine (or the replay driver) before anything is
+            # taken — the record dead-letters with provenance and
+            # nothing is lost
             self.faults.check("scoring.megabatch")
             if self.mesh is not None:
+                # the mesh-sharded dispatch's own chaos seam: same
+                # quarantine contract, armed only when scoring actually
+                # rides a device mesh
                 self.faults.check("scoring.mesh")
-        n = device_index.shape[0]
-        if n == 0:
-            return
+
+    def _queue(self, entry: _TenantEntry, dev: np.ndarray, val: np.ndarray,
+               ts: np.ndarray, ctx: BatchContext) -> None:
         now = time.monotonic()
-        entry.pending.append((device_index, value, ts,
-                              np.full(n, ctx.ingest_monotonic), ctx, now))
-        entry.pending_n += n
-        self._pending_max = max(self._pending_max, int(device_index.max()))
+        entry.pending.append(
+            (dev, val, ts, np.full(dev.shape[0], ctx.ingest_monotonic), ctx,
+             now))
+        entry.pending_n += dev.shape[0]
+        self._pending_max = max(self._pending_max, int(dev.max()))
         if self._deadline is None:
-            self._deadline = time.monotonic() + self._window_s
+            # the LIVE window (adaptive when cfg.window_auto): the
+            # tuner floats it above the configured floor, never below
+            self._deadline = now + self._window_s
         self._wake.set()
 
     # -- flushing -----------------------------------------------------------
@@ -669,13 +628,10 @@ class SharedScoringPool:
         return th
 
     def _bucket_for(self, n: int) -> int:
-        for b in self.cfg.batch_buckets:
-            if n <= b:
-                return self.stack.pad_batch(b)
-        # a data-axis multiple either way: the batch columns shard over
-        # the mesh `data` axis, and an uneven split would silently
-        # gather the ragged tail onto one device
-        return self.stack.pad_batch(self.cfg.batch_buckets[-1])
+        # a data-axis multiple: the batch columns shard over the mesh
+        # `data` axis, and an uneven split would silently gather the
+        # ragged tail onto one device
+        return self.stack.pad_batch(bucket_for(n, self.cfg.batch_buckets))
 
     # -- adaptive megabatch window (self-tuning dispatch) -------------------
 
@@ -848,7 +804,6 @@ class SharedScoringPool:
             # last batch's ctx, misattributing tenant/source/trace for
             # every earlier batch's leftover events)
             taken: list[tuple] = []
-            traces = []
             budget = self.cfg.batch_buckets[-1]
             now = time.monotonic()
             while e.pending and budget > 0:
@@ -857,14 +812,12 @@ class SharedScoringPool:
                 if n <= budget:
                     e.pending.pop(0)
                     taken.append(p)
-                    traces.append((p[4].trace_id, n, p[5]))
                     budget -= n
                 elif not taken:
                     head = tuple(c[:budget] for c in p[:4]) + (p[4], p[5])
                     e.pending[0] = tuple(c[budget:] for c in p[:4]) \
                         + (p[4], p[5])
                     taken.append(head)
-                    traces.append((p[4].trace_id, budget, p[5]))
                     budget = 0
                 else:
                     # leftover budget smaller than the next whole batch:
@@ -877,64 +830,38 @@ class SharedScoringPool:
                     # the rounds were packed to avoid. The remainder
                     # keeps its own ctx and leads the next round.
                     break
-                self.stage_batch.observe(now - p[5])
+                self.flights.stage_batch.observe(now - p[5])
             e.pending_n = sum(p[0].shape[0] for p in e.pending)
             if e.pending_n:
                 self._wake.set()
                 if self._deadline is None:
                     self._deadline = time.monotonic()
-            dev = np.concatenate([p[0] for p in taken])
-            val = np.concatenate([p[1] for p in taken])
-            ts = np.concatenate([p[2] for p in taken])
-            ing = np.concatenate([p[3] for p in taken])
-            # the take's delivery ctx: exact when one batch, merged
-            # sources when several (same convention as the dedicated
-            # session's _take_pending)
-            sources = {p[4].source for p in taken}
-            ctx = taken[0][4] if len(sources) == 1 else BatchContext(
-                tenant_id=tid, source="+".join(sorted(sources)),
-                ingest_monotonic=min(p[4].ingest_monotonic for p in taken))
-            takes[tid] = (dev, val, ts, ing, traces, ctx)
+            takes[tid] = merged_take(taken, tid)
         if self._total_pending == 0:
             self._pending_max = -1
         if not takes:
             return
         t_cap = self.ring.t_cap
 
-        # split every tenant's take into occurrence rounds
+        # every tenant's take as occurrence rounds; the stack's round r
+        # packs each tenant's round r
         # meta: (tid, slot, n, dev, ts, ing, traces, ev_rounds, ctx,
         #        version-at-dispatch)
         metas = []
         round_parts: list[list[tuple[int, np.ndarray, np.ndarray]]] = []
         ascending = True     # until a take has to be sorted
-        for tid, (dev, val, ts, ing, traces, ctx) in takes.items():
+        for tid, (dev, val, ts, ing, ctx, traces) in takes.items():
             slot = self.stack.slots[tid]
-            n = dev.shape[0]
+            rounds, in_order = occurrence_rounds(dev, val)
+            ascending = ascending and in_order
             ev_rounds = []
-            # O(n) fast path before the O(n log n) argsort/unique split:
-            # a strictly-ascending take (the replay engine's rank-round
-            # chunks; a gateway's frame) is one round as it stands. Any
-            # other is sorted, for the streaming ring wants every round
-            # ascending (scoring/stream.py, "Contract with the
-            # engines"): one round still where no id repeats
-            if n < 2 or bool((dev[1:] > dev[:-1]).all()):
-                parts = [(dev, val, None)]
-            else:
-                ascending = False
-                order = np.argsort(dev, kind="stable")
-                sd, sv = dev[order], val[order]
-                _, start, cnts = np.unique(sd, return_index=True,
-                                           return_counts=True)
-                cum = np.arange(n) - np.repeat(start, cnts)
-                parts = [(sd[cum == r], sv[cum == r], order[cum == r])
-                         for r in range(int(cum.max()) + 1)]
-            for r, (rdev, rval, rpos) in enumerate(parts):
-                while len(round_parts) <= r:
+            for r, (rdev, rval, rpos) in enumerate(rounds):
+                if r == len(round_parts):
                     round_parts.append([])
                 round_parts[r].append((slot, rdev, rval))
                 ev_rounds.append((r, rpos, rdev.shape[0]))
-            metas.append((tid, slot, n, dev, ts, ing, traces, ev_rounds,
-                          ctx, self.stack.versions.get(tid, 0)))
+            metas.append((tid, slot, dev.shape[0], dev, ts, ing, traces,
+                          ev_rounds, ctx, self.stack.versions.get(tid, 0)))
 
         t0 = time.monotonic()
         dispatches = []
@@ -957,156 +884,57 @@ class SharedScoringPool:
                             self.model, self.stack.stacked, dev_in, val_in))
         except Exception:
             logger.exception("pool dispatch failed; reseeding ring")
-            self.dropped.inc(sum(m[2] for m in metas))
+            self.flights.dropped.inc(sum(m[2] for m in metas))
             self._recover_ring()
             return
-        self.dispatches.inc(len(dispatches))
+        self.flights.dispatches.inc(len(dispatches))
         if ascending:
-            self.ascending.inc()
+            self.flights.ascending.inc()
         self.megabatch_dispatches.inc(len(dispatches))
         self.megabatch_tenants.observe(float(len(metas)))
         self._tune_window(len(metas))
-        # dispatch/settle split with megabatch tenant attribution:
-        # every packed tenant's traces get a queue-wait span here
-        # (its own admit time → this stacked dispatch) and the
-        # settle records the shared device half per tenant below
         for tid, _slot, _n, _dev, _ts, _ing, traces, *_ in metas:
-            for trace_id, n_ev, t_admit in traces:
-                self.tracer.record(trace_id,
-                                   "rule-processing.dispatch", tid,
-                                   t_admit, max(t0 - t_admit, 0.0),
-                                   n_ev)
-        self.inflight += 1
-        seq = self.dispatch_count
-        self.dispatch_count += 1
-        self._outstanding.add(seq)
+            self.flights.record_dispatch(traces, tid, t0)
         for tid, *_ in metas:
             e = self.tenants.get(tid)
             if e is not None:
                 e.inflight += 1
-        task = asyncio.get_running_loop().create_task(
-            self._settle_and_deliver(dispatches, metas, t0,
-                                     enqueue.t_end, seq),
-            name="scoring-settle")
-        self._settle_tasks.add(task)
-        task.add_done_callback(self._settle_task_done)
+        # SETTLE_POOL is read here, at each launch: replacing this
+        # module's name pins the pool's settles to other threads
+        self.flights.launch(
+            SETTLE_POOL, dispatches, sum(m[2] for m in metas), t0,
+            enqueue.t_end, functools.partial(self._assemble, metas, t0),
+            release=functools.partial(self._release, metas))
 
-    def _settle_task_done(self, task) -> None:
-        self._settle_tasks.discard(task)
-        if not task.cancelled() and task.exception() is not None:
-            # _settle_and_deliver's finally keeps the inflight
-            # accounting correct even here, but an escape is a bug —
-            # surface it instead of leaving the exception unretrieved
-            logger.error("pool settle task died unexpectedly",
-                         exc_info=task.exception())
+    def _release(self, metas) -> None:
+        """A settled megabatch leaves its tenants' in-flight counts."""
+        for tid, *_ in metas:
+            e = self.tenants.get(tid)
+            if e is not None:
+                e.inflight = max(0, e.inflight - 1)
 
-    async def _settle_and_deliver(self, dispatches, metas, t0: float,
-                                  t_enq: float,
-                                  seq: Optional[int] = None) -> None:
-        loop = asyncio.get_running_loop()
-        try:
-            try:
-                reads = await asyncio.gather(*[
-                    loop.run_in_executor(SETTLE_POOL, to_host, s)
-                    for s in dispatches])
-            except BaseException as exc:
-                self.dropped.inc(sum(m[2] for m in metas))
-                if isinstance(exc, Exception):
-                    logger.exception("pool settle failed")
-                    return
-                raise
-            # from here to the sinks the loop itself works (scores
-            # scattered back per tenant, thresholds, ScoredBatches): a
-            # span, which starts where the device stage's last part ends
-            with self.tracer.span("rule-processing.assemble") as assemble:
-                now = assemble.t_start
-                settled, instants = self.device_stage.observe(
-                    reads, t0, t_enq, now)
-                deliveries = self._assemble(settled, metas, now, t0)
-            for tid, _slot, _n, _dev, _ts, _ing, traces, *_ in metas:
-                self.device_stage.record(traces, tid, instants,
-                                         assemble.t_end)
-            # settle fan-out (kernel/egresslane.py deliver_scored — the
-            # ONE delivery contract with the dedicated session): every
-            # tenant of the megabatch delivers CONCURRENTLY, failures
-            # counted and isolated per tenant, so one tenant's slow or
-            # broken sink never holds the rest of the fleet's results
-            if deliveries:
-                await asyncio.gather(*[
-                    deliver_scored(deliver, scored, self.sink_failures,
-                                   self.stage_sink, label=f"tenant {tid}")
-                    for tid, deliver, scored in deliveries])
-        finally:
-            self.inflight -= 1
-            self.settled_count += 1
-            if seq is not None:
-                self._outstanding.discard(seq)
-            for tid, *_ in metas:
-                e = self.tenants.get(tid)
-                if e is not None:
-                    e.inflight = max(0, e.inflight - 1)
-
-    def _assemble(self, settled, metas, now: float,
-                  t0: float) -> list[tuple[str, Deliver, ScoredBatch]]:
-        """Settled rounds → one `ScoredBatch` a tenant still registered,
-        with the per-tenant accounting."""
-        from sitewhere_tpu.scoring.stream import sparse_take
-
+    def _assemble(self, metas, t0: float, settled: list,
+                  now: float) -> list:
+        """Settled rounds → one delivery a tenant of the megabatch
+        (scoring/settle.py `Flights.scored`, with the tenant's threshold
+        and the version snapshotted at DISPATCH, not the live one: a
+        swap landing mid-flight must not claim scores the old weights
+        computed). A tenant unregistered mid-flight keeps its spans and
+        gets no batch."""
         self._note_device_throughput(sum(m[2] for m in metas), now - t0)
-        sparse = bool(settled) and isinstance(settled[0], tuple)
-        deliveries: list[tuple[str, Deliver, ScoredBatch]] = []
-        for (tid, slot, n, dev, ts, ing, traces, ev_rounds, ctx,
+        sparse = isinstance(settled[0], tuple)
+        deliveries = []
+        for (tid, slot, _n, dev, ts, ing, traces, ev_rounds, ctx,
              version) in metas:
             e = self.tenants.get(tid)
-            if e is None:  # unregistered mid-flight
+            if e is None:
+                deliveries.append((tid, traces, None, None))
                 continue
-            self.scored_meter.mark(n)
-            self.latency.observe_array(now - ing)
-            if sparse:
-                # per-tenant anomalous subset: remap round-local
-                # positions back to this tenant's take positions
-                anom_pos: list[np.ndarray] = []
-                anom_scores: list[np.ndarray] = []
-                for r, rpos, k in ev_rounds:
-                    p, v_, overflow = sparse_take(
-                        settled[r][0][slot], settled[r][1][slot],
-                        settled[r][2][slot], k)
-                    if overflow:
-                        self.anomaly_overflow.inc(overflow)
-                    if p.shape[0] == 0:
-                        continue
-                    anom_pos.append(p if rpos is None else rpos[p])
-                    anom_scores.append(v_)
-                if anom_pos:
-                    fpos = np.concatenate(anom_pos)
-                    a_scores = np.concatenate(anom_scores)
-                else:
-                    fpos = np.empty(0, np.int64)
-                    a_scores = np.empty(0, np.float32)
-                self.anomalies.inc(int(fpos.shape[0]))
-                scored = ScoredBatch(
-                    ctx, dev[fpos], a_scores,
-                    np.ones(fpos.shape[0], bool), ts[fpos],
-                    # the version snapshotted at DISPATCH, not the
-                    # live one: a swap landing mid-flight must not
-                    # claim scores the old weights computed
-                    model_version=version,
-                    total_scored=n)
-            else:
-                scores = np.empty(n, np.float32)
-                for r, rpos, k in ev_rounds:
-                    if rpos is None:
-                        scores[:k] = settled[r][slot, :k]
-                    else:
-                        scores[rpos] = settled[r][slot, :k]
-                is_anom = scores >= e.threshold
-                n_anom = int(is_anom.sum())
-                if n_anom:
-                    self.anomalies.inc(n_anom)
-                scored = ScoredBatch(
-                    ctx, dev, scores, is_anom, ts,
-                    model_version=version)
-            deliveries.append((tid, e.deliver, scored))
+            rounds = [(tuple(part[slot] for part in settled[r]) if sparse
+                       else settled[r][slot], k, rpos)
+                      for r, rpos, k in ev_rounds]
+            deliveries.append((tid, traces, e.deliver, self.flights.scored(
+                ctx, dev, ts, ing, now, rounds, e.threshold, version)))
         return deliveries
 
     def _recover_ring(self, restart_warmup: bool = True) -> None:

@@ -24,6 +24,14 @@ hard parts called out in SURVEY.md §7:
 (b) per-tenant model multiplexing without recompiles → stacked-params
     tenant batching via the same bucket machinery (scoring/pool.py).
 
+What the session keeps of its own: admission and its deadline, the
+ring's regrow, the one-set-of-weights rule, warm-up and the query path.
+The host side after admission (the occurrence split, the settle, score
+placement, the flight book and its metrics) is scoring/settle.py's,
+shared with the pool. What a model's step says of itself the model
+declares (`stat_feeds`, models/seqblocks.py); the session feeds it
+without knowing its names.
+
 `score_devices` (the query/test path) still gathers windows from the
 host `TelemetryStore`; only admit/flush — the hot path — uses the ring.
 """
@@ -31,6 +39,7 @@ host `TelemetryStore`; only admit/flush — the hot path — uses the ring.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import time
 from dataclasses import dataclass
@@ -40,12 +49,18 @@ import jax
 import numpy as np
 
 from sitewhere_tpu.domain.batch import BatchContext, MeasurementBatch, ScoredBatch
-from sitewhere_tpu.kernel.egresslane import deliver_scored
 from sitewhere_tpu.kernel.metrics import MetricsRegistry
 from sitewhere_tpu.kernel.tracing import Tracer
 from sitewhere_tpu.persistence.telemetry import TelemetryStore
 from sitewhere_tpu.scoring.ring import DeviceRing
-from sitewhere_tpu.scoring.settle import SETTLE_POOL, DeviceStage, to_host
+from sitewhere_tpu.scoring.settle import (
+    SETTLE_POOL,
+    Flights,
+    booked,
+    bucket_for,
+    merged_take,
+    occurrence_rounds,
+)
 from sitewhere_tpu.utils.backend import device_memory_bytes
 from sitewhere_tpu.utils.retry import retry_backoff
 
@@ -102,21 +117,15 @@ class ScoringConfig:
         return self.backlog_cap or 4 * self.buckets[-1]
 
 
-def _held_expert_bytes(model) -> int:
-    """Bytes of the held experts' leaves over a model's expert layers
-    (`param_shapes()`: a layer's `experts`, a leaf a projection an
-    expert), which a step streams once whatever a frame routes; 0 for a
-    model without them."""
-    shapes = getattr(model, "param_shapes", dict)()
-    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
-               for block in shapes.values() if isinstance(block, dict)
-               for expert in block.get("experts", {}).values()
-               for shape, dtype in expert.values())
-
-
 class ScoringSession:
     """One tenant's scorer: model + device-resident params & history ring
     + bucketed compiled functions + admission queue."""
+
+    # the flight book's counts, which the consumer's commit barrier reads
+    inflight = booked("inflight")
+    dispatch_count = booked("dispatch_count")
+    settled_count = booked("settled_count")
+    settled_through = booked("settled_through")
 
     def __init__(self, model, telemetry: TelemetryStore,
                  metrics: MetricsRegistry, cfg: ScoringConfig = ScoringConfig(),
@@ -167,17 +176,7 @@ class ScoringSession:
         # refuses every time shows HERE — a caller waiting on `ready`
         # with a deadline reports this, not a bare timeout
         self.warmup_error: Optional[Exception] = None
-        self.inflight = 0
-        # monotonic flush progress: dispatch_count - settled_count ==
-        # inflight; the consumer's commit checkpoint compares these to
-        # know when everything admitted before a point has been published
-        self.dispatch_count = 0
-        self.settled_count = 0
-        self._outstanding: set[int] = set()   # dispatched, not yet settled
-        # strong refs to in-flight settle tasks: the loop keeps only
-        # weak ones, and a GC'd settle leaves `inflight`/`_outstanding`
-        # permanently stuck — the session never flushes again
-        self._settle_tasks: set = set()
+        self.flights = Flights(metrics, self.tracer)
         self._regrow_task: Optional[asyncio.Task] = None
         # pending admission state:
         # (device_index, value, ts, ingest, ctx, admit_monotonic)
@@ -186,88 +185,19 @@ class ScoringSession:
         self._pending_n = 0
         self._pending_max = -1      # highest device index waiting
         self._deadline: Optional[float] = None
-        # metrics (judge's metrics are first-class [SURVEY.md §5.5])
-        self.scored_meter = metrics.meter("scoring.events_scored")
-        self.latency = metrics.histogram("scoring.e2e_latency_s")
         self.batch_size_hist = metrics.histogram(
             "scoring.batch_size", buckets=[float(b) for b in cfg.buckets])
-        self.anomalies = metrics.counter("scoring.anomalies_detected")
-        self.anomaly_overflow = metrics.counter("scoring.anomaly_overflow")
-        self.dropped = metrics.counter("scoring.admissions_dropped")
-        self.sink_failures = metrics.counter("scoring.sink_failures")
-        # flush-path jit dispatches (one inc per compiled update+score
-        # call — chunks and occurrence rounds each count): the megabatch
-        # A/B's denominator. The pool incs the SAME registry counter, so
-        # `scoring.dispatches` is the instance-wide dispatch rate in
-        # both operating modes.
-        self.dispatches = metrics.counter("scoring.dispatches")
-        # takes whose ids arrived ascending: dispatched with no host sort
-        self.ascending = metrics.counter("scoring.ring.ascending")
-        # end-to-end latency decomposition (one observation per batch or
-        # per flush — negligible overhead, and the p99 stops being a
-        # single opaque number):
-        #   admit  = receiver arrival → admission (decode + bus hops + queue)
-        #   batch  = admission → dispatch (deadline batching + inflight gate)
-        #   device = dispatch → scores on host (XLA queue + compute + sync),
-        #            itself in three parts (scoring/settle.py DeviceStage)
-        #   sink   = settled → published (delivery/alert fan-out)
-        self.stage_admit = metrics.histogram("scoring.stage_admit_s")
-        self.stage_batch = metrics.histogram("scoring.stage_batch_s")
-        self.device_stage = DeviceStage(metrics, self.tracer)
-        self.stage_device = self.device_stage.total
-        self.stage_sink = metrics.histogram("scoring.stage_sink_s")
-        # the numbers a model's step returns beside its scores
-        # (`model.step_stats` names them), fed as they settle
-        octaves = [2.0 ** (i / 4) for i in range(53)]      # 1 to 8,192
-        feeds = {
-            "moe.assignments_held": lambda: metrics.counter(
-                "scoring.moe.assignments_held").inc,
-            "moe.assignments": lambda: metrics.counter(
-                "scoring.moe.assignments").inc,
-            "moe.expert_max_tokens": lambda: metrics.histogram(
-                "scoring.moe.expert_max_tokens", buckets=octaves).observe,
-            "ctx.positions": lambda: metrics.histogram(
-                "scoring.ctx.positions", buckets=octaves).observe,
-            "moe.runs_one_tile": lambda: metrics.counter(
-                "scoring.moe.runs_one_tile").inc,
-            "ctx.window_positions": lambda: metrics.histogram(
-                "scoring.ctx.window_positions", buckets=octaves).observe,
-            "ctx.wrapped": lambda: metrics.counter(
-                "scoring.ctx.wrapped").inc,
-            # a recurrent matrix state (models/olmo_hybrid.py): the mean
-            # decay a step applied to it, in (0, 1), and the largest
-            # magnitude the step found in the rows it read
-            "state.decay": lambda: metrics.histogram(
-                "scoring.state.decay",
-                buckets=[i / 64 for i in range(1, 65)]).observe,
-            "state.absmax": lambda: metrics.histogram(
-                "scoring.state.absmax",
-                buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe,
-            # live rows whose matrix state a step's kernel updated where
-            # it rested, over its linear layers; 0 on the plain path
-            "state.in_place": lambda: metrics.counter(
-                "scoring.state.in_place_rows").inc,
-            # live rows whose stored context a step's kernel read where
-            # it rested, over its layers; 0 on the plain path
-            "ctx.at_rest": lambda: metrics.counter(
-                "scoring.ctx.at_rest_rows").inc,
-            # a looped model (models/ouro.py): bytes of layer weights
-            # its passes stream a step, passes x layers x a layer's, from
-            # shapes; and the bytes of keys and values its equations
-            # read, over live rows and every (pass, layer) context
-            "loop.weight_bytes": lambda: metrics.counter(
-                "scoring.loop.weight_bytes").inc,
-            "ctx.attended_bytes": lambda: metrics.counter(
-                "scoring.ctx.attended_bytes").inc}
-        self._step_stats = [feeds[name]()
-                            for name in getattr(model, "step_stats", ())]
+        # what the model's step says of itself: a feed a number of
+        # `model.step_stats`, in its order, fed as the step settles, and
+        # what is counted once a dispatch
+        stat_feeds = getattr(model, "stat_feeds", None)
+        self._step_feeds, self._dispatch_feeds = (
+            stat_feeds(metrics) if stat_feeds is not None else ((), ()))
+        # rows whose windows were full and were seeded again on the way
         self.reseeds = metrics.counter("scoring.ctx.reseeds")
         # bytes of fixed-size state leaves the dispatches rewrote whole
         self.rewritten = metrics.counter("scoring.state.rewritten_bytes")
-        # bytes of held experts' leaves a dispatch's step streams: every
-        # expert layer's, read off the checkpoint's layout (no device work)
-        self.expert_weights = metrics.counter("scoring.moe.weight_bytes")
-        self._expert_bytes = _held_expert_bytes(model)
+
 
     def _fleet_rows(self) -> int:
         """Rows the ring is asked for: the fleet-size hint, or as far as
@@ -426,12 +356,6 @@ class ScoringSession:
             self._fns[bucket] = fn
         return fn
 
-    def _bucket_for(self, n: int) -> int:
-        for b in self.cfg.buckets:
-            if n <= b:
-                return b
-        return self.cfg.buckets[-1]
-
     async def score_devices(self, devices: np.ndarray, ts: np.ndarray,
                             ingest_mono: np.ndarray,
                             ctx: BatchContext) -> ScoredBatch:
@@ -450,7 +374,7 @@ class ScoringSession:
         for lo in range(0, devices.shape[0], max_b):
             chunk = devices[lo:lo + max_b]
             n = chunk.shape[0]
-            bucket = self._bucket_for(n)
+            bucket = bucket_for(n, self.cfg.buckets)
             x, valid = self.telemetry.window(chunk, w, mtype=self.cfg.mtype)
             if n < bucket:
                 pad = bucket - n
@@ -459,18 +383,11 @@ class ScoringSession:
             scores_dev = self._fn(bucket)(self.params, x, valid)
             self.batch_size_hist.observe(float(n))
             settles.append((loop.run_in_executor(
-                SETTLE_POOL, np.asarray, scores_dev), n))
-        outs = [(await fut)[:n] for fut, n in settles]
-        scores = np.concatenate(outs) if len(outs) > 1 else outs[0]
-        now = time.monotonic()
-        self.scored_meter.mark(devices.shape[0])
-        self.latency.observe_array(now - ingest_mono)
-        is_anom = scores >= self.cfg.threshold
-        n_anom = int(is_anom.sum())
-        if n_anom:
-            self.anomalies.inc(n_anom)
-        return ScoredBatch(ctx, devices, scores.astype(np.float32),
-                           is_anom, ts, model_version=self.version)
+                SETTLE_POOL, np.asarray, scores_dev), n, slice(lo, lo + n)))
+        rounds = [(await fut, n, at) for fut, n, at in settles]
+        return self.flights.scored(ctx, devices, ts, ingest_mono,
+                                   time.monotonic(), rounds,
+                                   self.cfg.threshold, self.version)
 
     # -- admission batching (the hot path) ---------------------------------
 
@@ -493,7 +410,7 @@ class ScoringSession:
         if dev.shape[0] == 0:
             return
         now = time.monotonic()
-        self.stage_admit.observe(now - batch.ctx.ingest_monotonic)
+        self.flights.stage_admit.observe(now - batch.ctx.ingest_monotonic)
         ingest = np.full(dev.shape[0], batch.ctx.ingest_monotonic)
         self._pending.append((dev, val, ts, ingest, batch.ctx, now))
         self._pending_n += dev.shape[0]
@@ -527,14 +444,6 @@ class ScoringSession:
         return self._pending_n == 0 and self.inflight == 0
 
     @property
-    def settled_through(self) -> int:
-        """Every dispatch with seq < this value has either settled (sink
-        delivery attempted) or been accounted as dropped — settles may
-        complete out of order, so this is the min outstanding seq (the
-        commit barrier)."""
-        return min(self._outstanding) if self._outstanding else self.dispatch_count
-
-    @property
     def flush_due(self) -> bool:
         if self._pending_n == 0 or not self.ready or self.params is None:
             return False
@@ -562,64 +471,21 @@ class ScoringSession:
         self._pending_max = -1
         now = time.monotonic()
         for p in pending:  # batching stage: admission → dispatch
-            self.stage_batch.observe(now - p[5])
-        if len(pending) == 1:
-            # single-admit flush (the saturation steady state: one
-            # fleet-sized batch per window): pass the columns through
-            # with NO copies — np.concatenate of a 1-element list
-            # memcpys every column, ~0.4 MB per 4096-event flush on
-            # the hot path for nothing
-            dev, val, ts, ingest, ctx, t_admit = pending[0]
-            return (dev, val.astype(np.float32, copy=False), ts, ingest,
-                    ctx, [(ctx.trace_id, dev.shape[0], t_admit)])
-        dev = np.concatenate([p[0] for p in pending])
-        val = np.concatenate([p[1] for p in pending]).astype(np.float32, copy=False)
-        ts = np.concatenate([p[2] for p in pending])
-        ingest = np.concatenate([p[3] for p in pending])
-        sources = {p[4].source for p in pending}
-        ctx = pending[0][4] if len(sources) == 1 else BatchContext(
-            tenant_id=pending[0][4].tenant_id, source="+".join(sorted(sources)),
-            ingest_monotonic=min(p[4].ingest_monotonic for p in pending))
-        # every admitted batch's trace gets its own dispatch/score span
-        # pair (a flush coalesces many traces; attributing all to one
-        # hides the rest) — admit time rides along so the dispatch span
-        # measures THAT batch's queue wait, not the flush's
-        traces = [(p[4].trace_id, p[0].shape[0], p[5]) for p in pending]
-        return dev, val, ts, ingest, ctx, traces
+            self.flights.stage_batch.observe(now - p[5])
+        return merged_take(pending, pending[0][4].tenant_id)
 
-    def _dispatch(self, dev, val):
-        """Append + score on device; returns a list of round dispatches
-        `(scores_dev, n, positions)` whose scores map back to the
-        original event positions.
-
-        When a flush carries several events for one device, occurrences
-        are applied AND scored in arrival order (one fused call per
-        occurrence round), so every event's score reflects the device's
-        window as of that event — a backlog coalesced into one flush
-        scores identically to the same events flushed one tick at a
-        time."""
-        n = dev.shape[0]
+    def _dispatch(self, dev, val) -> list:
+        """Append + score on device, one fused call an occurrence round
+        (`occurrence_rounds`); returns each round's `(result, n,
+        positions)`."""
         dev = dev.astype(np.int32, copy=False)
         self.ring.ensure_capacity(int(dev.max()))
-        # the ring wants each round's ids strictly ascending
-        # (scoring/stream.py, "Contract with the engines"): a take that
-        # arrives so (a gateway's frame) is one round as it stands, any
-        # other is sorted, which a take without repeats leaves one round
-        if n < 2 or bool((dev[1:] > dev[:-1]).all()):
-            rounds = [(dev, val, None)]  # identity mapping
-            self.ascending.inc()
-        else:
-            order = np.argsort(dev, kind="stable")
-            sd, sv = dev[order], val[order]
-            _, start, cnts = np.unique(sd, return_index=True, return_counts=True)
-            cum = np.arange(n) - np.repeat(start, cnts)
-            rounds = []
-            for r in range(int(cum.max()) + 1):
-                sel = cum == r
-                rounds.append((sd[sel], sv[sel], order[sel]))
-        dispatches = []
+        rounds, ascending = occurrence_rounds(dev, val)
+        if ascending:
+            self.flights.ascending.inc()
+        dispatched = []
         for rdev, rval, rpos in rounds:
-            bucket = self._bucket_for(rdev.shape[0])
+            bucket = bucket_for(rdev.shape[0], self.cfg.buckets)
             scores_dev = self.ring.update_and_score(
                 self.model, self.params, rdev, rval, bucket)
             # start the device→host DMA NOW (non-blocking): by the time a
@@ -630,11 +496,10 @@ class ScoringSession:
                         else (scores_dev,)):
                 arr.copy_to_host_async()
             self.batch_size_hist.observe(float(rdev.shape[0]))
-            self.dispatches.inc()
-            if self._expert_bytes:
-                self.expert_weights.inc(self._expert_bytes)
-            dispatches.append((scores_dev, rdev.shape[0], rpos))
-        # rows whose windows were full and were seeded again on the way
+            self.flights.dispatches.inc()
+            for feed in self._dispatch_feeds:
+                feed()
+            dispatched.append((scores_dev, rdev.shape[0], rpos))
         reseeded = getattr(self.ring, "reseeded", 0)
         if reseeded:
             self.reseeds.inc(reseeded)
@@ -643,113 +508,23 @@ class ScoringSession:
         if rewritten:
             self.rewritten.inc(rewritten)
             self.ring.rewritten_bytes = 0
-        return dispatches
+        return dispatched
 
-    async def _settle_and_deliver(self, dispatches, dev, ts,
-                                  ingest, ctx, t0: float, t_enq: float,
-                                  fut: Optional[asyncio.Future] = None,
-                                  seq: Optional[int] = None,
-                                  traces: Optional[list] = None):
-        # inflight covers settle AND sink delivery: drain()/the consumer
-        # commit gate must not consider a flush done until its scored
-        # output has been published
-        loop = asyncio.get_running_loop()
-        try:
-            try:
-                reads = await asyncio.gather(*[
-                    loop.run_in_executor(SETTLE_POOL, to_host, s)
-                    for s, _, _ in dispatches])
-            except BaseException as exc:
-                if fut is not None and not fut.done():
-                    fut.set_exception(exc if isinstance(exc, Exception)
-                                      else RuntimeError("settle cancelled"))
-                # these events' scores are lost; account them so the
-                # commit barrier advancing is an explicit drop, not a
-                # silent one
-                self.dropped.inc(dev.shape[0])
-                if isinstance(exc, Exception):
-                    logger.exception("scoring settle failed")
-                    return
-                raise
-            # the stretch from here to the sink (scores scattered back,
-            # threshold, ScoredBatch) is the loop's own work: a span,
-            # which starts where the device stage's last part ends
-            with self.tracer.span("rule-processing.assemble") as assemble:
-                now = assemble.t_start
-                settled, instants = self.device_stage.observe(
-                    reads, t0, t_enq, now)
-                scored = self._assemble(settled, dispatches, dev, ts,
-                                        ingest, ctx, now)
-            self.device_stage.record(
-                traces or [(ctx.trace_id, dev.shape[0])], ctx.tenant_id,
-                instants, assemble.t_end)
-            if fut is not None and not fut.done():
-                fut.set_result(scored)
-            if self.sink is not None:
-                # ONE delivery contract with the pool's megabatch
-                # fan-out (kernel/egresslane.py): failure isolation +
-                # stage_sink ownership live in deliver_scored
-                await deliver_scored(self.sink, scored,
-                                     self.sink_failures, self.stage_sink)
-        finally:
-            self.inflight -= 1
-            self.settled_count += 1
-            if seq is not None:
-                self._outstanding.discard(seq)
-
-    def _assemble(self, settled, dispatches, dev, ts, ingest, ctx,
-                  now: float) -> ScoredBatch:
-        """Settled rounds → the chunk's `ScoredBatch`, with the
-        mode-independent accounting: BOTH read-back paths scored every
-        event on device (sparse just ships fewer scores home)."""
-        self.scored_meter.mark(dev.shape[0])
-        self.latency.observe_array(now - ingest)
-        if settled and isinstance(settled[0], tuple):
-            # sparse anomaly readback: reconstruct the anomalous
-            # subset only
-            from sitewhere_tpu.scoring.stream import sparse_take
-
-            anom_flush_pos: list[np.ndarray] = []
-            anom_scores: list[np.ndarray] = []
-            for (n_anom, pos, vals), (_, n, rpos) in zip(settled,
-                                                         dispatches):
-                p, v_, overflow = sparse_take(n_anom, pos, vals, n)
-                if overflow:
-                    self.anomaly_overflow.inc(overflow)
-                if p.shape[0] == 0:
-                    continue
-                # rounds remap duplicate-device chunks back to the
-                # original flush positions
-                anom_flush_pos.append(p if rpos is None else rpos[p])
-                anom_scores.append(v_)
-            if anom_flush_pos:
-                fpos = np.concatenate(anom_flush_pos)
-                a_scores = np.concatenate(anom_scores)
-            else:
-                fpos = np.empty(0, np.int64)
-                a_scores = np.empty(0, np.float32)
-            self.anomalies.inc(int(fpos.shape[0]))
-            return ScoredBatch(
-                ctx, dev[fpos], a_scores,
-                np.ones(fpos.shape[0], bool), ts[fpos],
-                model_version=self.version,
-                total_scored=int(dev.shape[0]))
-        scores = np.empty(dev.shape[0], np.float32)
-        for scores_u, (_, n, rpos) in zip(settled, dispatches):
-            if self._step_stats:
-                stats = scores_u[-len(self._step_stats):]
-                for feed, value in zip(self._step_stats, stats):
+    def _assemble(self, dispatched, dev, ts, ingest, ctx, traces,
+                  settled: list, now: float) -> list:
+        """A settled chunk → its one delivery (scoring/settle.py
+        `Flights.scored`), the model's numbers fed on the way."""
+        feeds = self._step_feeds
+        if feeds and not isinstance(settled[0], tuple):
+            for row in settled:
+                for feed, value in zip(feeds, row[-len(feeds):]):
                     feed(float(value))
-            if rpos is None:
-                scores[:n] = scores_u[:n]
-            else:
-                scores[rpos] = scores_u[:n]
-        is_anom = scores >= self.cfg.threshold
-        n_anom = int(is_anom.sum())
-        if n_anom:
-            self.anomalies.inc(n_anom)
-        return ScoredBatch(ctx, dev, scores, is_anom, ts,
-                           model_version=self.version)
+        scored = self.flights.scored(
+            ctx, dev, ts, ingest, now,
+            [(row, n, rpos) for row, (_, n, rpos) in zip(settled, dispatched)],
+            self.cfg.threshold, self.version)
+        return [(ctx.tenant_id, traces or [(ctx.trace_id, dev.shape[0])],
+                 self.sink, scored)]
 
     def _dispatch_chunks(self, dev, val, ts, ingest, ctx, t0,
                          futs: Optional[list] = None,
@@ -760,52 +535,35 @@ class ScoringSession:
         loop = asyncio.get_running_loop()
         max_b = self.cfg.buckets[-1]
         if traces:
-            # the dispatch/settle split: this span is pure QUEUE WAIT
-            # (admission → jit dispatch: batching window + inflight
-            # gate); the settle records "rule-processing.score" for the
-            # device half (dispatch → scores on host)
-            for trace_id, n_ev, t_admit in traces:
-                self.tracer.record(trace_id, "rule-processing.dispatch",
-                                   ctx.tenant_id, t_admit,
-                                   max(t0 - t_admit, 0.0), n_ev)
+            self.flights.record_dispatch(traces, ctx.tenant_id, t0)
         n_chunks = 0
         for lo in range(0, dev.shape[0], max_b):
             hi = lo + max_b
+            chunk = dev[lo:hi]
             try:
                 with self.tracer.span(
                         "rule-processing.score.enqueue") as enqueue:
-                    dispatches = self._dispatch(dev[lo:hi], val[lo:hi])
+                    dispatched = self._dispatch(chunk, val[lo:hi])
             except Exception:
                 logger.exception("scoring dispatch failed; reloading ring")
-                self.dropped.inc(dev.shape[0] - lo)
+                self.flights.dropped.inc(dev.shape[0] - lo)
                 self._recover_ring()
                 break
-            self.inflight += 1
-            seq = self.dispatch_count
-            self.dispatch_count += 1
-            self._outstanding.add(seq)
-            fut = loop.create_future() if futs is not None else None
-            if fut is not None:
+            fut = None
+            if futs is not None:
+                fut = loop.create_future()
                 futs.append(fut)
-            task = loop.create_task(self._settle_and_deliver(
-                dispatches, dev[lo:hi], ts[lo:hi],
-                ingest[lo:hi], ctx, t0, enqueue.t_end, fut, seq,
-                traces if lo == 0 else None), name="scoring-settle")
-            self._settle_tasks.add(task)
-            task.add_done_callback(self._settle_task_done)
+            # SETTLE_POOL is read here, at each launch: replacing this
+            # module's name pins the session's settles to other threads
+            self.flights.launch(
+                SETTLE_POOL, [d[0] for d in dispatched], chunk.shape[0],
+                t0, enqueue.t_end, functools.partial(
+                    self._assemble, dispatched, chunk, ts[lo:hi],
+                    ingest[lo:hi], ctx, traces if lo == 0 else None), fut)
             n_chunks += 1
         else:
             return n_chunks, False
         return n_chunks, True  # broke out: a chunk's dispatch failed
-
-    def _settle_task_done(self, task) -> None:
-        self._settle_tasks.discard(task)
-        if not task.cancelled() and task.exception() is not None:
-            # _settle_and_deliver's finally keeps the inflight
-            # accounting correct even here, but an escape is a bug —
-            # surface it instead of leaving the exception unretrieved
-            logger.error("settle task died unexpectedly",
-                         exc_info=task.exception())
 
     def _start_regrow(self) -> None:
         """A pending event's device index outgrew the ring: grow and
@@ -901,3 +659,4 @@ class ScoringSession:
         self._fns.clear()
         self.ring.close()
         self.params = None
+

@@ -36,8 +36,8 @@ scatter drops them, so a step writes the rows it was given and no
 other. Every scatter says `unique_indices` (`DISTINCT_ROWS`): an
 engine that names a row twice in one step gets a table that no test of
 the step alone would catch. Both engines sort a take that does not
-ascend and split one with repeats into rounds
-(`ScoringSession._dispatch`, `SharedScoringPool`). That the rows ascend
+ascend and split one with repeats into rounds (scoring/settle.py
+`occurrence_rounds`). That the rows ascend
 is said to the gathers only: told so, a v5e's compiler made the row
 scatter a sixth slower and a context append thirty times (PERF.md
 section 6, PR 31).
@@ -50,7 +50,9 @@ row's own position)` into the donated buffer: a 1 MB context is read
 whole, as attention must, and 1 KB of it is written. Such a model's
 `step_score` also takes `live` (the rows that are no padding) and may
 return a third value, the numbers of `model.step_stats`, which ride
-home at the end of the score vector. Each window leaf has its own
+home at the end of the score vector; the session feeds them to the
+metrics the model declares (`stat_feeds`, models/seqblocks.py) without
+knowing their names. Each window leaf has its own
 bound, its `shape[1]`. One that the model names in `wraps` keeps the
 newest `shape[1]` positions: the entry of position `p` is written at
 `p mod shape[1]` over the oldest, so it never fills. The others are
@@ -389,10 +391,8 @@ def sparse_take(n_anom, pos, vals,
     """Host-side reconstruction for ONE sparse result row: clamp to the
     k slots, drop bucket-padding positions (>= n_real — device-side
     scratch masking makes this belt-and-braces), upcast scores.
-    Returns (positions, scores_f32, overflow). Shared by the dedicated
-    session's per-chunk settle and the pool's per-tenant-per-round
-    settle so the overflow/remap accounting cannot drift between the
-    two hot paths."""
+    Returns (positions, scores_f32, overflow). Called for every settled
+    round of either engine (scoring/settle.py `anomalous_subset`)."""
     k_eff = min(int(n_anom), pos.shape[0])
     overflow = max(0, int(n_anom) - pos.shape[0])
     if k_eff == 0:

@@ -210,7 +210,7 @@ def test_alerts_emitted_off_flush_path_and_counted(run):
             await wait_until(lambda: len(em.spi.alerts) >= 32, timeout=15.0)
             assert rt.metrics.snapshot().get("rules.alerts_emitted",
                                              0) >= 32
-            assert session.latency.count >= 32 * 9
+            assert session.flights.latency.count >= 32 * 9
 
     run(main())
 

@@ -126,7 +126,7 @@ async def _drive_and_collect(rt, n_sim=48, ticks=6):
         assert await receiver.submit(payload)
     session = rt.api("rule-processing").engine("acme").session
     expected = 32 * ticks  # only the registered 32 of n_sim are scored
-    await wait_until(lambda: session.latency.count >= expected,
+    await wait_until(lambda: session.flights.latency.count >= expected,
                      timeout=30.0)
     em = rt.api("event-management").management("acme")
     await wait_until(lambda: em.telemetry.total_events >= expected,
@@ -190,13 +190,13 @@ def test_fastlane_batches_not_rescored_at_enriched_hop(run):
                 "acme", TopicNaming.EVENT_SOURCE_DECODED)
             await rt.bus.produce(decoded, _measurements(32, 1000.0),
                                  key="gw")
-            await wait_until(lambda: session.latency.count >= 32)
+            await wait_until(lambda: session.flights.latency.count >= 32)
             # the enriched hop has long since seen the batch; give any
             # (wrong) second admission time to surface
             em = rt.api("event-management").management("acme")
             await wait_until(lambda: em.telemetry.total_events >= 32)
             await asyncio.sleep(0.3)
-            assert session.latency.count == 32
+            assert session.flights.latency.count == 32
 
     run(main())
 
@@ -215,7 +215,7 @@ def test_stale_fastlane_flag_cleared_by_staged_lane(run):
             batch.ctx.fastlane = True  # as a pre-toggle fused pass left it
             await rt.bus.produce(decoded, batch, key="gw")
             session = rt.api("rule-processing").engine("acme").session
-            await wait_until(lambda: session.latency.count >= 32)
+            await wait_until(lambda: session.flights.latency.count >= 32)
 
     run(main())
 
@@ -239,7 +239,7 @@ def test_fastlane_poison_record_quarantined(run):
             await rt.bus.produce(decoded, _measurements(32, 1001.0),
                                  key="gw")
             session = rt.api("rule-processing").engine("acme").session
-            await wait_until(lambda: session.latency.count >= 32)
+            await wait_until(lambda: session.flights.latency.count >= 32)
             entries = list_dead_letters(rt.bus, dlq)
             assert len(entries) == 1
             assert "fastlane" in entries[0][1]["stage"]
@@ -267,7 +267,7 @@ def test_fastlane_chaos_site_armed(run):
                                      key="gw")
             session = rt.api("rule-processing").engine("acme").session
             # 2 records quarantined, the other 2 score through
-            await wait_until(lambda: session.latency.count >= 64)
+            await wait_until(lambda: session.flights.latency.count >= 64)
             await wait_until(
                 lambda: len(list_dead_letters(rt.bus, dlq)) == 2)
             lane = rt.api("rule-processing").engine("acme").fastlane
@@ -298,12 +298,12 @@ def test_fastlane_shed_defer_and_degrade(run):
                 len(r.value) for r in rt.bus.peek(deferred, limit=-1)) >= 32)
             # spooled, persisted, NOT scored
             await wait_until(lambda: em.telemetry.total_events >= 32)
-            assert session.latency.count == 0
+            assert session.flights.latency.count == 0
             assert rt.metrics.snapshot().get("flow.shed_defer:acme", 0) >= 32
 
             # pressure clears → the rule processor drains the spool back
             rt.flow.force_mode("acme", "ok")
-            await wait_until(lambda: session.latency.count >= 32,
+            await wait_until(lambda: session.flights.latency.count >= 32,
                              timeout=15.0)
             assert rt.metrics.snapshot().get(
                 "flow.deferred_replayed:acme", 0) >= 32

@@ -227,13 +227,13 @@ def test_defer_mode_spools_then_replays(run):
                     deferred, limit=100)) >= 64)
             snap = rt.metrics.snapshot()
             assert snap.get("flow.shed_defer:acme", 0) >= 64
-            assert session.scored_meter.rate(60.0) == 0.0  # nothing scored
+            assert session.flights.scored_meter.rate(60.0) == 0.0  # nothing scored
             # overload clears → the spool drains back through the scorer
             rt.flow.force_mode("acme", "ok")
             await wait_until(
                 lambda: rt.metrics.snapshot().get(
                     "flow.deferred_replayed:acme", 0) >= 64, timeout=15.0)
-            await wait_until(lambda: session.latency.count >= 64,
+            await wait_until(lambda: session.flights.latency.count >= 64,
                              timeout=15.0)
 
     run(main())

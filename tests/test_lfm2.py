@@ -413,16 +413,16 @@ def test_held_expert_bytes_are_read_off_the_checkpoints_layout():
     """`scoring.moe.weight_bytes` a dispatch, for the models that were
     there: a layer's `experts` leaves and nothing else (not a shared
     expert, not a module's block that no step runs), 0 without them."""
-    from sitewhere_tpu.scoring.server import _held_expert_bytes
+    from sitewhere_tpu.models.seqblocks import held_expert_bytes
     from tests.test_dsv3 import MC as DSV3
     from tests.test_olmo_hybrid import MC as OLMO
 
     dsv3 = build_model("dsv3-stream", **DSV3)
     layers = DSV3["num_hidden_layers"] - DSV3["first_k_dense_replace"]
-    assert _held_expert_bytes(dsv3) == layers * dsv3.experts.held * 2 * 3 \
+    assert held_expert_bytes(dsv3) == layers * dsv3.experts.held * 2 * 3 \
         * DSV3["hidden_size"] * DSV3["moe_intermediate_size"] > 0
-    assert _held_expert_bytes(build_model("olmo-hybrid-stream", **OLMO)) == 0
-    assert _held_expert_bytes(build_model("lstm-stream")) == 0
+    assert held_expert_bytes(build_model("olmo-hybrid-stream", **OLMO)) == 0
+    assert held_expert_bytes(build_model("lstm-stream")) == 0
 
 
 def test_defaults_are_the_published_config_and_its_bytes():

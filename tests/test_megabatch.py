@@ -463,9 +463,9 @@ def test_settle_task_retained_until_delivery(run):
         await wait_until(lambda: pool.ready, timeout=60.0)
         slot.admit(_batch("a"))
         pool._flush_round()
-        assert len(pool._settle_tasks) == 1  # strong ref while in flight
+        assert len(pool.flights.tasks) == 1  # strong ref while in flight
         await wait_until(lambda: len(delivered) == 1, timeout=30.0)
-        await wait_until(lambda: not pool._settle_tasks, timeout=5.0)
+        await wait_until(lambda: not pool.flights.tasks, timeout=5.0)
         pool.close()
 
     run(main())
@@ -476,19 +476,21 @@ def test_settle_task_failure_is_logged(run, caplog):
     supervisor callback instead of dying unretrieved."""
     import logging
 
+    from sitewhere_tpu.scoring.settle import Flights
+
     async def main():
-        pool = SharedScoringPool.__new__(SharedScoringPool)
-        pool._settle_tasks = set()
+        flights = Flights.__new__(Flights)
+        flights.tasks = set()
 
         async def boom():
             raise RuntimeError("settle exploded")
 
         task = asyncio.get_running_loop().create_task(boom())
-        pool._settle_tasks.add(task)
-        task.add_done_callback(pool._settle_task_done)
-        while pool._settle_tasks:
+        flights.tasks.add(task)
+        task.add_done_callback(flights.task_done)
+        while flights.tasks:
             await asyncio.sleep(0)
 
-    with caplog.at_level(logging.ERROR, logger="sitewhere_tpu.scoring.pool"):
+    with caplog.at_level(logging.ERROR, logger="sitewhere_tpu.scoring.settle"):
         run(main())
     assert any("settle task died" in r.getMessage() for r in caplog.records)

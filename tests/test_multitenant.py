@@ -210,7 +210,7 @@ def test_e2e_multitenant_pooled_scoring(run):
             await wait_until(
                 lambda em=em: em.telemetry.total_events == 24 * 50, timeout=20.0)
         # drain history scoring before injecting anomalies
-        await wait_until(lambda: pool.latency.count >= 4 * 24 * 50, timeout=60.0)
+        await wait_until(lambda: pool.flights.latency.count >= 4 * 24 * 50, timeout=60.0)
 
         # partial-window z-scores can legitimately alert during history
         # (e.g. a sine swing over an 8-sample window); only alerts raised

@@ -198,7 +198,7 @@ def test_pipeline_spans_recorded(run):
             session = rt.api("rule-processing").engine("acme").session
             for k in range(20):
                 await receiver.submit(sim.payload(t=60.0 * k)[0])
-            await wait_until(lambda: session.latency.count >= 400)
+            await wait_until(lambda: session.flights.latency.count >= 400)
             summary = rt.tracer.stage_summary()
             for stage in ("event-sources.decode", "inbound.enrich",
                           "event-management.persist", "rule-processing.score"):

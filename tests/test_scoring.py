@@ -114,7 +114,7 @@ def test_e2e_scoring_alerts_in_pipeline(run):
             # post-anomaly window and yields extra (correct-but-untracked)
             # alerts for the same devices
             session = rt.api("rule-processing").engine("acme").session
-            await wait_until(lambda: session.latency.count >= 4000,
+            await wait_until(lambda: session.flights.latency.count >= 4000,
                              timeout=30.0)
 
             # anomaly tick

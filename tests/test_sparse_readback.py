@@ -70,7 +70,7 @@ def test_sparse_matches_full_readback(run):
             assert scored_s.total_scored == 200
             assert scored_f.total_scored == -1
         # every event was scored in both modes
-        assert full.latency.count == sparse.latency.count == 1000
+        assert full.flights.latency.count == sparse.flights.latency.count == 1000
         full.close()
         sparse.close()
 
@@ -115,8 +115,8 @@ def test_sparse_topk_overflow_is_counted(run):
         s.admit(batch)
         scored = await s.flush()
         assert len(scored) == 4                      # k slots
-        assert s.anomaly_overflow.value > 0
-        assert len(scored) + s.anomaly_overflow.value >= 150
+        assert s.flights.anomaly_overflow.value > 0
+        assert len(scored) + s.flights.anomaly_overflow.value >= 150
         assert scored.total_scored == 200
         s.close()
 
