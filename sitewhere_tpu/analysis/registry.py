@@ -198,6 +198,10 @@ COUNTERS = (
     # ring's table, summed over the step's linear layers: what was
     # lowered (ops/state_kernel.py), 0 where the plain path runs
     "scoring.state.in_place_rows",
+    # the bytes the state kernel must move for those rows: each read and
+    # written whole, in_place_rows x a layer's row x 2, from shapes
+    # (models/seqblocks.py `state_stats`)
+    "scoring.state.kernel_bytes",
     # live rows whose stored context (a window leaf's row of keys and of
     # values) a step read where it rested in the ring's table, summed
     # over the step's layers: what was lowered (ops/context_kernel.py),

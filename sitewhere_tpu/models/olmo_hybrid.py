@@ -188,6 +188,9 @@ class OlmoHybridStreamModel(SeqBlocks):
             cfg.linear_key_head_dim, self._group * cfg.linear_value_head_dim)
         self._taps_shape = ((cfg.linear_conv_kernel_dim - 1)
                             * cfg.conv_channels // 128, 128)
+        # bytes of a layer's state a row, which the kernel reads and
+        # writes whole (`SeqBlocks.state_stats`)
+        self.state_row_bytes = 4 * math.prod(self._state_shape)
         # state leaves that are windows -> the leaf that holds the
         # position a step appends at (scoring/stream.py); the linear
         # layers' leaves are rows, rewritten whole
@@ -351,9 +354,8 @@ class OlmoHybridStreamModel(SeqBlocks):
             vec = jnp.stack([v.reshape(b, groups, -1), self._lanes(alpha),
                              self._lanes(beta),
                              self._lanes((k * q).sum(-1))], 1)
-            table, out = state_kernel.update_rows(table, dev, keys, vec)
-        return (table, out[:, :groups].reshape(b, -1), taps,
-                out[:, groups, 0],
+            table, o, held = state_kernel.update_rows(table, dev, keys, vec)
+        return (table, o.reshape(b, -1), taps, held,
                 (dev < table.shape[0] - 1).sum(dtype=jnp.int32))
 
     def _gdn_out(self, p, x, o, gate):

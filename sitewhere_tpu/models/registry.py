@@ -18,6 +18,10 @@ from sitewhere_tpu.models.lstm import (
     LstmConfig,
     StreamingLstmModel,
 )
+from sitewhere_tpu.models.nemotron_h import (
+    NemotronHConfig,
+    NemotronHStreamModel,
+)
 from sitewhere_tpu.models.olmo_hybrid import (
     OlmoHybridConfig,
     OlmoHybridStreamModel,
@@ -49,6 +53,11 @@ MODEL_REGISTRY: dict[str, tuple[type, type]] = {
     # event, each pass over its own key-value contexts) as a streaming
     # scorer
     "ouro-stream": (OuroConfig, OuroStreamModel),
+    # NVIDIA-Nemotron-3-Super-120B-A12B's block (Mamba-2 layers with a
+    # matrix state a head, latent experts of relu squared with one shared
+    # expert, grouped-query attention, each layer one of the three) as a
+    # streaming scorer
+    "nemotron-h-stream": (NemotronHConfig, NemotronHStreamModel),
     "tft": (TftConfig, TftForecaster),
     "zscore": (ZScoreConfig, ZScoreModel),
     "longwin": (LongWindowConfig, LongWindowModel),

@@ -1,12 +1,12 @@
 """What the streaming sequence scorers have in common (`dsv3-stream`,
 models/dsv3.py; `laguna-stream`, models/laguna.py; `olmo-hybrid-stream`,
 models/olmo_hybrid.py; `lfm2-stream`, models/lfm2.py; `ouro-stream`,
-models/ouro.py): RMSNorm, rope in
+models/ouro.py; `nemotron-h-stream`, models/nemotron_h.py): RMSNorm, rope in
 its two pairings and its YaRN tables, the quantiser
 that makes a measurement a token, the surprisal score with its
 short-history gate, attention over stored keys and values in a prefill
 and a decode form, and the expert layer that is told which experts it
-holds.
+holds, its experts SiLU-gated (three leaves) or `relu` squared (two).
 
 A measurement becomes a token by the device's capped running mean and
 variance (the leaves and the update `lstm-stream` has):
@@ -151,7 +151,7 @@ class SeqBlocks:
     # scores (`step_stats`), each declared with its metric's full name,
     # kind and buckets by one of the families a model names
     # (`stat_families`); a family maps a number to what registers its
-    # metric and returns the feed
+    # metric and returns the feed, and is called with the model
 
     step_stats: tuple = ()
     stat_families: tuple = ()
@@ -162,14 +162,13 @@ class SeqBlocks:
         dispatch (the bytes of held experts' leaves a step streams)."""
         declared = {}
         for family in self.stat_families:
-            declared.update(family(metrics))
+            declared.update(family(self, metrics))
         per_step = [declared[name]() for name in self.step_stats]
         held = held_expert_bytes(self)
         return per_step, [functools.partial(metrics.counter(
             "scoring.moe.weight_bytes").inc, held)] if held else []
 
-    @staticmethod
-    def expert_stats(metrics) -> dict:
+    def expert_stats(self, metrics) -> dict:
         """An expert layer's (`Experts`): pairs held, pairs routed, the
         most tokens one held expert took, runs served in one tile."""
         return {
@@ -182,8 +181,7 @@ class SeqBlocks:
             "moe.runs_one_tile": lambda: metrics.counter(
                 "scoring.moe.runs_one_tile").inc}
 
-    @staticmethod
-    def context_stats(metrics) -> dict:
+    def context_stats(self, metrics) -> dict:
         """Attention over stored contexts: the mean attended length over
         the bounded window leaves and over those that wrap, live rows
         whose append overwrote a wrapping leaf's oldest position, live
@@ -201,12 +199,24 @@ class SeqBlocks:
             "ctx.attended_bytes": lambda: metrics.counter(
                 "scoring.ctx.attended_bytes").inc}
 
-    @staticmethod
-    def state_stats(metrics) -> dict:
-        """A recurrent matrix state's (models/olmo_hybrid.py): the mean
-        decay a step applied, in (0, 1), the largest magnitude in the
-        rows it read, and live rows whose state its kernel updated where
-        it rested (0 on the plain path)."""
+    def state_stats(self, metrics) -> dict:
+        """A recurrent matrix state's (models/olmo_hybrid.py,
+        models/nemotron_h.py): the mean decay a step applied, in (0, 1),
+        the largest magnitude in the rows it read, and live rows whose
+        state its kernel updated where it rested (0 on the plain path),
+        each of which the kernel reads and writes whole: the bytes it
+        must move, live rows x layers x the model's `state_row_bytes`
+        (a layer's row) x 2, from shapes."""
+        def in_place():
+            rows = metrics.counter("scoring.state.in_place_rows")
+            moved = metrics.counter("scoring.state.kernel_bytes")
+
+            def feed(n):
+                rows.inc(n)
+                moved.inc(2 * self.state_row_bytes * n)
+
+            return feed
+
         return {
             "state.decay": lambda: metrics.histogram(
                 "scoring.state.decay",
@@ -214,11 +224,9 @@ class SeqBlocks:
             "state.absmax": lambda: metrics.histogram(
                 "scoring.state.absmax",
                 buckets=[2.0 ** (i / 4) for i in range(-96, 33)]).observe,
-            "state.in_place": lambda: metrics.counter(
-                "scoring.state.in_place_rows").inc}
+            "state.in_place": in_place}
 
-    @staticmethod
-    def loop_stats(metrics) -> dict:
+    def loop_stats(self, metrics) -> dict:
         """A looped model's (models/ouro.py): bytes of layer weights its
         passes stream a step, passes x layers x a layer's, from shapes."""
         return {"loop.weight_bytes": lambda: metrics.counter(
@@ -237,6 +245,11 @@ class SeqBlocks:
                           precision=precision(cdt))
 
     def _mlp(self, p, x):
+        """A SiLU-gated MLP (`gate`, `up`, `down`), or where it has no
+        `gate` the ungated `relu(x up)^2 down`."""
+        if "gate" not in p:
+            return self._mm(jnp.square(jax.nn.relu(self._mm(x, p["up"]))),
+                            p["down"])
         return self._mm(jax.nn.silu(self._mm(x, p["gate"]))
                         * self._mm(x, p["up"]), p["down"])
 
@@ -367,9 +380,10 @@ class SeqBlocks:
                     self._mlp(expert, xs[at]) * wt[at, None])
             return out
 
-        hidden, inter = experts[0]["gate"].shape
+        hidden, inter = experts[0]["up"].shape
         if (jnp.dtype(self.cfg.compute_dtype) != jnp.bfloat16
-                or not expert_kernel.fits(t, hidden, inter, tile)):
+                or not expert_kernel.fits(t, hidden, inter, tile,
+                                          len(experts[0]))):
             return plain(experts, xc[rows], rows, wt, counts)
         return jax.lax.platform_dependent(
             experts, xc[rows], rows, wt, counts, default=plain,

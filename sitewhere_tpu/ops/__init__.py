@@ -3,12 +3,15 @@ table. Each kernel ships with a pure-jax reference path and an
 auto-selection helper; CPU/test runs always take the reference path
 (Pallas interpret mode is exercised by dedicated parity tests).
 `expert_kernel` (a layer's held experts of `dsv3-stream`, of
-`laguna-stream` and of `lfm2-stream`) is imported by its one caller,
+`laguna-stream` and of `lfm2-stream`, SiLU-gated, and of
+`nemotron-h-stream`, `relu` squared) is imported by its one caller,
 models/seqblocks.py,
-which holds its plain twin. `state_kernel` (a linear layer's matrix
-states of `olmo-hybrid-stream`, updated in the rows of the ring's table
-they rest in) is imported by models/olmo_hybrid.py, whose `_gdn_cell`
-is its plain twin: the kernel is handed to `RowsInTurn.update`
+which holds its plain twin. `state_kernel` (a layer's matrix states,
+updated in the rows of the ring's table they rest in: the gated delta
+rule of `olmo-hybrid-stream` and Mamba-2's decay and write of
+`nemotron-h-stream`) is imported by models/olmo_hybrid.py and
+models/nemotron_h.py, whose `_gdn_cell` and `_ssm_cell` are its plain
+twins: the kernel is handed to `RowsInTurn.update`
 (scoring/stream.py), which promises when it runs; the kernel promises
 that rows the frame does not name, the scratch row among them, come
 back as they were, and that every write has landed when it returns.

@@ -182,9 +182,10 @@ def _inside_whiles(comps: dict) -> set:
 
 
 def _expert_leaves_read_once(hlo: str, held: int, layers: list,
-                             hidden: int, inter: int) -> None:
+                             hidden: int, inter: int,
+                             names: tuple = ("gate", "up", "down")) -> None:
     """The held experts as one grouped pass a layer: each of an expert
-    layer's `3 * held` leaves goes to a kernel (or a product) of the
+    layer's `len(names) * held` leaves goes to a kernel (or a product) of the
     entry computation, outside any `while`; the `while`s that remain are
     the overflow's, ONE a layer (further passes of the same kind, tile
     `n` of every held expert at once), under `moe_experts`, and only they
@@ -202,8 +203,8 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
                 named.setdefault(name, []).append(line)
     for layer in layers:
         leaves = re.findall(rf"(params__layer{layer}____experts____e\d+____"
-                            r"(?:gate|up|down)__[.\d]*): bf16", hlo)
-        assert len(set(leaves)) == 3 * held
+                            rf"(?:{'|'.join(names)})__[.\d]*): bf16", hlo)
+        assert len(set(leaves)) == len(names) * held
         for leaf in set(leaves):
             uses = named.get(leaf, [])
             # the kernel (or a product) takes the leaf as it rests, or the
@@ -221,9 +222,10 @@ def _expert_leaves_read_once(hlo: str, held: int, layers: list,
                        or " tuple(" in line for line in uses
                        if line not in reads and line not in ahead), (leaf,
                                                                      uses)
-    # (a step's other kernels, `context_rows`, are attention's)
+    # (a step's other kernels, `context_rows` and `state_rows`, are
+    # attention's and a recurrence's)
     kernels = [line for line in body if "tpu_custom_call" in line
-               and "context_rows" not in line]
+               and "context_rows" not in line and "state_rows" not in line]
     assert len(kernels) == len(layers)
     assert all("moe_experts" in line for line in kernels)
     whiles = [line for lines in comps.values() for line in lines
@@ -537,6 +539,10 @@ def test_olmo_step_moves_no_state_table_and_holds_a_layers_rows_at_a_time(
         " parameter(" in line or " get-tuple-element(" in line
         for line in of_a_table), of_a_table
     assert f"f32[{OLMO_FRAME},15,96,384]" not in hlo
+    # the kernel's delta rule: a row whole, one block, four vectors a row
+    (module,) = _mosaic_modules(kernels)
+    text = module.operation.get_asm(enable_debug_info=False)
+    assert "memref<1x4x15x384xf32" in text and "memref<1x15x96x384xf32" in text
     for shape, leaves, scattered in ((table, 3, 0),
                                      (f"bf16[{OLMO_ROWS},384,3840]", 2, 2),
                                      (f"bf16[{OLMO_ROWS},270,128]", 3, 3)):
@@ -780,3 +786,89 @@ def test_ouro_step_is_one_pass_body_that_moves_no_table_and_no_weight(
     # transposed their stacked projections to make that a view)
     assert 13.0e9 < mem.argument_size_in_bytes < 13.2e9
     assert mem.temp_size_in_bytes < 0.02e9
+
+
+# -- `nemotron-h-stream` at `nemotron-3-super-ep8`'s own size --------------------
+
+NEMOTRON_ROWS, NEMOTRON_FRAME = 385, 128
+
+
+@pytest.fixture(scope="module")
+def nemotron_step(one_chip):
+    """(model, state shapes, the ring step compiled for the described
+    chip) at the benchmark configuration's `model_config` as it stands."""
+    import json
+    import os
+
+    from sitewhere_tpu.models import build_model
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "nemotron-3-super-ep8.json")) as fh:
+        model = build_model("nemotron-h-stream",
+                            **json.load(fh)["model_config"])
+    state, compiled = _compile_step(model, NEMOTRON_ROWS, NEMOTRON_FRAME,
+                                    one_chip, jnp.float32)
+    return model, state, compiled
+
+
+def test_nemotron_step_lowers_both_kernels_and_moves_no_table(nemotron_step):
+    """Five Mamba-2 states of 4 MiB a row (`f32[385, 64, 128, 128]`: two
+    heads of 64 to a row of lanes over a state of 128), five leaves of
+    conv taps, one attention layer's two context tables and five expert
+    layers of 64 held latent experts of 1024 x 2688. Each Mamba-2 layer
+    is ONE `state_rows` call (ops/state_kernel.py's decay-and-write rule,
+    three vectors a row, the row in two blocks) that takes its table and
+    hands it back in the same buffer, and nothing of a frame's rows of
+    it exists; each expert layer is ONE `expert_tiles` call of the
+    two-leaf form (and the one in its overflow's loop) that takes its
+    128 leaves as they rest; the attention layer is ONE `context_rows`
+    call over its two tables. No table is copied, transposed or sliced,
+    and the step's scratch is small beside the 13.9 GB it is handed."""
+    from chip_smoke import _table_moves
+    from sitewhere_tpu.ops import context_kernel, expert_kernel, state_kernel
+
+    model, state, compiled = nemotron_step
+    hlo = compiled.as_text()
+    lines = hlo.splitlines()
+    table = f"f32[{NEMOTRON_ROWS},64,128,128]"
+    context = f"bf16[{NEMOTRON_ROWS},512,256]"
+    assert state_kernel.blocks((NEMOTRON_ROWS, 64, 128, 128)) == 2
+    assert expert_kernel.fits(NEMOTRON_FRAME, 1024, 2688, 128, 2)
+    assert context_kernel.fits((NEMOTRON_ROWS, 512, 256), jnp.bfloat16, 32, 2)
+    assert _table_moves(hlo, NEMOTRON_ROWS) == []
+    shapes = set(re.findall(rf"\w+\[{NEMOTRON_ROWS}(?:,\d+)*\]", hlo))
+    assert shapes == {table, f"bf16[{NEMOTRON_ROWS},240,128]", context,
+                      f"bf16[{NEMOTRON_ROWS},4096]", f"f32[{NEMOTRON_ROWS}]",
+                      f"s32[{NEMOTRON_ROWS}]"}, shapes
+    states = [line for line in lines if "tpu_custom_call" in line
+              and "state_rows" in line]
+    assert len(states) == 5
+    assert all(re.search(rf"= \({re.escape(table)}\S*, f32\["
+                         rf"{2 * NEMOTRON_FRAME},33,128\]", line)
+               and "output_to_operand_aliasing={{0}: (1, {})}" in line
+               and "ssm_state" in line for line in states), states
+    assert len({re.search(r"custom-call\(%\S+, (%state__s\d__\S*),",
+                          line).group(1) for line in states}) == 5
+    assert f"f32[{NEMOTRON_FRAME},64,128,128]" not in hlo
+    of_a_table = [line for line in lines if re.match(
+        rf"\s*(?:ROOT )?%\S+ = {re.escape(table)}", line)]
+    assert all(" parameter(" in line or " get-tuple-element(" in line
+               for line in of_a_table), of_a_table
+    # the kernel's decay-and-write rule: three vectors of 32 rows of
+    # lanes a block
+    (module,) = _mosaic_modules(states)
+    text = module.operation.get_asm(enable_debug_info=False)
+    assert "memref<1x3x32x128xf32" in text and "memref<1x32x128x128xf32" in text
+    (attends,) = _context_kernels(lines, NEMOTRON_FRAME, 32, context)
+    assert "attn_full" in attends
+    _expert_leaves_read_once(hlo, 64, [1, 3, 5, 8, 10], 1024, 2688,
+                             names=("up", "down"))
+    _expert_kernels(lines, 64, 2 * 5)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes > 8.3e9
+    # 5.53 GB of weights and 8.40 of state
+    assert 13.9e9 < mem.argument_size_in_bytes < 14.0e9
+    assert mem.temp_size_in_bytes < 0.2e9

@@ -252,6 +252,53 @@ def test_expert_kernel_matches_the_plain_products_interpret(
         assert (np.asarray(got)[untouched] == 0).all()
 
 
+@pytest.mark.parametrize("held, tile", [(1, 32), (3, 16), (5, 16)])
+def test_ungated_experts_match_the_plain_products_interpret(held, tile):
+    """The kernel's two-leaf form at `nemotron-h-stream`'s published
+    latent experts (1,024 x 2,688 in 21 blocks of 128, and back), chosen
+    by the leaves it is handed, against `_mlp`'s ungated twin and one
+    scatter-add: `relu(x up)^2` in f32, rounded to bf16 once before the
+    down product, the weight in f32, a token's experts summed in f32; a
+    full tile, an empty run and a partial one. It asks VMEM for two
+    leaves' blocks where the gated form asks for three."""
+    from sitewhere_tpu.models import build_model
+    from sitewhere_tpu.ops.expert_kernel import expert_tiles, fits, vmem_bytes
+
+    hidden, inter, tokens = 1024, 2688, 40
+    assert fits(128, hidden, inter, 128, 2) and fits(2016, hidden, inter,
+                                                     128, 2)
+    assert vmem_bytes(128, hidden, 128, 2) + 4 * hidden * 128 \
+        == vmem_bytes(128, hidden, 128)
+    keys = iter(jax.random.split(jax.random.PRNGKey(6), 2 * held + 3))
+    experts = [{name: (jax.random.normal(next(keys), shape, jnp.float32)
+                       * 0.03).astype(jnp.bfloat16)
+                for name, shape in (("up", (hidden, inter)),
+                                    ("down", (inter, hidden)))}
+               for _ in range(held)]
+    rng = np.random.default_rng(held)
+    rows = np.concatenate([np.sort(rng.permutation(tokens)[:tile])
+                           for _ in range(held)]).astype(np.int32)
+    x = jax.random.normal(next(keys), (tokens, hidden)).astype(jnp.bfloat16)
+    wts = jax.random.uniform(next(keys), (held * tile,))
+    model = build_model("nemotron-h-stream", num_hidden_layers=1,
+                        hybrid_override_pattern="E",
+                        num_nextn_predict_layers=0)
+    counts = np.resize([tile, 0, 5], held).astype(np.int32)
+    got = expert_tiles(experts, x[rows], rows, wts, counts, tokens,
+                       interpret=True)
+    real = (np.arange(tile)[None, :] < counts[:, None]).reshape(-1)
+    ys = jnp.concatenate([
+        model._mlp(expert, x[rows[e * tile:(e + 1) * tile]])
+        for e, expert in enumerate(experts)]) * (wts * real)[:, None]
+    want = jnp.zeros((tokens, hidden), jnp.float32).at[rows].add(ys)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    scale = float(jnp.abs(want).max())
+    assert 0.1 < scale < 10
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * scale
+    untouched = np.setdiff1d(np.arange(tokens), rows[real])
+    assert (np.asarray(got)[untouched] == 0).all()
+
+
 # -- ops/state_kernel.py: a matrix state updated where it rests ---------------
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -355,22 +402,117 @@ def test_state_kernel_matches_the_cell_and_writes_no_other_row_interpret(
                                                             ).all()
 
 
+# (heads, head width, state, groups of B and C): the published Mamba-2
+# layer, a row of 4 MiB taken in two blocks of 32 rows of lanes, and a
+# small one, a row of one block
+MAMBA_SHAPES = {"published_heads": (128, 64, 128, 8), "small": (4, 64, 64, 2)}
+
+
+def _mamba_model(heads, dim, state, groups):
+    from sitewhere_tpu.models import build_model
+
+    return build_model(
+        "nemotron-h-stream", compute_dtype=jnp.float32, hidden_size=128,
+        expand=heads * dim // 128, mamba_num_heads=heads,
+        mamba_head_dim=dim, ssm_state_size=state, n_groups=groups,
+        num_hidden_layers=1, hybrid_override_pattern="M",
+        num_nextn_predict_layers=0, vocab_size=64, window=8,
+        context_positions=16)
+
+
+@pytest.mark.parametrize("shape", MAMBA_SHAPES)
+def test_state_kernel_decay_rule_matches_the_mamba2_cell_interpret(
+        shape, monkeypatch):
+    """ops/state_kernel.py's decay-and-write rule (three vectors a row)
+    against `NemotronHStreamModel._ssm_cell` on random states and
+    operands: the next state to 1e-5 of its scale, `y` to 1e-4, the
+    largest magnitude a row held exactly; at the published widths the
+    row is taken in two blocks, and what each block found is one row's
+    again; rows the frame does not name are bit-equal after the call,
+    the scratch row among them; padding writes nothing and reads 0; a
+    second dispatch over rows that overlap the first's equals two plain
+    steps."""
+    from sitewhere_tpu.ops import state_kernel
+    from sitewhere_tpu.scoring.stream import pad_rows
+
+    model = _mamba_model(*MAMBA_SHAPES[shape])
+    c = model.cfg
+    rows, frame, live = 5, 4, 3
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 6))
+    p = {"conv": jax.random.normal(next(keys), (4, c.conv_channels)) * 0.5,
+         "conv_bias": jnp.zeros(c.conv_channels),
+         "D": jnp.linspace(0.5, 1.5, c.mamba_num_heads)}
+    table = jax.random.normal(next(keys), (rows,) + model._state_shape) * 0.3
+    taps = jax.random.normal(next(keys), (frame, 3 * c.conv_channels))
+    xbc = jax.random.normal(next(keys), (frame, c.conv_channels))
+    dt = jax.random.uniform(next(keys), (frame, c.mamba_num_heads),
+                            minval=0.01, maxval=0.5)
+    a = jnp.exp(-dt * 4.0)
+    assert state_kernel.fits(table.shape, table.dtype)
+    assert state_kernel.blocks(table.shape) == (2 if shape == "published_heads"
+                                                else 1)
+    _interpreted(monkeypatch)
+    scratch = rows - 1
+
+    def plain(table, dev):
+        y, s, _, held = model._ssm_cell(p, table[jnp.minimum(dev, scratch)],
+                                        taps, xbc, dt, a)
+        return table.at[dev].set(s, mode="drop"), y, held
+
+    def kernel(table, dev):
+        table, y, _, held, n = model._ssm_rows(p, table, dev, taps, xbc, dt,
+                                               a)
+        return table, y, held, n
+
+    first = np.concatenate([[0, 1, 3], pad_rows(scratch, frame - live)])
+    second = np.concatenate([[1, 2], pad_rows(scratch, frame - 2)])
+    want, got = table, table
+    for dev, n_live in ((first, live), (second, 2)):
+        dev = jnp.asarray(dev, jnp.int32)
+        before = np.asarray(got)
+        want, y_want, held_want = jax.jit(plain)(want, dev)
+        got, y_got, held_got, n = jax.jit(kernel)(got, dev)
+        assert int(n) == n_live
+        scale = float(jnp.abs(want).max())
+        assert 0.5 < scale < 10
+        assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+        y_scale = float(jnp.abs(y_want[:n_live]).max())
+        assert float(jnp.abs(y_got - y_want)[:n_live].max()) < 1e-4 * y_scale
+        assert (np.asarray(held_got)[:n_live]
+                == np.asarray(held_want)[:n_live]).all()
+        assert not np.asarray(held_got)[n_live:].any()
+        unnamed = np.setdiff1d(np.arange(rows), np.asarray(dev)[:n_live])
+        assert (np.asarray(got)[unnamed] == before[unnamed]).all()
+
+
 def test_state_kernel_takes_float32_rows_of_whole_tiles_that_vmem_holds():
-    """`fits` reads the leaf's shape and dtype: the published row and the
+    """`fits` reads the leaf's shape and dtype: the published rows and the
     tests' small ones; not a bfloat16 leaf, a row of keys that is no
-    whole sublane tile, lanes that are no whole lane tile, nor a row
-    four of which pass the VMEM the call asks for; and `update_rows`
-    refuses what `fits` does not take."""
+    whole sublane tile, lanes that are no whole lane tile, nor a row of
+    which no block of whole groups, four blocks at a time, stays under
+    the VMEM the call asks for; a row that four of pass it whole is
+    taken in the fewest blocks of its groups that do not (`blocks`); and
+    `update_rows` refuses what `fits` does not take."""
     from sitewhere_tpu.ops import state_kernel
 
     fits = state_kernel.fits
     assert fits((769, 15, 96, 384), jnp.float32)
+    assert state_kernel.blocks((769, 15, 96, 384)) == 1
     assert state_kernel.vmem_bytes((769, 15, 96, 384)) < 10 << 20
+    # a Mamba-2 row of 128 heads of 64 over a state of 128: 4 MiB, two
+    # blocks of 2 MiB
+    assert fits((385, 64, 128, 128), jnp.float32)
+    assert state_kernel.blocks((385, 64, 128, 128)) == 2
+    assert state_kernel.vmem_bytes((385, 64, 128, 128)) > 12 << 20
+    assert state_kernel.vmem_bytes((385, 64, 128, 128), 2) < 10 << 20
     assert fits((7, 2, 16, 128), jnp.float32)
     assert not fits((769, 15, 96, 384), jnp.bfloat16)
     assert not fits((769, 15, 92, 384), jnp.float32)
     assert not fits((769, 15, 96, 192), jnp.float32)
-    assert not fits((769, 30, 96, 384), jnp.float32)
+    assert fits((769, 30, 96, 384), jnp.float32)
+    assert state_kernel.blocks((769, 30, 96, 384)) == 2
+    assert not fits((769, 1, 1024, 1024), jnp.float32)
+    assert state_kernel.blocks((769, 1, 1024, 1024)) == 0
     assert not fits((769, 96, 5760), jnp.float32)
     with pytest.raises(ValueError, match="takes no table"):
         state_kernel.update_rows(

@@ -219,7 +219,10 @@ WEIGHTS = ("scoring.moe.weight_bytes", "Counter", None)
 
 # what the session registered for each sequence model's step, in the
 # order of its `step_stats`, before the models declared it (and, last,
-# the counter fed once a dispatch for the models that hold experts)
+# the counter fed once a dispatch for the models that hold experts);
+# beside the in-place rows, the bytes the state kernel moves for them,
+# and the model with Mamba-2 layers, whose step declares all three
+# families
 REGISTERED = {
     "dsv3-stream": MOE + [AT_REST, WEIGHTS],
     "laguna-stream": MOE + [
@@ -230,7 +233,13 @@ REGISTERED = {
         ("scoring.ctx.positions", "Histogram", OCTAVES),
         ("scoring.state.decay", "Histogram", DECAY),
         ("scoring.state.absmax", "Histogram", ABSMAX),
-        ("scoring.state.in_place_rows", "Counter", None), AT_REST],
+        ("scoring.state.in_place_rows", "Counter", None),
+        ("scoring.state.kernel_bytes", "Counter", None), AT_REST],
+    "nemotron-h-stream": MOE + [
+        AT_REST, ("scoring.state.decay", "Histogram", DECAY),
+        ("scoring.state.absmax", "Histogram", ABSMAX),
+        ("scoring.state.in_place_rows", "Counter", None),
+        ("scoring.state.kernel_bytes", "Counter", None), WEIGHTS],
     "ouro-stream": [
         ("scoring.ctx.positions", "Histogram", OCTAVES), AT_REST,
         ("scoring.loop.weight_bytes", "Counter", None),
@@ -244,12 +253,13 @@ def test_each_sequence_models_declarations_register_what_the_session_did(
     from tests.test_dsv3 import MC as DSV3
     from tests.test_laguna import MC as LAGUNA
     from tests.test_lfm2 import MC as LFM2
+    from tests.test_nemotron_h import MC as NEMOTRON
     from tests.test_olmo_hybrid import MC as OLMO
     from tests.test_ouro import MC as OURO
 
     widths = {"dsv3-stream": DSV3, "laguna-stream": LAGUNA,
               "lfm2-stream": LFM2, "olmo-hybrid-stream": OLMO,
-              "ouro-stream": OURO}[name]
+              "ouro-stream": OURO, "nemotron-h-stream": NEMOTRON}[name]
     model = build_model(name, **widths)
     metrics = MetricsRegistry()
     per_step, per_dispatch = model.stat_feeds(metrics)
