@@ -207,6 +207,10 @@ COUNTERS = (
     # over the step's layers: what was lowered (ops/context_kernel.py),
     # 0 where the plain path gathers the rows
     "scoring.ctx.at_rest_rows",
+    # ...and the positions of each of their tables the kernel copied: a
+    # row's prefix up to its position, rounded up to a position block
+    # (ops/context_kernel.py `reads`), 0 where the plain path runs
+    "scoring.ctx.read_positions",
     # a looped model's step (models/ouro.py `step_stats`): the bytes of
     # layer weights its passes stream (passes x layers x a layer's, from
     # shapes) and of keys and values its equations read ((pos + 1) x

@@ -165,7 +165,7 @@ class LagunaStreamModel(SeqBlocks):
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
                   "moe.runs_one_tile", "ctx.window_positions",
-                  "ctx.wrapped", "ctx.at_rest")
+                  "ctx.wrapped", "ctx.at_rest", "ctx.read_positions")
     stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats)
 
     def __init__(self, cfg: LagunaConfig = LagunaConfig()):
@@ -396,12 +396,13 @@ class LagunaStreamModel(SeqBlocks):
         pos = rows["pos"]
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
-        held = busiest = one_tile = at_rest = jnp.zeros((), jnp.int32)
+        held = busiest = one_tile = at_rest = read = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
             x, counts = self._block_decode(
                 l, params[f"layer{l}"], x, rows[f"k{l}"], rows[f"v{l}"], pos,
                 live)
             at_rest += rows[f"k{l}"].read_rows
+            read += rows[f"k{l}"].read_positions
             if counts is not None:
                 held += counts.sum()
                 busiest = jnp.maximum(busiest, counts.max())
@@ -428,7 +429,8 @@ class LagunaStreamModel(SeqBlocks):
             busiest.astype(jnp.float32),
             mean_live(pos),
             one_tile.astype(jnp.float32),
-            attended, wrapped, at_rest.astype(jnp.float32)])
+            attended, wrapped, at_rest.astype(jnp.float32),
+            read.astype(jnp.float32)])
         return score, out, stats
 
     def _seeded(self, name, leaf, entry, count):

@@ -164,7 +164,7 @@ class Lfm2StreamModel(SeqBlocks):
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
-                  "moe.runs_one_tile", "ctx.at_rest")
+                  "moe.runs_one_tile", "ctx.at_rest", "ctx.read_positions")
     stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats)
 
     def __init__(self, cfg: Lfm2Config = Lfm2Config()):
@@ -429,7 +429,7 @@ class Lfm2StreamModel(SeqBlocks):
         pos = rows["pos"]
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
-        held = busiest = one_tile = at_rest = jnp.zeros((), jnp.int32)
+        held = busiest = one_tile = at_rest = read = jnp.zeros((), jnp.int32)
         for l in range(self.layers):
             p = params[f"layer{l}"]
             if self.kinds[l] == CONV:
@@ -442,6 +442,7 @@ class Lfm2StreamModel(SeqBlocks):
                     self._decode_at_rest(q, k, v, kctx, vctx, pos,
                                          c.num_key_value_heads))
                 at_rest += kctx.read_rows
+                read += kctx.read_positions
             x, counts = self._ffn_half(p, x, live)
             if counts is not None:
                 held += counts.sum()
@@ -457,7 +458,7 @@ class Lfm2StreamModel(SeqBlocks):
             busiest.astype(jnp.float32),
             jnp.where(live, pos, 0).sum() / jnp.maximum(n_live, 1),
             one_tile.astype(jnp.float32),
-            at_rest.astype(jnp.float32)])
+            at_rest.astype(jnp.float32), read.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:

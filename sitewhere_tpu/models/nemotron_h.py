@@ -206,8 +206,8 @@ class NemotronHStreamModel(SeqBlocks):
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("moe.assignments_held", "moe.assignments",
                   "moe.expert_max_tokens", "ctx.positions",
-                  "moe.runs_one_tile", "ctx.at_rest", "state.decay",
-                  "state.absmax", "state.in_place")
+                  "moe.runs_one_tile", "ctx.at_rest", "ctx.read_positions",
+                  "state.decay", "state.absmax", "state.in_place")
     stat_families = (SeqBlocks.expert_stats, SeqBlocks.context_stats,
                      SeqBlocks.state_stats)
 
@@ -646,7 +646,8 @@ class NemotronHStreamModel(SeqBlocks):
         pos = rows["pos"]
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
-        held = busiest = one_tile = at_rest = in_place = jnp.int32(0)
+        held = busiest = one_tile = at_rest = read = jnp.int32(0)
+        in_place = jnp.int32(0)
         decay, largest = jnp.float32(0), jnp.float32(0)
         for l in range(self.layers):
             p = params[f"layer{l}"]
@@ -667,6 +668,7 @@ class NemotronHStreamModel(SeqBlocks):
                     self._decode_at_rest(q, k, v, kctx, vctx, pos,
                                          c.num_key_value_heads))
                 at_rest += kctx.read_rows
+                read += kctx.read_positions
             else:
                 x, counts = self._moe(p, x, live)
                 held += counts.sum()
@@ -682,7 +684,7 @@ class NemotronHStreamModel(SeqBlocks):
             busiest.astype(jnp.float32),
             jnp.where(live, pos, 0).sum() / n_live,
             one_tile.astype(jnp.float32),
-            at_rest.astype(jnp.float32),
+            at_rest.astype(jnp.float32), read.astype(jnp.float32),
             decay / (n_live * max(self.kinds.count(MAMBA), 1)
                      * c.mamba_num_heads),
             largest, in_place.astype(jnp.float32)])

@@ -144,7 +144,7 @@ class OlmoHybridStreamModel(SeqBlocks):
     # the numbers `step_score` returns beside the scores, by the names
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("ctx.positions", "state.decay", "state.absmax",
-                  "state.in_place", "ctx.at_rest")
+                  "state.in_place", "ctx.at_rest", "ctx.read_positions")
     stat_families = (SeqBlocks.context_stats, SeqBlocks.state_stats)
 
     def __init__(self, cfg: OlmoHybridConfig = OlmoHybridConfig()):
@@ -514,7 +514,7 @@ class OlmoHybridStreamModel(SeqBlocks):
         token, score, out = self._arrive(params, rows, v)
         x = params["embed"][token].astype(jnp.float32)
         decay, largest = jnp.float32(0), jnp.float32(0)
-        in_place = at_rest = jnp.int32(0)
+        in_place = at_rest = read = jnp.int32(0)
         for l in range(self.layers):
             p = params[f"layer{l}"]
             first, second = self._leaves(l)
@@ -534,6 +534,7 @@ class OlmoHybridStreamModel(SeqBlocks):
                     vctx=rows[second]: self._decode_at_rest(
                         q, k, v, kctx, vctx, pos, c.num_key_value_heads))
                 at_rest += rows[first].read_rows
+                read += rows[first].read_positions
             x = self._mlp_half(p, x)
         out["hn"] = rms(x, params["norm"], c.rms_norm_eps).astype(
             c.compute_dtype)
@@ -543,7 +544,7 @@ class OlmoHybridStreamModel(SeqBlocks):
             decay / (n_live * max(self.kinds.count(LINEAR), 1)
                      * c.linear_num_value_heads),
             largest, in_place.astype(jnp.float32),
-            at_rest.astype(jnp.float32)])
+            at_rest.astype(jnp.float32), read.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:

@@ -142,7 +142,7 @@ class OuroStreamModel(SeqBlocks):
     # the numbers `step_score` returns beside the scores, by the names
     # the session feeds the metrics registry under (`scoring.<name>`)
     step_stats = ("ctx.positions", "ctx.at_rest", "loop.weight_bytes",
-                  "ctx.attended_bytes")
+                  "ctx.attended_bytes", "ctx.read_positions")
     stat_families = (SeqBlocks.context_stats, SeqBlocks.loop_stats)
 
     def __init__(self, cfg: OuroConfig = OuroConfig()):
@@ -313,20 +313,21 @@ class OuroStreamModel(SeqBlocks):
         and takes the entry beside it (ops/context_kernel.py); elsewhere
         the rows are gathered and `_decode_rows` reads the block with the
         entry laid in: one algorithm, the plain path the kernel's twin in
-        the tests. -> (`[B, heads, d]`, live rows read at rest)."""
+        the tests. -> (`[B, heads, d]`, live rows read at rest, positions
+        copied of each table for them)."""
         c = self.cfg
         kv, width, dev = c.num_key_value_heads, c.kv_width, keys.dev
 
         def plain(ktab, vtab, q, k, v):
             return (self._decode_rows(q, k, v, keys.rows(slot, width),
                                       vals.rows(slot, width), pos, kv),
-                    jnp.int32(0))
+                    jnp.int32(0), jnp.int32(0))
 
         def rested(ktab, vtab, q, k, v):
             return (context_kernel.context_rows(
                 ktab, vtab, dev, pos, q, slot, (k, v), kv=kv,
                 scale=self._scale),
-                (dev < ktab.shape[0] - 1).sum(dtype=jnp.int32))
+                *context_kernel.reads(ktab.shape, dev, pos, own=True))
 
         args = (keys.table, vals.table, q, k, v)
         if (jnp.dtype(c.compute_dtype) != jnp.bfloat16
@@ -363,22 +364,24 @@ class OuroStreamModel(SeqBlocks):
         token, score, out = self._arrive(params, rows, v)
 
         def layer(p, y, carry, slot):
-            lines, at_rest = carry
+            lines, at_rest, copied = carry
             read = []
 
             def attend(q, k, v):
-                a, rows_read = self._attend(q, k, v, kctx, vctx, pos, slot)
-                read.append(rows_read)
+                a, *counts = self._attend(q, k, v, kctx, vctx, pos, slot)
+                read.append(counts)
                 return a
 
             y, k, v = self._layer(p, y, at, attend)
-            return y, (self._into_slot(lines, slot, k, v), at_rest + read[0])
+            (rows_read, positions), = read
+            return y, (self._into_slot(lines, slot, k, v),
+                       at_rest + rows_read, copied + positions)
 
         empty = jnp.zeros((pos.shape[0], self.slots * c.kv_width),
                           c.compute_dtype)
-        y, ((klines, vlines), at_rest) = self._loop(
+        y, ((klines, vlines), at_rest, copied) = self._loop(
             params, params["embed"][token], layer,
-            ((empty, empty), jnp.int32(0)))
+            ((empty, empty), jnp.int32(0), jnp.int32(0)))
         kctx.append(klines)
         vctx.append(vlines)
         out["hn"] = rms(y, params["norm"], c.rms_norm_eps).astype(
@@ -390,7 +393,7 @@ class OuroStreamModel(SeqBlocks):
             at_rest.astype(jnp.float32),
             jnp.float32(self._loop_bytes),
             jnp.where(live, pos + 1, 0).sum().astype(jnp.float32)
-            * (self.slots * entry)])
+            * (self.slots * entry), copied.astype(jnp.float32)])
         return score, out, stats
 
     def warm_state(self, params: dict, x: jax.Array, valid: jax.Array) -> dict:

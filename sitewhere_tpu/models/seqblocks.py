@@ -185,8 +185,9 @@ class SeqBlocks:
         """Attention over stored contexts: the mean attended length over
         the bounded window leaves and over those that wrap, live rows
         whose append overwrote a wrapping leaf's oldest position, live
-        rows whose context the step's kernel read where it rested (0 on
-        the plain path), bytes of keys and values a looped model read."""
+        rows whose context the step's kernel read where it rested and the
+        positions it copied of each of their tables (0 on the plain
+        path), bytes of keys and values a looped model read."""
         return {
             "ctx.positions": lambda: metrics.histogram(
                 "scoring.ctx.positions", buckets=OCTAVES).observe,
@@ -196,6 +197,8 @@ class SeqBlocks:
                 "scoring.ctx.wrapped").inc,
             "ctx.at_rest": lambda: metrics.counter(
                 "scoring.ctx.at_rest_rows").inc,
+            "ctx.read_positions": lambda: metrics.counter(
+                "scoring.ctx.read_positions").inc,
             "ctx.attended_bytes": lambda: metrics.counter(
                 "scoring.ctx.attended_bytes").inc}
 
@@ -468,8 +471,9 @@ class SeqBlocks:
         lines, no gathered copy. Elsewhere the rows are gathered, `_decode_rows`
         reads them and the entries are appended: one algorithm, and the
         plain path is the kernel's twin in the tests. `kctx.read_rows`
-        is left saying how many live rows were read at rest. -> `[B,
-        heads, d]`."""
+        and `kctx.read_positions` are left saying how many live rows
+        were read at rest and how many positions of each table were
+        copied for them (`context_kernel.reads`). -> `[B, heads, d]`."""
         dev, slot = kctx.dev, kctx.slot
 
         def handles(ktab, vtab):
@@ -479,7 +483,8 @@ class SeqBlocks:
             keys, vals = handles(ktab, vtab)
             out = self._decode_rows(q, k, v, keys.rows(), vals.rows(), pos,
                                     kv, wraps)
-            return keys.append(k), vals.append(v), out, jnp.int32(0)
+            return (keys.append(k), vals.append(v), out, jnp.int32(0),
+                    jnp.int32(0))
 
         def rested(ktab, vtab, q, k, v):
             keys, vals = handles(ktab, vtab)
@@ -487,7 +492,7 @@ class SeqBlocks:
             out = context_kernel.context_rows(ktab, vtab, dev, pos, q,
                                               kv=kv, scale=self._scale)
             return (ktab, vtab, out,
-                    (dev < ktab.shape[0] - 1).sum(dtype=jnp.int32))
+                    *context_kernel.reads(ktab.shape, dev, pos))
 
         args = (kctx.table, vctx.table, q, k, v)
         leaf = (kctx.table.shape, kctx.table.dtype, q.shape[1], kv)
@@ -498,7 +503,8 @@ class SeqBlocks:
         else:
             took = jax.lax.platform_dependent(*args, default=plain,
                                               tpu=rested)
-        kctx.table, vctx.table, out, kctx.read_rows = took
+        (kctx.table, vctx.table, out, kctx.read_rows,
+         kctx.read_positions) = took
         return out
 
     # -- tokens and the score -------------------------------------------------

@@ -8,15 +8,32 @@ frame names 256 rows, and the equations read a row once. As XLA's
 gather, the decode form's write of the position's own entry into the
 gathered copy and its second reading, a row crossed HBM three times:
 ten gathers were 9.85 ms of Laguna's 25.1 ms step on a v5e before the
-model had read a byte, two were 4.6 of Olmo's 19.8 (PERF.md section 6,
-PR 38). Here the two tables are the kernel's inputs as they are, the
-frame's row indices and positions are prefetched scalars that the block
-index is read from, and the pipeline brings row `dev[i + 1]`'s keys and
-values into VMEM while row `dev[i]` computes. Nothing of shape `[frame,
-positions, width]` exists. The kernel only reads: the position's own
-entry is in the table already, appended by the ring before the call
-(scoring/stream.py `ContextAtRest`), or handed beside it (below), and no
-output aliases a table.
+model had read a byte, two were 4.6 of Olmo's 19.8 (PERF.md section
+6). Here the two tables stay where they rest (`pl.ANY`) and the
+kernel copies what it reads itself, as ops/expert_kernel.py does: the
+frame's row indices and positions are prefetched scalars, a grid step
+is a row, and step `i` starts row `dev[i + 1]`'s copies into the other
+half of a double buffer before row `dev[i]` computes. Nothing of shape
+`[frame, positions, width]` exists. The kernel only reads: the
+position's own entry is in the table already, appended by the ring
+before the call (scoring/stream.py `ContextAtRest`), or handed beside it
+(below), and no output aliases a table.
+
+A row copies only the prefix it has filled: `n = ceil(len / B)`
+position blocks of keys and of values, `len = min(pos + 1, P)`
+(`min(pos, P)` where the own entry comes beside the table), one block at
+the least, `B = position_block(P)` (64 at 384, 448, 512 and 768), and
+the row's products run over those `n * B` positions alone, so nothing
+it did not copy reaches one. A row that has wrapped (`pos >= P`) copies
+all of it. Copying whole rows, `ouro-stream`'s kernel moved 2.82 GB a
+step at 90% of HBM's peak where its equations need 1.43 (PERF.md
+section 5): only a shorter copy could make it faster. Each row starts
+ONE copy a table whose length is one of the `P / B` static lengths,
+picked by a tree of branches on `n` (`expert_kernel.pick`), and waits on
+it through the same descriptor in the same branch. The grid is not split
+over position blocks: a grid step costs about 0.35 us whether it copies
+or not, and `ouro-stream`'s 768 row-steps a step at 7 blocks a row
+would cost 1.9 ms, the whole saving.
 
     a row, `q` `[heads, d]`, keys and values `[P, kv * d]` as stored:
         wide   = q, head h over the lanes of key-value head h // g
@@ -33,8 +50,8 @@ nothing to mask: `p <= pos` says both. The block-diagonal `wide` costs
 byte of the context enters the MXU once, which is what bounds the
 products (1.5 TB/s over four MXUs against HBM's 819 GB/s).
 
-Padding (`dev >= scratch`, the table's last row) is clipped onto the
-scratch row; its output is 0 and it writes nothing. Heads are padded to
+Padding (`dev >= scratch`, the table's last row) copies nothing; its
+output is 0 and it writes nothing. Heads are padded to
 whole `(16, 128)` tiles of `probs`; the padded rows' lanes are all zero
 and are cut off again.
 
@@ -82,18 +99,19 @@ twice, as keys and as values, the two-table form would read each row
 twice (503 MB a layer where 252 is the row once) and write a float32
 output of the whole width (335 MB a layer) for XLA to cut. `q` comes in
 bfloat16, the dtype the product reads it in (a float32 `q` is 168 MB a
-layer more to read). Padding is clipped onto the scratch row and
-handed back 0, as above. A row is 546 KB of DMA (its context 246 KB, `q`
-164 KB, the output 131 KB), about what a grid step costs of its own, so
+layer more to read). Padding is clipped onto the scratch row, which
+its block reads whole, and handed back 0. A row is 546 KB of DMA (its
+context 246 KB, `q` 164 KB, the output 131 KB), about what a grid step costs of its own, so
 a grid step takes `LATENT_ROWS` rows of the frame, each a block of its
 own whose index is read from the prefetched row indices: at a frame of
 1,024 rows of `[192, 640]` and 128 heads a call took 1.08 ms on a v5e at
 one row a step, 0.88 at four, 0.85 at eight and at sixteen (553 MB:
 654 GB/s).
 
-VMEM: a row's keys and values twice each (`vmem_bytes`: 6.3 MB of
-blocks for Laguna's full layer, 4.2 its sliding one, 11.8 Olmo's, 9.7
-one of Ouro's 48 blocks, 2.1 LFM2's) and the row's small operands; the
+VMEM: a whole row's keys and values twice each, of which a row fills
+its prefix (`vmem_bytes`: 6.3 MB of buffers for Laguna's full layer, 4.2
+its sliding one, 11.8 Olmo's, 9.7 one of Ouro's 48 blocks, 2.1 LFM2's)
+and the row's small operands; the
 one-table form's rows, `q` and output twice each (`latent_vmem_bytes`).
 `fits` (heads of whole lane tiles), `fits_paired` (heads of half of
 one) and `fits_latent` (one table) say whether a call stays under
@@ -117,6 +135,7 @@ HEAD_TILE = 16            # query rows come in whole bfloat16 tiles
 LANES = 128               # a lane tile
 HALF = LANES // 2         # a key-value head of half a lane tile
 LATENT_ROWS = 8           # rows a grid step of the one-table form
+MAX_BLOCKS = 16           # position blocks a row of the two-table form
 
 
 def _padded(heads: int) -> int:
@@ -200,29 +219,96 @@ def _own_lanes(hp: int, width: int, kv: int, group: int) -> jax.Array:
                    for j in range(1, kv)))
 
 
+def position_block(positions: int) -> int:
+    """The positions a row's copy is rounded up to: the least multiple
+    of 64 that divides `positions` into at most `MAX_BLOCKS` blocks (64
+    at 384, 448, 512 and 768); `positions` itself where none does."""
+    return next((b for b in range(64, positions, 64) if positions % b == 0
+                 and positions // b <= MAX_BLOCKS), positions)
+
+
+def _blocks(pos, positions: int, size: int, own: bool):
+    """Position blocks of `size` that hold what a row at `pos` attends
+    to in the table, one at the least: `min(pos + 1, positions)`
+    positions, `min(pos, positions)` where the own entry comes beside."""
+    held = jnp.minimum(pos if own else pos + 1, positions)
+    return jnp.maximum((held + size - 1) // size, 1)
+
+
+def reads(shape: tuple, dev, pos, own: bool = False) -> tuple:
+    """What a two-table call over tables of `shape` reads: (live rows,
+    positions copied of each table over them), int32 scalars."""
+    rows, positions = shape[:2]
+    live = dev < rows - 1
+    size = position_block(positions)
+    copied = _blocks(pos, positions, size, own) * size
+    return (live.sum(dtype=jnp.int32),
+            jnp.where(live, copied, 0).sum(dtype=jnp.int32))
+
+
 def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
-            scale: float, own: bool):
+            scale: float, own: bool, blocked: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from sitewhere_tpu.ops.expert_kernel import pick
+
+    *refs, kbuf, vbuf, sem = refs
     if own:
-        q_ref, k_ref, v_ref, ko_ref, vo_ref, o_ref = refs[-6:]
+        q_ref, k_hbm, v_hbm, ko_ref, vo_ref, o_ref = refs[-6:]
     else:
-        q_ref, k_ref, v_ref, o_ref = refs[-4:]   # (after a block's scalar)
-    i = pl.program_id(0)
+        q_ref, k_hbm, v_hbm, o_ref = refs[-4:]
+    i, frame = pl.program_id(0), pl.num_programs(0)
     hp, d = q_ref.shape[1:]
-    positions, width = k_ref.shape[1:]
+    positions, width = kbuf.shape[1:]
+    size = position_block(positions)
+    # the context's lanes of a row: the block `block` names, or all of it
+    lanes = (pl.ds(pl.multiple_of(refs[0][0] * width, LANES), width)
+             if blocked else slice(None))
     # a key-value head of half a lane tile: `q` comes twice side by side
     half = width // kv == HALF
-    live = dev_ref[i] < scratch
 
-    @pl.when(live)
+    def each_length(j, then):
+        """`then(n)` for the static count `n` of position blocks row `j`
+        copies: a tree of branches over the `positions // size` counts."""
+        pick(_blocks(pos_ref[j], positions, size, own), 1,
+             positions // size + 1, then)
+
+    def copies(j, n: int, slot):
+        """The copies of row `j`'s first `n` position blocks of keys and
+        of values into half `slot` of the buffers."""
+        held = pl.ds(0, n * size)
+        return [pltpu.make_async_copy(table.at[dev_ref[j], held, lanes],
+                                      buf.at[slot, held], sem.at[slot, t])
+                for t, (table, buf) in enumerate(((k_hbm, kbuf),
+                                                  (v_hbm, vbuf)))]
+
+    def fetch(j):
+        """Start row `j`'s copies, if it is live, into half `j % 2`."""
+        @pl.when(dev_ref[j] < scratch)
+        def _():
+            each_length(j, lambda n: [c.start() for c in copies(j, n, j % 2)])
+
+    @pl.when(i == 0)
     def _():
+        fetch(0)
+
+    # row `i + 1`'s copies run while row `i` computes
+    @pl.when(i + 1 < frame)
+    def _():
+        fetch(i + 1)
+
+    def attend(n: int):
+        slot, length = i % 2, n * size
+        for c in copies(i, n, slot):
+            c.wait()
+        keys = kbuf[slot, pl.ds(0, length)]
+        values = vbuf[slot, pl.ds(0, length)]
         if half:
             mine = _own_lanes(hp, width, kv, group)
             wide = jnp.where(
                 mine, jnp.concatenate([q_ref[0]] * (width // LANES), axis=1),
-                0.0).astype(k_ref.dtype)
+                0.0).astype(keys.dtype)
         else:
             row = jax.lax.broadcasted_iota(jnp.int32, (hp, d), 0)
             # a query row's own key-value head: rows `j * group ...`
@@ -231,11 +317,11 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
             q = q_ref[0]
             wide = jnp.concatenate(
                 [jnp.where(m, q, 0.0) for m in mine],
-                axis=1).astype(k_ref.dtype)
+                axis=1).astype(keys.dtype)
         logits = jax.lax.dot_general(
-            wide, k_ref[0], (((1,), (1,)), ((), ())),
+            wide, keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        at = jax.lax.broadcasted_iota(jnp.int32, (hp, positions), 1)
+        at = jax.lax.broadcasted_iota(jnp.int32, (hp, length), 1)
         # where the own entry comes apart, the table's slot for it is stale
         seen = at < pos_ref[i] if own else at <= pos_ref[i]
         logits = jnp.where(seen, logits, -jnp.inf)
@@ -252,10 +338,10 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
         if own:
             total = total + e_own
         probs = e / total
-        out = jnp.dot(probs.astype(v_ref.dtype), v_ref[0],
+        out = jnp.dot(probs.astype(values.dtype), values,
                       preferred_element_type=jnp.float32)
         if own:
-            out = out + (e_own / total).astype(v_ref.dtype).astype(
+            out = out + (e_own / total).astype(values.dtype).astype(
                 jnp.float32) * vo_ref[0].astype(jnp.float32)
         if half:
             # the row's own lanes, the lane tiles summed, then a tile's
@@ -270,6 +356,12 @@ def _kernel(dev_ref, pos_ref, *refs, scratch: int, kv: int, group: int,
             for j, m in enumerate(mine):
                 o = jnp.where(m, out[:, j * d:(j + 1) * d], o)
             o_ref[0] = o
+
+    live = dev_ref[i] < scratch
+
+    @pl.when(live)
+    def _():
+        each_length(i, attend)
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -405,14 +497,12 @@ def context_rows(keys: jax.Array, values: jax.Array | None, dev: jax.Array,
     scalars = (dev, pos) if block is None else (
         dev, pos, jnp.asarray(block, jnp.int32).reshape(1))
 
-    def row(i, dev, pos, *block):
-        return (jnp.minimum(dev[i], scratch), 0, block[0][0] if block else 0)
-
     def frame_row(i, *_):
         return (i, 0, 0)
 
-    context = pl.BlockSpec((1, positions, width), row)
-    in_specs = [pl.BlockSpec((1, hp, lanes), frame_row), context, context]
+    # the tables stay where they rest: the kernel copies a row's prefix
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, hp, lanes), frame_row), hbm, hbm]
     operands = (q, keys, values)
     if own is not None:
         in_specs += [pl.BlockSpec((1, 1, width), frame_row)] * 2
@@ -421,13 +511,17 @@ def context_rows(keys: jax.Array, values: jax.Array | None, dev: jax.Array,
     out = pl.pallas_call(
         functools.partial(_kernel, scratch=scratch, kv=kv,
                           group=heads // kv, scale=scale,
-                          own=own is not None),
+                          own=own is not None, blocked=block is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(frame,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hp, lanes), frame_row)),
+            out_specs=pl.BlockSpec((1, hp, lanes), frame_row),
+            scratch_shapes=[pltpu.VMEM((2, positions, width), keys.dtype),
+                            pltpu.VMEM((2, positions, width), keys.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=jax.ShapeDtypeStruct((frame, hp, lanes), jnp.float32),
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_bytes(keys.shape, heads, kv, width)),
         name="context_rows",
         interpret=interpret,

@@ -223,13 +223,13 @@ class ContextAtRest:
     """One window leaf that its model reads where it rests, handed to
     `step_score` in the gathered rows' place ("Contract with the
     model"): the append is the ring's, WHEN it runs and what reads the
-    table behind it are the model's. `read_rows` is what the model's
-    last reading took where they rested: live rows, 0 from the plain
-    path."""
+    table behind it are the model's. `read_rows` and `read_positions`
+    are what the model's last reading took where they rested: live
+    rows and the positions copied for them, 0 from the plain path."""
 
     def __init__(self, table, dev, slot):
         self.table, self.dev, self.slot = table, dev, slot
-        self.read_rows = 0
+        self.read_rows = self.read_positions = 0
 
     def rows(self, block=None, width=None):
         """Rows `dev` gathered out of the table as it rests; of a table

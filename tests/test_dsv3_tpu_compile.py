@@ -872,3 +872,122 @@ def test_nemotron_step_lowers_both_kernels_and_moves_no_table(nemotron_step):
     # 5.53 GB of weights and 8.40 of state
     assert 13.9e9 < mem.argument_size_in_bytes < 14.0e9
     assert mem.temp_size_in_bytes < 0.2e9
+
+
+# -- `context_rows` alone, at the served leaves' shapes --------------------------
+
+# (the tables' shape, the frame, query heads, key-value heads, a head's
+# width, whether a row holds contexts side by side of which one block is
+# read beside the own entry): each model's two-table call in its step
+TWO_TABLES = {
+    "ouro-2.6b-pp4": ((OURO_ROWS, 448, 98304), OURO_FRAME, 16, 16, 128,
+                      True),
+    "olmo-hybrid-7b-pp8": ((OLMO_ROWS, 384, 3840), OLMO_FRAME, 30, 30, 128,
+                           False),
+    "lfm2-24b-a2b-pp5": ((LFM2_ROWS, 512, 512), LFM2_FRAME, 32, 8, 64,
+                         False),
+    "laguna-s-2.1-ep8.full": ((LAGUNA_ROWS, 768, 1024), LAGUNA_FRAME, 48, 8,
+                              128, False),
+    "laguna-s-2.1-ep8.sliding": ((LAGUNA_ROWS, 512, 1024), LAGUNA_FRAME, 72,
+                                 8, 128, False),
+}
+
+
+def _kernel_call(fn, args: list) -> tuple:
+    """`fn` over `args` (shapes) compiled for the described chip: its one
+    kernel's HLO line and backend config."""
+    import json
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    (line,) = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    at = line.index("backend_config=") + len("backend_config=")
+    return line, json.JSONDecoder().raw_decode(line[at:])[0]
+
+
+def _op_counts(op, counts=None) -> dict:
+    """How many operations of each name `op` holds, at any depth."""
+    counts = {} if counts is None else counts
+    for region in op.regions:
+        for block in region.blocks:
+            for inner in block.operations:
+                name = inner.operation.name
+                counts[name] = counts.get(name, 0) + 1
+                _op_counts(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("leaf", TWO_TABLES)
+def test_context_rows_copies_a_rows_prefix_in_the_rows_own_grid_step(
+        leaf, one_chip):
+    """The two-table form at a served leaf's shape, compiled for the
+    described chip: ONE grid axis, the frame's rows; the two tables stay
+    where they rest (`any`) and a row's prefix comes into two double
+    buffers of a whole row; the copies are started in two trees with one
+    branch a length of prefix (the first row's, and the next row's while
+    a row computes) and waited on in one: one copy of each table a length,
+    so two a row, and no loop of a copy a position block; and what the
+    call uses of VMEM stays within `vmem_bytes`, which it asks for."""
+    from sitewhere_tpu.ops import context_kernel
+
+    shape, frame, heads, kv, d, blocked = TWO_TABLES[leaf]
+    width = kv * d
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [described(shape, jnp.bfloat16)] * 2 + [
+        described((frame,), jnp.int32)] * 2 + [
+        described((frame, heads, d), jnp.float32)]
+    if blocked:
+        args += [described((), jnp.int32),
+                 (described((frame, width), jnp.bfloat16),) * 2]
+    line, config = _kernel_call(lambda *a: context_kernel.context_rows(
+        *a, kv=kv, scale=d ** -0.5), args)
+    (module,) = _mosaic_modules([line])
+    func = module.body.operations[0]
+    assert str(func.attributes["iteration_bounds"]) == f"array<i64: {frame}>"
+    kinds = str(func.attributes["function_type"])
+    table = "x".join(map(str, shape))
+    assert kinds.count(f"memref<{table}xbf16, #tpu.memory_space<any>>") == 2
+    assert kinds.count(f"memref<2x{shape[1]}x{width}xbf16, "
+                       "#tpu.memory_space<vmem>>") == 2
+    lengths = shape[1] // context_kernel.position_block(shape[1])
+    assert 6 <= lengths <= context_kernel.MAX_BLOCKS
+    ops = _op_counts(func)
+    assert ops["tpu.enqueue_dma"] == 2 * 2 * lengths
+    assert ops["tpu.wait_dma2"] == 2 * lengths
+    assert "scf.for" not in ops and "scf.while" not in ops
+    (asked,), (used,) = (config[key] for key in (
+        "scoped_memory_configs", "used_scoped_memory_configs"))
+    assert int(used["size"]) <= int(asked["size"]) == \
+        context_kernel.vmem_bytes(shape, heads, kv, width)
+
+
+def test_the_one_table_form_is_left_as_it_lowered(one_chip):
+    """`context_rows`' one-table form at `deepseek-v3-ep16`'s shape (four
+    blocks of 1,024 rows of `[192, 640]` and the scratch row, 128 heads,
+    512 lanes of values): its Mosaic module, printed without source
+    locations, hashes to the text it lowered to while the two-table form
+    still copied whole rows, so that form's prefixes leave it alone."""
+    import hashlib
+
+    from sitewhere_tpu.ops import context_kernel
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    frame = 1024
+    line, _ = _kernel_call(lambda t, d, p, q: context_kernel.context_rows(
+        t, None, d, p, q, scale=0.1, value_width=512), [
+        described((4097, 192, 640), jnp.bfloat16),
+        described((frame,), jnp.int32), described((frame,), jnp.int32),
+        described((frame, 128, 640), jnp.bfloat16)])
+    (module,) = _mosaic_modules([line])
+    text = module.operation.get_asm(enable_debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "de51685004caafe60d06205d87d8de89dba14f7843fca1c326c2e059937f878c")

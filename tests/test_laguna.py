@@ -414,6 +414,7 @@ def test_the_steps_new_numbers_reach_the_registry_through_a_session(run):
         assert snap["scoring.moe.runs_one_tile"].value == 14 * 2 * 4
         # the CPU's step is the plain path: no context read where it rests
         assert snap["scoring.ctx.at_rest_rows"].value == 0
+        assert snap["scoring.ctx.read_positions"].value == 0
         s.close()
 
     run(main())
@@ -435,9 +436,9 @@ def test_a_model_without_wrapping_leaves_reports_no_window():
     ring.load(hist, np.full(D, W))
     out = np.asarray(ring.update_and_score(
         model, params, np.arange(D, dtype=np.int32), frames[0], 8))
-    assert model.step_stats[-3:] == ("ctx.window_positions", "ctx.wrapped",
-                                     "ctx.at_rest")
-    assert (out[-3:] == 0).all() and out[-5] == W
+    assert model.step_stats[-4:] == ("ctx.window_positions", "ctx.wrapped",
+                                     "ctx.at_rest", "ctx.read_positions")
+    assert (out[-4:] == 0).all() and out[-6] == W
 
 
 def test_configuration_the_model_cannot_compute_is_refused():
@@ -477,7 +478,10 @@ def _lowered_step(model, rows, bucket, out_dtype):
 # in its turn and appends its entries there, where the step gathered every
 # layer's when it started and appended them when it ended, the same
 # numbers to the bit (tests/test_dsv3.py, against the step as it was).
-# `olmo-hybrid-stream`'s two are tests/test_lfm2.py's and did not move
+# `laguna-stream`'s two were recorded anew when the step came to return
+# one number more, `ctx.read_positions` (on the CPU a zero a layer and
+# their sum: constants, adds, a convert and a broadcast more, no other
+# line). `olmo-hybrid-stream`'s two are tests/test_lfm2.py's
 PARENTS_STEPS = {
     "dsv3-stream_float32": (
         "a4d3e50512730efbfabfa72ee17ab449984647a909fe1f887ded0cc3f7094b4e"),
@@ -486,9 +490,9 @@ PARENTS_STEPS = {
     "lstm-stream": (
         "a2bd1f0a98b51e60dd3cc8f6c6d127a580d5712cec9d5c7608bf3d8c512680c8"),
     "laguna-stream_float32": (
-        "a3e0acb35c313639e52e10adb33b038d3729fa309a895d8917ef62378aad220a"),
+        "2716b26e00e1e69971908d7e76e714aa7afa7c19de8e10e1900d530a949a7fdc"),
     "laguna-stream_bfloat16": (
-        "157b00bda287cf92bf8c2d83aa012012038564f70ac18f099afb2314394cfc61"),
+        "39d7bc19a7615bcc3fe67025aa01e349d02d0c62271ef0cee0026a82546f59bd"),
 }
 
 

@@ -331,7 +331,7 @@ def test_conv_states_and_contexts_are_written_in_place_in_their_turn():
     assert scores.shape == (8 + len(model.step_stats),)
     # 6 live rows x 2 a token x 3 expert layers, every pair held; the
     # CPU's step gathers its contexts: none read at rest
-    assert list(np.asarray(scores[8:])[[0, 1, 3, 5]]) == [36, 36, W, 0]
+    assert list(np.asarray(scores[8:])[[0, 1, 3, 5, 6]]) == [36, 36, W, 0, 0]
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry",
                         compiled.as_text()).group(1)
     assert aliases.count("may-alias") + aliases.count("must-alias") \
@@ -398,6 +398,7 @@ def test_the_steps_numbers_reach_the_registry_through_a_session(run):
             == snap["scoring.dispatches"].value * 3 * 8 * 3 * 256 * 64 * 4 \
             == 10 * 4_718_592
         assert snap["scoring.ctx.at_rest_rows"].value == 0
+        assert snap["scoring.ctx.read_positions"].value == 0
         assert snap["scoring.ctx.reseeds"].value == 0
         # 6 live rows of: three conv states of 2 x 256 float32 (the
         # products' type here), hn, 4 scalars
@@ -493,12 +494,14 @@ def test_configuration_the_model_cannot_compute_is_refused():
 # the same function, there, on the same arguments. (The other models'
 # pins are tests/test_laguna.py's: `lstm-stream`'s holds too; the two
 # models with held experts moved with `routed`'s overflow loop, and their
-# pins there say so.)
+# pins there say so.) Recorded anew when the step came to return one
+# number more, `ctx.read_positions` (0 on the CPU: a constant, an add, a
+# convert and a broadcast more, no other line).
 OLMO_PARENTS_STEPS = {
     "float32": (
-        "894f2a43f2ed013f4e80c4dd93daa1b3b53fbb4ee5144c979483ffd4f93f5a6f"),
+        "52c99a085594729227c11bb1845e4aaddaecb84f51542a32b540e2ab5750b7dd"),
     "bfloat16": (
-        "a7d0b807ad3671da4b756babe167f259193684f8b87e683557c774583c065e41"),
+        "972aaf8b1eb5ccfed20487f6de8eff8fb93b72dd6a4483b11bef947f2e86d48e"),
 }
 
 

@@ -249,6 +249,7 @@ def test_the_steps_numbers_reach_the_registry_through_a_session(run):
         assert snap["scoring.state.in_place_rows"].value == 0
         assert snap["scoring.state.kernel_bytes"].value == 0
         assert snap["scoring.ctx.at_rest_rows"].value == 0
+        assert snap["scoring.ctx.read_positions"].value == 0
         assert snap["scoring.ctx.reseeds"].value == 0
         s.close()
 

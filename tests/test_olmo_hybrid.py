@@ -309,9 +309,10 @@ def test_a_turn_through_update_is_the_read_cell_and_write_it_replaced(
 
     monkeypatch.setattr(type(model), "_linear_decode", as_before)
     want_state, want = two_steps()
-    assert model.step_stats[-2:] == ("state.in_place", "ctx.at_rest")
+    assert model.step_stats[-3:] == ("state.in_place", "ctx.at_rest",
+                                     "ctx.read_positions")
     for a, b in zip(got, want):
-        assert (a == b).all() and not a[-2:].any()
+        assert (a == b).all() and not a[-3:].any()
     for name, leaf in want_state.items():
         assert (got_state[name] == leaf).all(), name
 
@@ -355,6 +356,7 @@ def test_the_steps_numbers_reach_the_registry_through_a_session(run):
         assert snap["scoring.state.in_place_rows"].value == 0
         # ...and no context read where it rests: its rows are gathered
         assert snap["scoring.ctx.at_rest_rows"].value == 0
+        assert snap["scoring.ctx.read_positions"].value == 0
         assert snap["scoring.ctx.reseeds"].value == 0
         # 6 live rows of: three states of 32 x 128 float32, three of
         # taps 3 x 256 float32 (the products' type here), hn, 4 scalars

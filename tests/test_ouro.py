@@ -284,6 +284,7 @@ def test_each_pass_and_layer_writes_its_own_lanes_in_place():
     stats = dict(zip(model.step_stats, np.asarray(scores[8:])))
     # the CPU's step gathers its contexts: none read at rest
     assert stats["ctx.positions"] == W and stats["ctx.at_rest"] == 0
+    assert stats["ctx.read_positions"] == 0
     layer = 4 * (4 * 256 * 256 + 3 * 256 * 512 + 4 * 256)
     assert stats["loop.weight_bytes"] == 3 * 2 * layer
     assert stats["ctx.attended_bytes"] == D * (W + 1) * 6 * 2 * 256 * 4
@@ -354,6 +355,7 @@ def test_the_steps_numbers_reach_the_registry_through_a_session(run):
         assert snap["scoring.ctx.attended_bytes"].value \
             == D * sum(W + 1 + k for k in range(10)) * 6 * 2 * 256 * 4
         assert snap["scoring.ctx.at_rest_rows"].value == 0
+        assert snap["scoring.ctx.read_positions"].value == 0
         assert snap["scoring.ctx.reseeds"].value == 0
         s.close()
 

@@ -558,11 +558,12 @@ def test_the_step_lowered_for_a_tpu_is_the_plain_step(case, heads, in_place,
     stats = len(model.step_stats)
     # (the full layer's context is float32 here, heads of 64: no leaf
     # for ops/context_kernel.py, whose count stays 0 on both sides)
-    assert model.step_stats[-2:] == ("state.in_place", "ctx.at_rest")
-    assert float(want[-2]) == 0 and float(want[-1]) == float(got[-1]) == 0
-    assert float(got[-2]) == (3 * live if in_place else 0)
+    assert model.step_stats[-3:] == ("state.in_place", "ctx.at_rest",
+                                     "ctx.read_positions")
+    assert float(want[-3]) == 0 and not want[-2:].any() and not got[-2:].any()
+    assert float(got[-3]) == (3 * live if in_place else 0)
     np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
-    np.testing.assert_allclose(got[-stats:-2], want[-stats:-2], rtol=1e-6)
+    np.testing.assert_allclose(got[-stats:-3], want[-stats:-3], rtol=1e-6)
     for name, leaf in want_state.items():
         np.testing.assert_allclose(got_state[name], leaf, atol=1e-5,
                                    err_msg=name)
@@ -751,6 +752,98 @@ def test_context_kernel_reads_a_block_beside_the_own_entry_interpret(case):
     assert float(jnp.abs(other - want)[:live].max()) > 0.1 * scale
 
 
+# (head width, key-value heads, query heads, contexts a row (0: a row is
+# one context and holds the own entry), wraps, each live row's position,
+# padding rows): tables of 192 positions, which a row copies in blocks of
+# 64 up to what it attends to
+PREFIX_CASES = {
+    # the first position, either side of a block's edge, the last
+    "block_edges": (128, 2, 4, 0, False, [0, 63, 64, 65, 191], 1),
+    "block_edges_heads_of_half_a_lane_tile": (64, 2, 4, 0, False,
+                                              [0, 63, 64, 65, 191], 2),
+    # the own entry beside the table: position `pos` is not copied, so 64
+    # positions are one block
+    "block_edges_beside_the_own_entry": (128, 2, 4, 3, False,
+                                         [0, 63, 64, 65, 191], 1),
+    # rows that have wrapped copy the whole row, beside rows that have not
+    "wrapped_rows": (128, 2, 4, 0, True, [5, 191, 192, 300], 1),
+    # each row's copy another length than the row's before it, which was
+    # in flight while that row computed
+    "lengths_that_alternate": (128, 2, 2, 0, False,
+                               [190, 3, 127, 64, 0, 150], 3),
+    "lengths_that_alternate_half_tile_heads_beside_the_own_entry": (
+        64, 2, 4, 2, False, [190, 3, 127, 64, 0, 150], 2),
+}
+
+
+@pytest.mark.parametrize("case", PREFIX_CASES)
+def test_context_kernel_copies_only_the_positions_a_row_holds_interpret(
+        case):
+    """`context_rows` copies a row's position blocks up to what the row
+    attends to and no further (`position_block`: 64 of 192 here): with
+    every position past them, and the whole scratch row, made NaN, the
+    heads' outputs are bit-equal to those over the clean tables and
+    match `_decode_rows` over the clean rows, a padding row's 0; `reads`
+    counts the live rows and, over them, `ceil(len / 64) * 64`
+    positions, `len` the row's `min(pos + 1, 192)` (`min(pos, 192)`
+    beside the own entry), a block at the least."""
+    from sitewhere_tpu.ops import context_kernel
+    from sitewhere_tpu.scoring.stream import ContextAtRest, pad_rows
+
+    d, kv, heads, blocks, wraps, at, padding = PREFIX_CASES[case]
+    positions, rows, live = 192, 11, len(at)
+    assert context_kernel.position_block(positions) == 64
+    own, width, scratch = blocks > 0, kv * d, rows - 1
+    keys = iter(jax.random.split(jax.random.PRNGKey(live + d), 5))
+    tables = [jax.random.normal(next(keys), (
+        rows, positions, max(blocks, 1) * width)).astype(jnp.bfloat16)
+        for _ in range(2)]
+    assert _context_takes(d, tables[0].shape, tables[0].dtype, heads, kv,
+                          width)
+    frame = live + padding
+    q = jax.random.normal(next(keys), (frame, heads, d)) * 2.0
+    k, v = (jax.random.normal(next(keys), (frame, width)).astype(
+        jnp.bfloat16) for _ in range(2))
+    dev = jnp.asarray(np.concatenate([
+        np.sort(np.random.default_rng(live).permutation(scratch)[:live]),
+        pad_rows(scratch, padding)]), jnp.int32)
+    pos = jnp.asarray(at + [0] * padding, jnp.int32)
+    slot = pos % positions if wraps else pos
+    block = blocks - 1 if own else None
+    if not own:
+        # the ring's append: the own entry is in the table
+        tables = [t.at[dev[:live], slot[:live]].set(e[:live])
+                  for t, e in zip(tables, (k, v))]
+    ktab, vtab = (ContextAtRest(t, dev, slot) for t in tables)
+    want = jax.jit(lambda: _blocks()._decode_rows(
+        q, k, v, ktab.rows(block, width), vtab.rows(block, width), pos, kv,
+        wraps))()
+    held = np.minimum(np.asarray(at) + (0 if own else 1), positions)
+    copied = np.maximum(-(-held // 64), 1) * 64
+    poisoned = []
+    for t in tables:
+        t = np.array(t.astype(jnp.float32))
+        for row, n in zip(np.asarray(dev[:live]), copied):
+            t[row, n:] = np.nan
+        t[scratch] = np.nan
+        poisoned.append(jnp.asarray(t, jnp.bfloat16))
+    got, clean = (context_kernel.context_rows(
+        *read, dev, pos, q, block, (k, v) if own else None, kv=kv,
+        scale=128 ** -0.5, interpret=True) for read in (poisoned, tables))
+    assert (np.asarray(got) == np.asarray(clean)).all()
+    scale = float(jnp.abs(want[:live]).max())
+    assert 0.3 < scale < 10
+    # float32 round-off, and a weight that a float32 sum in another order
+    # rounds to the neighbouring bfloat16: 2^-8 of a weight of about
+    # 1/192 times a value of about 3, 1.5e-5 of the scale (8.9e-6 in the
+    # wrapped case's row at 191, as the kernel read whole rows before)
+    assert float(jnp.abs(got - want)[:live].max()) < 3e-5 * scale
+    assert not np.asarray(got)[live:].any()
+    rows_read, positions_read = context_kernel.reads(
+        tables[0].shape, dev, pos, own)
+    assert (int(rows_read), int(positions_read)) == (live, copied.sum())
+
+
 def test_context_kernel_takes_bfloat16_rows_of_whole_tiles_that_vmem_holds():
     """`fits` (heads of whole lane tiles) and `fits_paired` (heads of
     half of one) read the leaf's shape and dtype and the heads: the four
@@ -917,7 +1010,7 @@ def test_the_one_table_form_takes_bfloat16_tables_of_whole_tiles():
                                     value_width=128, interpret=True)
 
 
-def _laguna_of_128_wide_heads():
+def _laguna_of_128_wide_heads(positions=48):
     from sitewhere_tpu.models import build_model
 
     return build_model(
@@ -926,7 +1019,7 @@ def _laguna_of_128_wide_heads():
         num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
         head_dim=128, num_experts=8, num_experts_per_tok=2, vocab_size=64,
         num_experts_held=4, sliding_window=16, window=20,
-        context_positions=48, mlp_only_layers=[0],
+        context_positions=positions, mlp_only_layers=[0],
         layer_types=["full_attention", "sliding_attention",
                      "full_attention"],
         mlp_layer_types=["dense", "sparse", "sparse"],
@@ -934,25 +1027,27 @@ def _laguna_of_128_wide_heads():
         num_attention_heads_per_layer=[4, 6, 4]), 3
 
 
-def _olmo_of_128_wide_heads():
+def _olmo_of_128_wide_heads(positions=32):
     model = _linear_model(4, 16, 64, compute_dtype=jnp.bfloat16,
                           hidden_size=256, num_hidden_layers=2,
-                          layer_types=[LINEAR, FULL], context_positions=32)
+                          layer_types=[LINEAR, FULL],
+                          context_positions=positions)
     return model, 1
 
 
-def _ouro_of_128_wide_heads():
+def _ouro_of_128_wide_heads(positions=32):
     from sitewhere_tpu.models import build_model
 
     model = build_model(
         "ouro-stream", hidden_size=256, intermediate_size=256,
         num_hidden_layers=2, layer_types=["full_attention"] * 2,
         num_attention_heads=2, num_key_value_heads=2, head_dim=128,
-        vocab_size=64, total_ut_steps=3, window=12, context_positions=32)
+        vocab_size=64, total_ut_steps=3, window=12,
+        context_positions=positions)
     return model, model.slots
 
 
-def _lfm2_of_64_wide_heads():
+def _lfm2_of_64_wide_heads(positions=32):
     from sitewhere_tpu.models import build_model
 
     conv, full = "conv", "full_attention"
@@ -962,7 +1057,7 @@ def _lfm2_of_64_wide_heads():
         num_key_value_heads=2, vocab_size=64, num_experts=8,
         num_experts_per_tok=2, num_hidden_layers=4, num_dense_layers=1,
         layer_types=[conv, full, conv, full], window=16,
-        context_positions=32), 2
+        context_positions=positions), 2
 
 
 def _dsv3_of_a_128_wide_latent():
@@ -1022,11 +1117,62 @@ def test_the_step_that_reads_contexts_at_rest_is_the_plain_step(
     assert float(got[frame + at]) == layers * live
     assert float(jnp.abs(want[:live]).max()) > 1.0
     np.testing.assert_allclose(got[:live], want[:live], atol=2e-2)
-    others = [i for i in range(stats) if i != at
-              and model.step_stats[i] != "state.in_place"]
+    others = [i for i in range(stats) if i != at and model.step_stats[i]
+              not in ("state.in_place", "ctx.read_positions")]
     np.testing.assert_allclose(np.asarray(got[frame:])[others],
                                np.asarray(want[frame:])[others], rtol=1e-2)
     for name, leaf in want_state.items():
         np.testing.assert_allclose(
             np.asarray(got_state[name], np.float32),
             np.asarray(leaf, np.float32), atol=2e-2, err_msg=name)
+
+
+# (what builds the model, each table its kernel reads a step at 128
+# positions: (positions, the own entry beside, calls a step))
+COPIED = {
+    # two full layers of 128, a sliding one of 16 (one block, wrapped)
+    "laguna-stream": (_laguna_of_128_wide_heads,
+                      [(128, False, 2), (16, False, 1)]),
+    "olmo-hybrid-stream": (_olmo_of_128_wide_heads, [(128, False, 1)]),
+    # three passes of two layers, each (pass, layer) its own context
+    "ouro-stream": (_ouro_of_128_wide_heads, [(128, True, 6)]),
+    "lfm2-stream": (_lfm2_of_64_wide_heads, [(128, False, 2)]),
+}
+
+
+@pytest.mark.parametrize("name", COPIED)
+def test_the_step_counts_the_positions_the_context_kernel_copied(
+        name, monkeypatch):
+    """`ctx.read_positions` among a step's numbers, with the TPU's branch
+    taken (the kernels in interpret mode): over the live rows and every
+    call of the context kernel, `ceil(len / B) * B`, `len` the row's
+    `min(pos + 1, P)` (`min(pos, P)` where the own entry comes beside
+    the table) and `B` the table's position block (64 of 128 positions;
+    16 of 16); 0 as the CPU lowers the step."""
+    from sitewhere_tpu.ops import context_kernel
+    from sitewhere_tpu.scoring.stream import pad_rows, streaming_step
+
+    build, tables = COPIED[name]
+    model, _ = build(positions=128)
+    params = model.init(jax.random.PRNGKey(0))
+    cap, frame, at = 9, 8, [3, 63, 64, 100, 127]
+    live = len(at)
+    state = model.init_state(cap + 1)
+    dev = np.concatenate([[0, 1, 4, 6, 8], pad_rows(cap, frame - live)]
+                         ).astype(np.int32)
+    state["pos"] = state["pos"].at[dev[:live]].set(np.asarray(at))
+    v = np.linspace(-1, 1, frame).astype(np.float32)
+    read = frame + model.step_stats.index("ctx.read_positions")
+    _, want = jax.jit(streaming_step(model))(params, state, dev, v)
+    _interpreted(monkeypatch)
+    _context_interpreted(monkeypatch)
+    _, got = jax.jit(streaming_step(model))(params, state, dev, v)
+    assert [context_kernel.position_block(p) for p, _, _ in tables] == [
+        64 if p == 128 else p for p, _, _ in tables]
+    copied = 0
+    for positions, own, calls in tables:
+        block = 64 if positions == 128 else positions
+        held = np.minimum(np.asarray(at) + (0 if own else 1), positions)
+        copied += calls * int((np.maximum(-(-held // block), 1) * block).sum())
+    assert float(want[read]) == 0
+    assert float(got[read]) == copied

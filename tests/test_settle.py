@@ -215,6 +215,7 @@ MOE = [("scoring.moe.assignments_held", "Counter", None),
        ("scoring.ctx.positions", "Histogram", OCTAVES),
        ("scoring.moe.runs_one_tile", "Counter", None)]
 AT_REST = ("scoring.ctx.at_rest_rows", "Counter", None)
+READ = ("scoring.ctx.read_positions", "Counter", None)
 WEIGHTS = ("scoring.moe.weight_bytes", "Counter", None)
 
 # what the session registered for each sequence model's step, in the
@@ -222,28 +223,29 @@ WEIGHTS = ("scoring.moe.weight_bytes", "Counter", None)
 # the counter fed once a dispatch for the models that hold experts);
 # beside the in-place rows, the bytes the state kernel moves for them,
 # and the model with Mamba-2 layers, whose step declares all three
-# families
+# families; the positions the context kernel copied, of every model but
+# the one whose latent context it reads in its one-table form
 REGISTERED = {
     "dsv3-stream": MOE + [AT_REST, WEIGHTS],
     "laguna-stream": MOE + [
         ("scoring.ctx.window_positions", "Histogram", OCTAVES),
-        ("scoring.ctx.wrapped", "Counter", None), AT_REST, WEIGHTS],
-    "lfm2-stream": MOE + [AT_REST, WEIGHTS],
+        ("scoring.ctx.wrapped", "Counter", None), AT_REST, READ, WEIGHTS],
+    "lfm2-stream": MOE + [AT_REST, READ, WEIGHTS],
     "olmo-hybrid-stream": [
         ("scoring.ctx.positions", "Histogram", OCTAVES),
         ("scoring.state.decay", "Histogram", DECAY),
         ("scoring.state.absmax", "Histogram", ABSMAX),
         ("scoring.state.in_place_rows", "Counter", None),
-        ("scoring.state.kernel_bytes", "Counter", None), AT_REST],
+        ("scoring.state.kernel_bytes", "Counter", None), AT_REST, READ],
     "nemotron-h-stream": MOE + [
-        AT_REST, ("scoring.state.decay", "Histogram", DECAY),
+        AT_REST, READ, ("scoring.state.decay", "Histogram", DECAY),
         ("scoring.state.absmax", "Histogram", ABSMAX),
         ("scoring.state.in_place_rows", "Counter", None),
         ("scoring.state.kernel_bytes", "Counter", None), WEIGHTS],
     "ouro-stream": [
         ("scoring.ctx.positions", "Histogram", OCTAVES), AT_REST,
         ("scoring.loop.weight_bytes", "Counter", None),
-        ("scoring.ctx.attended_bytes", "Counter", None)],
+        ("scoring.ctx.attended_bytes", "Counter", None), READ],
 }
 
 
